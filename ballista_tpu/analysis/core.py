@@ -15,7 +15,7 @@ The moving parts:
   ones are reported separately; baseline entries that no longer match any
   finding are flagged as stale so the file can only shrink.
 - `Analyzer` — collects the scan set (the `ballista_tpu` package + `dev/`
-  + `bench.py`, minus generated protos), runs the passes, applies
+  + `chip_smoke.py`, minus generated protos), runs the passes, applies
   suppressions and the baseline, and returns an `AnalysisReport`.
 """
 
@@ -302,7 +302,7 @@ class Analyzer:
                     out.append(SourceFile.from_path(ap, os.path.relpath(ap, self.root)))
                 if not recurse:
                     break
-        for single in ("bench.py",):
+        for single in ("chip_smoke.py",):
             ap = os.path.join(self.root, single)
             if os.path.exists(ap):
                 out.append(SourceFile.from_path(ap, single))

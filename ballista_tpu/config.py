@@ -162,7 +162,6 @@ TPU_TOPK_MAX_K = "ballista.tpu.topk.max.k"
 TPU_FILL_THREADS = "ballista.tpu.fill.threads"
 TPU_FILL_CHUNK_ROWS = "ballista.tpu.fill.chunk_rows"
 TPU_COMPILE_OVERLAP = "ballista.tpu.compile.overlap"
-TPU_COMPILE_CACHE_DIR = "ballista.tpu.compile.cache_dir"
 # out-of-core execution (HBM-budgeted admission, host spill, grace fallback)
 TPU_HBM_BUDGET_BYTES = "ballista.tpu.hbm.budget.bytes"
 TPU_HBM_BUDGET_FRACTION = "ballista.tpu.hbm.budget.fraction"
@@ -971,9 +970,9 @@ _ENTRIES: list[ConfigEntry] = [
     ConfigEntry(
         TPU_MESH_DEVICES,
         "Device-mesh width for mesh-wide stages. 0 = every visible device "
-        "(make_mesh falls back to CPU virtual devices when the default "
-        "platform has fewer). A mesh below 2 devices demotes the exchange "
-        "to the host split.",
+        "of the default backend; asking for more than it has demotes the "
+        "exchange to the host split (reason no-mesh), as does a mesh below "
+        "2 devices.",
         int, 0, _nonneg,
     ),
     ConfigEntry(
@@ -1029,16 +1028,6 @@ _ENTRIES: list[ConfigEntry] = [
         "reports the hidden seconds as compile_overlap_s. Env escape "
         "hatch: BALLISTA_TPU_COMPILE_OVERLAP=0.",
         bool, _env_bool("BALLISTA_TPU_COMPILE_OVERLAP", True),
-    ),
-    ConfigEntry(
-        TPU_COMPILE_CACHE_DIR,
-        "Directory for JAX's persistent (on-disk) XLA compilation cache. "
-        "When set, compiled stage programs survive process restarts: a "
-        "re-admitted or redeployed executor fetches its XLA binaries from "
-        "disk instead of recompiling (RUN_STATS xla_compile_s ~ 0 on warm "
-        "starts). Empty = disabled. Env default: BALLISTA_TPU_COMPILE_CACHE "
-        "(also honored by bare runtime users with no session config).",
-        str, _env_str("BALLISTA_TPU_COMPILE_CACHE", ""),
     ),
     ConfigEntry(
         TPU_DAEMON_ENABLED,
@@ -1241,22 +1230,6 @@ _ENV_KNOBS: list[EnvKnob] = [
         "lost recompute path). 0 = disarmed. Env-only: the migration runs "
         "in scheduler/launcher context, which has no session config.",
         int, 0,
-    ),
-    EnvKnob(
-        "BALLISTA_BENCH_DAEMON_CHAOS",
-        "bench.py opt-in: run dev/daemon_chaos_exercise.py --quick in the "
-        "device leg as a sanity probe before the timed iterations (the "
-        "daemon failure domain must hold on this machine; divergence fails "
-        "the leg). Env-only: bench plumbing, not engine config.",
-        bool, False,
-    ),
-    EnvKnob(
-        "BALLISTA_BENCH_LIFECYCLE",
-        "bench.py opt-in: run dev/lifecycle_exercise.py --quick (graceful "
-        "drain / disk_full / rolling-restart smoke, docs/lifecycle.md) and "
-        "record the verdict under lifecycle_smoke in the bench artifact. "
-        "Env-only: bench plumbing, not engine config.",
-        bool, False,
     ),
     EnvKnob(
         "BALLISTA_TPU_DAEMON_IDLE_TIMEOUT_S",

@@ -21,7 +21,7 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(prog="python -m ballista_tpu.device_daemon")
     ap.add_argument("--socket", default=protocol.default_socket_path())
     ap.add_argument("--parent-pid", type=int, default=0,
-                    help="exit when this pid dies (bench legs, tests); "
+                    help="exit when this pid dies (tests, spawn-and-adopt); "
                          "0 = no parent watch")
     ap.add_argument("--device-ordinal", type=int, default=-1,
                     help="pin the daemon's chip via bind_process_ordinal "

@@ -6,8 +6,8 @@ the claim phase by phase), then runs platform init as a supervised,
 phase-instrumented state machine:
 
     platform_probe   import jax + configure the runtime (fast, pure host)
-    jax_devices      jax.devices() — the backend claim; THE statement
-                     that has hung whole bench rounds on this pool
+    jax_devices      jax.devices() — the backend claim (exclusive: it
+                     blocks or fails while another process holds the chip)
     first_compile    a tiny jitted matmul through XLA end-to-end
 
 Each phase runs under a bounded wall-clock ceiling
@@ -57,6 +57,16 @@ import sys
 import threading
 import time
 import traceback
+
+# Load Arrow HERE, on the thread that imports this module (the daemon's main
+# thread). libarrow's allocator binds its process-wide heap to the thread
+# that first loads the library; every other import of pyarrow in this
+# package is lazy, so the first `status` request used to load it on a
+# per-connection thread that exits — later threads that inherit the dead
+# thread's id then share that heap unsynchronised and the process dies with
+# SIGSEGV inside the next Arrow allocation (tests/test_device_daemon.py::
+# test_arrow_loaded_on_main_thread describes the stand-alone reproduction).
+import pyarrow  # noqa: F401
 
 from ballista_tpu.device_daemon import protocol
 
@@ -282,9 +292,9 @@ class DaemonServer:
         return 0
 
     def _reaper(self) -> None:
-        """Parent-death + idle watchdog: a daemon spawned for a bench leg
-        or a test must not outlive its reason to exist and sit on the
-        device claim forever."""
+        """Parent-death + idle watchdog: a daemon spawned for a test must
+        not outlive its reason to exist and sit on the device claim
+        forever."""
         while not self._stop.wait(2.0):
             if self.parent_pid:
                 try:
@@ -627,7 +637,7 @@ class DaemonServer:
                 went["phase"] = "pack"
                 segments, resp_body = protocol.pack_results(results)
                 # mirror this run's engine stats back to the caller: the
-                # client's RUN_STATS (heartbeat, bench events) reports the
+                # client's RUN_STATS (heartbeat, chip_smoke.py) reports the
                 # device work even though it happened in this process. Merge
                 # every rec the request CHANGED (a daemon-routed final/mesh
                 # stage runs inner partial stages under their own tags), with

@@ -50,16 +50,8 @@ def _concretize_dynamic_joins(node: ExecutionPlan) -> ExecutionPlan:
 
 
 def maybe_compile_tpu(physical: ExecutionPlan, config: BallistaConfig) -> ExecutionPlan:
-    from ballista_tpu.config import TPU_COMPILE_CACHE_DIR
-    from ballista_tpu.ops.tpu import runtime
     from ballista_tpu.ops.tpu.final_stage import TpuFinalStageExec, match_final_stage
     from ballista_tpu.ops.tpu.stage_compiler import TpuStageExec
-
-    # activate the persistent XLA cache before any stage compiles, so even
-    # the first stage of a restarted process can hit on-disk artifacts
-    cc_dir = str(config.get(TPU_COMPILE_CACHE_DIR) or "")
-    if cc_dir:
-        runtime.init_compile_cache(cc_dir)
 
     # capture the AQE resolve-time stamp before any rewrite rebuilds the
     # root node (with_children does not carry ad-hoc attributes)

@@ -194,6 +194,26 @@ class ExecutorProcess:
                 tls_cert=tls_cert, tls_key=tls_key, tls_client_ca=tls_ca,
             )
 
+        # one executor per chip: a pinned TPU-engine executor claims its
+        # device NOW. A chip it cannot claim (held by another process, a
+        # misconfigured ordinal) is a start-up failure, not a first-query
+        # demotion to the CPU engine; the heartbeat's tpu_device_* gauges
+        # say what it holds from the first beat on.
+        self._device_gauges: list[tuple[str, float]] = []
+        if engine == "tpu" and device_ordinal >= 0:
+            from ballista_tpu.ops.tpu import runtime
+
+            dev = runtime.bound_device(device_ordinal)
+            n_local = len(dev.client.local_devices())
+            log.info("executor pinned to ordinal %d: %r platform=%s "
+                     "local_devices=%d device_kind=%s", device_ordinal, dev,
+                     dev.platform, n_local, dev.device_kind)
+            self._device_gauges = [
+                ("tpu_device_id", float(dev.id)),
+                ("tpu_device_is_tpu", 1.0 if dev.platform == "tpu" else 0.0),
+                ("tpu_local_device_count", float(n_local)),
+            ]
+
         self.memory_pool_bytes = memory_pool_bytes or int(detect_memory_limit() * memory_fraction)
         self.metadata = ExecutorMetadata(
             id=str(new_executor_id()), host=host, flight_port=bound_flight, vcores=vcores,
@@ -332,6 +352,7 @@ class ExecutorProcess:
             ("orphans_reclaimed", float(self.executor.orphans_reclaimed)),
         ])
         metrics.extend(self._tpu_metrics())
+        metrics.extend(self._device_gauges)
         return metrics
 
     @staticmethod
@@ -407,6 +428,13 @@ class ExecutorProcess:
             # RUN_STATS for bench/exercise output)
             mesh = 1.0 if str(stats["mesh_mode_reason"]) == "mesh" else 0.0
             out.append(("tpu_mesh_mode", mesh))
+        # where this process's device stages ran: the cumulative ledger
+        # (docs/tpu_engine.md#observability)
+        led = sc.STAGE_OUTCOMES.snapshot()
+        out.append(("tpu_stage_device_runs", float(led["device"])))
+        out.append(("tpu_stage_below_row_floor", float(led["below_row_floor"])))
+        out.append(("tpu_stage_declined", float(led["declined"])))
+        out.append(("tpu_stage_errors", float(led["error"])))
         from ballista_tpu.ops.tpu import runtime
 
         cc = runtime.compile_cache_stats()
@@ -584,13 +612,13 @@ def main(argv=None) -> None:
     init_logging(args.log_level, args.log_file, args.log_rotation)
 
     if args.device_ordinal >= 0:
-        # must happen before jax's backend initialises: on real TPU hardware
-        # each chip is claimed exclusively, so a pinned daemon filters its
-        # runtime visibility down to its one chip
+        # must happen before jax's backend initialises: a chip is claimed
+        # exclusively by one process, so a pinned executor filters its
+        # runtime visibility down to its one chip (raises if it is too late)
         from ballista_tpu.ops.tpu.runtime import bind_process_ordinal
 
-        if bind_process_ordinal(args.device_ordinal):
-            log.info("process bound to device ordinal %d", args.device_ordinal)
+        bind_process_ordinal(args.device_ordinal)
+        log.info("process bound to device ordinal %d", args.device_ordinal)
 
     proc = ExecutorProcess(
         args.scheduler, args.bind_host, args.external_host, args.grpc_port,

@@ -67,7 +67,7 @@ def _is_fixed_point(vals: np.ndarray, scale: int = 2) -> bool:
 
 
 def _narrow_int(vals: np.ndarray) -> np.ndarray:
-    """Transfer-dtype narrowing: the PCIe/tunnel link is the bottleneck, so
+    """Transfer-dtype narrowing: the host↔device link is the bottleneck, so
     ship the smallest int that holds the range; device readers upcast to
     int64 in HBM (free relative to the link)."""
     if len(vals) == 0:

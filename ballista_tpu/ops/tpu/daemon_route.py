@@ -228,7 +228,7 @@ def protocol_deadline(config, est_bytes: int) -> float:
 
 def _mirror_success(tag: str, resp: dict, reason: str, retried: bool) -> None:
     """Publish the daemon's mirrored engine stats under this stage's tag:
-    the client's RUN_STATS (heartbeat, bench events) reports the device
+    the client's RUN_STATS (heartbeat, chip_smoke.py) reports the device
     work even though it happened in the daemon process."""
     with RUN_STATS.run(tag) as rec:
         for k, v in resp.get("stats", {}).items():

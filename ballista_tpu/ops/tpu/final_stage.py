@@ -5,9 +5,9 @@ The reference engine executes EVERY stage of a query
 lowered only partial-aggregation chains to the device. This module lowers
 the stage class that sits ABOVE the shuffle: merge the hash-partitioned
 partial accumulators in HBM, apply the post-aggregation projections and
-HAVING filters, and run ORDER BY (+ LIMIT) with one lexicographic
-`lax.sort` — so a q3-class stage fetches 10 rows back to the host instead
-of millions.
+HAVING filters, and run ORDER BY (+ LIMIT) through one lexicographic
+ordering permutation (`kernels.lex_order`) — so a q3-class stage fetches 10
+rows back to the host instead of millions.
 
 Stage shape handled (top-down):
 
@@ -40,8 +40,17 @@ import pyarrow as pa
 
 from ballista_tpu.config import TPU_MAX_DEVICE_BYTES, TPU_MIN_ROWS, BallistaConfig, _env_int
 from ballista_tpu.ops.tpu.columnar import encode_column, next_bucket
-from ballista_tpu.ops.tpu.stage_compiler import LruDict
-from ballista_tpu.ops.tpu.kernels import DevVal, Lowering, Unsupported, lower_expr, true_mask
+from ballista_tpu.ops.tpu.stage_compiler import STAGE_OUTCOMES, LruDict
+from ballista_tpu.ops.tpu.kernels import (
+    BelowRowFloor,
+    DevVal,
+    Lowering,
+    Unsupported,
+    int_cumsum,
+    lex_order,
+    lower_expr,
+    true_mask,
+)
 from ballista_tpu.ops.tpu.runtime import ensure_jax
 from ballista_tpu.plan.expressions import Alias, Column, SortKey
 from ballista_tpu.plan.physical import (
@@ -263,8 +272,6 @@ class TpuFinalStageExec(ExecutionPlan):
     def _run(self, partition: int, ctx: TaskContext) -> list[pa.RecordBatch]:
         import logging
 
-        from ballista_tpu.ops.tpu.runtime import device_scope
-
         with self._results_lock:
             if self._results is None:
                 # protected-surface routing (docs/device_daemon.md): ship
@@ -274,19 +281,18 @@ class TpuFinalStageExec(ExecutionPlan):
                 routed = self._daemon_run_all(ctx)
                 if routed is not None:
                     self._results = routed
+                    STAGE_OUTCOMES.note("final", "device", "daemon-routed")
                     self.tpu_count += 1
                     self._device_ok = True
                     self._mat_input = None
             if self._results is None:
                 try:
-                    with device_scope(ctx.device_ordinal):
-                        self._results = self._tpu_run_all(ctx)
-                    self.tpu_count += 1
-                    self._device_ok = True
+                    self._results = self._device_run(ctx)
                     self._mat_input = None  # success: release the host copy
                 except Unsupported as e:
                     logging.getLogger(__name__).info(
                         "tpu final-stage fallback (%s): %s", e, self.agg.node_str())
+                    STAGE_OUTCOMES.note_fallback("final", e)
                     self._results = {}
                 except Exception as e:  # noqa: BLE001 — classified below
                     self._results = {}
@@ -311,32 +317,29 @@ class TpuFinalStageExec(ExecutionPlan):
                         hbm.note_oom(self.fingerprint)
                         hbm.consume_oom_hint(self.fingerprint)  # no grace rung here
                         try:
-                            with device_scope(ctx.device_ordinal):
-                                self._results = self._tpu_run_all(ctx)
-                            self.tpu_count += 1
-                            self._device_ok = True
+                            self._results = self._device_run(ctx)
                             self._mat_input = None
                             _sc.RUN_STATS.set("hbm_oom_retries",
                                               hbm.oom_retry_count())
-                        except Exception:  # noqa: BLE001
+                        except Exception as e2:  # noqa: BLE001
                             logging.getLogger(__name__).warning(
                                 "final stage OOM persisted after spill+retry; "
                                 "falling back to cpu for %s",
                                 self.agg.node_str(), exc_info=True)
+                            STAGE_OUTCOMES.note_fallback("final", e2)
                             self._results = {}
                     else:
                         logging.getLogger(__name__).warning(
                             "tpu final stage raised; falling back to cpu for %s",
                             self.agg.node_str(), exc_info=True,
                         )
+                        STAGE_OUTCOMES.note_fallback("final", e)
             if partition not in self._results and self._device_ok:
                 # results were already consumed (a consumer re-executed this
                 # partition); caches are hot, so re-running the device path
                 # costs ~one dispatch — never a host re-aggregation
                 try:
-                    with device_scope(ctx.device_ordinal):
-                        self._results.update(self._tpu_run_all(ctx))
-                    self.tpu_count += 1
+                    self._results.update(self._device_run(ctx))
                     self._mat_input = None
                     self._served_since_dispatch = set()
                     # serve WITHOUT popping: one re-dispatch covers all K
@@ -345,16 +348,29 @@ class TpuFinalStageExec(ExecutionPlan):
                         out = list(self._results[partition])
                         self._note_served_locked(partition)
                         return out
-                except Exception:  # noqa: BLE001
+                except Exception as e:  # noqa: BLE001
                     logging.getLogger(__name__).warning(
                         "tpu final-stage re-run failed; cpu fallback for %s",
                         self.agg.node_str(), exc_info=True)
+                    STAGE_OUTCOMES.note_fallback("final", e)
                     self._device_ok = False
             if partition in self._results:
                 out = self._results.pop(partition)
                 self._note_served_locked(partition)
                 return out
         return self._fallback(partition, ctx)
+
+    def _device_run(self, ctx: TaskContext) -> dict[int, list[pa.RecordBatch]]:
+        """One local device dispatch of the whole stage on the task's bound
+        device, counted (call under _results_lock)."""
+        from ballista_tpu.ops.tpu.runtime import device_scope
+
+        with device_scope(ctx.device_ordinal):
+            out = self._tpu_run_all(ctx)
+        STAGE_OUTCOMES.note("final", "device")
+        self.tpu_count += 1
+        self._device_ok = True
+        return out
 
     def _note_served_locked(self, partition: int) -> None:
         """Bound re-run retention (call under _results_lock): when every
@@ -509,7 +525,7 @@ class TpuFinalStageExec(ExecutionPlan):
             # declined BEFORE ensure_jax(): a daemon-attached client whose
             # final merge is tiny (the common shape — partials did the heavy
             # lifting device-side) never pays a platform init of its own
-            raise Unsupported(f"only {total} rows (< tpu min)")
+            raise BelowRowFloor(total)
         jax = ensure_jax()
 
         full = pa.concat_tables(tables)
@@ -748,13 +764,14 @@ class TpuFinalStageExec(ExecutionPlan):
                 pays.append(arr)
                 pay_plan.append((len(pays) - 1, ncnt_idx))
 
-            operands = [(~valid).astype(jnp.int32), pid] + keyops + pays
-            n_sortkeys = 2 + len(keyops)
-            sorted_ = jax.lax.sort(tuple(operands), num_keys=n_sortkeys)
-            svalid = sorted_[0] == 0
-            spid = sorted_[1]
-            skeys = sorted_[2:2 + len(keyops)]
-            spays = list(sorted_[2 + len(keyops):])
+            # ordering permutation + gathers, not one wide lax.sort: the
+            # chip compiler's time for a sort explodes with its operand
+            # count (kernels.lex_order)
+            perm1 = lex_order([~valid, pid] + keyops)
+            svalid = valid[perm1]
+            spid = pid[perm1]
+            skeys = [k[perm1] for k in keyops]
+            spays = [p[perm1] for p in pays]
 
             diff = jnp.zeros((M,), bool).at[0].set(True)
             diff = diff | jnp.concatenate(
@@ -763,7 +780,7 @@ class TpuFinalStageExec(ExecutionPlan):
                 diff = diff | jnp.concatenate(
                     [jnp.ones((1,), bool), k[1:] != k[:-1]])
             boundary = svalid & diff
-            seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+            seg = int_cumsum(boundary.astype(jnp.int32)) - 1
             bor_inv = boundary | ~svalid
             is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
             n_seg = boundary.sum().astype(jnp.int32)
@@ -785,7 +802,7 @@ class TpuFinalStageExec(ExecutionPlan):
 
             def int_segsum(sv):
                 w = sv.astype(jnp.int64)
-                csum = jnp.cumsum(w)
+                csum = int_cumsum(w)
                 presum = csum - w
                 return compact(csum - presum[start])
 
@@ -849,8 +866,8 @@ class TpuFinalStageExec(ExecutionPlan):
                                  v.valid is not None))
             meta_holder["out"] = out_meta
 
-            # ---- phase 2 sort: (dead, pid, user keys...) + perm --------
-            ops2: list = [(~alive).astype(jnp.int32), pid_c]
+            # ---- phase 2 sort: (dead, pid, user keys...) → perm --------
+            ops2: list = [~alive, pid_c]
             for (kf, asc, nf, lut_idx) in sort_specs:
                 v = kf(cols, luts)
                 arr = v.arr
@@ -867,11 +884,9 @@ class TpuFinalStageExec(ExecutionPlan):
                     marker = jnp.broadcast_to(~v.valid, (C,)).astype(jnp.int32)
                     ops2.append(-marker if nf else marker)  # nulls first → ahead
                 ops2.append(arr)
-            ops2.append(arangeC)
-            sorted2 = jax.lax.sort(tuple(ops2), num_keys=len(ops2) - 1)
-            alive_s = sorted2[0] == 0
-            spid2 = sorted2[1]
-            perm = sorted2[-1]
+            perm = lex_order(ops2)  # stable: ties keep compacted order
+            alive_s = alive[perm]
+            spid2 = pid_c[perm]
 
             b2 = alive_s & jnp.concatenate(
                 [jnp.ones((1,), bool), spid2[1:] != spid2[:-1]])
@@ -884,7 +899,7 @@ class TpuFinalStageExec(ExecutionPlan):
             keep_out = alive_s
             if fetch is not None:
                 keep_out = keep_out & (rank < fetch)
-            out_pos = jnp.cumsum(keep_out.astype(jnp.int32)) - 1
+            out_pos = int_cumsum(keep_out.astype(jnp.int32)) - 1
             n_out = keep_out.sum().astype(jnp.int32)
             scatter_idx = jnp.where(keep_out, out_pos, C)
             row_src = (

@@ -35,10 +35,14 @@ Decision rules (auto mode):
 
 Pallas eligibility = grouped aggregation over a bounded code domain
 (1 < G ≤ pallas.max.groups), single expansion lane, no
-aggregate-through-join weights, and only sum/count/count_all aggregates
-(the kernel accumulates f32 sums + i32 counts). `fused_pallas` is never
+aggregate-through-join weights, only sum/count/count_all aggregates, and
+value lanes the kernel takes (the kernel accumulates f32 sums + i32 counts:
+sums over non-nullable f64 columns, counts over non-nullable columns —
+exact int64 money stays on the XLA reductions). `fused_pallas` is never
 auto-picked on CPU backends: the interpreter-mode kernel is for test
-parity, not speed.
+parity, not speed. On a TPU only the kernels its compiler accepts exist
+(`TPU_KERNELS`): the others are never selected there, whatever the knobs
+say, because a kernel that is selected must run compiled.
 """
 
 from __future__ import annotations
@@ -51,6 +55,19 @@ def _pow2(n: int) -> int:
     while p < max(n, 1):
         p *= 2
     return p
+
+
+# Pallas kernels the TPU's compiler accepts; tests/test_tpu_compile.py compiles
+# each for a described v5e at SF10 stage shapes. hash_probe (whole-table VMEM
+# gather) and the int64 sort/top-k/scan family (64-bit operands cannot cross
+# into a TPU kernel) run only in the CPU backend's Pallas interpreter.
+TPU_KERNELS = frozenset({"masked_group_reduce", "dict_filter"})
+
+
+def kernel_runs_on(kernel: str, platform: str) -> bool:
+    """Whether a Pallas kernel of ops/tpu/pallas_kernels.py may be selected
+    on this platform (the CPU backend interprets all of them)."""
+    return platform != "tpu" or kernel in TPU_KERNELS
 
 
 PREDICATE = "predicate"
@@ -87,6 +104,10 @@ class StageEstimate:
     n_joins: int
     max_probe_table: int  # largest direct build table (entries), 0 if none
     agg_funcs: tuple = ()
+    # every aggregate's value lane is one the f32 group-reduce kernel takes:
+    # sums over non-nullable f64 columns, counts over non-nullable columns
+    # (what _compile's trace finds, known here from the encode metadata)
+    f32_value_lanes: bool = True
     spans: list = field(default_factory=list)
     # HBM working-set bytes (admission inputs for the out-of-core planner).
     # table_bytes reproduces DeviceTable.nbytes exactly — data stacks +
@@ -179,8 +200,10 @@ def estimate_stage(scan, ops, agg, dt, builds) -> StageEstimate:
     scan_filters = len(getattr(scan, "filters", []) or [])
     spans = plan_spans(scan_filters, ops, agg)
 
-    # provenance env: per current-schema slot, (kind, dictionary) or None
-    env: list = [(k, d) for k, d in zip(dt.kinds, dt.dicts)]
+    # provenance env: per current-schema slot, (kind, dictionary, nullable)
+    # or None
+    env: list = [(k, d, v is not None)
+                 for k, d, v in zip(dt.kinds, dt.dicts, dt.valids)]
     cur_schema = scan.df_schema
     n_filters = scan_filters
     n_projections = 0
@@ -219,9 +242,14 @@ def estimate_stage(scan, ops, agg, dt, builds) -> StageEstimate:
                 if not membership and not is_mult:
                     lanes *= max(1, int(bt.dup))
             if not membership and not is_mult and bt is not None:
-                # build fields prepend, like _compile's env rebinding
+                # build fields prepend, like _compile's env rebinding; an
+                # outer join's unmatched gathers are NULL, and so is a
+                # column whose payload carries a validity plane
+                outer = op.join_type == "right"
                 env = [
-                    (k, d) for k, d in zip(bt.kinds, bt.dicts)
+                    (k, d, outer or pp is None
+                     or bt.pay_valids[pp] is not None)
+                    for k, d, pp in zip(bt.kinds, bt.dicts, bt.pay_pos)
                 ] + env
                 cur_schema = op.df_schema
             elif is_mult:
@@ -257,6 +285,22 @@ def estimate_stage(scan, ops, agg, dt, builds) -> StageEstimate:
                 break
             group_domain *= _pow2(len(slot[1]))
 
+    def slot_of(e):
+        inner = e.expr if isinstance(e, Alias) else e
+        if not isinstance(inner, Column):
+            return None
+        i = cur_schema.maybe_index_of(inner.name, inner.qualifier)
+        return env[i] if i is not None and i < len(env) else None
+
+    f32_value_lanes = True
+    for d in (agg.aggs if agg is not None else ()):
+        if d.expr is None:
+            continue  # count(*)
+        slot = slot_of(d.expr)
+        if slot is None or slot[2] or (d.func == "sum" and slot[0] != "f64"):
+            f32_value_lanes = False
+            break
+
     import numpy as np
 
     P, N = dt.shape
@@ -291,6 +335,7 @@ def estimate_stage(scan, ops, agg, dt, builds) -> StageEstimate:
         n_joins=n_joins,
         max_probe_table=max_probe_table,
         agg_funcs=agg_funcs,
+        f32_value_lanes=f32_value_lanes,
         spans=spans,
         table_bytes=table_bytes,
         dict_bytes=dict_bytes,
@@ -370,7 +415,7 @@ class CostModel:
     topk_max_k: int = 1024  # above this, ORDER BY...LIMIT full-sorts
 
     @classmethod
-    def from_config(cls, config) -> "CostModel":
+    def from_config(cls, config, platform: str) -> "CostModel":
         from ballista_tpu.config import (
             TPU_FUSION_ENABLED,
             TPU_FUSION_MIN_ROWS,
@@ -391,6 +436,7 @@ class CostModel:
             force_pallas=bool(config.get(TPU_PALLAS)),
             sort_max_rows=int(config.get(TPU_SORT_PALLAS_MAX_ROWS)),
             topk_max_k=int(config.get(TPU_TOPK_MAX_K)),
+            platform=platform,
         )
 
     def _pallas_eligible(self, est: StageEstimate) -> bool:
@@ -405,6 +451,7 @@ class CostModel:
             and not est.has_mult
             and bool(est.agg_funcs)
             and all(f in ("sum", "count", "count_all") for f in est.agg_funcs)
+            and est.f32_value_lanes
         )
 
     def _staged_eligible(self, est: StageEstimate) -> bool:
@@ -449,6 +496,8 @@ class CostModel:
             why.append(f"{est.lanes} expansion lanes")
         if est.has_mult:
             why.append("aggregate-through-join weights")
+        if not est.f32_value_lanes:
+            why.append("exact int64 or nullable value lanes")
         if self.platform != "tpu":
             why.append(f"platform={self.platform}")
         return FusionDecision(
@@ -458,6 +507,9 @@ class CostModel:
     def _sort_pallas_eligible(self, est: StageEstimate) -> tuple[bool, str]:
         from ballista_tpu.ops.tpu.pallas_kernels import MAX_SORT_LANES
 
+        if not kernel_runs_on("segmented_sort", self.platform):
+            return False, (f"the int64 sort/top-k/scan kernels do not lower "
+                           f"for platform={self.platform}")
         cap = min(self.sort_max_rows, MAX_SORT_LANES)
         if est.sort_lanes > cap:
             return False, f"{est.sort_lanes} padded lanes > sort ceiling {cap}"

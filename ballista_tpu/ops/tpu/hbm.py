@@ -124,16 +124,14 @@ def maybe_chaos_oom() -> None:
 # budget resolution
 
 def detect_device_memory_bytes() -> int:
-    """Bytes of device memory on the executing chip via jax memory_stats
-    (0 when the backend does not report — CPU-jax, interpret mode)."""
-    try:
-        import jax
+    """Bytes of device memory on the chip this thread dispatches to, via its
+    memory_stats. 0 only where the backend itself reports none (the CPU
+    backend's memory_stats() is None); a backend that cannot be asked
+    raises."""
+    from ballista_tpu.ops.tpu import runtime
 
-        dev = jax.devices()[0]
-        stats = dev.memory_stats() or {}
-        return int(stats.get("bytes_limit", 0) or 0)
-    except Exception:  # noqa: BLE001 — detection is best-effort by design
-        return 0
+    stats = runtime.current_device().memory_stats() or {}
+    return int(stats.get("bytes_limit", 0))
 
 
 # per-session HBM quota (device daemon multi-tenancy): the daemon wraps

@@ -67,6 +67,15 @@ class Unsupported(Exception):
     """Raised at lowering time → subtree falls back to the CPU engine."""
 
 
+class BelowRowFloor(Unsupported):
+    """The stage's input is under ballista.tpu.min.rows: it stays on the CPU
+    engine by policy (compile and dispatch cost dominate), which says
+    nothing about the device — counted apart from the other declines."""
+
+    def __init__(self, rows: int):
+        super().__init__(f"only {rows} rows (< tpu min)")
+
+
 def vand(*valids):
     """Null-strict validity combine: result is null if ANY input is null
     (the SQL rule for comparisons, arithmetic, casts, function args)."""
@@ -84,6 +93,96 @@ def true_mask(v: DevVal):
     if v.valid is None:
         return v.arr
     return v.arr & v.valid
+
+
+# -- ordering ----------------------------------------------------------------
+
+
+def _float_rank(k):
+    """A float sort key as its dense rank (int32) under `lax.sort`'s own
+    float order — NaNs last and equal to each other, -0.0 == +0.0. Floats
+    cannot be re-read as order-preserving integers on a TPU (the compiler's
+    64-bit rewrite has no f64 <-> s64 bitcast), so a float key costs one
+    more two-operand sort."""
+    import jax
+
+    jnp = _jnp()
+    M = k.shape[0]
+    sk, idx = jax.lax.sort((k, jnp.arange(M, dtype=jnp.int32)), num_keys=1)
+    same = (sk[1:] == sk[:-1]) | (jnp.isnan(sk[1:]) & jnp.isnan(sk[:-1]))
+    steps = jnp.concatenate([jnp.zeros((1,), jnp.int32), (~same).astype(jnp.int32)])
+    return jnp.zeros((M,), jnp.int32).at[idx].set(
+        int_cumsum(steps), unique_indices=True)
+
+
+def _order_lanes(k) -> list:
+    """Order-preserving int32 lanes of one sort key, most significant first:
+    comparing the lanes lexicographically as signed int32 orders exactly as
+    `lax.sort` orders the key itself."""
+    import jax
+
+    jnp = _jnp()
+    dt = k.dtype
+    if jnp.issubdtype(dt, jnp.floating):
+        return [_float_rank(k)]
+    if dt == jnp.bool_ or (dt.itemsize < 4 and jnp.issubdtype(dt, jnp.integer)):
+        return [k.astype(jnp.int32)]
+    if dt == jnp.int32:
+        return [k]
+    if dt == jnp.int64:
+        # high word signed; low word unsigned, re-read as signed by flipping
+        # its top bit
+        lo = (k & jnp.int64(0xFFFFFFFF)).astype(jnp.uint32) ^ jnp.uint32(0x80000000)
+        return [(k >> 32).astype(jnp.int32),
+                jax.lax.bitcast_convert_type(lo, jnp.int32)]
+    raise Unsupported(f"sort key dtype {dt}")
+
+
+def lex_order(keys):
+    """Stable ascending lexicographic ordering permutation (int32 [M]) of M
+    rows by `keys`, most significant first — the permutation a multi-operand
+    `lax.sort(keys + (iota,), num_keys=len(keys) + 1)` returns, in a form the
+    TPU's compiler takes quickly.
+
+    The chip compiler's time for a sort grows steeply with its operand count
+    (a 64-bit operand counts twice, a stable sort adds one) times log²(M):
+    compiling for a v5e, a stable two-operand int32 sort of 2^26 rows takes
+    about 40 s, q3's partial-aggregate sort (four keys, two of them 64-bit,
+    one 64-bit payload) about 480 s, and every sorted-path stage, final stage
+    and window stage emits such a sort. So: ONE stable two-operand sort,
+    inside a `lax.scan` over the keys' 32-bit lanes taken least significant
+    first (LSD radix passes, 32 bits a digit). A program compiles one small
+    sort whatever its integer key list (a float key adds one, _float_rank);
+    payloads move afterwards by `x[perm]`."""
+    import jax
+
+    jnp = _jnp()
+    lanes = [lane for k in keys for lane in _order_lanes(k)]
+    M = lanes[0].shape[0]
+
+    def radix_pass(perm, lane):
+        _, perm = jax.lax.sort((lane[perm], perm), num_keys=1, is_stable=True)
+        return perm, None
+
+    perm, _ = jax.lax.scan(radix_pass, jnp.arange(M, dtype=jnp.int32),
+                           jnp.stack(lanes[::-1]))
+    return perm
+
+
+def int_cumsum(x, block: int = 2048):
+    """Inclusive cumulative sum of a 1-D INTEGER array in its own dtype, as
+    per-block cumsums plus the running block totals. Exact (integer addition
+    is associative) and far cheaper for the chip's compiler than one flat
+    scan: compiling for a v5e at 2^26 int64 rows, `jnp.cumsum` takes 77 s,
+    this form 3 s. Lengths the block does not divide take the flat scan."""
+    jnp = _jnp()
+    M = x.shape[0]
+    if M <= block or M % block:
+        return jnp.cumsum(x, dtype=x.dtype)
+    inner = jnp.cumsum(x.reshape(-1, block), axis=1, dtype=x.dtype)
+    totals = inner[:, -1]
+    before = jnp.cumsum(totals, dtype=x.dtype) - totals
+    return (inner + before[:, None]).reshape(-1)
 
 
 # -- bit-exact twin of ops/hashing.py ---------------------------------------

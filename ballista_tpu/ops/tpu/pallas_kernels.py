@@ -1,69 +1,95 @@
-"""Pallas TPU kernel family for the fused stage-execution hot path.
+"""Pallas kernel family for the fused stage-execution hot path.
 
-Four kernel groups back `fusion_mode=fused_pallas`:
+Two kernels back `fusion_mode=fused_pallas` on a TPU; the chip's compiler
+accepts both (tests/test_tpu_compile.py compiles them for a described v5e at
+SF10 stage shapes) and chip_smoke.py checks them against their XLA forms on
+the chip:
 
 - `masked_group_reduce`: per-(partition, group) masked (sum, count) over
-  [P, N] value lanes. The per-group reduction is VECTORIZED inside the
-  kernel as a one-hot matmul — each row block builds a [block_n, 128]
-  one-hot membership tile (group id == lane, AND the stage mask) and a
-  single `jnp.dot` yields all 128 group sums at once on the MXU, instead
-  of the old O(G) static Python unroll that emitted two VPU reductions
-  per group. Group domains beyond one 128-lane tile run on a multi-tile
-  grid axis (G up to MAX_GROUPS), so compile time and kernel size no
-  longer grow linearly with the group count.
-- `hash_probe`: tiled direct-mode join probe. The build side's dense
-  key→row int32 table stays VMEM-resident per block while probe-key
-  blocks stream through; the gather and the downstream predicate mask
-  (in-range AND probe-valid AND row-present) fuse into one kernel so the
-  match mask never round-trips through HBM.
+  [P, N] value lanes. Each (8-row, block_n-lane) block builds, per row, a
+  [group-tile, block_n] membership mask (group id == sublane index, AND the
+  stage mask) and reduces it along the lanes on the VPU — exact f32 adds, no
+  MXU pass, so the result does not depend on matmul precision. Group
+  domains beyond one 128-group tile run on a multi-tile grid axis (G up to
+  MAX_GROUPS).
+- `dict_filter`: string predicates (eq / prefix / LIKE-literal) as a
+  boolean LUT over dictionary codes, fused with the incoming predicate
+  mask. The LUT sits in VMEM as [T/128, 128] rows and every 128-lane vreg of
+  codes is answered by in-register lane gathers, one per LUT row; LUTs past
+  MAX_DICT_LUT entries take the plain XLA gather.
 
+The rest run ONLY in the CPU backend's Pallas interpreter — the TPU lowering
+refuses them, fusion.py never selects them on a TPU (`fusion.TPU_KERNELS`),
+and they stay for the CPU parity tests until a chip cell decides their
+fate (ROADMAP S6/D2):
+
+- `hash_probe`: direct-mode join probe as one `table[keys]` gather from a
+  whole VMEM-resident table; the TPU has no such gather past one vreg.
 - `segmented_sort` / `topk_select`: the ORDER BY family over the int64
   lane encoding (ints/dates widened, floats bit-twiddled order-preserving,
   strings as lexicographic-rank dictionary codes, validity as a leading
-  null-rank operand). Each [P, N] row sorts independently with a bitonic
-  network expressed as static reshape + compare-exchange passes (no
-  gathers), over the lexicographic triple (key, tiebreak, position) — the
-  position operand makes the network's output identical to a STABLE sort
-  by (key, tiebreak). `topk_select` never materializes the full sort:
-  chunks of C = pow2(≥k) lanes sort locally, then pairs fold with the
-  elementwise-min bitonic trick (keep the C smallest of 2C, re-merge),
-  log2(N/C) rounds down to one sorted chunk.
+  null-rank operand) as a bitonic network of reshape + compare-exchange
+  passes over the lexicographic triple (key, tiebreak, position).
+  `topk_select` never materializes the full sort: chunks of C = pow2(≥k)
+  lanes sort locally, then pairs fold with the elementwise-min bitonic
+  trick, log2(N/C) rounds down to one sorted chunk. 64-bit operands cannot
+  cross into a TPU kernel at all.
 - `segmented_scan`: inclusive segmented sum/min/max over [P, N] lanes with
-  boundary resets — the window-aggregate primitive (Hillis-Steele with
-  flag propagation, log2(N) shift passes).
-- `dict_filter`: string predicates (eq / prefix / LIKE-literal) as a
-  VMEM-resident boolean LUT gather over dictionary codes, fused with the
-  incoming predicate mask — the hash_probe pattern applied to the host-
-  compiled predicate LUTs.
+  boundary resets (Hillis-Steele with flag propagation), int64/f64 lanes.
 
-Grid = (partition, [group tile,] row block); reduction outputs are
-revisited across row blocks and accumulated in place (the standard
-Pallas reduction pattern, pallas_guide.md).
+Blocks follow the TPU rule: the last two block dimensions are multiples of
+(8, 128) or span the whole array (`_tile`). Reduction outputs are revisited
+across row blocks and accumulated in place (pallas_guide.md). Every scalar
+inside a kernel is 32-bit by construction — the engine runs with x64 on, and
+a weak Python literal or an index-map `0` would otherwise enter as 64-bit,
+which the TPU lowering refuses.
 
 Scope follows TPU arithmetic reality: f32 sums + i32 counts (the VPU's
 native widths). The exact int64-cents money path stays on the XLA
-reduction. Mode selection lives in ops/tpu/fusion.py (cost model); on
-CPU backends both kernels run in interpreter mode so tier-1 tests cover
-the exact same code path.
+reduction. Mode selection lives in ops/tpu/fusion.py (cost model).
 """
 
 from __future__ import annotations
 
 import functools
 
-GROUP_LANES = 128  # output tile width (one VPU lane row)
+LANES = 128  # vreg lane width: every lane block is a multiple of it
+GROUP_LANES = 128  # group-tile height ceiling (groups ride sublanes)
 MAX_GROUP_TILES = 32
 MAX_GROUPS = GROUP_LANES * MAX_GROUP_TILES  # multi-tile grid ceiling
 
 
 def _on_cpu() -> bool:
-    from ballista_tpu.ops.tpu.runtime import ensure_jax
+    """Interpret mode is for the CPU backend only: on any other platform a
+    selected kernel runs compiled or fails to compile, loudly."""
+    from ballista_tpu.ops.tpu import runtime
 
-    jax = ensure_jax()
-    try:
-        return jax.devices()[0].platform == "cpu"
-    except Exception:  # noqa: BLE001
-        return True
+    return runtime.platform() == "cpu"
+
+
+def _tile(P: int, N: int, block_n: int) -> tuple[int, int, int]:
+    """(padded P, padded N, lane block) for a [P, N] operand under the TPU
+    block rule. Up to 8 partitions ride as one whole-axis row block; more
+    are padded to a multiple of 8 and blocked by 8 (builders take
+    min(P, 8)). Lanes pad to a multiple of 128 (a no-op for the engine's
+    shape buckets) and block by the largest multiple of 128 that divides
+    them and is at most block_n."""
+    Pp = P if P <= 8 else -(-P // 8) * 8
+    Np = -(-N // LANES) * LANES
+    k = max(1, min(block_n, Np) // LANES)
+    while (Np // LANES) % k:
+        k -= 1
+    return Pp, Np, k * LANES
+
+
+def _pad2(x, Pp: int, Np: int):
+    """Zero-pad a [P, N] operand up to [Pp, Np] (zero mask = dead lanes)."""
+    import jax.numpy as jnp
+
+    P, N = x.shape
+    if (P, N) == (Pp, Np):
+        return x
+    return jnp.pad(x, ((0, Pp - P), (0, Np - N)))
 
 
 @functools.lru_cache(maxsize=32)
@@ -71,8 +97,13 @@ def _build_group_reduce(P: int, N: int, block_n: int, G: int, interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    n_tiles = -(-G // GROUP_LANES)
+    Pb = min(P, 8)
+    # groups ride the sublane axis: a tile is as tall as the domain needs
+    # (rounded to the 8-sublane vreg), 128 at most
+    Gt = min(GROUP_LANES, -(-G // 8) * 8)
+    n_tiles = -(-G // Gt)
 
     def kernel(vals_ref, gid_ref, mask_ref, sums_ref, cnts_ref):
         gt = pl.program_id(1)
@@ -83,41 +114,34 @@ def _build_group_reduce(P: int, N: int, block_n: int, G: int, interpret: bool):
             sums_ref[...] = jnp.zeros_like(sums_ref)
             cnts_ref[...] = jnp.zeros_like(cnts_ref)
 
-        v = vals_ref[...]  # [1, block_n]
-        g = gid_ref[0, :]
-        m = mask_ref[0, :] != 0
-        # one-hot membership tile for this kernel's 128 group lanes:
-        # [block_n, GROUP_LANES], mask folded in — ONE matmul then computes
-        # every lane's masked sum (MXU), no per-group unroll
-        lanes = gt * GROUP_LANES + jax.lax.broadcasted_iota(
-            jnp.int32, (1, GROUP_LANES), 1
-        )
-        oh = ((g[:, None] == lanes) & m[:, None]).astype(jnp.float32)
-        sums_ref[...] += jnp.dot(v, oh, preferred_element_type=jnp.float32)
-        ones = jnp.ones((1, block_n), jnp.float32)
-        # block_n ≤ 2048 < 2^24: per-block f32 counts are exact
-        cnts_ref[...] += jnp.dot(
-            ones, oh, preferred_element_type=jnp.float32
-        ).astype(jnp.int32)
+        groups = gt * Gt + jax.lax.broadcasted_iota(jnp.int32, (Gt, 1), 0)
+        for r in range(Pb):
+            v = vals_ref[r:r + 1, :]  # [1, block_n]
+            g = gid_ref[r:r + 1, :]
+            m = mask_ref[r:r + 1, :] != 0
+            hit = (g == groups) & m  # [Gt, block_n]: group on sublanes
+            sums_ref[r] += jnp.sum(jnp.where(hit, v, jnp.zeros_like(v)),
+                                   axis=1, keepdims=True, dtype=jnp.float32)
+            cnts_ref[r] += jnp.sum(hit.astype(jnp.int32),
+                                   axis=1, keepdims=True, dtype=jnp.int32)
 
-    grid = (P, n_tiles, N // block_n)
+    in_spec = pl.BlockSpec((Pb, block_n), lambda i, gt, j: (i, j))
+    # [P, groups, 1]: a tile's sums land as one sublane column per row
+    # (an int32 zero made inside the index map: a bare 0 enters as int64)
+    out_spec = pl.BlockSpec((Pb, Gt, 1),
+                            lambda i, gt, j: (i, gt, jnp.int32(0)))
     fn = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda i, gt, j: (i, j)),
-            pl.BlockSpec((1, block_n), lambda i, gt, j: (i, j)),
-            pl.BlockSpec((1, block_n), lambda i, gt, j: (i, j)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, GROUP_LANES), lambda i, gt, j: (i, gt)),
-            pl.BlockSpec((1, GROUP_LANES), lambda i, gt, j: (i, gt)),
-        ),
+        grid=(P // Pb, n_tiles, N // block_n),
+        in_specs=[in_spec, in_spec, in_spec],
+        out_specs=(out_spec, out_spec),
         out_shape=(
-            jax.ShapeDtypeStruct((P, n_tiles * GROUP_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((P, n_tiles * GROUP_LANES), jnp.int32),
+            jax.ShapeDtypeStruct((P, n_tiles * Gt, 1), jnp.float32),
+            jax.ShapeDtypeStruct((P, n_tiles * Gt, 1), jnp.int32),
         ),
         interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )
     return jax.jit(fn)
 
@@ -133,14 +157,14 @@ def masked_group_reduce(vals, gid, mask, num_groups: int, block_n: int = 2048):
     if num_groups > MAX_GROUPS:
         raise ValueError(f"num_groups {num_groups} > {MAX_GROUPS}")
     P, N = vals.shape
-    bn = min(block_n, N)
-    while N % bn:
-        bn //= 2
-    fn = _build_group_reduce(P, N, bn, num_groups, interpret=_on_cpu())
+    Pp, Np, bn = _tile(P, N, block_n)
+    fn = _build_group_reduce(Pp, Np, bn, num_groups, interpret=_on_cpu())
     sums, cnts = fn(
-        vals.astype(jnp.float32), gid.astype(jnp.int32), mask.astype(jnp.int32)
+        _pad2(vals.astype(jnp.float32), Pp, Np),
+        _pad2(gid.astype(jnp.int32), Pp, Np),
+        _pad2(mask.astype(jnp.int32), Pp, Np),
     )
-    return sums[:, :num_groups], cnts[:, :num_groups]
+    return sums[:P, :num_groups, 0], cnts[:P, :num_groups, 0]
 
 
 @functools.lru_cache(maxsize=32)
@@ -436,30 +460,54 @@ def segmented_scan(vals, boundary, func: str):
 # ---------------------------------------------------------------------------
 
 
+MAX_DICT_LUT = 1024  # 8 lane-gather rounds per vreg of codes
+
+
 @functools.lru_cache(maxsize=32)
 def _build_dict_filter(P: int, N: int, block_n: int, T: int, interpret: bool):
     import jax
     import jax.numpy as jnp
+    from jax import lax
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Pb = min(P, 8)
+    n_rows = T // LANES
+    # the one gather the TPU lowering takes: per-row, within one 128-lane
+    # vreg, int32 indices (jnp.take_along_axis would widen them to int64)
+    lane_gather = lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
 
     def kernel(codes_ref, mask_ref, lut_ref, keep_ref):
-        c = codes_ref[0, :]
-        m = mask_ref[0, :] != 0
-        lut = lut_ref[...]  # full [T] boolean LUT, VMEM-resident
-        keep_ref[0, :] = (m & (lut[c] != 0)).astype(jnp.int8)
+        for c in range(block_n // LANES):
+            sl = slice(c * LANES, (c + 1) * LANES)
+            codes = codes_ref[:, sl]  # [Pb, 128]
+            hit = jnp.zeros_like(codes)
+            for k in range(n_rows):
+                row = jnp.broadcast_to(lut_ref[k:k + 1, :], codes.shape)
+                local = codes - jnp.int32(k * LANES)
+                here = (local >= 0) & (local < LANES)
+                got = lax.gather(
+                    row, jnp.where(here, local, jnp.zeros_like(local))[..., None],
+                    lane_gather, slice_sizes=(1, 1),
+                    mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+                hit = jnp.where(here, got, hit)
+            keep_ref[:, sl] = (
+                (mask_ref[:, sl] != 0) & (hit != 0)).astype(jnp.int32)
 
-    grid = (P, N // block_n)
+    spec = pl.BlockSpec((Pb, block_n), lambda i, j: (i, j))
     fn = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((T,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((P, N), jnp.int8),
+        grid=(P // Pb, N // block_n),
+        in_specs=[spec, spec,
+                  pl.BlockSpec((n_rows, LANES),
+                               lambda i, j: (jnp.int32(0), jnp.int32(0)))],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((P, N), jnp.int32),
         interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )
     return jax.jit(fn)
 
@@ -470,15 +518,19 @@ def dict_filter(codes, lut, mask, block_n: int = 2048):
     codes: i32 [P, N] dictionary indices (pre-clamped into [0, T));
     lut: bool [T] host-compiled predicate truth table (eq / prefix /
     LIKE-literal evaluated per dictionary entry, pow2-padded); mask:
-    bool [P, N]. Returns keep bool [P, N] — the gather and the mask
-    conjunction never round-trip through HBM."""
+    bool [P, N]. Returns keep bool [P, N] — for LUTs of up to MAX_DICT_LUT
+    entries the gather and the mask conjunction never round-trip through
+    HBM; larger dictionaries take the XLA gather."""
     import jax.numpy as jnp
 
+    T = int(lut.shape[0])
+    if T > MAX_DICT_LUT:
+        return mask & lut[codes]
     P, N = codes.shape
-    bn = min(block_n, N)
-    while N % bn:
-        bn //= 2
-    fn = _build_dict_filter(P, N, bn, int(lut.shape[0]), interpret=_on_cpu())
-    keep = fn(codes.astype(jnp.int32), mask.astype(jnp.int32),
-              lut.astype(jnp.int8))
-    return keep != 0
+    Pp, Np, bn = _tile(P, N, block_n)
+    Tp = -(-T // LANES) * LANES
+    lut2 = jnp.pad(lut.astype(jnp.int32), (0, Tp - T)).reshape(Tp // LANES, LANES)
+    fn = _build_dict_filter(Pp, Np, bn, Tp, interpret=_on_cpu())
+    keep = fn(_pad2(codes.astype(jnp.int32), Pp, Np),
+              _pad2(mask.astype(jnp.int32), Pp, Np), lut2)
+    return keep[:P, :N] != 0

@@ -12,10 +12,12 @@ split of labor is the parity contract:
   `pa.Table.take` — payload columns never leave the host, so the output
   bytes are the CPU engine's bytes by construction.
 - The DEVICE computes only the permutation (and, for windows, the
-  segmented scans): `fused_pallas` runs the bitonic `segmented_sort` /
-  `topk_select` / `segmented_scan` kernels, `fused_xla` one `lax.sort`
-  over all key operands, `staged` one stable `lax.sort` per key (LSD
-  passes). `CostModel.choose_sort` picks per shape with the demotion
+  segmented scans): `fused_pallas` (CPU interpreter only) runs the bitonic
+  `segmented_sort` / `topk_select` / `segmented_scan` kernels; `fused_xla`
+  and `staged` run `kernels.lex_order` (stable LSD radix passes over the
+  keys' 32-bit lanes) and an associative segmented scan, both jitted at
+  power-of-two lane counts so one compilation serves every partition of
+  a bucket. `CostModel.choose_sort` picks per shape with the demotion
   ladder; an ineligible shape raises Unsupported and the operator falls
   back to the CPU oracle over the SAME materialized input (never
   re-executing the child).
@@ -47,6 +49,7 @@ reimplementations.
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -63,7 +66,7 @@ from ballista_tpu.config import (
 )
 from ballista_tpu.ops.phys_expr import bind_expr, evaluate_to_array
 from ballista_tpu.ops.tpu.columnar import encode_column
-from ballista_tpu.ops.tpu.kernels import Unsupported
+from ballista_tpu.ops.tpu.kernels import BelowRowFloor, Unsupported
 from ballista_tpu.ops.tpu.runtime import device_scope, ensure_jax
 from ballista_tpu.plan.expressions import SortKey, WindowFunction
 from ballista_tpu.plan.physical import (
@@ -79,6 +82,8 @@ log = logging.getLogger(__name__)
 
 _I64_MAX = (1 << 63) - 1
 _I64_MIN = -(1 << 63)
+_I32_MAX = (1 << 31) - 1
+_I32_MIN = -(1 << 31)
 _SIGN = 1 << 63
 
 _WINDOW_DEVICE_FUNCS = ("row_number", "rank", "count", "sum", "min", "max")
@@ -160,6 +165,19 @@ def _dict_ranks(dictionary: list) -> np.ndarray:
     return ranks
 
 
+def _f64_to_ordered(v: np.ndarray) -> np.ndarray:
+    """float64 → int64 with the same order (sign-fold of the raw bits);
+    exactly invertible, ±0.0 and NaN payloads included."""
+    bits = np.ascontiguousarray(v, dtype=np.float64).view(np.int64)
+    return np.where(bits >= 0, bits, (~bits) | np.int64(-_SIGN))
+
+
+def _ordered_to_f64(lane: np.ndarray) -> np.ndarray:
+    lane = np.ascontiguousarray(lane, dtype=np.int64)
+    bits = np.where(lane >= 0, lane, ~(lane & np.int64(_I64_MAX)))
+    return bits.view(np.float64)
+
+
 def _order_lane(arr: pa.Array):
     """Encode one evaluated key column as an order-preserving int64 lane.
     Returns (lane i64[n], is_valid bool[n] | None, nan bool[n] | None,
@@ -176,8 +194,7 @@ def _order_lane(arr: pa.Array):
         lane = _dict_ranks(dc.dictionary)[dc.data.astype(np.int64, copy=False)]
     elif dc.kind == "f64":
         v = dc.data + 0.0  # canonicalize -0.0 → +0.0
-        bits = v.view(np.int64)
-        lane = np.where(bits >= 0, bits, (~bits) | np.int64(-_SIGN))
+        lane = _f64_to_ordered(v)
         nan = np.isnan(v)  # placed after the direction flip, see caller
     else:
         raise Unsupported(f"sort key kind {dc.kind}")
@@ -219,14 +236,9 @@ def _encode_key_arrays(arrays: list, orders: list) -> tuple[list, list]:
 
 
 def _sort_cost_model(config: BallistaConfig):
-    from ballista_tpu.ops.tpu import fusion
+    from ballista_tpu.ops.tpu import fusion, runtime
 
-    cm = fusion.CostModel.from_config(config)
-    try:
-        cm.platform = ensure_jax().devices()[0].platform
-    except Exception:  # noqa: BLE001
-        cm.platform = "cpu"
-    return cm
+    return fusion.CostModel.from_config(config, runtime.platform())
 
 
 def _admit(est, config: BallistaConfig) -> None:
@@ -274,34 +286,29 @@ def _perm_full(key_ops: list, n: int, mode: str, up: _Uploads) -> np.ndarray:
         # BOTH operands) sort strictly after every real row because real
         # null-rank operands are 0/1.
         for nrank, lane in reversed(key_ops):
-            a = up.put(_pad_i64(nrank if nrank is not None else
+            a = up.put(_pad_max(nrank if nrank is not None else
                                 np.zeros(n, np.int64), L))
-            b = up.put(_pad_i64(lane, L))
+            b = up.put(_pad_max(lane, L))
             _, _, p = segmented_sort(a[perm][None, :], b[perm][None, :],
                                      pos[None, :])
             perm = perm[p[0]]
         return np.asarray(jax.device_get(perm))[:n]
+    # the stable lexicographic order over every operand, at a power-of-two
+    # lane count: max-value sentinels pad the tail and, the order being
+    # stable, stay behind every real row — the first n of the permutation
+    # are the real rows in order. Lanes whose values fit ship as int32 (one
+    # radix pass instead of two).
+    L = _pow2(n)
     flat: list = []
     for nrank, lane in key_ops:
         if nrank is not None:
-            flat.append(up.put(nrank))
-        flat.append(up.put(lane))
-    pos = jnp.arange(n, dtype=jnp.int32)
-    up.bytes += n * 4
-    if mode == "staged":
-        # one stable lax.sort per key, least-significant first
-        perm = pos
-        i = len(flat)
-        for nrank, lane in reversed(key_ops):
-            w = 2 if nrank is not None else 1
-            i -= w
-            ops = tuple(o[perm] for o in flat[i:i + w]) + (perm,)
-            perm = jax.lax.sort(ops, num_keys=w, is_stable=True)[-1]
-        return np.asarray(jax.device_get(perm))
-    # fused_xla: one sort over every operand; the position operand is the
-    # final key, so the result is the stable lexicographic order
-    res = jax.lax.sort(tuple(flat) + (pos,), num_keys=len(flat) + 1)
-    return np.asarray(jax.device_get(res[-1]))
+            flat.append(up.put(_pad_max(nrank.astype(np.int32), L)))
+        if len(lane) and _I32_MIN <= lane.min() and lane.max() <= _I32_MAX:
+            lane = lane.astype(np.int32)
+        flat.append(up.put(_pad_max(lane, L)))
+    up.bytes += L * 4
+    perm = _lex_order_jit()(*flat)
+    return np.asarray(jax.device_get(perm))[:n]
 
 
 def _perm_topk(key_ops: list, n: int, k: int, up: _Uploads) -> np.ndarray:
@@ -313,8 +320,8 @@ def _perm_topk(key_ops: list, n: int, k: int, up: _Uploads) -> np.ndarray:
 
     (nrank, lane), = key_ops
     L = _pow2(n)
-    a = up.put(_pad_i64(nrank if nrank is not None else np.zeros(n, np.int64), L))
-    b = up.put(_pad_i64(lane, L))
+    a = up.put(_pad_max(nrank if nrank is not None else np.zeros(n, np.int64), L))
+    b = up.put(_pad_max(lane, L))
     pos = jnp.arange(L, dtype=jnp.int32)
     up.bytes += L * 4
     kk = min(int(k), n)
@@ -322,12 +329,28 @@ def _perm_topk(key_ops: list, n: int, k: int, up: _Uploads) -> np.ndarray:
     return np.asarray(jax.device_get(sp[0]))[:kk]
 
 
-def _pad_i64(a: np.ndarray, L: int) -> np.ndarray:
+def _pad_max(a: np.ndarray, L: int) -> np.ndarray:
+    """`a` padded to L lanes with its dtype's largest value."""
     if len(a) == L:
-        return np.ascontiguousarray(a, dtype=np.int64)
-    out = np.full(L, _I64_MAX, dtype=np.int64)
+        return np.ascontiguousarray(a)
+    out = np.full(L, np.iinfo(a.dtype).max, dtype=a.dtype)
     out[: len(a)] = a
     return out
+
+
+@functools.lru_cache(maxsize=1)
+def _lex_order_jit():
+    from ballista_tpu.ops.tpu.kernels import lex_order
+
+    return ensure_jax().jit(lambda *keys: lex_order(list(keys)))
+
+
+@functools.lru_cache(maxsize=8)
+def _segscan_jit(func: str):
+    from ballista_tpu.ops.tpu.stage_compiler import _segscan
+
+    jax = ensure_jax()
+    return jax.jit(lambda v, b: _segscan(jax.numpy, v, b, func))
 
 
 def _pow2(n: int) -> int:
@@ -403,7 +426,7 @@ def _device_sort(tbl: pa.Table, df_schema: DFSchema, keys: list,
     if n == 0:
         return tbl
     if n < max(int(config.get(TPU_MIN_ROWS)), 1):
-        raise Unsupported(f"only {n} rows (< tpu min)")
+        raise BelowRowFloor(n)
     batch = tbl.combine_chunks().to_batches()[0]
     arrays = [evaluate_to_array(bind_expr(k.expr, df_schema), batch)
               for k in keys]
@@ -480,16 +503,22 @@ class TpuSortStageExec(ExecutionPlan):
     def _run(self, partition: int, ctx: TaskContext):
         batches = [b for b in self.input.execute(partition, ctx) if b.num_rows]
         tbl = _concat(batches, self.schema())
+        from ballista_tpu.ops.tpu.stage_compiler import STAGE_OUTCOMES
+
         try:
             with device_scope(ctx.device_ordinal):
                 out = _device_sort(tbl, self.df_schema, self.keys, self.fetch,
                                    self.config)
+            if tbl.num_rows:  # an empty partition dispatches nothing
+                STAGE_OUTCOMES.note("sort", "device")
             self.tpu_count += 1
         except Unsupported as e:
             log.info("tpu sort fallback (%s)", e)
+            STAGE_OUTCOMES.note_fallback("sort", e)
             out = self._host_sort(tbl)
-        except Exception:  # noqa: BLE001 — device trouble never fails the query
+        except Exception as e:  # noqa: BLE001 — device trouble never fails the query
             log.warning("tpu sort raised; falling back to cpu", exc_info=True)
+            STAGE_OUTCOMES.note_fallback("sort", e)
             out = self._host_sort(tbl)
         if out.num_rows == 0:
             yield _empty_batch(self.schema())
@@ -561,22 +590,21 @@ def _seg_scan(vals: np.ndarray, boundary: np.ndarray, func: str, mode: str,
               up: _Uploads) -> np.ndarray:
     """Device inclusive segmented scan (reset at boundary lanes)."""
     jax = ensure_jax()
-    jnp = jax.numpy
     n = len(vals)
+    # power-of-two lanes either way: one compilation per bucket, not per
+    # partition row count
+    L = _pow2(n)
+    v = np.zeros(L, dtype=vals.dtype)
+    v[:n] = vals
+    f = np.ones(L, dtype=bool)  # padding lanes self-reset
+    f[:n] = boundary
     if mode == "fused_pallas":
         from ballista_tpu.ops.tpu.pallas_kernels import segmented_scan
 
-        L = _pow2(n)
-        v = np.zeros(L, dtype=vals.dtype)
-        v[:n] = vals
-        f = np.ones(L, dtype=bool)  # padding lanes self-reset
-        f[:n] = boundary
-        out = segmented_scan(up.put(v)[None, :], up.put(f)[None, :], func)
-        return np.asarray(jax.device_get(out[0]))[:n]
-    from ballista_tpu.ops.tpu.stage_compiler import _segscan
-
-    out = _segscan(jnp, up.put(vals), up.put(boundary), func)
-    return np.asarray(jax.device_get(out))
+        out = segmented_scan(up.put(v)[None, :], up.put(f)[None, :], func)[0]
+    else:
+        out = _segscan_jit(func)(up.put(v), up.put(f))
+    return np.asarray(jax.device_get(out))[:n]
 
 
 def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
@@ -658,14 +686,24 @@ def _emit_scan_agg(batch, w, schema, fr, mode, boundary, up, out_type,
     else:  # min / max
         is_f = (np.issubdtype(np.asarray(vals).dtype, np.floating)
                 or pa.types.is_floating(out_type))
-        v = np.asarray(vals, dtype=np.float64 if is_f else np.int64)
+        # the running extreme only ever compares: the device scans int64
+        # lanes, never floats. A TPU emulates f64 inexactly (a value does
+        # not come back bit-identical), so floats ride as their order-
+        # preserving int64 image and are decoded here. NaN propagates like
+        # np.minimum/np.maximum.accumulate: it takes the extreme the scan
+        # is looking for; null slots take the other one (the identity).
+        ident, nan_mark = ((_I64_MAX, _I64_MIN) if w.func == "min"
+                           else (_I64_MIN, _I64_MAX))
         if is_f:
-            sentinel = np.inf if w.func == "min" else -np.inf
+            fv = np.asarray(vals, dtype=np.float64)
+            v = np.where(np.isnan(fv), np.int64(nan_mark), _f64_to_ordered(fv))
         else:
-            sentinel = (np.iinfo(np.int64).max if w.func == "min"
-                        else np.iinfo(np.int64).min)
-        v = np.where(valid, v, sentinel)
+            v = np.asarray(vals, dtype=np.int64)
+        v = np.where(valid, v, np.int64(ident))
         out_sorted = _seg_scan(v, boundary, w.func, mode, up)[last]
+        if is_f:
+            out_sorted = np.where(out_sorted == nan_mark, np.nan,
+                                  _ordered_to_f64(out_sorted))
     mask_sorted = seg_cnt[last] == 0  # SQL: aggregate over zero rows is NULL
 
     out = np.empty(n, dtype=out_sorted.dtype)
@@ -679,7 +717,7 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
                     schema: DFSchema, config: BallistaConfig) -> list[pa.Array]:
     n = batch.num_rows
     if n < max(int(config.get(TPU_MIN_ROWS)), 1):
-        raise Unsupported(f"only {n} rows (< tpu min)")
+        raise BelowRowFloor(n)
     if not window_static_ok(window_exprs, schema):
         raise Unsupported("window shape not device-eligible")
     groups: dict[tuple, int] = {}
@@ -750,16 +788,21 @@ class TpuWindowStageExec(ExecutionPlan):
         if batch is None:
             yield _empty_batch(self.schema())
             return
+        from ballista_tpu.ops.tpu.stage_compiler import STAGE_OUTCOMES
+
         try:
             with device_scope(ctx.device_ordinal):
                 wins = _device_windows(batch, self.window_exprs,
                                        self.input.df_schema, self.config)
+            STAGE_OUTCOMES.note("window", "device")
             self.tpu_count += 1
         except Unsupported as e:
             log.info("tpu window fallback (%s)", e)
+            STAGE_OUTCOMES.note_fallback("window", e)
             wins = self._host_windows(batch)
-        except Exception:  # noqa: BLE001 — device trouble never fails the query
+        except Exception as e:  # noqa: BLE001 — device trouble never fails the query
             log.warning("tpu window raised; falling back to cpu", exc_info=True)
+            STAGE_OUTCOMES.note_fallback("window", e)
             wins = self._host_windows(batch)
         arrays = [batch.column(i) for i in range(batch.num_columns)] + wins
         out = pa.RecordBatch.from_arrays(arrays, schema=self.schema())

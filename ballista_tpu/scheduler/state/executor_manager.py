@@ -54,6 +54,11 @@ class ExecutorSlot:
     tpu_hbm_spill_bytes: float = 0.0
     tpu_hbm_spill_events: float = 0.0
     tpu_grace_splits: float = 0.0
+    # where this executor's device stages ran (stage_compiler.STAGE_OUTCOMES)
+    # and the device a pinned executor claimed at start-up: every
+    # tpu_stage_* / tpu_device_* / tpu_local_device_count heartbeat gauge,
+    # latest value, by name
+    tpu_stage_gauges: dict = field(default_factory=dict)
     # -- lifecycle & storage (docs/lifecycle.md) -----------------------------
     lifecycle_state: str = "active"  # active | draining (drained = ledger)
     disk_used_bytes: float = 0.0
@@ -148,6 +153,10 @@ class ExecutorManager:
                     metrics.get("tpu_hbm_spill_events", ex.tpu_hbm_spill_events))
                 ex.tpu_grace_splits = float(
                     metrics.get("tpu_grace_splits", ex.tpu_grace_splits))
+                ex.tpu_stage_gauges.update(
+                    (k, float(v)) for k, v in metrics.items()
+                    if k.startswith(("tpu_stage_", "tpu_device_",
+                                     "tpu_local_device_")))
                 ex.disk_used_bytes = float(
                     metrics.get("disk_used_bytes", ex.disk_used_bytes))
                 ex.disk_free_bytes = float(
@@ -495,6 +504,7 @@ class ExecutorManager:
                     "hbm_spill_bytes": int(e.tpu_hbm_spill_bytes),
                     "hbm_spill_events": int(e.tpu_hbm_spill_events),
                     "grace_splits": int(e.tpu_grace_splits),
+                    "tpu_stages": dict(e.tpu_stage_gauges),
                     "lifecycle_state": e.lifecycle_state,
                     "disk_used_bytes": int(e.disk_used_bytes),
                     "disk_free_bytes": int(e.disk_free_bytes),

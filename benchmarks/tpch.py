@@ -33,7 +33,9 @@ def cmd_data(args) -> None:
     print(f"generated sf={args.scale} at {args.out} in {time.time() - t0:.1f}s")
 
 
-def cmd_run(args) -> None:
+def cmd_run(args) -> int:
+    """Run the queries; returns how many FAILED (raised, or — with --verify —
+    disagreed with the oracle), which becomes the exit code."""
     from ballista_tpu.client.context import SessionContext
     from ballista_tpu.config import BallistaConfig, DEFAULT_SHUFFLE_PARTITIONS, EXECUTOR_ENGINE, TARGET_PARTITIONS
     from ballista_tpu.testing.tpchgen import register_tpch
@@ -60,6 +62,7 @@ def cmd_run(args) -> None:
 
     results = {}
     total = 0.0
+    failed = 0
     for q in queries:
         sql = open(q_path(q)).read()
         times = []
@@ -77,16 +80,21 @@ def cmd_run(args) -> None:
 
                 problems = compare_results(out, run_reference(q, ref_tables), q)
                 status += "  ✓" if not problems else f"  MISMATCH: {problems[0]}"
+                failed += bool(problems)
             results[f"q{q}"] = round(best, 4)
             print(f"q{q:<3} {status}")
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, go on, fail at the end
             print(f"q{q:<3} FAILED: {e}")
             results[f"q{q}"] = None
+            failed += 1
     print(f"\ntotal (best-of-{args.iterations}): {total:.3f}s  engine={args.engine} mode={args.mode}")
     if args.json_out:
         with open(args.json_out, "w") as f:
             json.dump({"engine": args.engine, "mode": args.mode, "total_s": round(total, 3),
                        "queries": results}, f, indent=1)
+    if failed:
+        print(f"{failed} of {len(queries)} queries failed", file=sys.stderr)
+    return failed
 
 
 def main(argv=None) -> None:
@@ -113,8 +121,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.cmd == "data":
         cmd_data(args)
-    else:
-        cmd_run(args)
+    elif cmd_run(args):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -27,8 +27,7 @@ Legs (full mode; --quick runs one of each kind for the bench probe):
    byte-identically, and a rerun must touch no daemon at all (the
    crash-loop check: zero new crashes).
 
-Exits non-zero on any divergence. bench.py's device leg runs the
---quick variant as a sanity probe when BALLISTA_BENCH_DAEMON_CHAOS=1.
+Exits non-zero on any divergence.
 """
 
 import io

@@ -9,7 +9,7 @@ Two legs:
    `compile_overlap_s > 0` (chunked uploads stretch the fill enough to
    make the overlap deterministic on fast CPU backends).
 2. restart — two fresh processes run the same q1 stage sharing one
-   persistent compile cache dir (`BALLISTA_TPU_COMPILE_CACHE`). The warm
+   persistent compile cache dir (`JAX_COMPILATION_CACHE_DIR`). The warm
    process must fetch its XLA binary from disk: warm `xla_compile_s`
    ≤ 0.1× cold, warm `compile_s` strictly below cold, and the warm run
    reports persistent-cache hits.
@@ -72,7 +72,7 @@ def child(data_dir: str) -> None:
 def spawn(data_dir: str, cache_dir: str) -> dict:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["BALLISTA_TPU_COMPILE_CACHE"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", data_dir],
         env=env, capture_output=True, text=True, timeout=600)

@@ -27,8 +27,7 @@ Legs (full mode; --quick drops the drain_kill leg for the bench probe):
    loop: every query must keep succeeding with oracle-correct results
    and the handoffs must migrate real partitions.
 
-Exits non-zero on any divergence. bench.py runs the --quick variant as
-a sanity probe when BALLISTA_BENCH_LIFECYCLE=1.
+Exits non-zero on any divergence.
 """
 
 import os

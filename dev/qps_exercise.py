@@ -32,8 +32,7 @@ merged into cached state) must be byte-identical to — and in aggregate
 faster than — a from-scratch execution in a caches-off session.
 
 Exits non-zero if any check fails. `run_qps_comparison`,
-`run_shard_comparison`, and `run_refresh_comparison` are importable
-(bench.py's serving leg reuses them).
+`run_shard_comparison`, and `run_refresh_comparison` are importable.
 """
 
 import os

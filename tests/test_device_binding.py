@@ -39,6 +39,60 @@ def test_bound_device_and_scope():
         assert y.devices() == {devs[0]}
 
 
+def test_platform_questions_are_asked_of_the_bound_device():
+    """current_device()/platform() follow the thread's device_scope pin, not
+    jax.devices()[0] — on a process that sees several devices the cost
+    model, the Pallas interpret switch and the HBM budget must ask the
+    device the task is bound to."""
+    import jax
+
+    from ballista_tpu.ops.tpu import hbm, runtime
+
+    devs = jax.devices()
+    assert runtime.current_device() is devs[0]
+    with runtime.device_scope(5):
+        assert runtime.current_device() is devs[5]
+        assert runtime.platform() == "cpu"
+        # the CPU backend reports no memory stats: 0 by observation, and
+        # the budget falls to the configured ceiling — not by exception
+        assert hbm.detect_device_memory_bytes() == 0
+    assert runtime.current_device() is devs[0]
+
+
+def test_bind_process_ordinal_after_backend_init_is_an_error():
+    """A process whose jax backend is up already holds every chip it could
+    see: pinning it then must fail loudly, never carry on unpinned."""
+    import jax
+
+    from ballista_tpu.ops.tpu.runtime import bind_process_ordinal
+
+    jax.devices()
+    with pytest.raises(RuntimeError, match="already initialised"):
+        bind_process_ordinal(1)
+    with pytest.raises(ValueError):
+        bind_process_ordinal(-1)
+
+
+def test_bind_process_ordinal_before_backend_init_pins_one_chip():
+    """Before the backend initialises (jax merely imported is fine) the bind
+    writes what libtpu reads: one visible chip, one-chip process bounds and
+    a slice-builder port of the process's own."""
+    code = (
+        "import os, jax\n"
+        "from ballista_tpu.ops.tpu.runtime import bind_process_ordinal\n"
+        "bind_process_ordinal(2)\n"
+        "print(os.environ['TPU_VISIBLE_CHIPS'],"
+        " os.environ['TPU_CHIPS_PER_PROCESS_BOUNDS'],"
+        " os.environ['TPU_PROCESS_BOUNDS'], os.environ['TPU_PROCESS_PORT'],"
+        " os.environ['TPU_PROCESS_ADDRESSES'], len(jax.devices()))\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == ["2", "1,1,1", "1,1,1", "8478",
+                                "localhost:8478", "8"]
+
+
 def test_metadata_serde_roundtrip_ordinal():
     from ballista_tpu.executor.executor import ExecutorMetadata
     from ballista_tpu.serde_control import decode_executor_metadata, encode_executor_metadata

@@ -171,6 +171,82 @@ def test_attached_byte_identical_to_in_process(daemon):
     assert att_stats.get("init_jax_devices_s") is not None
 
 
+def test_two_routed_stages_back_to_back_on_fresh_daemon(tmp_path):
+    """A FRESH daemon (status poll first, as every attach does) serves two
+    daemon-routed stages over 3-partition input back to back and is still
+    the same live process afterwards — the sequence that used to kill it
+    with SIGSEGV on the first execute."""
+    sock = str(tmp_path / "fresh.sock")
+    proc, client = _spawn_and_wait(sock)
+    try:
+        pid = client.ping()["pid"]
+        tbl = _table()
+        base, _ = _run_query(tbl)
+        for _ in range(2):
+            out, stats = _run_query(tbl, **_daemon_cfg(sock))
+            assert stats.get("daemon_mode") == "attached", stats.get(
+                "daemon_mode_reason")
+            assert out.equals(base)
+        assert proc.poll() is None
+        assert client.ping()["pid"] == pid
+        assert client.status()["execute_count"] >= 2
+    finally:
+        client.shutdown()
+        try:
+            proc.wait(timeout=10)
+        except Exception:  # noqa: BLE001
+            proc.kill()
+
+
+def test_attached_client_never_initialises_jax(daemon):
+    """One process per chip: with ballista.tpu.daemon.enabled the daemon owns
+    the device, so the ATTACHING process must finish a daemon-routed query
+    without initialising a jax backend of its own (on a TPU host that init
+    would fight the daemon for the chip). Checked on the CPU: the client
+    process's jax stays uninitialised."""
+    import subprocess
+    import sys
+
+    sock, _ = daemon
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys\n"
+        "from tests.test_device_daemon import _daemon_cfg, _run_query, _table\n"
+        f"out, stats = _run_query(_table(), **_daemon_cfg({sock!r}))\n"
+        "assert stats.get('daemon_mode') == 'attached', stats\n"
+        "assert out.num_rows == 7\n"
+        "if 'jax' in sys.modules:\n"
+        "    from jax._src import xla_bridge\n"
+        "    assert not xla_bridge.backends_are_initialized()\n"
+        "print('client-jax-uninitialised')\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=180)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "client-jax-uninitialised" in r.stdout
+
+
+def test_arrow_loaded_on_main_thread():
+    """The cause of that crash, pinned: importing the daemon's server module
+    loads Arrow on the importing (main) thread. Loaded first on a thread
+    that exits — which every lazy import on a connection thread was —
+    libarrow's allocator keeps its process heap bound to the dead thread
+    and later threads crash inside Arrow allocations. Stand-alone
+    reproduction: a thread that does `import pyarrow` and exits, then a few
+    ThreadPoolExecutor rounds of `pa.array([...])` from fresh threads."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys, threading\n"
+            "import ballista_tpu.device_daemon.server\n"
+            "assert 'pyarrow' in sys.modules\n"
+            "assert threading.current_thread() is threading.main_thread()\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, timeout=120)
+    assert r.returncode == 0, r.stderr.decode()[-2000:]
+
+
 def test_executor_heartbeat_exports_daemon_gauges(daemon):
     sock, _ = daemon
     _run_query(_table(), **_daemon_cfg(sock))

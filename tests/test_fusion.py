@@ -63,14 +63,50 @@ def test_small_but_staged_ineligible_fuses():
 
 
 def test_tpu_platform_picks_pallas_when_eligible():
+    # the group-reduce kernel compiles for the chip (test_tpu_compile.py), so
+    # a TPU backend still auto-picks it — but only for stages whose value
+    # lanes it really takes: the choice must be the mode that runs
     cm = CostModel(platform="tpu")
     dec = cm.choose(_est(group_domain=256))
     assert dec.mode == "fused_pallas"
+    dec = cm.choose(_est(group_domain=256, f32_value_lanes=False))
+    assert dec.mode == "fused_xla"
+    assert "exact int64 or nullable value lanes" in dec.reason
 
 
 def test_cpu_platform_never_auto_picks_pallas():
     cm = CostModel(platform="cpu")
     assert cm.choose(_est(group_domain=256)).mode == "fused_xla"
+
+
+def test_tpu_platform_never_picks_kernels_its_compiler_refuses():
+    """hash_probe and the int64 sort/top-k/scan family run only in the CPU
+    backend's interpreter: on a TPU the sort family stays on lax.sort even
+    when fused_pallas is forced or the legacy knob is on."""
+    from ballista_tpu.ops.tpu.fusion import (
+        TPU_KERNELS,
+        estimate_sort_stage,
+        kernel_runs_on,
+    )
+
+    assert TPU_KERNELS == {"masked_group_reduce", "dict_filter"}
+    for k in ("hash_probe", "segmented_sort", "topk_select", "segmented_scan"):
+        assert kernel_runs_on(k, "cpu") and not kernel_runs_on(k, "tpu")
+    for k in TPU_KERNELS:
+        assert kernel_runs_on(k, "tpu")
+    shapes = (estimate_sort_stage(50_000, [("i64", False)]),
+              estimate_sort_stage(50_000, [("i64", False)], fetch=10),
+              estimate_sort_stage(50_000, [("i64", False)], window_funcs=1))
+    for est in shapes:
+        for cm in (CostModel(platform="tpu"),
+                   CostModel(platform="tpu", mode="fused_pallas"),
+                   CostModel(platform="tpu", force_pallas=True)):
+            dec = cm.choose_sort(est)
+            assert dec.mode == "fused_xla"
+            assert "do not lower for platform=tpu" in dec.reason
+        # the CPU backend's interpreter still takes a forced request
+        assert CostModel(platform="cpu", mode="fused_pallas").choose_sort(
+            est).mode == "fused_pallas"
 
 
 def test_pallas_ineligibility_boundaries():
@@ -207,3 +243,56 @@ def test_hash_probe_matches_numpy():
     exp_matched = mask & (table[keys] >= 0)
     np.testing.assert_array_equal(matched, exp_matched)
     np.testing.assert_array_equal(rows, np.where(exp_matched, table[keys], 0))
+
+
+# ------------------------------------------------ ordering + prefix sum
+
+
+def test_lex_order_is_lax_sorts_stable_order():
+    """kernels.lex_order (LSD radix passes over 32-bit lanes) returns the
+    permutation one wide stable `lax.sort` over the same keys returns — for
+    every key dtype the stages pass, 64-bit keys split into two lanes, and
+    float keys (ranked) with NaN, ±0.0 and ±inf."""
+    from ballista_tpu.ops.tpu.runtime import ensure_jax
+
+    jax = ensure_jax()
+    jnp = jax.numpy
+    from ballista_tpu.ops.tpu.kernels import lex_order
+
+    rng = np.random.default_rng(0)
+    M = 5000
+    f = rng.normal(size=M)
+    f[::17], f[::13], f[::11], f[::7], f[::5] = np.nan, -0.0, 0.0, np.inf, -np.inf
+    pool = {
+        "i64": jnp.asarray(rng.integers(-2**62, 2**62, M)),
+        "i64_ties": jnp.asarray(rng.integers(-5, 5, M)),
+        "i32": jnp.asarray(rng.integers(-2**31, 2**31 - 1, M).astype(np.int32)),
+        "i16": jnp.asarray(rng.integers(-3, 3, M).astype(np.int16)),
+        "bool": jnp.asarray(rng.random(M) < 0.5),
+        "f64": jnp.asarray(f),
+        "f64_ties": jnp.asarray(np.round(rng.normal(size=M))),
+    }
+    order = jax.jit(lambda *k: lex_order(list(k)))
+    for names in (["i64"], ["i64_ties", "i32"], ["bool", "i16", "i64_ties"],
+                  ["f64"], ["i16", "f64"], ["f64_ties", "i64_ties"],
+                  ["bool", "i16", "i64_ties", "i32", "f64_ties"]):
+        keys = [pool[n] for n in names]
+        want = jax.lax.sort(tuple(keys) + (jnp.arange(M, dtype=jnp.int32),),
+                            num_keys=len(keys) + 1)[-1]
+        assert np.array_equal(np.asarray(order(*keys)), np.asarray(want)), names
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("M", [7, 2048, 4096, 10_240, 10_241])
+def test_int_cumsum_is_exact(dtype, M):
+    """The blocked prefix sum equals the flat one in the array's own dtype,
+    whether or not the block divides the length."""
+    from ballista_tpu.ops.tpu.runtime import ensure_jax
+
+    jnp = ensure_jax().numpy
+    from ballista_tpu.ops.tpu.kernels import int_cumsum
+
+    x = np.random.default_rng(M).integers(-1000, 1000, M).astype(dtype)
+    got = int_cumsum(jnp.asarray(x))
+    assert got.dtype == dtype
+    assert np.array_equal(np.asarray(got), np.cumsum(x, dtype=dtype))

@@ -17,3 +17,36 @@ def test_tpch_local_cpu(q, tpch_ctx, tpch_ref_tables):
     ref = run_reference(q, tpch_ref_tables)
     problems = compare_results(eng, ref, q)
     assert not problems, "\n".join(problems)
+
+
+@pytest.mark.parametrize("breakage", ["none", "mismatch", "raises"])
+def test_benchmark_runner_exit_code_tells_failure(breakage, tpch_dir, monkeypatch):
+    """`benchmarks/tpch.py run --verify` (the README's quick start) must not
+    exit 0 over a failed query or an oracle mismatch."""
+    import importlib.util
+    import os
+
+    import ballista_tpu.testing.reference as reference
+    from ballista_tpu.client.context import DataFrame
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_tpch", os.path.join(root, "benchmarks", "tpch.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    if breakage == "mismatch":
+        monkeypatch.setattr(reference, "compare_results",
+                            lambda *a, **k: ["q6: injected mismatch"])
+    elif breakage == "raises":
+        def boom(self):
+            raise RuntimeError("injected query failure")
+
+        monkeypatch.setattr(DataFrame, "collect", boom)
+    argv = ["run", "--data", tpch_dir, "--query", "6", "--iterations", "1",
+            "--verify"]
+    if breakage == "none":
+        bench.main(argv)  # returns: exit code 0
+    else:
+        with pytest.raises(SystemExit) as e:
+            bench.main(argv)
+        assert e.value.code == 1

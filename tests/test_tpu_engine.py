@@ -904,3 +904,37 @@ def test_expression_group_key_hoisted_to_device():
     # the 11-char prefix folds 30 warehouses into 3 groups — the hoist must
     # actually merge finer device groups downstream
     assert len(tp) == 3
+
+
+def test_stage_ledger_tells_row_floor_from_declines_and_errors(tpch_dir):
+    """STAGE_OUTCOMES is what chip_smoke.py and the heartbeat read instead of
+    per-task operator counters: a stage under ballista.tpu.min.rows is a
+    `below_row_floor` (policy), any other Unsupported a `declined`, and a
+    non-Unsupported exception an `error` — the demotion that would hide a
+    broken device path behind right answers."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.ops.tpu.kernels import BelowRowFloor, Unsupported
+    from ballista_tpu.testing.tpchgen import register_tpch
+
+    led = sc.StageOutcomes()
+    led.note("partial", "device")
+    led.note_fallback("final", BelowRowFloor(32))
+    led.note_fallback("sort", Unsupported("unencodable column x"))
+    led.note_fallback("window", ValueError("lowering refused"))
+    snap = led.snapshot()
+    assert {k: snap[k] for k in led.KINDS} == {
+        "device": 1, "below_row_floor": 1, "declined": 1, "error": 1}
+    assert snap["recent"][-1] == ("window", "error", "ValueError: lowering refused")
+    assert snap["recent"][1][2] == "BelowRowFloor: only 32 rows (< tpu min)"
+
+    # end to end at the default row floor: q1's partial stage runs on the
+    # device, its 4-group final merge stays under the floor, nothing errs
+    ctx = SessionContext(BallistaConfig({EXECUTOR_ENGINE: "tpu"}))
+    register_tpch(ctx, tpch_dir)
+    before = sc.STAGE_OUTCOMES.snapshot()
+    ctx.sql(tpch_query(1)).collect()
+    after = sc.STAGE_OUTCOMES.snapshot()
+    delta = {k: after[k] - before[k] for k in led.KINDS}
+    assert delta["device"] >= 1 and delta["below_row_floor"] >= 1
+    assert delta["declined"] == 0 and delta["error"] == 0

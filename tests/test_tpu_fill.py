@@ -20,7 +20,6 @@ import pytest
 from ballista_tpu.config import (
     BallistaConfig,
     EXECUTOR_ENGINE,
-    TPU_COMPILE_CACHE_DIR,
     TPU_COMPILE_OVERLAP,
     TPU_FILL_CHUNK_ROWS,
     TPU_FILL_THREADS,
@@ -196,19 +195,19 @@ def test_overlap_off_is_serial_and_correct(tpch_dir, tpch_ref_tables):
     assert not problems, "\n".join(problems)
 
 
-def test_persistent_cache_roundtrip(tpch_dir, tmp_path):
+def test_persistent_cache_roundtrip(tpch_dir):
     """Simulated restart: clear every in-process cache, rerun the same
-    stage — the XLA recompile must be served from the on-disk cache."""
+    stage — the XLA recompile must be served from the on-disk cache, which
+    lives where JAX_COMPILATION_CACHE_DIR (set by conftest) says."""
     from ballista_tpu.client.context import SessionContext
     from ballista_tpu.ops.tpu import runtime
     from ballista_tpu.testing.tpchgen import register_tpch
     import ballista_tpu.ops.tpu.stage_compiler as sc
 
-    cache_dir = str(tmp_path / "xla-cache")
-    cfg = BallistaConfig({
-        EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0,
-        TPU_COMPILE_CACHE_DIR: cache_dir,
-    })
+    import os
+
+    cache_dir = os.environ[runtime.CACHE_DIR_ENV]
+    cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0})
     ctx = SessionContext(cfg)
     register_tpch(ctx, tpch_dir)
 
@@ -217,8 +216,6 @@ def test_persistent_cache_roundtrip(tpch_dir, tmp_path):
     cold = runtime.compile_cache_stats()
     assert cold["dir"] == cache_dir
     assert cold["requests"] > 0
-    import os
-
     assert os.listdir(cache_dir), "persistent cache wrote nothing"
 
     # "restart": drop the in-process compile/LUT/build/device caches so the
@@ -285,14 +282,21 @@ def test_run_stats_isolation_across_concurrent_stages():
     t1.start(); t2.start(); t1.join(); t2.join()
 
     stages = rs.stages()
-    assert stages["stage_a"] == {"fill_s": 1.0, "fill_s_tls": 2.0}
-    assert stages["stage_b"] == {"exec_s": 2.0, "exec_s_tls": 3.0}
+    assert stages["stage_a"] == {"fill_s": 1.0, "fill_s_tls": 2.0,
+                                 "dispatches": 1}
+    assert stages["stage_b"] == {"exec_s": 2.0, "exec_s_tls": 3.0,
+                                 "dispatches": 1}
     merged = rs.snapshot()
     assert merged["fill_s"] == 1.0 and merged["exec_s"] == 2.0
     # legacy surfaces: Mapping view and item assignment outside a run scope
     assert dict(rs)["fill_s"] == 1.0
     rs["device_bytes"] = 7
     assert rs["device_bytes"] == 7
+    # a stage's later dispatch merges over its record, keeping the cold keys
+    with rs.run("stage_a") as rec:
+        rec["exec_s"] = 0.5
+    assert rs.stages()["stage_a"] == {"fill_s": 1.0, "fill_s_tls": 2.0,
+                                      "exec_s": 0.5, "dispatches": 2}
     rs.clear()
     assert not rs.snapshot() and not rs.stages()
 
@@ -302,7 +306,42 @@ def test_fill_and_cache_knobs_registered():
     assert int(cfg.get(TPU_FILL_THREADS)) == 0
     assert int(cfg.get(TPU_FILL_CHUNK_ROWS)) == 0
     assert bool(cfg.get(TPU_COMPILE_OVERLAP)) is True
-    assert str(cfg.get(TPU_COMPILE_CACHE_DIR) or "") == ""
+    # the compile cache has no key: it is placed from outside the program
+    from ballista_tpu.config import _ENTRIES
+
+    assert not [e.name for e in _ENTRIES if "compile.cache" in e.name]
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the cache lives there and the
+    program names no directory of its own; unset, it is one fixed path
+    inside the checkout — never a temp name, pid or time."""
+    import os
+    import subprocess
+    import sys
+
+    from ballista_tpu.ops.tpu import runtime
+
+    code = ("import jax\n"
+            "from ballista_tpu.ops.tpu import runtime\n"
+            "runtime.ensure_jax()\n"
+            "print(runtime.compile_cache_dir())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def run(env):
+        r = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return r.stdout.split()
+
+    placed = str(tmp_path / "placed")
+    env = dict(os.environ, **{runtime.CACHE_DIR_ENV: placed})
+    assert run(env) == [placed, placed]
+    env.pop(runtime.CACHE_DIR_ENV)
+    fixed = os.path.join(root, ".jax_compile_cache")
+    assert run(env) == [fixed, fixed]
+    assert run(env) == [fixed, fixed]  # same path on every start
 
 
 def test_estimate_stage_matches_actual_device_bytes():

@@ -217,6 +217,33 @@ def test_auto_small_input_staged():
     assert stats2.get("fusion_mode") == "fused_xla"
 
 
+def test_cost_model_choice_is_the_mode_that_runs():
+    """Auto selection (driven here by the legacy pallas knob, the CPU
+    backend's stand-in for platform=tpu) knows from the encode metadata
+    which value lanes the f32 kernel takes: an f64 sum is chosen AND runs
+    fused_pallas, an exact-money sum is chosen AND runs fused_xla — the
+    request is never silently clamped to another mode — and both land in
+    the process-wide stage ledger as device runs over the [P, N] stack the
+    stats name."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.config import TPU_PALLAS
+
+    tbl = _synth(n=20_000, seed=4)
+    sc.STAGE_OUTCOMES.clear()
+    for col, want in (("w", "fused_pallas"), ("price", "fused_xla")):
+        sql = (f"select cat, sum({col}) s, count(*) c from t group by cat "
+               "order by cat")
+        _, stats = _run_mode(sql, "auto", {"t": (tbl, 2)}, **{TPU_PALLAS: True})
+        assert stats.get("fusion_choice") == want, stats.get("fusion_reason")
+        assert stats.get("fusion_mode") == want
+    assert "exact int64 or nullable value lanes" in stats["fusion_reason"]
+    led = sc.STAGE_OUTCOMES.snapshot()
+    assert led["device"] >= 2 and led["error"] == 0 and led["declined"] == 0
+    P, N = stats["table_shape"]
+    assert P == 2 and N >= 10_000 and N & (N - 1) == 0  # a [P, bucket] stack
+    assert {f for f, kind, _ in led["recent"] if kind == "device"} >= {"partial"}
+
+
 def test_fusion_disabled_lands_staged():
     sql = "select cat, sum(w) s from t group by cat order by cat"
     tbl = _synth(n=20_000, seed=2)

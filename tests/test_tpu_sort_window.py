@@ -407,3 +407,23 @@ def test_topk_knob_disables_fused_cut():
     _assert_parity(cpu, devp, cfg)
     after = counters_snapshot()["sort_full_materializations"]
     assert after == before + 1
+
+
+def test_float_extremes_cross_the_device_as_ordered_int64():
+    """Window min/max scans never carry floats (a TPU does not return f64
+    values bit-identical): the order-preserving int64 image is exactly
+    invertible — ±0.0, subnormals, infinities, NaN payloads — and orders
+    like the floats."""
+    from ballista_tpu.ops.tpu.sort_window import _f64_to_ordered, _ordered_to_f64
+
+    rng = np.random.default_rng(3)
+    v = np.concatenate([rng.normal(size=2000) * 1e5,
+                        [0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324,
+                         1e-310, -1e-310, np.nan, -np.nan]])
+    enc = _f64_to_ordered(v)
+    assert enc.dtype == np.int64
+    assert np.array_equal(_ordered_to_f64(enc).view(np.int64), v.view(np.int64))
+    real = ~np.isnan(v)
+    assert np.array_equal(v[real][np.argsort(enc[real], kind="stable")],
+                          np.sort(v[real], kind="stable"))
+    assert _f64_to_ordered(np.array([-0.0]))[0] < _f64_to_ordered(np.array([0.0]))[0]
