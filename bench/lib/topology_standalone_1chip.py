@@ -1,0 +1,61 @@
+"""Topology `standalone_1chip`: scheduler, one executor and the Flight server
+in this process (`SessionContext.standalone`), the TPU engine in-process, so
+the process that runs the queries is the one that holds the chip and can
+trace it. Everything the benchmark takes from the program is in this file:
+the system under test and its counters.
+"""
+
+from __future__ import annotations
+
+import os
+
+
+def open_session(config: dict, data_dir: str):
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import BallistaConfig
+    from ballista_tpu.ops import native
+    from ballista_tpu.plan.provider import ParquetTable
+
+    # the shuffle's row router builds itself from native/ on first use (~7 s
+    # of g++ in a new checkout): here, in set-up, not inside the first query
+    native.get_lib()
+    session = SessionContext.standalone(BallistaConfig(dict(config["session"])),
+                                        num_executors=config["num_executors"])
+    for table in config["tables"]:
+        session.register_table(table, ParquetTable(os.path.join(data_dir, table)))
+    return session
+
+
+def close_session(session) -> None:
+    session.shutdown()
+
+
+class Probes:
+    """The program's own counters, read between queries."""
+
+    def __init__(self):
+        import ballista_tpu.ops.tpu.stage_compiler as sc
+        from ballista_tpu.ops.tpu import runtime
+
+        runtime.ensure_jax()
+        self._sc, self._runtime = sc, runtime
+
+    def clear_run_stats(self) -> None:
+        self._sc.RUN_STATS.clear()
+
+    def run_stats_stages(self) -> dict:
+        """RunStats per stage since the last clear: numbers and short strings."""
+        return {tag: {k: v for k, v in rec.items()
+                      if isinstance(v, (int, float, str, list, tuple))}
+                for tag, rec in self._sc.RUN_STATS.stages().items()}
+
+    def outcomes(self) -> dict:
+        snap = self._sc.STAGE_OUTCOMES.snapshot()
+        return {k: snap[k] for k in self._sc.StageOutcomes.KINDS}
+
+    def outcomes_recent(self) -> list:
+        return [list(r) for r in self._sc.STAGE_OUTCOMES.snapshot()["recent"]
+                if r[1] != "device"]
+
+    def compile_cache(self) -> dict:
+        return self._runtime.compile_cache_stats()
