@@ -7,11 +7,12 @@ keys as `tpu_*` gauges. Two drift modes have bitten:
   `exchange_bytes_on_device` emission was nearly lost to a refactor and
   is invisible to grep because the `.set(` call spans lines), and
 - an emitted key that is neither exported as a gauge nor documented in
-  the RunStats docstring (diagnostics nobody can discover).
+  the one table of keys, docs/tpu_engine.md#observability (diagnostics
+  nobody can discover).
 
 So: every key `_tpu_metrics` consumes must be emitted somewhere under
 `ops/tpu/`, and every emitted key must be consumed by `_tpu_metrics` OR
-named in the RunStats class docstring. Emission sites are found by AST —
+named (in backticks) in that section. Emission sites are found by AST —
 `<anything>.set("key", ...)`-style calls where the receiver smells like a
 stats sink (RUN_STATS / rec / stats / run-scope handles) and string
 subscript stores on the same receivers.
@@ -20,11 +21,14 @@ subscript stores on the same receivers.
 from __future__ import annotations
 
 import ast
+import os
+import re
 
 from ballista_tpu.analysis.core import AnalysisPass, Analyzer, Finding
 
 EXEC_REL = "ballista_tpu/executor/executor_process.py"
-STATS_REL = "ballista_tpu/ops/tpu/stage_compiler.py"
+DOCS_REL = "docs/tpu_engine.md"
+DOCS_SECTION = "## Observability"
 
 _SINK_NAMES = {"RUN_STATS", "rec", "stats", "run_stats", "_rec", "srec"}
 
@@ -93,14 +97,19 @@ def consumed_keys(analyzer: Analyzer) -> dict[str, int]:
     return out
 
 
-def _runstats_docstring(analyzer: Analyzer) -> str:
-    src = analyzer.file(STATS_REL)
-    if src is None or src.tree is None:
-        return ""
-    for node in src.tree.body:
-        if isinstance(node, ast.ClassDef) and node.name == "RunStats":
-            return ast.get_docstring(node) or ""
-    return ""
+def documented_keys(analyzer: Analyzer) -> set[str]:
+    """The backticked names in docs/tpu_engine.md's Observability section
+    (its table of RunStats keys), up to the next section."""
+    try:
+        with open(os.path.join(analyzer.root, DOCS_REL)) as f:
+            text = f.read()
+    except OSError:
+        return set()
+    _, found, section = text.partition(DOCS_SECTION)
+    if not found:
+        return set()
+    section = section.split("\n## ", 1)[0]
+    return set(re.findall(r"`([A-Za-z_][A-Za-z_0-9]*)`", section))
 
 
 class StatsRegistrySyncPass(AnalysisPass):
@@ -111,7 +120,7 @@ class StatsRegistrySyncPass(AnalysisPass):
         findings: list[Finding] = []
         emitted = emitted_keys(analyzer)
         consumed = consumed_keys(analyzer)
-        doc = _runstats_docstring(analyzer)
+        doc = documented_keys(analyzer)
 
         for key, lineno in sorted(consumed.items()):
             if key not in emitted:
@@ -127,7 +136,7 @@ class StatsRegistrySyncPass(AnalysisPass):
             findings.append(Finding(
                 self.pass_id, rel, lineno,
                 f"RunStats key '{key}' is emitted but neither exported by the "
-                f"heartbeat nor documented in the RunStats docstring",
+                f"heartbeat nor documented in {DOCS_REL}#observability",
                 symbol=f"emitted:{key}",
             ))
         return findings

@@ -475,31 +475,39 @@ class DataFrame:
         """Submit through the in-process scheduler: real stages, real
         shuffle files, results fetched from the final stage's partitions
         (the DistributedQueryExec flow, distributed_query.rs:211)."""
+        from ballista_tpu.config import CLIENT_JOB_TIMEOUT_S
         from ballista_tpu.errors import ExecutionError
+        from ballista_tpu.tracing import RUN_STATS
 
         cluster = self.ctx._ensure_cluster()
         scheduler = cluster.scheduler
-        session_id = scheduler.sessions.create_or_update(
-            self.ctx.config.to_key_value_pairs(), str(self.ctx.session_id)
-        )
-        if self.sql_text is not None and not self.ctx._has_memory_tables():
-            # inline_results: this process can accept a result table right
-            # in the status dict (serving-tier result-cache hits)
-            job_id = scheduler.submit_sql(self.sql_text, session_id, inline_results=True)
-        else:
-            # in-memory tables can't be re-resolved from SQL on the
-            # scheduler: plan CLIENT-side and submit the physical plan
-            # (MemoryScanNode ships the batches as IPC bytes) — the
-            # reference's BallistaQueryPlanner flow
-            physical = self.ctx.create_physical_plan(self.plan)
-            job_id = scheduler.submit_physical_plan(physical, session_id)
-        from ballista_tpu.config import CLIENT_JOB_TIMEOUT_S
-
-        status = scheduler.wait_for_job(
-            job_id, timeout=float(self.ctx.config.get(CLIENT_JOB_TIMEOUT_S)))
-        if status["state"] != "successful":
-            raise ExecutionError(f"job {job_id} {status['state']}: {status.get('error', '')}")
-        return fetch_job_results(status, self.ctx.config)
+        # the root span of the query: opened before the job has an id, given
+        # it when the submit returns; its close publishes the job's spans
+        with RUN_STATS.span("bt.client.collect", root=True) as root:
+            with RUN_STATS.span("bt.client.submit"):
+                session_id = scheduler.sessions.create_or_update(
+                    self.ctx.config.to_key_value_pairs(), str(self.ctx.session_id)
+                )
+                if self.sql_text is not None and not self.ctx._has_memory_tables():
+                    # inline_results: this process can accept a result table right
+                    # in the status dict (serving-tier result-cache hits)
+                    job_id = scheduler.submit_sql(self.sql_text, session_id,
+                                                  inline_results=True)
+                else:
+                    # in-memory tables can't be re-resolved from SQL on the
+                    # scheduler: plan CLIENT-side and submit the physical plan
+                    # (MemoryScanNode ships the batches as IPC bytes) — the
+                    # reference's BallistaQueryPlanner flow
+                    physical = self.ctx.create_physical_plan(self.plan)
+                    job_id = scheduler.submit_physical_plan(physical, session_id)
+                root.set(job=job_id)
+            with RUN_STATS.span("bt.client.wait"):
+                status = scheduler.wait_for_job(
+                    job_id, timeout=float(self.ctx.config.get(CLIENT_JOB_TIMEOUT_S)))
+            if status["state"] != "successful":
+                raise ExecutionError(
+                    f"job {job_id} {status['state']}: {status.get('error', '')}")
+            return fetch_job_results(status, self.ctx.config)
 
     def _collect_explain(self) -> pa.Table:
         assert isinstance(self.plan, Explain)
@@ -614,6 +622,8 @@ def fetch_job_results(status: dict, config: BallistaConfig) -> pa.Table:
 
     from ballista_tpu.config import FLIGHT_PROXY, SHUFFLE_READER_FORCE_REMOTE
 
+    from ballista_tpu.tracing import RUN_STATS
+
     # serving-tier result-cache hit: the table rode back in the status
     # dict; nothing to fetch
     inline = status.get("inline_result")
@@ -626,13 +636,16 @@ def fetch_job_results(status: dict, config: BallistaConfig) -> pa.Table:
     # never take the same-path local shortcut (it only holds when the client
     # shares the executor's filesystem)
     force = bool(config.get(SHUFFLE_READER_FORCE_REMOTE)) or bool(config.get(FLIGHT_PROXY))
-    batches = []
-    for loc in locs:
-        for b in fetch_partition(loc, ctx, force_remote=force):
-            if b.num_rows:
-                batches.append(b)
-    if not batches:
-        if schema is None:
-            return pa.table({})
-        return pa.table({f.name: pa.array([], f.type) for f in schema}, schema=schema)
-    return pa.Table.from_batches(batches, schema=batches[0].schema)
+    with RUN_STATS.span("bt.client.fetch_results", partitions=len(locs)) as span:
+        batches = []
+        for loc in locs:
+            for b in fetch_partition(loc, ctx, force_remote=force):
+                if b.num_rows:
+                    batches.append(b)
+        if not batches:
+            if schema is None:
+                return pa.table({})
+            return pa.table({f.name: pa.array([], f.type) for f in schema}, schema=schema)
+        table = pa.Table.from_batches(batches, schema=batches[0].schema)
+        span.set(rows=table.num_rows, bytes=table.nbytes)
+        return table

@@ -308,26 +308,36 @@ class RemoteSchedulerClient:
     def collect(self, df) -> pa.Table:
         from ballista_tpu.client.context import fetch_job_results
         from ballista_tpu.config import PUSH_STATUS
+        from ballista_tpu.tracing import RUN_STATS
 
         timeout = float(self.config.get(CLIENT_JOB_TIMEOUT_S))
         sql_ok = df.sql_text is not None and not df.ctx._has_memory_tables()
-        if sql_ok and bool(self.config.get(PUSH_STATUS)):
-            status = self.execute_sql_push(df.sql_text, timeout=timeout)
-        elif sql_ok:
-            job_id = self.execute_sql(df.sql_text)
-            status = self.wait_for_job(job_id, timeout=timeout)
-        else:
-            # memory tables can't be re-resolved from SQL on the scheduler:
-            # plan client-side, ship the physical plan (MemoryScanNode
-            # carries the batches as IPC bytes)
-            physical = df.ctx.create_physical_plan(df.plan)
-            job_id = self.execute_physical(physical)
-            status = self.wait_for_job(job_id, timeout=timeout)
-        if status["state"] != "successful":
-            raise ExecutionError(
-                f"job {status.get('job_id', '?')} {status['state']}: {status.get('error', '')}"
-            )
-        return fetch_job_results(status, self.config)
+        # this process's part of the query: the scheduler's and the executors'
+        # spans stay in their processes, under the same job id
+        with RUN_STATS.span("bt.client.collect", root=True) as root:
+            if sql_ok and bool(self.config.get(PUSH_STATUS)):
+                # submit and wait are one streaming rpc
+                with RUN_STATS.span("bt.client.wait"):
+                    status = self.execute_sql_push(df.sql_text, timeout=timeout)
+                root.set(job=status.get("job_id"))
+            else:
+                with RUN_STATS.span("bt.client.submit"):
+                    if sql_ok:
+                        job_id = self.execute_sql(df.sql_text)
+                    else:
+                        # memory tables can't be re-resolved from SQL on the
+                        # scheduler: plan client-side, ship the physical plan
+                        # (MemoryScanNode carries the batches as IPC bytes)
+                        physical = df.ctx.create_physical_plan(df.plan)
+                        job_id = self.execute_physical(physical)
+                    root.set(job=job_id)
+                with RUN_STATS.span("bt.client.wait"):
+                    status = self.wait_for_job(job_id, timeout=timeout)
+            if status["state"] != "successful":
+                raise ExecutionError(
+                    f"job {status.get('job_id', '?')} {status['state']}: {status.get('error', '')}"
+                )
+            return fetch_job_results(status, self.config)
 
 
 class SubscriptionStream:

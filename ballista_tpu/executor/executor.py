@@ -30,6 +30,7 @@ from ballista_tpu.plan.physical import ExecutionPlan, TaskContext, collect_metri
 from ballista_tpu.scheduler.state.execution_graph import TaskDescription
 from ballista_tpu.shuffle.types import PartitionLocation
 from ballista_tpu.shuffle.writer import ShuffleWriterExec, metadata_to_locations
+from ballista_tpu.tracing import RUN_STATS
 from ballista_tpu.version import WIRE_PROTOCOL_VERSION
 
 log = logging.getLogger(__name__)
@@ -260,6 +261,13 @@ class Executor:
         )
 
     def execute_task(self, task: TaskDescription, config: BallistaConfig | None = None) -> TaskResult:
+        ids = {"job": task.job_id, "stage": task.stage_id, "task": task.task_id}
+        # the time the work waited for a slot and a thread
+        RUN_STATS.add_span("bt.task.queued", task.created_ns, **ids)
+        with RUN_STATS.span("bt.task.run", partitions=len(task.partitions), **ids):
+            return self._execute_task(task, config)
+
+    def _execute_task(self, task: TaskDescription, config: BallistaConfig | None) -> TaskResult:
         cfg = config or self.default_config
         from ballista_tpu import udf
 
@@ -276,13 +284,15 @@ class Executor:
             task_id=task.task_id, job_id=task.job_id, stage_id=task.stage_id,
             stage_attempt=task.stage_attempt, partitions=list(task.partitions), state="failed",
         )
-        start = time.time()
+        started = time.monotonic()
         deadline = float(getattr(task, "deadline_seconds", 0.0) or 0.0)
-        deadline_at = start + deadline if deadline > 0 else 0.0
+        # an absolute wall-clock instant: operators compare it with time.time()
+        deadline_at = time.time() + deadline if deadline > 0 else 0.0
         try:
             plan = task.plan
             assert isinstance(plan, ShuffleWriterExec), f"stage root must be a shuffle writer: {plan}"
-            prepared = self.engine.create_query_stage_exec(plan, cfg, task.stage_attempt)
+            with RUN_STATS.span("bt.task.prepare"):
+                prepared = self.engine.create_query_stage_exec(plan, cfg, task.stage_attempt)
             locations: list[PartitionLocation] = []
             for p in task.partitions:
                 if self._is_cancelled(task.job_id, task.stage_id, task.task_id):
@@ -290,7 +300,7 @@ class Executor:
                 if deadline_at and time.time() > deadline_at:
                     self.tasks_failed += 1
                     base.error = (f"task {task.task_id} exceeded its {deadline:.1f}s deadline "
-                                  f"after {time.time() - start:.1f}s")
+                                  f"after {time.monotonic() - started:.1f}s")
                     base.error_kind = "ExecutionError"
                     base.retryable = True
                     base.timed_out = True

@@ -40,7 +40,7 @@ import pyarrow as pa
 
 from ballista_tpu.config import TPU_MAX_DEVICE_BYTES, TPU_MIN_ROWS, BallistaConfig, _env_int
 from ballista_tpu.ops.tpu.columnar import encode_column, next_bucket
-from ballista_tpu.ops.tpu.stage_compiler import STAGE_OUTCOMES, LruDict
+from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS, STAGE_OUTCOMES, LruDict
 from ballista_tpu.ops.tpu.kernels import (
     BelowRowFloor,
     DevVal,
@@ -365,7 +365,8 @@ class TpuFinalStageExec(ExecutionPlan):
         device, counted (call under _results_lock)."""
         from ballista_tpu.ops.tpu.runtime import device_scope
 
-        with device_scope(ctx.device_ordinal):
+        with device_scope(ctx.device_ordinal), \
+                RUN_STATS.span("bt.stage.dispatch", family="final"):
             out = self._tpu_run_all(ctx)
         STAGE_OUTCOMES.note("final", "device")
         self.tpu_count += 1
@@ -462,7 +463,8 @@ class TpuFinalStageExec(ExecutionPlan):
             node = op.with_children([node])
         if self.sort is not None:
             node = self.sort.with_children([node])
-        out = [b for b in node.execute(partition, ctx)]
+        with RUN_STATS.span("bt.stage.fallback", family="final"):
+            out = [b for b in node.execute(partition, ctx)]
         if mat is not None:
             self._note_mat_served(partition, merged_mat)
         return out
@@ -505,9 +507,10 @@ class TpuFinalStageExec(ExecutionPlan):
         # final stage would run its inner partials with no ceiling
         from ballista_tpu.ops.tpu import hbm
         quota = hbm.active_session_quota()
+        dispatch = RUN_STATS.current_span()
 
         def read(p):
-            with hbm.session_quota(quota):
+            with hbm.session_quota(quota), RUN_STATS.attach(dispatch):
                 return _concat([b for b in child.execute(p, ctx) if b.num_rows],
                                child.schema())
 
@@ -554,9 +557,6 @@ class TpuFinalStageExec(ExecutionPlan):
         # stage has no build side to grace-split, so the ladder here is just
         # run-whole vs CPU demotion — but the decision still lands in
         # RunStats so /api/executors sees WHY a final stage left the device
-        from ballista_tpu.ops.tpu import hbm
-        from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
         budget = hbm.resolve_hbm_budget(self.config)
         if budget > 0:
             max_bytes = min(max_bytes, budget)
@@ -606,9 +606,10 @@ class TpuFinalStageExec(ExecutionPlan):
         with _FINAL_COMPILE_LOCK:
             cached = _FINAL_COMPILE_CACHE.get(key)
             if cached is None:
-                fn, lowering, meta = self._compile(
-                    kinds, scales, dicts, valids_np, cols_np, P, N,
-                    merge_all=bypass)
+                with RUN_STATS.span("bt.compile.trace"):
+                    fn, lowering, meta = self._compile(
+                        kinds, scales, dicts, valids_np, cols_np, P, N,
+                        merge_all=bypass)
                 # per-entry run lock: the jitted closure mutates its shared
                 # trace-time `cell` dict if jax ever retraces it (e.g. jit
                 # cache eviction); serializing execution of THIS entry keeps
@@ -622,9 +623,13 @@ class TpuFinalStageExec(ExecutionPlan):
             _put(None, v) for v in valids_np if v is not None
         ]
         mask = _put(None, mask_np)
-        with run_lock:
+        # a fresh entry's first call compiles inside it; `_decode` fetches a
+        # count first and then a sliced fetch, so its fetches sit inside it
+        with run_lock, RUN_STATS.span("bt.device.exec"):
             outs = fn(flat, luts, mask)
-        return self._decode(outs, meta, P_result, dicts)
+            jax.block_until_ready(list(outs))
+        with RUN_STATS.span("bt.decode", family="final"):
+            return self._decode(outs, meta, P_result, dicts)
 
     # ------------------------------------------------------------------
 
@@ -724,202 +729,208 @@ class TpuFinalStageExec(ExecutionPlan):
                 return arr.reshape(-1), (None if vplane is None else vplane.reshape(-1))
 
             # ---- phase 1 sort: (invalid, pid, group keys) --------------
-            keyops: list = []
-            key_layout: list = []  # per group key: (marker_pos|None, value_pos)
-            for i in range(n_group):
-                arr, vplane = read_col(i)
-                mpos = None
-                if vplane is not None:
-                    mpos = len(keyops)
-                    keyops.append((~vplane).astype(jnp.int32))
-                key_layout.append((mpos, len(keyops)))
-                keyops.append(arr)
-            meta_holder["key_layout"] = key_layout
-
-            pays: list = []
-            pay_plan: list = []  # per agg: (pay_idx, ncnt_idx|None)
-            for ai, d in enumerate(agg_descs):
-                arr, vplane = read_col(n_group + ai)
-                if d.func in ("count", "count_all"):
-                    # partial counts are non-null; sum them exactly
-                    a = arr.astype(jnp.int64)
+            with jax.named_scope("sorted_agg"):
+                keyops: list = []
+                key_layout: list = []  # per group key: (marker_pos|None, value_pos)
+                for i in range(n_group):
+                    arr, vplane = read_col(i)
+                    mpos = None
                     if vplane is not None:
-                        a = jnp.where(vplane, a, 0)
-                    pays.append(a)
-                    pay_plan.append((len(pays) - 1, None))
-                    continue
-                ncnt_idx = None
-                if vplane is not None:
-                    if d.func == "sum":
-                        neutral = jnp.zeros((), dtype=arr.dtype)
-                    elif d.func == "min":
-                        neutral = (jnp.iinfo(arr.dtype).max
-                                   if jnp.issubdtype(arr.dtype, jnp.integer) else jnp.inf)
-                    else:
-                        neutral = (jnp.iinfo(arr.dtype).min
-                                   if jnp.issubdtype(arr.dtype, jnp.integer) else -jnp.inf)
-                    arr = jnp.where(vplane, arr, neutral)
-                    pays.append(vplane.astype(jnp.int64))
-                    ncnt_idx = len(pays) - 1
-                pays.append(arr)
-                pay_plan.append((len(pays) - 1, ncnt_idx))
+                        mpos = len(keyops)
+                        keyops.append((~vplane).astype(jnp.int32))
+                    key_layout.append((mpos, len(keyops)))
+                    keyops.append(arr)
+                meta_holder["key_layout"] = key_layout
 
-            # ordering permutation + gathers, not one wide lax.sort: the
-            # chip compiler's time for a sort explodes with its operand
-            # count (kernels.lex_order)
-            perm1 = lex_order([~valid, pid] + keyops)
-            svalid = valid[perm1]
-            spid = pid[perm1]
-            skeys = [k[perm1] for k in keyops]
-            spays = [p[perm1] for p in pays]
+                pays: list = []
+                pay_plan: list = []  # per agg: (pay_idx, ncnt_idx|None)
+                for ai, d in enumerate(agg_descs):
+                    arr, vplane = read_col(n_group + ai)
+                    if d.func in ("count", "count_all"):
+                        # partial counts are non-null; sum them exactly
+                        a = arr.astype(jnp.int64)
+                        if vplane is not None:
+                            a = jnp.where(vplane, a, 0)
+                        pays.append(a)
+                        pay_plan.append((len(pays) - 1, None))
+                        continue
+                    ncnt_idx = None
+                    if vplane is not None:
+                        if d.func == "sum":
+                            neutral = jnp.zeros((), dtype=arr.dtype)
+                        elif d.func == "min":
+                            neutral = (jnp.iinfo(arr.dtype).max
+                                       if jnp.issubdtype(arr.dtype, jnp.integer) else jnp.inf)
+                        else:
+                            neutral = (jnp.iinfo(arr.dtype).min
+                                       if jnp.issubdtype(arr.dtype, jnp.integer) else -jnp.inf)
+                        arr = jnp.where(vplane, arr, neutral)
+                        pays.append(vplane.astype(jnp.int64))
+                        ncnt_idx = len(pays) - 1
+                    pays.append(arr)
+                    pay_plan.append((len(pays) - 1, ncnt_idx))
 
-            diff = jnp.zeros((M,), bool).at[0].set(True)
-            diff = diff | jnp.concatenate(
-                [jnp.ones((1,), bool), spid[1:] != spid[:-1]])
-            for k in skeys:
+                # ordering permutation + gathers, not one wide lax.sort: the
+                # chip compiler's time for a sort explodes with its operand
+                # count (kernels.lex_order)
+                perm1 = lex_order([~valid, pid] + keyops)
+                svalid = valid[perm1]
+                spid = pid[perm1]
+                skeys = [k[perm1] for k in keyops]
+                spays = [p[perm1] for p in pays]
+
+                diff = jnp.zeros((M,), bool).at[0].set(True)
                 diff = diff | jnp.concatenate(
-                    [jnp.ones((1,), bool), k[1:] != k[:-1]])
-            boundary = svalid & diff
-            seg = int_cumsum(boundary.astype(jnp.int32)) - 1
-            bor_inv = boundary | ~svalid
-            is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
-            n_seg = boundary.sum().astype(jnp.int32)
+                    [jnp.ones((1,), bool), spid[1:] != spid[:-1]])
+                for k in skeys:
+                    diff = diff | jnp.concatenate(
+                        [jnp.ones((1,), bool), k[1:] != k[:-1]])
+                boundary = svalid & diff
+                seg = int_cumsum(boundary.astype(jnp.int32)) - 1
+                bor_inv = boundary | ~svalid
+                is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
+                n_seg = boundary.sum().astype(jnp.int32)
 
-            spos = (
-                jnp.zeros((C,), jnp.int32)
-                .at[jnp.where(boundary, seg, C)]
-                .set(arangeM, mode="drop", unique_indices=True)
-            )
-            start = spos[jnp.clip(seg, 0, C - 1)]
-            end_idx = jnp.where(is_end, seg, C)
-
-            def compact(src):
-                return (
-                    jnp.zeros((C,), src.dtype)
-                    .at[end_idx]
-                    .set(src, mode="drop", unique_indices=True)
+                spos = (
+                    jnp.zeros((C,), jnp.int32)
+                    .at[jnp.where(boundary, seg, C)]
+                    .set(arangeM, mode="drop", unique_indices=True)
                 )
+                start = spos[jnp.clip(seg, 0, C - 1)]
+                end_idx = jnp.where(is_end, seg, C)
 
-            def int_segsum(sv):
-                w = sv.astype(jnp.int64)
-                csum = int_cumsum(w)
-                presum = csum - w
-                return compact(csum - presum[start])
+                def compact(src):
+                    return (
+                        jnp.zeros((C,), src.dtype)
+                        .at[end_idx]
+                        .set(src, mode="drop", unique_indices=True)
+                    )
 
-            pid_c = compact(spid)
-            key_vals: list = []
-            key_valid: list = []
-            for (mpos, vpos) in key_layout:
-                key_vals.append(compact(skeys[vpos]))
-                if mpos is None:
-                    key_valid.append(None)
-                else:
-                    key_valid.append(compact(skeys[mpos]) == 0)
+                def int_segsum(sv):
+                    w = sv.astype(jnp.int64)
+                    csum = int_cumsum(w)
+                    presum = csum - w
+                    return compact(csum - presum[start])
 
-            accs: list = []
-            acc_valid: list = []
-            acc_kind: list = []
-            acc_scale: list = []
-            for ai, (d, (pay_idx, ncnt_idx)) in enumerate(zip(agg_descs, pay_plan)):
-                sv = spays[pay_idx]
-                if d.func in ("count", "count_all"):
-                    accs.append(int_segsum(sv))
-                    acc_valid.append(None)
-                    acc_kind.append("i64")
-                    acc_scale.append(0)
-                    continue
-                src = n_group + ai
-                fname = d.func
-                if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
-                    accs.append(int_segsum(sv))
-                elif fname == "sum":
-                    accs.append(compact(_segscan(jnp, sv, boundary, "sum")))
-                else:
-                    out = compact(_segscan(jnp, sv, boundary, fname))
-                    if kinds[src] in ("i64", "money") and out.dtype != jnp.int64:
-                        out = out.astype(jnp.int64)
-                    accs.append(out)
-                if ncnt_idx is not None:
-                    acc_valid.append(int_segsum(spays[ncnt_idx]) > 0)
-                else:
-                    acc_valid.append(None)
-                acc_kind.append(kinds[src])
-                acc_scale.append(scales[src])
-            cell["keys"] = key_vals
-            cell["key_valid"] = key_valid
-            cell["accs"] = accs
-            cell["acc_valid"] = acc_valid
-            cell["acc_kind"] = acc_kind
-            cell["acc_scale"] = acc_scale
+                pid_c = compact(spid)
+                key_vals: list = []
+                key_valid: list = []
+                for (mpos, vpos) in key_layout:
+                    key_vals.append(compact(skeys[vpos]))
+                    if mpos is None:
+                        key_valid.append(None)
+                    else:
+                        key_valid.append(compact(skeys[mpos]) == 0)
+
+                accs: list = []
+                acc_valid: list = []
+                acc_kind: list = []
+                acc_scale: list = []
+                for ai, (d, (pay_idx, ncnt_idx)) in enumerate(zip(agg_descs, pay_plan)):
+                    sv = spays[pay_idx]
+                    if d.func in ("count", "count_all"):
+                        accs.append(int_segsum(sv))
+                        acc_valid.append(None)
+                        acc_kind.append("i64")
+                        acc_scale.append(0)
+                        continue
+                    src = n_group + ai
+                    fname = d.func
+                    if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
+                        accs.append(int_segsum(sv))
+                    elif fname == "sum":
+                        accs.append(compact(_segscan(jnp, sv, boundary, "sum")))
+                    else:
+                        out = compact(_segscan(jnp, sv, boundary, fname))
+                        if kinds[src] in ("i64", "money") and out.dtype != jnp.int64:
+                            out = out.astype(jnp.int64)
+                        accs.append(out)
+                    if ncnt_idx is not None:
+                        acc_valid.append(int_segsum(spays[ncnt_idx]) > 0)
+                    else:
+                        acc_valid.append(None)
+                    acc_kind.append(kinds[src])
+                    acc_scale.append(scales[src])
+                cell["keys"] = key_vals
+                cell["key_valid"] = key_valid
+                cell["accs"] = accs
+                cell["acc_valid"] = acc_valid
+                cell["acc_kind"] = acc_kind
+                cell["acc_scale"] = acc_scale
 
             arangeC = jnp.arange(C, dtype=jnp.int32)
             alive = arangeC < n_seg
-            for kf in keep_fns:
-                alive = alive & true_mask(kf(cols, luts))
+            with jax.named_scope("filter"):
+                for kf in keep_fns:
+                    alive = alive & true_mask(kf(cols, luts))
 
-            out_vals = [f(cols, luts) for f in out_fns]
-            out_meta = []
-            for v, slot in zip(out_vals, out_slots):
-                if v.kind == "code" and (slot is None or not isinstance(slot, int)):
-                    raise Unsupported("computed string output")
-                out_meta.append((v.kind, v.scale, slot,
-                                 v.valid is not None))
-            meta_holder["out"] = out_meta
+            with jax.named_scope("project"):
+                out_vals = [f(cols, luts) for f in out_fns]
+                out_meta = []
+                for v, slot in zip(out_vals, out_slots):
+                    if v.kind == "code" and (slot is None or not isinstance(slot, int)):
+                        raise Unsupported("computed string output")
+                    out_meta.append((v.kind, v.scale, slot,
+                                     v.valid is not None))
+                meta_holder["out"] = out_meta
 
             # ---- phase 2 sort: (dead, pid, user keys...) → perm --------
-            ops2: list = [~alive, pid_c]
-            for (kf, asc, nf, lut_idx) in sort_specs:
-                v = kf(cols, luts)
-                arr = v.arr
-                if v.kind == "code":
-                    if lut_idx is None:
-                        raise Unsupported("unranked string sort key")
-                    arr = luts[lut_idx][arr]
-                if arr.dtype == jnp.bool_:
-                    arr = arr.astype(jnp.int32)
-                arr = jnp.broadcast_to(arr, (C,))
-                if not asc:
-                    arr = -arr
-                if v.valid is not None:
-                    marker = jnp.broadcast_to(~v.valid, (C,)).astype(jnp.int32)
-                    ops2.append(-marker if nf else marker)  # nulls first → ahead
-                ops2.append(arr)
-            perm = lex_order(ops2)  # stable: ties keep compacted order
-            alive_s = alive[perm]
-            spid2 = pid_c[perm]
+            with jax.named_scope("topk" if fetch is not None else "sort"):
+                ops2: list = [~alive, pid_c]
+                for (kf, asc, nf, lut_idx) in sort_specs:
+                    v = kf(cols, luts)
+                    arr = v.arr
+                    if v.kind == "code":
+                        if lut_idx is None:
+                            raise Unsupported("unranked string sort key")
+                        arr = luts[lut_idx][arr]
+                    if arr.dtype == jnp.bool_:
+                        arr = arr.astype(jnp.int32)
+                    arr = jnp.broadcast_to(arr, (C,))
+                    if not asc:
+                        arr = -arr
+                    if v.valid is not None:
+                        marker = jnp.broadcast_to(~v.valid, (C,)).astype(jnp.int32)
+                        ops2.append(-marker if nf else marker)  # nulls first → ahead
+                    ops2.append(arr)
+                perm = lex_order(ops2)  # stable: ties keep compacted order
+                alive_s = alive[perm]
+                spid2 = pid_c[perm]
 
-            b2 = alive_s & jnp.concatenate(
-                [jnp.ones((1,), bool), spid2[1:] != spid2[:-1]])
-            spos_pid = (
-                jnp.zeros((P_out,), jnp.int32)
-                .at[jnp.where(b2, spid2, P_out)]
-                .set(arangeC, mode="drop", unique_indices=True)
-            )
-            rank = arangeC - spos_pid[jnp.clip(spid2, 0, P_out - 1)]
-            keep_out = alive_s
-            if fetch is not None:
-                keep_out = keep_out & (rank < fetch)
-            out_pos = int_cumsum(keep_out.astype(jnp.int32)) - 1
-            n_out = keep_out.sum().astype(jnp.int32)
-            scatter_idx = jnp.where(keep_out, out_pos, C)
-            row_src = (
-                jnp.zeros((C,), jnp.int32)
-                .at[scatter_idx].set(perm, mode="drop", unique_indices=True)
-            )
-            pid_final = (
-                jnp.zeros((C,), jnp.int32)
-                .at[scatter_idx].set(spid2, mode="drop", unique_indices=True)
-            )
+                b2 = alive_s & jnp.concatenate(
+                    [jnp.ones((1,), bool), spid2[1:] != spid2[:-1]])
+                spos_pid = (
+                    jnp.zeros((P_out,), jnp.int32)
+                    .at[jnp.where(b2, spid2, P_out)]
+                    .set(arangeC, mode="drop", unique_indices=True)
+                )
+                rank = arangeC - spos_pid[jnp.clip(spid2, 0, P_out - 1)]
+                keep_out = alive_s
+                if fetch is not None:
+                    keep_out = keep_out & (rank < fetch)
+                out_pos = int_cumsum(keep_out.astype(jnp.int32)) - 1
+                n_out = keep_out.sum().astype(jnp.int32)
+                scatter_idx = jnp.where(keep_out, out_pos, C)
+                row_src = (
+                    jnp.zeros((C,), jnp.int32)
+                    .at[scatter_idx].set(perm, mode="drop", unique_indices=True)
+                )
+                pid_final = (
+                    jnp.zeros((C,), jnp.int32)
+                    .at[scatter_idx].set(spid2, mode="drop", unique_indices=True)
+                )
 
-            outs: list = []
-            for v in out_vals:
-                arr = jnp.broadcast_to(v.arr, (C,))
-                outs.append(arr[row_src])
-            for v in out_vals:
-                if v.valid is not None:
-                    outs.append(jnp.broadcast_to(v.valid, (C,))[row_src])
+            with jax.named_scope("emit"):
+                outs: list = []
+                for v in out_vals:
+                    arr = jnp.broadcast_to(v.arr, (C,))
+                    outs.append(arr[row_src])
+                for v in out_vals:
+                    if v.valid is not None:
+                        outs.append(jnp.broadcast_to(v.valid, (C,))[row_src])
             return tuple(outs) + (pid_final, n_seg, n_out)
 
+        raw.__name__ = raw.__qualname__ = "stage_final"
         jitted = jax.jit(raw)
         cols_spec = [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in cols_np] + [
             jax.ShapeDtypeStruct(v.shape, np.bool_) for v in valids_np if v is not None
@@ -944,7 +955,8 @@ class TpuFinalStageExec(ExecutionPlan):
         schema = self.schema()
         C = meta["C"]
         P_out = meta["P_out"]  # kernel pid space; ≤ P_result under bypass
-        n_seg, n_out = (int(x) for x in jax.device_get(outs[-2:]))
+        with RUN_STATS.span("bt.device.fetch", what="count"):
+            n_seg, n_out = (int(x) for x in jax.device_get(outs[-2:]))
         if n_seg > C:
             raise Unsupported(f"group capacity overflow ({n_seg} > {C})")
         if self.sort is not None and self.sort.fetch is not None:
@@ -955,7 +967,8 @@ class TpuFinalStageExec(ExecutionPlan):
         if n_out == 0:
             return results
         cp = min(_pow2(n_out), C)
-        data = jax.device_get([o[:cp] for o in outs[:-2]])
+        with RUN_STATS.span("bt.device.fetch", rows=n_out):
+            data = jax.device_get([o[:cp] for o in outs[:-2]])
         out_meta = meta["out"]
         n_cols = len(out_meta)
         vals = data[:n_cols]

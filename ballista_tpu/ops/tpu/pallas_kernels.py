@@ -67,6 +67,13 @@ def _on_cpu() -> bool:
     return runtime.platform() == "cpu"
 
 
+def _jit_named(jax, fn, name: str):
+    """`fn` jitted under the kernel's own name: the profiler's trace then
+    reads jit_<kernel>(..)/<kernel>, not jit_wrapped."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 def _tile(P: int, N: int, block_n: int) -> tuple[int, int, int]:
     """(padded P, padded N, lane block) for a [P, N] operand under the TPU
     block rule. Up to 8 partitions ride as one whole-axis row block; more
@@ -132,6 +139,7 @@ def _build_group_reduce(P: int, N: int, block_n: int, G: int, interpret: bool):
                             lambda i, gt, j: (i, gt, jnp.int32(0)))
     fn = pl.pallas_call(
         kernel,
+        name="masked_group_reduce",
         grid=(P // Pb, n_tiles, N // block_n),
         in_specs=[in_spec, in_spec, in_spec],
         out_specs=(out_spec, out_spec),
@@ -143,7 +151,7 @@ def _build_group_reduce(P: int, N: int, block_n: int, G: int, interpret: bool):
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )
-    return jax.jit(fn)
+    return _jit_named(jax, fn, "masked_group_reduce")
 
 
 def masked_group_reduce(vals, gid, mask, num_groups: int, block_n: int = 2048):
@@ -187,6 +195,7 @@ def _build_hash_probe(P: int, N: int, block_n: int, T: int, interpret: bool):
     grid = (P, N // block_n)
     fn = pl.pallas_call(
         kernel,
+        name="hash_probe",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
@@ -203,7 +212,7 @@ def _build_hash_probe(P: int, N: int, block_n: int, T: int, interpret: bool):
         ),
         interpret=interpret,
     )
-    return jax.jit(fn)
+    return _jit_named(jax, fn, "hash_probe")
 
 
 def hash_probe(keys, table, mask, block_n: int = 2048):
@@ -292,6 +301,7 @@ def _build_segmented_sort(P: int, N: int, interpret: bool):
     spec = pl.BlockSpec((1, N), lambda i: (i, 0))
     fn = pl.pallas_call(
         kernel,
+        name="segmented_sort",
         grid=(P,),
         in_specs=[spec, spec, spec],
         out_specs=(spec, spec, spec),
@@ -302,7 +312,7 @@ def _build_segmented_sort(P: int, N: int, interpret: bool):
         ),
         interpret=interpret,
     )
-    return jax.jit(fn)
+    return _jit_named(jax, fn, "segmented_sort")
 
 
 def segmented_sort(a, b, pos):
@@ -359,6 +369,7 @@ def _build_topk(P: int, N: int, C: int, interpret: bool):
     out_spec = pl.BlockSpec((1, C), lambda i: (i, 0))
     fn = pl.pallas_call(
         kernel,
+        name="topk_select",
         grid=(P,),
         in_specs=[in_spec, in_spec, in_spec],
         out_specs=(out_spec, out_spec, out_spec),
@@ -369,7 +380,7 @@ def _build_topk(P: int, N: int, C: int, interpret: bool):
         ),
         interpret=interpret,
     )
-    return jax.jit(fn)
+    return _jit_named(jax, fn, "topk_select")
 
 
 def topk_select(a, b, pos, k: int):
@@ -433,13 +444,14 @@ def _build_seg_scan(P: int, N: int, func: str, dtype_name: str, interpret: bool)
     spec = pl.BlockSpec((1, N), lambda i: (i, 0))
     fn = pl.pallas_call(
         kernel,
+        name="segmented_scan",
         grid=(P,),
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((P, N), dtype),
         interpret=interpret,
     )
-    return jax.jit(fn)
+    return _jit_named(jax, fn, "segmented_scan")
 
 
 def segmented_scan(vals, boundary, func: str):
@@ -499,6 +511,7 @@ def _build_dict_filter(P: int, N: int, block_n: int, T: int, interpret: bool):
     spec = pl.BlockSpec((Pb, block_n), lambda i, j: (i, j))
     fn = pl.pallas_call(
         kernel,
+        name="dict_filter",
         grid=(P // Pb, N // block_n),
         in_specs=[spec, spec,
                   pl.BlockSpec((n_rows, LANES),
@@ -509,7 +522,7 @@ def _build_dict_filter(P: int, N: int, block_n: int, T: int, interpret: bool):
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )
-    return jax.jit(fn)
+    return _jit_named(jax, fn, "dict_filter")
 
 
 def dict_filter(codes, lut, mask, block_n: int = 2048):

@@ -52,7 +52,6 @@ from __future__ import annotations
 import functools
 import logging
 import threading
-import time
 from typing import Iterator, Optional
 
 import numpy as np
@@ -77,6 +76,7 @@ from ballista_tpu.plan.physical import (
     _sort_table,
 )
 from ballista_tpu.plan.schema import DFSchema
+from ballista_tpu.tracing import RUN_STATS, STAGE_OUTCOMES
 
 log = logging.getLogger(__name__)
 
@@ -116,8 +116,6 @@ def _count(key: str, delta: int = 1) -> int:
 def _publish_counters() -> None:
     """Mirror the cumulative counters into RUN_STATS (literal keys — the
     stats-sync pass matches emit sites by string constant)."""
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
     with _CTR_LOCK:
         snap = dict(_COUNTERS)
     RUN_STATS.set("sort_invocations", snap["sort_invocations"])
@@ -130,8 +128,6 @@ def _publish_counters() -> None:
 
 
 def _note_kernel_s(dt: float) -> None:
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
     with _CTR_LOCK:
         _KERNEL_S[0] += dt
         val = round(_KERNEL_S[0], 4)
@@ -245,8 +241,6 @@ def _admit(est, config: BallistaConfig) -> None:
     """HBM admission for a sort/window stage: no splittable build side, so
     the ladder is run-whole vs CPU demotion, reason recorded."""
     from ballista_tpu.ops.tpu import hbm
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
     budget = hbm.resolve_hbm_budget(config)
     plan = hbm.plan_stage(est, budget, grace_eligible=False, grace_fanout=2,
                           grace_max_depth=0)
@@ -342,7 +336,10 @@ def _pad_max(a: np.ndarray, L: int) -> np.ndarray:
 def _lex_order_jit():
     from ballista_tpu.ops.tpu.kernels import lex_order
 
-    return ensure_jax().jit(lambda *keys: lex_order(list(keys)))
+    def sort_lex_order(*keys):
+        return lex_order(list(keys))
+
+    return ensure_jax().jit(sort_lex_order)
 
 
 @functools.lru_cache(maxsize=8)
@@ -350,7 +347,12 @@ def _segscan_jit(func: str):
     from ballista_tpu.ops.tpu.stage_compiler import _segscan
 
     jax = ensure_jax()
-    return jax.jit(lambda v, b: _segscan(jax.numpy, v, b, func))
+
+    def window_segscan(v, b):
+        return _segscan(jax.numpy, v, b, func)
+
+    window_segscan.__name__ = window_segscan.__qualname__ = f"window_segscan_{func}"
+    return jax.jit(window_segscan)
 
 
 def _pow2(n: int) -> int:
@@ -420,8 +422,6 @@ def window_static_ok(window_exprs: list, schema: DFSchema) -> bool:
 def _device_sort(tbl: pa.Table, df_schema: DFSchema, keys: list,
                  fetch: Optional[int], config: BallistaConfig) -> pa.Table:
     from ballista_tpu.ops.tpu import fusion
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
     n = tbl.num_rows
     if n == 0:
         return tbl
@@ -443,19 +443,20 @@ def _device_sort(tbl: pa.Table, df_schema: DFSchema, keys: list,
     RUN_STATS.set("fusion_reason", dec.reason)
 
     up = _Uploads()
-    t0 = time.time()
-    if dec.mode == "fused_pallas" and topk_wanted:
-        # choose_sort only keeps topk_k on the pallas rung when the kernel
-        # can take it (single key, k under the ceiling)
-        perm = _perm_topk(key_ops, n, int(fetch), up)
-        _count("topk_invocations")
-        _count("topk_rows_kept", len(perm))
-    else:
-        perm = _perm_full(key_ops, n, dec.mode, up)
-        _count("sort_invocations")
-        if fetch is not None:
-            _count("sort_full_materializations")
-    _note_kernel_s(time.time() - t0)
+    # upload, kernel and the permutation's fetch: the host blocked on the device
+    with RUN_STATS.span("bt.device.exec", rows=n) as span:
+        if dec.mode == "fused_pallas" and topk_wanted:
+            # choose_sort only keeps topk_k on the pallas rung when the kernel
+            # can take it (single key, k under the ceiling)
+            perm = _perm_topk(key_ops, n, int(fetch), up)
+            _count("topk_invocations")
+            _count("topk_rows_kept", len(perm))
+        else:
+            perm = _perm_full(key_ops, n, dec.mode, up)
+            _count("sort_invocations")
+            if fetch is not None:
+                _count("sort_full_materializations")
+    _note_kernel_s(span.seconds)
     RUN_STATS.set("device_bytes", up.bytes)
 
     out = tbl.take(pa.array(perm))
@@ -503,10 +504,9 @@ class TpuSortStageExec(ExecutionPlan):
     def _run(self, partition: int, ctx: TaskContext):
         batches = [b for b in self.input.execute(partition, ctx) if b.num_rows]
         tbl = _concat(batches, self.schema())
-        from ballista_tpu.ops.tpu.stage_compiler import STAGE_OUTCOMES
-
         try:
-            with device_scope(ctx.device_ordinal):
+            with device_scope(ctx.device_ordinal), \
+                    RUN_STATS.span("bt.stage.dispatch", family="sort"):
                 out = _device_sort(tbl, self.df_schema, self.keys, self.fetch,
                                    self.config)
             if tbl.num_rows:  # an empty partition dispatches nothing
@@ -528,10 +528,11 @@ class TpuSortStageExec(ExecutionPlan):
 
     def _host_sort(self, tbl: pa.Table) -> pa.Table:
         self.fallback_count += 1
-        out = _sort_table(tbl, self.df_schema, self.keys)
-        if self.fetch is not None:
-            out = out.slice(0, self.fetch)
-        return out
+        with RUN_STATS.span("bt.stage.fallback", family="sort"):
+            out = _sort_table(tbl, self.df_schema, self.keys)
+            if self.fetch is not None:
+                out = out.slice(0, self.fetch)
+            return out
 
 
 # ---------------------------------------------------------------------------
@@ -545,8 +546,6 @@ def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
     peers) so peer semantics cannot drift. Returns (_Frame, mode)."""
     from ballista_tpu.ops.cpu.window import _Frame, _changes, _first_only
     from ballista_tpu.ops.tpu import fusion
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
     n = batch.num_rows
     part_arrays = [evaluate_to_array(bind_expr(e, schema), batch)
                    for e in w.partition_by]
@@ -564,12 +563,12 @@ def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
     RUN_STATS.set("fusion_mode", dec.mode)
     RUN_STATS.set("fusion_reason", dec.reason)
 
-    t0 = time.time()
-    if key_ops:
-        idx = _perm_full(key_ops, n, dec.mode, up).astype(np.int64)
-    else:
-        idx = np.arange(n, dtype=np.int64)
-    _note_kernel_s(time.time() - t0)
+    with RUN_STATS.span("bt.device.exec", rows=n) as span:
+        if key_ops:
+            idx = _perm_full(key_ops, n, dec.mode, up).astype(np.int64)
+        else:
+            idx = np.arange(n, dtype=np.int64)
+    _note_kernel_s(span.seconds)
 
     inv = np.empty(n, dtype=np.int64)
     inv[idx] = np.arange(n, dtype=np.int64)
@@ -613,29 +612,29 @@ def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
     """One window expression over a shared frame: device segmented scans
     inside the oracle's gather/scatter/emit skeleton."""
     from ballista_tpu.ops.cpu.window import _decimal_prepare, _emit_agg, _peer_last
-
     n = batch.num_rows
     out_type = w.data_type(schema)
     if n == 0:
         return pa.array([], out_type)
-    t0 = time.time()
-    boundary = fr.new_part.copy()
-    boundary[0] = True
-    arange = np.arange(n, dtype=np.int64)
+    with RUN_STATS.span("bt.device.exec", rows=n) as span:
+        boundary = fr.new_part.copy()
+        boundary[0] = True
+        arange = np.arange(n, dtype=np.int64)
 
-    if w.func == "row_number":
-        out_sorted = _seg_scan(np.ones(n, np.int64), boundary, "sum", mode, up)
-    elif w.func == "rank":
-        marked = np.where(fr.new_peer, arange, np.int64(_I64_MIN))
-        peer_start = _seg_scan(marked, boundary, "max", mode, up)
-        out_sorted = peer_start - fr.seg_start + 1
-    else:
-        arr = _emit_scan_agg(batch, w, schema, fr, mode, boundary, up,
-                             out_type, _decimal_prepare, _emit_agg,
-                             _peer_last, n)
-        _note_kernel_s(time.time() - t0)
+        arr = None
+        if w.func == "row_number":
+            out_sorted = _seg_scan(np.ones(n, np.int64), boundary, "sum", mode, up)
+        elif w.func == "rank":
+            marked = np.where(fr.new_peer, arange, np.int64(_I64_MIN))
+            peer_start = _seg_scan(marked, boundary, "max", mode, up)
+            out_sorted = peer_start - fr.seg_start + 1
+        else:
+            arr = _emit_scan_agg(batch, w, schema, fr, mode, boundary, up,
+                                 out_type, _decimal_prepare, _emit_agg,
+                                 _peer_last, n)
+    _note_kernel_s(span.seconds)
+    if arr is not None:
         return arr
-    _note_kernel_s(time.time() - t0)
     out = np.empty(n, dtype=np.int64)
     out[fr.idx] = out_sorted
     return pa.array(out, out_type)
@@ -736,8 +735,6 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
                                         groups[key], up)
         fr, mode = frames[key]
         out.append(_device_compute_one(batch, w, schema, fr, mode, up))
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
     RUN_STATS.set("device_bytes", up.bytes)
     _count("window_invocations")
     return out
@@ -788,10 +785,9 @@ class TpuWindowStageExec(ExecutionPlan):
         if batch is None:
             yield _empty_batch(self.schema())
             return
-        from ballista_tpu.ops.tpu.stage_compiler import STAGE_OUTCOMES
-
         try:
-            with device_scope(ctx.device_ordinal):
+            with device_scope(ctx.device_ordinal), \
+                    RUN_STATS.span("bt.stage.dispatch", family="window"):
                 wins = _device_windows(batch, self.window_exprs,
                                        self.input.df_schema, self.config)
             STAGE_OUTCOMES.note("window", "device")
@@ -811,9 +807,9 @@ class TpuWindowStageExec(ExecutionPlan):
 
     def _host_windows(self, batch: pa.RecordBatch) -> list[pa.Array]:
         from ballista_tpu.ops.cpu.window import compute_windows
-
         self.fallback_count += 1
-        return compute_windows(batch, self.window_exprs, self.input.df_schema)
+        with RUN_STATS.span("bt.stage.fallback", family="window"):
+            return compute_windows(batch, self.window_exprs, self.input.df_schema)
 
 
 def sort_family_enabled(config: BallistaConfig) -> bool:

@@ -25,14 +25,12 @@ domains, or tiny inputs re-run the original subtree on the CPU engine.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import logging
 import os
 import threading
 import time
 import zlib
-from collections.abc import Mapping
 from typing import Iterator
 
 import numpy as np
@@ -107,209 +105,17 @@ _BUILD_CACHE = LruDict(
 )
 
 
-class RunStats(Mapping):
-    """Per-stage-run diagnostics for chip_smoke.py, the benchmark and the
-    executor heartbeat.
-
-    Concurrent stages used to scribble over one bare module dict; now every
-    `_tpu_run_all` opens a `run(tag)` scope that collects into a private
-    per-run dict (helper threads write through an explicit `rec=` handle)
-    and publishes atomically on exit: the merged view (`dict(RUN_STATS)`,
-    `snapshot()`) is always a consistent most-recent-run-wins snapshot, and
-    `stages()` keeps the last few per-stage records for overlap analysis
-    (merged over a stage's dispatches since the last clear(), most recent
-    value wins, with their number as `dispatches`).
-
-    Keys: fill_s (whole device fill), encode_s (host encode wall),
-    upload_s (device_put issue + flush), device_bytes, table_shape (the
-    [P, N] partition stack the stage kernel ran over), trace_s (python
-    trace+lower), xla_compile_s (backend compile / persistent-cache fetch),
-    compile_s (trace_s + xla_compile_s, the legacy total), compile_overlap_s
-    (compile seconds hidden under the fill), exec_s (dispatch + fetch +
-    decode), persist_cache_hits and persist_cache_misses (per-run deltas),
-    fusion_mode
-    (staged | fused_xla | fused_pallas — the mode that actually ran),
-    fusion_choice (the mode the cost model asked for; differs from
-    fusion_mode only where _compile had to clamp the request),
-    fusion_reason (the cost model's stated rationale), fused_spans
-    (operator spans compiled into the single kernel; 0 in staged mode),
-    fused_kernel_s (device seconds of the fused dispatch, or the sum of
-    per-span times in staged mode; span_s carries the per-span split),
-    mesh_devices (devices participating in a mesh-fused exchange stage),
-    exchange_bytes_on_device (bytes moved by the on-device all_to_all),
-    exchange_s (wall seconds of the exchange collective), mesh_mode_reason
-    (why the mesh merge pass did or did not fuse the exchange),
-    hbm_budget_bytes (the resolved device budget the stage was admitted
-    against), hbm_plan (run_whole | spill_colds | grace_split | cpu_demote)
-    and hbm_plan_reason (the admission ladder's stated rationale),
-    hbm_spill_bytes / hbm_spill_events / hbm_reupload_events (cumulative
-    host-spill-pool counters), grace_splits (sub-buckets actually executed
-    by a grace-partitioned join), hbm_oom_retries (cumulative stage re-runs
-    after a caught RESOURCE_EXHAUSTED; the evict-spill-retry rung),
-    sort_kernel_s (cumulative device seconds in the sort/window/top-k
-    family), sort_invocations / topk_invocations / window_invocations
-    (cumulative per-family kernel dispatch counts), topk_rows_kept
-    (cumulative rows surviving fused top-k cuts), window_partitions
-    (cumulative partitions swept by device window stages), and
-    sort_full_materializations (ORDER BY ... LIMIT stages that fell back
-    to a full sort instead of the fused top-k — nonzero means the top-k
-    rung demoted). Warm-daemon routing (docs/device_daemon.md):
-    daemon_mode ("attached" when the stage was shipped to the device
-    daemon, "in_process" when the session opted in but execution stayed
-    local) and daemon_mode_reason (why — "daemon disabled",
-    "attach_failed: ...", "execute_failed: ..." or the socket attached
-    to); the numeric twins daemon_attached / daemon_sessions /
-    daemon_queue_depth and the daemon's per-phase init timings
-    init_platform_probe_s / init_jax_devices_s / init_first_compile_s
-    flow to the executor heartbeat as gauges. Daemon failure-domain
-    outcomes (ops/tpu/daemon_route.py,
-    docs/device_daemon.md#failure-domain): daemon_failover
-    ("daemon_restarted" when a crash was recovered by respawn+retry,
-    "crashed" when the retry also died, "poisoned" when the stage sits
-    in — or just entered — the on-disk quarantine) with the narrative in
-    daemon_failover_reason, plus the process-lifetime recovery counters
-    daemon_restarts / daemon_crashes_detected / watchdog_kills /
-    poisoned_stages mirrored from the daemon client into the merged view
-    so they ride the heartbeat. AQE decision counters
-    (ops/tpu/aqe_stats.py, docs/aqe.md): skew_splits (hot reduce
-    partitions split into slice tasks), coalesced_partitions (reduce
-    partitions merged away), broadcast_promotions / broadcast_demotions
-    (runtime join mode switches in either direction), and
-    aqe_mesh_replans (mesh stages whose bucket count was replanned or
-    whose fused exchange was demoted on skew) — all cumulative, all
-    forwarded to the heartbeat under their own names. Append ingestion
-    (serving/incremental.py, docs/streaming.md): delta_fill_rows — rows
-    a memory-backed (delta-grafted) scan filled onto the device, so the
-    heartbeat shows ingested-delta volume reaching the TPU tier."""
-
-    _MAX_STAGES = 32
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._merged: dict = {}
-        import collections
-
-        self._stages: "collections.OrderedDict[str, dict]" = collections.OrderedDict()
-        self._tls = threading.local()
-
-    @contextlib.contextmanager
-    def run(self, tag: str):
-        rec: dict = {}
-        prev = getattr(self._tls, "rec", None)
-        self._tls.rec = rec
-        try:
-            yield rec
-        finally:
-            self._tls.rec = prev
-            self._publish(tag, rec)
-
-    def _publish(self, tag: str, rec: dict) -> None:
-        if not rec:
-            return
-        with self._lock:
-            self._merged.update(rec)
-            # one record per stage, merged over its dispatches: a stage's
-            # map tasks each dispatch it, and only the first carries the
-            # cold-path keys (fill_s, xla_compile_s, persist_cache_*) — a
-            # later task's record must not erase them
-            prev = self._stages.pop(tag, {})
-            self._stages[tag] = {**prev, **rec,
-                                 "dispatches": prev.get("dispatches", 0) + 1}
-            while len(self._stages) > self._MAX_STAGES:
-                self._stages.popitem(last=False)
-
-    def set(self, key: str, value, rec: dict | None = None) -> None:
-        """Record one stat. With `rec` (a run's private dict, threadable to
-        helper threads) the write lands in that run; otherwise in the
-        calling thread's open run scope, else directly in the merged view."""
-        if rec is None:
-            rec = getattr(self._tls, "rec", None)
-        if rec is not None:
-            rec[key] = value
-        else:
-            with self._lock:
-                self._merged[key] = value
-
-    def __setitem__(self, key: str, value) -> None:  # legacy write path
-        self.set(key, value)
-
-    def current(self) -> dict | None:
-        return getattr(self._tls, "rec", None)
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return dict(self._merged)
-
-    def stages(self) -> dict:
-        with self._lock:
-            return {t: dict(r) for t, r in self._stages.items()}
-
-    def clear(self) -> None:
-        with self._lock:
-            self._merged.clear()
-            self._stages.clear()
-
-    # Mapping protocol over the merged snapshot (dict(RUN_STATS) works)
-    def __getitem__(self, key):
-        with self._lock:
-            return self._merged[key]
-
-    def __iter__(self):
-        with self._lock:
-            return iter(list(self._merged))
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._merged)
-
-
-RUN_STATS = RunStats()
-
-
-class StageOutcomes:
-    """Process-wide, cumulative ledger of where device-stage operators ran.
-
-    Every stage family (partial / final / sort / window) notes one outcome
-    per dispatch attempt: `device` (ran on the device), `below_row_floor`
-    (stayed on the CPU under ballista.tpu.min.rows, by policy), `declined`
-    (any other Unsupported — the documented per-subtree fallback) or `error`
-    (a non-Unsupported exception demoted to the CPU engine: the query still
-    answers, but this is the fallback that would hide a broken device path).
-    The operators' own tpu_count / fallback_count live on per-task plan
-    objects nobody keeps; this is what chip_smoke.py, tests and the executor
-    heartbeat (`tpu_stage_*` gauges) read instead."""
-
-    KINDS = ("device", "below_row_floor", "declined", "error")
-
-    def __init__(self):
-        import collections
-
-        self._lock = threading.Lock()
-        self._counts = dict.fromkeys(self.KINDS, 0)
-        self._recent: "collections.deque[tuple]" = collections.deque(maxlen=64)
-
-    def note(self, family: str, kind: str, detail: str = "") -> None:
-        with self._lock:
-            self._counts[kind] += 1
-            self._recent.append((family, kind, detail))
-
-    def note_fallback(self, family: str, exc: BaseException) -> None:
-        kind = ("below_row_floor" if isinstance(exc, BelowRowFloor)
-                else "declined" if isinstance(exc, Unsupported) else "error")
-        self.note(family, kind, f"{type(exc).__name__}: {exc}"[:300])
-
-    def snapshot(self) -> dict:
-        """Counts per kind and the last 64 (family, kind, detail) notes."""
-        with self._lock:
-            return {**self._counts, "recent": list(self._recent)}
-
-    def clear(self) -> None:
-        with self._lock:
-            self._counts = dict.fromkeys(self.KINDS, 0)
-            self._recent.clear()
-
-
-STAGE_OUTCOMES = StageOutcomes()
+# RunStats / StageOutcomes live in ballista_tpu/tracing.py (jax-free, so the
+# client and the scheduler record spans into the same recorder without
+# loading this module). Re-exported here: readers import them from
+# stage_compiler, and the executor heartbeat keys its TPU gauges on this
+# module being loaded.
+from ballista_tpu.tracing import (  # noqa: E402,F401
+    RUN_STATS,
+    STAGE_OUTCOMES,
+    RunStats,
+    StageOutcomes,
+)
 
 KEY_SHIFT = 21  # multi-key combine: k = k1 << 21 | k2 (guarded ranges)
 
@@ -462,20 +268,21 @@ class DeviceTableCache:
                 raise Unsupported("peer encode failed")
             return hit
         try:
-            t0 = time.time()
-            # spilled-entry fast path: a previously demoted table re-uploads
-            # from its host (or disk) copy instead of re-running the whole
-            # read+encode fill — the transparent-on-touch half of the spill
-            # contract. on_spec still fires so compile/fill overlap holds.
-            restored = spill_pool.pop(key) if spill_pool is not None else None
-            if restored is not None:
-                dt = _restore_device_table(restored, mesh)
-                if on_spec is not None:
-                    on_spec(dt)
-            else:
-                dt = self._load(scan, buckets, ctx, mesh, fill_threads=fill_threads,
-                                chunk_rows=chunk_rows, stats=stats, on_spec=on_spec)
-            RUN_STATS.set("fill_s", round(time.time() - t0, 3), rec=stats)
+            with RUN_STATS.span("bt.device.fill") as span:
+                # spilled-entry fast path: a previously demoted table re-uploads
+                # from its host (or disk) copy instead of re-running the whole
+                # read+encode fill — the transparent-on-touch half of the spill
+                # contract. on_spec still fires so compile/fill overlap holds.
+                restored = spill_pool.pop(key) if spill_pool is not None else None
+                if restored is not None:
+                    dt = _restore_device_table(restored, mesh)
+                    if on_spec is not None:
+                        on_spec(dt)
+                else:
+                    dt = self._load(scan, buckets, ctx, mesh, fill_threads=fill_threads,
+                                    chunk_rows=chunk_rows, stats=stats, on_spec=on_spec)
+                span.set(bytes=dt.nbytes)
+            RUN_STATS.set("fill_s", round(span.seconds, 3), rec=stats)
             RUN_STATS.set("device_bytes", dt.nbytes, rec=stats)
             if getattr(scan, "mem_token", None) is not None:
                 # memory-backed fill = ingested delta rows riding a grafted
@@ -628,7 +435,7 @@ class DeviceTableCache:
         nbytes = 0
         meta_lock = threading.Lock()
         left = [n_cols]
-        t_enc0 = time.time()
+        t_enc0 = time.perf_counter()
 
         def spec_table() -> DeviceTable:
             sds = jax.ShapeDtypeStruct
@@ -654,7 +461,7 @@ class DeviceTableCache:
             if done:
                 # the compile key (shapes, dtypes, kinds, dict sizes) is now
                 # fully determined even though uploads are still streaming
-                RUN_STATS.set("encode_s", round(time.time() - t_enc0, 3), rec=stats)
+                RUN_STATS.set("encode_s", round(time.perf_counter() - t_enc0, 3), rec=stats)
                 if on_spec is not None:
                     on_spec(spec_table())
             return dc
@@ -663,13 +470,13 @@ class DeviceTableCache:
 
         def upload(i: int, dc) -> None:
             nonlocal nbytes, t_up
-            t0u = time.time()
+            t0u = time.perf_counter()
             cols[i] = _put_chunked(mesh, dc.data, spec, chunk_rows)
             nbytes += dc.data.nbytes
             if dc.valid is not None:
                 valids[i] = _put_chunked(mesh, dc.valid, spec, chunk_rows)
                 nbytes += dc.valid.nbytes
-            t_up += time.time() - t0u
+            t_up += time.perf_counter() - t0u
 
         if pipelined:
             # lazy submission window: at most (threads + 2) encoded stacks
@@ -705,10 +512,10 @@ class DeviceTableCache:
 
         # drain the async transfers before publishing: fill_s must mean
         # "table resident", not "last copy enqueued"
-        t0u = time.time()
+        t0u = time.perf_counter()
         jax.block_until_ready([c for c in cols if c is not None]
                               + [v for v in valids if v is not None] + [mask])
-        t_up += time.time() - t0u
+        t_up += time.perf_counter() - t0u
         RUN_STATS.set("upload_s", round(t_up, 3), rec=stats)
         return DeviceTable(kinds, scales, dicts, cols, mask, part_rows, nbytes, valids)
 
@@ -981,7 +788,8 @@ class TpuStageExec(ExecutionPlan):
                 return [_empty_batch(self.schema())]
             node = CoalescePartitionsExec(node)
         agg = self.partial_agg.with_children([node])
-        return [b for b in agg.execute(partition, ctx)]
+        with RUN_STATS.span("bt.stage.fallback", family="partial"):
+            return [b for b in agg.execute(partition, ctx)]
 
     # ------------------------------------------------------------------
 
@@ -1167,7 +975,10 @@ class TpuStageExec(ExecutionPlan):
 
     def _tpu_run_all(self, ctx: TaskContext) -> dict[int, list[pa.RecordBatch]]:
         tag = f"stage_{zlib.crc32(self.fingerprint.encode()):08x}"
-        with RUN_STATS.run(tag) as rec:
+        # one span per dispatch, never merged: a stage's map tasks each
+        # dispatch it, and RunStats keeps only the last of their records
+        with RUN_STATS.run(tag) as rec, \
+                RUN_STATS.span("bt.stage.dispatch", family="partial"):
             try:
                 return self._tpu_run_all_inner(ctx, rec)
             except Unsupported:
@@ -1258,10 +1069,10 @@ class TpuStageExec(ExecutionPlan):
             cached = _COMPILE_CACHE.get(key)
             if cached is not None:
                 return cached, False, None
-            t0 = time.time()
-            fn, lowering, meta, lowered = self._compile(
-                dt, kinds, dt.dicts, P, N, builds, mode_req=mode_req)
-            RUN_STATS.set("trace_s", round(time.time() - t0, 3), rec=rec)
+            with RUN_STATS.span("bt.compile.trace") as span:
+                fn, lowering, meta, lowered = self._compile(
+                    dt, kinds, dt.dicts, P, N, builds, mode_req=mode_req)
+            RUN_STATS.set("trace_s", round(span.seconds, 3), rec=rec)
             # the dispatched flag lives with the entry: the FIRST call of a
             # jitted fn runs the backend compile, so the first dispatcher
             # attributes that wall time to xla_compile_s, not exec_s
@@ -1317,6 +1128,8 @@ class TpuStageExec(ExecutionPlan):
             import concurrent.futures as cf
 
             spec_ev = threading.Event()
+            # the helper threads' spans hang under this dispatch
+            dispatch = RUN_STATS.current_span()
 
             def on_spec(sdt: DeviceTable) -> None:
                 holder.setdefault("spec", sdt)
@@ -1328,7 +1141,7 @@ class TpuStageExec(ExecutionPlan):
                 def prep(op, jidx):
                     # jax.default_device is thread-local config state: every
                     # helper thread re-enters the executor's chip pin
-                    with device_scope(ctx.device_ordinal):
+                    with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch):
                         return self._prepare_build(op, jidx, ctx, table_key, mesh)
 
                 build_futs = [pool.submit(prep, op, jidx)
@@ -1341,8 +1154,8 @@ class TpuStageExec(ExecutionPlan):
                     if sdt is None:
                         return None  # fill failed; main thread raises
                     bts = [f.result() for f in build_futs]
-                    t0 = time.time()
-                    with device_scope(ctx.device_ordinal):
+                    t0 = time.perf_counter()
+                    with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch):
                         dec, _ = self._fusion_decision(sdt, bts)
                         entry, fresh, lowered = self._compile_with_fallback(
                             sdt, bts, rec, dec.mode)
@@ -1353,15 +1166,15 @@ class TpuStageExec(ExecutionPlan):
                             # thread's dispatch-time compile becomes a disk
                             # fetch — the seconds-long XLA phase overlaps
                             # the fill instead of serializing after it
-                            t1 = time.time()
                             try:
-                                lowered.compile()
-                                holder["xla_s"] = time.time() - t1
+                                with RUN_STATS.span("bt.compile.xla", ahead=1) as span:
+                                    lowered.compile()
+                                holder["xla_s"] = span.seconds
                             except Exception:  # noqa: BLE001 — warm-up only
                                 log.debug("background XLA precompile failed",
                                           exc_info=True)
                     holder["compile_t0"] = t0
-                    holder["compile_t1"] = time.time()
+                    holder["compile_t1"] = time.perf_counter()
                     return entry
 
                 compile_fut = pool.submit(compile_ahead)
@@ -1369,7 +1182,7 @@ class TpuStageExec(ExecutionPlan):
                     self.scan, self.buckets, ctx, max_bytes, mesh,
                     fill_threads=fill_threads, chunk_rows=chunk_rows,
                     stats=rec, on_spec=on_spec, spill_pool=spill_pool)
-                fill_end = time.time()
+                fill_end = time.perf_counter()
                 if not spec_ev.is_set():
                     # device-cache hit: the fill never ran, so the spec never
                     # fired — the resident table IS the spec
@@ -1470,14 +1283,17 @@ class TpuStageExec(ExecutionPlan):
         first_dispatch = not state["dispatched"]
         state["dispatched"] = True
         span_s: dict[str, float] = {}
-        t0 = time.time()
-        if meta.get("exec") == "staged":
-            outs = fn(dt.flat_cols(), luts, dt.mask, build_args, span_s)
-        else:
-            outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
-            jax.block_until_ready(list(outs))
-        t_call = time.time() - t0
-        # device seconds of the stage kernel(s): the fused dispatch (synced)
+        t0 = time.perf_counter()
+        # host blocked on the device; a fresh entry's first call compiles
+        # (or loads the persistent cache's binary) inside it, and is named so
+        with RUN_STATS.span("bt.compile.xla" if first_dispatch else "bt.device.exec") as span:
+            if meta.get("exec") == "staged":
+                outs = fn(dt.flat_cols(), luts, dt.mask, build_args, span_s)
+            else:
+                outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
+                jax.block_until_ready(list(outs))
+        t_call = span.seconds
+        # host seconds around the synced stage kernel(s): the fused dispatch,
         # or the per-span sum. The cold call folds the backend compile in;
         # xla_compile_s below carries the honest attribution
         rec["fused_kernel_s"] = round(sum(span_s.values()) or t_call, 6)
@@ -1488,12 +1304,8 @@ class TpuStageExec(ExecutionPlan):
             # first call; when the overlap worker already AOT-compiled, the
             # honest figure is ITS compile time (which ran under the fill)
             rec["xla_compile_s"] = round(holder.get("xla_s", t_call), 6)
-        if meta["mode"] == "sorted":
-            res = self._decode_sorted(outs, meta, P, dicts, [b.dicts for b in builds])
-        else:
-            outs = jax.device_get(list(outs))  # ONE batched fetch
-            res = self._decode_all(outs, meta, P, dicts, [b.dicts for b in builds])
-        exec_s = time.time() - t0
+        res = self._fetch_decode(outs, meta, P, dicts, [b.dicts for b in builds])
+        exec_s = time.perf_counter() - t0
         if first_dispatch and "xla_s" not in holder:
             exec_s = max(0.0, exec_s - t_call)  # compile time isn't exec time
         rec["exec_s"] = round(exec_s, 6)
@@ -1557,18 +1369,14 @@ class TpuStageExec(ExecutionPlan):
                     for l in lowering.build_luts(dicts, [sb.dicts for sb in sub_builds])]
             build_args = [sb.flat_arrays() for sb in sub_builds]
             span_s: dict[str, float] = {}
-            if meta.get("exec") == "staged":
-                outs = fn(dt.flat_cols(), luts, dt.mask, build_args, span_s)
-            else:
-                outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
-                jax.block_until_ready(list(outs))
-            if meta["mode"] == "sorted":
-                res = self._decode_sorted(outs, meta, P, dicts,
-                                          [sb.dicts for sb in sub_builds])
-            else:
-                outs = jax.device_get(list(outs))
-                res = self._decode_all(outs, meta, P, dicts,
-                                       [sb.dicts for sb in sub_builds])
+            with RUN_STATS.span("bt.device.exec", grace_bucket=b):
+                if meta.get("exec") == "staged":
+                    outs = fn(dt.flat_cols(), luts, dt.mask, build_args, span_s)
+                else:
+                    outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
+                    jax.block_until_ready(list(outs))
+            res = self._fetch_decode(outs, meta, P, dicts,
+                                     [sb.dicts for sb in sub_builds])
             for p, bl in res.items():
                 merged[p].extend(x for x in bl if x.num_rows)
             buckets_run.append(b)
@@ -1622,7 +1430,8 @@ class TpuStageExec(ExecutionPlan):
         n_flat_cols = len(dt.cols) + sum(1 for v in dt.valids if v is not None)
         env_fns = []
         for i, (kind, scale) in enumerate(kinds):
-            env_fns.append(_mk_col_reader(i, kind, scale, dicts[i], valid_idx[i]))
+            env_fns.append(_scoped(
+                "scan_decode", _mk_col_reader(i, kind, scale, dicts[i], valid_idx[i])))
         env_meta = [(k, s, d, i) for i, ((k, s), d) in enumerate(zip(kinds, dicts))]
         ctx.env_fns = env_fns
         ctx.env_meta = env_meta
@@ -1680,8 +1489,9 @@ class TpuStageExec(ExecutionPlan):
                     and bt.dup == 1
                     and int(bt.keys.shape[0]) <= pallas_probe_max
                 )
-                finder = _mk_join_finder(off, probe_fns, bt, lane_cells[jidx],
-                                         pallas=probe_pallas)
+                probe_scope = f"join_probe_{jidx}"
+                finder = _scoped(probe_scope, _mk_join_finder(
+                    off, probe_fns, bt, lane_cells[jidx], pallas=probe_pallas))
                 pv_idx = bt.pay_valid_flat_idx()
                 if op.join_type in ("right_semi", "right_anti"):
                     neg = op.join_type == "right_anti"
@@ -1705,7 +1515,8 @@ class TpuStageExec(ExecutionPlan):
                         saved_fns, saved_meta = list(ctx.env_fns), list(ctx.env_meta)
                         combined_schema = op.left.df_schema.merge(cur_schema)
                         for d in range(bt.dup):
-                            finder_d = _mk_join_finder(off, probe_fns, bt, {"d": d})
+                            finder_d = _scoped(probe_scope, _mk_join_finder(
+                                off, probe_fns, bt, {"d": d}))
                             gfns, gmeta = [], []
                             for ci, pp in enumerate(bt.pay_pos):
                                 if pp is None:
@@ -1741,7 +1552,7 @@ class TpuStageExec(ExecutionPlan):
                 if jidx == mult_jidx:
                     # aggregate-through-join: ONE count gather replaces all
                     # dup match lanes; build columns are never materialized
-                    counter = _mk_join_counter(off, probe_fns, bt)
+                    counter = _scoped(probe_scope, _mk_join_counter(off, probe_fns, bt))
                     if op.join_type == "inner":
                         filter_fns.append(
                             lambda cols, luts, _c=counter:
@@ -2025,6 +1836,12 @@ class TpuStageExec(ExecutionPlan):
             meta_holder["pallas_used"] = True
             return outs_lane, counts
 
+        # each device operation's metadata says which operator span it came from
+        eval_pred = _scoped("filter", eval_pred)
+        eval_proj = _scoped("project", eval_proj)
+        aggregate_lane = _scoped("partial_agg", aggregate_lane)
+        pallas_lane = _scoped("partial_agg", pallas_lane)
+
         staged_ok = (
             mode_req == "staged" and len(lane_sets) == 1
             and mult_weight_fn is None
@@ -2099,6 +1916,10 @@ class TpuStageExec(ExecutionPlan):
                     nullcnts = [p_ + c_ for p_, c_ in zip(nullcnts, nullcnt_lane)]
             return tuple(outs) + tuple(nullcnts) + (presence,)
 
+        # a stable name from what the stage is, never a plan hash: the trace
+        # reads jit_stage_partial_direct_fused_xla(..fingerprint)/fusion.N
+        raw.__name__ = raw.__qualname__ = (
+            "stage_partial_direct_" + ("fused_pallas" if use_pallas else "fused_xla"))
         jitted = jax.jit(raw)
         cols_spec = [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in dt.flat_cols()]
         luts0 = ctx.build_luts(dicts, [b.dicts for b in builds])
@@ -2181,6 +2002,9 @@ class TpuStageExec(ExecutionPlan):
         # single expansion lane (the staged gate): pin the lane cells once
         for cell, d_ in zip(ctx.lane_cells, ctx.lane_sets[0]):
             cell["d"] = d_
+        pred_raw.__name__ = pred_raw.__qualname__ = "stage_pred"
+        proj_raw.__name__ = proj_raw.__qualname__ = "stage_proj"
+        agg_raw.__name__ = agg_raw.__qualname__ = "stage_agg"
         jp = jax.jit(pred_raw)
         jproj = jax.jit(proj_raw)
         jagg = jax.jit(agg_raw)
@@ -2202,16 +2026,16 @@ class TpuStageExec(ExecutionPlan):
         jax.eval_shape(agg_raw, mask_spec, pv_spec)
 
         def staged_fn(cols, luts, mask, build_args, span_s=None):
-            t0 = time.time()
+            t0 = time.perf_counter()
             m = jp(cols, luts, mask, build_args)
             jax.block_until_ready(m)
-            t1 = time.time()
+            t1 = time.perf_counter()
             pv = jproj(cols, luts, mask, build_args)
             jax.block_until_ready(pv)
-            t2 = time.time()
+            t2 = time.perf_counter()
             outs = jagg(m, pv)
             jax.block_until_ready(list(outs))
-            t3 = time.time()
+            t3 = time.perf_counter()
             if span_s is not None:
                 span_s["predicate"] = t1 - t0
                 span_s["project"] = t2 - t1
@@ -2315,238 +2139,243 @@ class TpuStageExec(ExecutionPlan):
                 for cell, d_ in zip(lane_cells, lane):
                     cell["d"] = d_
                 m = mask
-                for ff in filter_fns:
-                    m = m & true_mask(ff(cols, luts))
+                with jax.named_scope("filter"):
+                    for ff in filter_fns:
+                        m = m & true_mask(ff(cols, luts))
                 lane_valid.append(m.reshape(-1))
-                keyops = []  # flat key operand list
-                key_meta = []  # per key: (kind, scale, slot, has_null)
-                key_narrow = []  # per key OPERAND: orders as int32
-                for gf, slot in zip(group_fns, key_slots):
-                    v = gf(cols, luts)
-                    if v.kind == "f64":
-                        raise Unsupported("f64 group key")
-                    if v.kind == "code" and slot is None:
-                        raise Unsupported("code group key without a dictionary slot")
-                    arr = v.arr
-                    if arr.dtype == jnp.bool_:
-                        arr = arr.astype(jnp.int32)
-                    has_null = v.valid is not None
-                    if has_null:
-                        marker = jnp.broadcast_to(~v.valid, mask.shape).reshape(-1)
-                        keyops.append(marker.astype(jnp.int32))
-                        key_narrow.append(True)
-                    keyops.append(jnp.broadcast_to(arr, mask.shape).reshape(-1))
-                    key_narrow.append(slot_fits_int32(slot))
-                    key_meta.append((v.kind, v.scale, slot, has_null))
-                meta_holder["key_meta"] = key_meta
-                lane_keyops.append(keyops)
-                w_b = m_eff = None
-                if mult is not None:
-                    wfn, mouter = mult
-                    w_b = jnp.broadcast_to(wfn(cols, luts), mask.shape)
-                    m_eff = jnp.maximum(w_b, 1) if mouter else w_b
-                # payload plan: per agg → (pay_idx|None, ncnt_idx|None)
-                pays = []
-                pay_plan = []
-                out_meta = []
-                # the welford (mean, m2) pair shares one Cast expr object:
-                # ship its value/validity lanes through the sort ONCE
-                welford_pay: dict[int, tuple] = {}
-                for ai, (d, af) in enumerate(zip(aggs, agg_fns)):
-                    if agg_modes is not None and agg_modes[ai] == "build_cnt":
-                        # count of a mult-join build column == match count
-                        out_meta.append(("i64", 0))
-                        pays.append(w_b.reshape(-1).astype(jnp.int64))
-                        pay_plan.append((len(pays) - 1, None))
-                        continue
-                    v = af(cols, luts) if af is not None else None
-                    if d.func in ("count", "count_all"):
-                        out_meta.append(("i64", 0))
-                        if v is None or v.valid is None:
-                            if m_eff is None:
-                                pay_plan.append((None, None))  # segment length
-                            else:
-                                pays.append(m_eff.reshape(-1).astype(jnp.int64))
-                                pay_plan.append((len(pays) - 1, None))
-                        else:
-                            # count(x): number of non-null x per group (each
-                            # probe row weighted by its join multiplicity)
-                            vb = jnp.broadcast_to(v.valid, mask.shape)
-                            cnt1 = m_eff if m_eff is not None else 1
-                            pays.append(jnp.where(vb, cnt1, 0)
-                                        .reshape(-1).astype(jnp.int64))
+                with jax.named_scope("project"):
+                    keyops = []  # flat key operand list
+                    key_meta = []  # per key: (kind, scale, slot, has_null)
+                    key_narrow = []  # per key OPERAND: orders as int32
+                    for gf, slot in zip(group_fns, key_slots):
+                        v = gf(cols, luts)
+                        if v.kind == "f64":
+                            raise Unsupported("f64 group key")
+                        if v.kind == "code" and slot is None:
+                            raise Unsupported("code group key without a dictionary slot")
+                        arr = v.arr
+                        if arr.dtype == jnp.bool_:
+                            arr = arr.astype(jnp.int32)
+                        has_null = v.valid is not None
+                        if has_null:
+                            marker = jnp.broadcast_to(~v.valid, mask.shape).reshape(-1)
+                            keyops.append(marker.astype(jnp.int32))
+                            key_narrow.append(True)
+                        keyops.append(jnp.broadcast_to(arr, mask.shape).reshape(-1))
+                        key_narrow.append(slot_fits_int32(slot))
+                        key_meta.append((v.kind, v.scale, slot, has_null))
+                    meta_holder["key_meta"] = key_meta
+                    lane_keyops.append(keyops)
+                    w_b = m_eff = None
+                    if mult is not None:
+                        wfn, mouter = mult
+                        w_b = jnp.broadcast_to(wfn(cols, luts), mask.shape)
+                        m_eff = jnp.maximum(w_b, 1) if mouter else w_b
+                    # payload plan: per agg → (pay_idx|None, ncnt_idx|None)
+                    pays = []
+                    pay_plan = []
+                    out_meta = []
+                    # the welford (mean, m2) pair shares one Cast expr object:
+                    # ship its value/validity lanes through the sort ONCE
+                    welford_pay: dict[int, tuple] = {}
+                    for ai, (d, af) in enumerate(zip(aggs, agg_fns)):
+                        if agg_modes is not None and agg_modes[ai] == "build_cnt":
+                            # count of a mult-join build column == match count
+                            out_meta.append(("i64", 0))
+                            pays.append(w_b.reshape(-1).astype(jnp.int64))
                             pay_plan.append((len(pays) - 1, None))
-                        continue
-                    if (d.func in ("welford_mean", "welford_m2")
-                            and id(d.expr) in welford_pay):
-                        out_meta.append(("f64", 0))
-                        pay_plan.append(welford_pay[id(d.expr)])
-                        continue
-                    out_meta.append((v.kind, v.scale))
-                    arr = v.arr
-                    if m_eff is not None and d.func == "sum":
-                        arr = arr * m_eff.astype(arr.dtype)
-                    ncnt_idx = None
-                    if v.valid is not None:
-                        # null-skip: neutralize invalid slots for the reduce,
-                        # and carry a valid-count so all-NULL groups decode
-                        # to NULL rather than 0 / ±inf
-                        if d.func in ("sum", "welford_mean", "welford_m2"):
-                            neutral = jnp.zeros((), dtype=arr.dtype)
-                        elif d.func == "min":
-                            neutral = (jnp.iinfo(arr.dtype).max
-                                       if jnp.issubdtype(arr.dtype, jnp.integer) else jnp.inf)
-                        else:
-                            neutral = (jnp.iinfo(arr.dtype).min
-                                       if jnp.issubdtype(arr.dtype, jnp.integer) else -jnp.inf)
-                        arr = jnp.where(v.valid, arr, neutral)
-                        pays.append(jnp.broadcast_to(
-                            v.valid, mask.shape).reshape(-1).astype(jnp.int64))
-                        ncnt_idx = len(pays) - 1
-                    pays.append(jnp.broadcast_to(arr, mask.shape).reshape(-1))
-                    pay_plan.append((len(pays) - 1, ncnt_idx))
-                    if d.func in ("welford_mean", "welford_m2"):
-                        welford_pay[id(d.expr)] = pay_plan[-1]
-                meta_holder["out"] = out_meta
-                meta_holder["pay_plan"] = pay_plan
-                lane_pays.append(pays)
+                            continue
+                        v = af(cols, luts) if af is not None else None
+                        if d.func in ("count", "count_all"):
+                            out_meta.append(("i64", 0))
+                            if v is None or v.valid is None:
+                                if m_eff is None:
+                                    pay_plan.append((None, None))  # segment length
+                                else:
+                                    pays.append(m_eff.reshape(-1).astype(jnp.int64))
+                                    pay_plan.append((len(pays) - 1, None))
+                            else:
+                                # count(x): number of non-null x per group (each
+                                # probe row weighted by its join multiplicity)
+                                vb = jnp.broadcast_to(v.valid, mask.shape)
+                                cnt1 = m_eff if m_eff is not None else 1
+                                pays.append(jnp.where(vb, cnt1, 0)
+                                            .reshape(-1).astype(jnp.int64))
+                                pay_plan.append((len(pays) - 1, None))
+                            continue
+                        if (d.func in ("welford_mean", "welford_m2")
+                                and id(d.expr) in welford_pay):
+                            out_meta.append(("f64", 0))
+                            pay_plan.append(welford_pay[id(d.expr)])
+                            continue
+                        out_meta.append((v.kind, v.scale))
+                        arr = v.arr
+                        if m_eff is not None and d.func == "sum":
+                            arr = arr * m_eff.astype(arr.dtype)
+                        ncnt_idx = None
+                        if v.valid is not None:
+                            # null-skip: neutralize invalid slots for the reduce,
+                            # and carry a valid-count so all-NULL groups decode
+                            # to NULL rather than 0 / ±inf
+                            if d.func in ("sum", "welford_mean", "welford_m2"):
+                                neutral = jnp.zeros((), dtype=arr.dtype)
+                            elif d.func == "min":
+                                neutral = (jnp.iinfo(arr.dtype).max
+                                           if jnp.issubdtype(arr.dtype, jnp.integer) else jnp.inf)
+                            else:
+                                neutral = (jnp.iinfo(arr.dtype).min
+                                           if jnp.issubdtype(arr.dtype, jnp.integer) else -jnp.inf)
+                            arr = jnp.where(v.valid, arr, neutral)
+                            pays.append(jnp.broadcast_to(
+                                v.valid, mask.shape).reshape(-1).astype(jnp.int64))
+                            ncnt_idx = len(pays) - 1
+                        pays.append(jnp.broadcast_to(arr, mask.shape).reshape(-1))
+                        pay_plan.append((len(pays) - 1, ncnt_idx))
+                        if d.func in ("welford_mean", "welford_m2"):
+                            welford_pay[id(d.expr)] = pay_plan[-1]
+                    meta_holder["out"] = out_meta
+                    meta_holder["pay_plan"] = pay_plan
+                    lane_pays.append(pays)
 
-            valid = jnp.concatenate(lane_valid)
-            n_keyops = len(lane_keyops[0])
-            cat_keys = [
-                jnp.concatenate([lk[i] for lk in lane_keyops]) for i in range(n_keyops)
-            ]
-            cat_pays = [
-                jnp.concatenate([lp[i] for lp in lane_pays])
-                for i in range(len(lane_pays[0]))
-            ]
-            perm = lex_order([~valid] + [
-                k.astype(jnp.int32) if fits and k.dtype == jnp.int64 else k
-                for k, fits in zip(cat_keys, key_narrow)])
-            svalid = valid[perm]
-            skeys = [k[perm] for k in cat_keys]
-            spays = [p[perm] for p in cat_pays]
+            with jax.named_scope("sorted_agg"):
+                valid = jnp.concatenate(lane_valid)
+                n_keyops = len(lane_keyops[0])
+                cat_keys = [
+                    jnp.concatenate([lk[i] for lk in lane_keyops]) for i in range(n_keyops)
+                ]
+                cat_pays = [
+                    jnp.concatenate([lp[i] for lp in lane_pays])
+                    for i in range(len(lane_pays[0]))
+                ]
+                perm = lex_order([~valid] + [
+                    k.astype(jnp.int32) if fits and k.dtype == jnp.int64 else k
+                    for k, fits in zip(cat_keys, key_narrow)])
+                svalid = valid[perm]
+                skeys = [k[perm] for k in cat_keys]
+                spays = [p[perm] for p in cat_pays]
 
-            diff = jnp.zeros((M,), bool).at[0].set(True)
-            for k in skeys:
-                diff = diff | jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
-            boundary = svalid & diff
-            seg = int_cumsum(boundary.astype(jnp.int32)) - 1
-            bor_inv = boundary | ~svalid
-            is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
-            n_seg = boundary.sum().astype(jnp.int32)
+                diff = jnp.zeros((M,), bool).at[0].set(True)
+                for k in skeys:
+                    diff = diff | jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+                boundary = svalid & diff
+                seg = int_cumsum(boundary.astype(jnp.int32)) - 1
+                bor_inv = boundary | ~svalid
+                is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
+                n_seg = boundary.sum().astype(jnp.int32)
 
-            arange = jnp.arange(M, dtype=jnp.int32)
-            # segment-start position of each row's segment, via one scatter
-            # + gather (indices unique: one boundary row per segment)
-            spos = (
-                jnp.zeros((C,), jnp.int32)
-                .at[jnp.where(boundary, seg, C)]
-                .set(arange, mode="drop", unique_indices=True)
-            )
-            start = spos[jnp.clip(seg, 0, C - 1)]
-            end_idx = jnp.where(is_end, seg, C)
-
-            def compact(src):
-                return (
-                    jnp.zeros((C,), src.dtype)
-                    .at[end_idx]
-                    .set(src, mode="drop", unique_indices=True)
+                arange = jnp.arange(M, dtype=jnp.int32)
+                # segment-start position of each row's segment, via one scatter
+                # + gather (indices unique: one boundary row per segment)
+                spos = (
+                    jnp.zeros((C,), jnp.int32)
+                    .at[jnp.where(boundary, seg, C)]
+                    .set(arange, mode="drop", unique_indices=True)
                 )
+                start = spos[jnp.clip(seg, 0, C - 1)]
+                end_idx = jnp.where(is_end, seg, C)
 
-            def int_segsum(sv):
-                # exact int64: global cumsum minus prefix-at-segment-start
-                w = sv.astype(jnp.int64)
-                csum = int_cumsum(w)
-                presum = csum - w  # exclusive
-                return compact(csum - presum[start])
+                def compact(src):
+                    return (
+                        jnp.zeros((C,), src.dtype)
+                        .at[end_idx]
+                        .set(src, mode="drop", unique_indices=True)
+                    )
 
-            key_outs = [compact(k) for k in skeys]
-            agg_outs = []
-            ncnt_outs = []
-            ncnt_map: dict[int, int] = {}
-            welford_stats: dict[int, tuple] = {}  # pay_idx → (c_c, mean_c, ncnt_pos)
-            for ai, (d, (pay_idx, ncnt_idx)) in enumerate(
-                zip(aggs, meta_holder["pay_plan"])
-            ):
-                if pay_idx is None:
-                    agg_outs.append(compact((arange - start + 1).astype(jnp.int64)))
-                    continue
-                sv = spays[pay_idx]
-                if d.func in ("welford_mean", "welford_m2"):
-                    # two-pass variance partial over sorted segments: segment
-                    # mean via float segscan, then gather the mean back per
-                    # row (seg indexes the compacted [C] space) for the
-                    # centered square sum — stable, no cancellation. The
-                    # (mean, m2) pair shares payload lanes and stats.
-                    if pay_idx in welford_stats:
-                        c_c, mean_c, ncnt_pos = welford_stats[pay_idx]
-                    else:
-                        if ncnt_idx is not None:
-                            c_c = int_segsum(spays[ncnt_idx])
+                def int_segsum(sv):
+                    # exact int64: global cumsum minus prefix-at-segment-start
+                    w = sv.astype(jnp.int64)
+                    csum = int_cumsum(w)
+                    presum = csum - w  # exclusive
+                    return compact(csum - presum[start])
+
+                key_outs = [compact(k) for k in skeys]
+                agg_outs = []
+                ncnt_outs = []
+                ncnt_map: dict[int, int] = {}
+                welford_stats: dict[int, tuple] = {}  # pay_idx → (c_c, mean_c, ncnt_pos)
+                for ai, (d, (pay_idx, ncnt_idx)) in enumerate(
+                    zip(aggs, meta_holder["pay_plan"])
+                ):
+                    if pay_idx is None:
+                        agg_outs.append(compact((arange - start + 1).astype(jnp.int64)))
+                        continue
+                    sv = spays[pay_idx]
+                    if d.func in ("welford_mean", "welford_m2"):
+                        # two-pass variance partial over sorted segments: segment
+                        # mean via float segscan, then gather the mean back per
+                        # row (seg indexes the compacted [C] space) for the
+                        # centered square sum — stable, no cancellation. The
+                        # (mean, m2) pair shares payload lanes and stats.
+                        if pay_idx in welford_stats:
+                            c_c, mean_c, ncnt_pos = welford_stats[pay_idx]
                         else:
-                            c_c = compact((arange - start + 1).astype(jnp.int64))
-                        s1_c = compact(_segscan(jnp, sv, boundary, "sum"))
-                        mean_c = s1_c / jnp.maximum(c_c, 1).astype(sv.dtype)
-                        ncnt_pos = None
-                        if ncnt_idx is not None:
-                            ncnt_pos = len(ncnt_outs)
-                            ncnt_outs.append(c_c)
-                        welford_stats[pay_idx] = (c_c, mean_c, ncnt_pos)
-                    if d.func == "welford_mean":
-                        agg_outs.append(mean_c)
+                            if ncnt_idx is not None:
+                                c_c = int_segsum(spays[ncnt_idx])
+                            else:
+                                c_c = compact((arange - start + 1).astype(jnp.int64))
+                            s1_c = compact(_segscan(jnp, sv, boundary, "sum"))
+                            mean_c = s1_c / jnp.maximum(c_c, 1).astype(sv.dtype)
+                            ncnt_pos = None
+                            if ncnt_idx is not None:
+                                ncnt_pos = len(ncnt_outs)
+                                ncnt_outs.append(c_c)
+                            welford_stats[pay_idx] = (c_c, mean_c, ncnt_pos)
+                        if d.func == "welford_mean":
+                            agg_outs.append(mean_c)
+                        else:
+                            mean_row = mean_c[jnp.clip(seg, 0, C - 1)]
+                            d2 = (sv - mean_row) ** 2
+                            if ncnt_idx is not None:
+                                # null x slots were sum-neutralized to 0; keep
+                                # them out of the square sum too
+                                d2 = jnp.where(spays[ncnt_idx] > 0, d2, 0.0)
+                            agg_outs.append(compact(_segscan(jnp, d2, boundary, "sum")))
+                        if ncnt_pos is not None:
+                            ncnt_map[ai] = ncnt_pos
+                        continue
+                    fname = "sum" if d.func in ("count", "count_all") else d.func
+                    if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
+                        agg_outs.append(int_segsum(sv))
                     else:
-                        mean_row = mean_c[jnp.clip(seg, 0, C - 1)]
-                        d2 = (sv - mean_row) ** 2
-                        if ncnt_idx is not None:
-                            # null x slots were sum-neutralized to 0; keep
-                            # them out of the square sum too
-                            d2 = jnp.where(spays[ncnt_idx] > 0, d2, 0.0)
-                        agg_outs.append(compact(_segscan(jnp, d2, boundary, "sum")))
-                    if ncnt_pos is not None:
-                        ncnt_map[ai] = ncnt_pos
-                    continue
-                fname = "sum" if d.func in ("count", "count_all") else d.func
-                if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
-                    agg_outs.append(int_segsum(sv))
-                else:
-                    # float sums use the segmented scan too: cumsum-subtract
-                    # would difference two near-equal whole-table totals
-                    # (catastrophic cancellation for small late segments)
-                    agg_outs.append(compact(_segscan(jnp, sv, boundary, fname)))
-                if ncnt_idx is not None:
-                    ncnt_map[ai] = len(ncnt_outs)
-                    ncnt_outs.append(int_segsum(spays[ncnt_idx]))
-            meta_holder["nullcnt_map"] = ncnt_map
+                        # float sums use the segmented scan too: cumsum-subtract
+                        # would difference two near-equal whole-table totals
+                        # (catastrophic cancellation for small late segments)
+                        agg_outs.append(compact(_segscan(jnp, sv, boundary, fname)))
+                    if ncnt_idx is not None:
+                        ncnt_map[ai] = len(ncnt_outs)
+                        ncnt_outs.append(int_segsum(spays[ncnt_idx]))
+                meta_holder["nullcnt_map"] = ncnt_map
 
             if emit_keys is not None:
                 from ballista_tpu.ops.tpu.kernels import hash64, hash_combine_jax
 
-                # key_outs layout: optional marker precedes each nullable
-                # key's value — build a key→(marker, value) position map
-                pos = 0
-                key_pos = []
-                for (_k, _s, _slot, hn) in meta_holder["key_meta"]:
-                    key_pos.append((pos if hn else None, pos + (1 if hn else 0)))
-                    pos += 2 if hn else 1
-                _NULL_TAG = jnp.uint64(0x9E3779B97F4A7C15)
-                h = jnp.zeros((C,), jnp.uint64)
-                for ki in emit_keys:
-                    kind, scale, slot, _hn = meta_holder["key_meta"][ki]
-                    mpos, vpos = key_pos[ki]
-                    arr = key_outs[vpos]
-                    if kind == "code":
-                        enc = luts[emit_luts[ki]][arr]
-                    else:  # i64 / date / bool — value-preserving int64 bits
-                        enc = arr.astype(jnp.int64).astype(jnp.uint64)
-                    hv = hash64(enc)
-                    if mpos is not None:
-                        hv = jnp.where(key_outs[mpos] != 0, _NULL_TAG, hv)
-                    h = hash_combine_jax(h, hv)
-                pid = (h % jnp.uint64(emit_k)).astype(jnp.int32)
+                with jax.named_scope("emit"):
+                    # key_outs layout: optional marker precedes each nullable
+                    # key's value — build a key→(marker, value) position map
+                    pos = 0
+                    key_pos = []
+                    for (_k, _s, _slot, hn) in meta_holder["key_meta"]:
+                        key_pos.append((pos if hn else None, pos + (1 if hn else 0)))
+                        pos += 2 if hn else 1
+                    _NULL_TAG = jnp.uint64(0x9E3779B97F4A7C15)
+                    h = jnp.zeros((C,), jnp.uint64)
+                    for ki in emit_keys:
+                        kind, scale, slot, _hn = meta_holder["key_meta"][ki]
+                        mpos, vpos = key_pos[ki]
+                        arr = key_outs[vpos]
+                        if kind == "code":
+                            enc = luts[emit_luts[ki]][arr]
+                        else:  # i64 / date / bool — value-preserving int64 bits
+                            enc = arr.astype(jnp.int64).astype(jnp.uint64)
+                        hv = hash64(enc)
+                        if mpos is not None:
+                            hv = jnp.where(key_outs[mpos] != 0, _NULL_TAG, hv)
+                        h = hash_combine_jax(h, hv)
+                    pid = (h % jnp.uint64(emit_k)).astype(jnp.int32)
                 return tuple(key_outs) + tuple(agg_outs) + tuple(ncnt_outs) + (pid, n_seg)
             return tuple(key_outs) + tuple(agg_outs) + tuple(ncnt_outs) + (n_seg,)
 
+        raw.__name__ = raw.__qualname__ = "stage_partial_sorted_fused_xla"
         jitted = jax.jit(raw)
         cols_spec = [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in dt.flat_cols()]
         luts0 = ctx.build_luts(dt.dicts, [b.dicts for b in builds])
@@ -2569,6 +2398,18 @@ class TpuStageExec(ExecutionPlan):
 
     # ------------------------------------------------------------------
 
+    def _fetch_decode(self, outs, meta: dict, P: int, dicts,
+                      build_dicts: list) -> dict[int, list[pa.RecordBatch]]:
+        """Device outputs to Arrow batches per partition. The sorted path
+        fetches inside its decode (a count first, then a sliced fetch)."""
+        if meta["mode"] == "sorted":
+            with RUN_STATS.span("bt.decode", mode="sorted"):
+                return self._decode_sorted(outs, meta, P, dicts, build_dicts)
+        with RUN_STATS.span("bt.device.fetch"):
+            outs = ensure_jax().device_get(list(outs))  # ONE batched fetch
+        with RUN_STATS.span("bt.decode"):
+            return self._decode_all(outs, meta, P, dicts, build_dicts)
+
     def _decode_sorted(self, outs, meta: dict, P: int, dicts,
                        build_dicts: list) -> dict[int, list[pa.RecordBatch]]:
         """Decode the sorted-path compacted outputs. Partial-agg results are
@@ -2581,7 +2422,8 @@ class TpuStageExec(ExecutionPlan):
         n_keys = len(key_meta)
         n_keyops = sum(2 if km[3] else 1 for km in key_meta)
         C = meta["C"]
-        n = int(jax.device_get(outs[-1]))
+        with RUN_STATS.span("bt.device.fetch", what="count"):
+            n = int(jax.device_get(outs[-1]))
         if n > C:
             raise Unsupported(f"group capacity overflow ({n} > {C})")
         results = {p: [_empty_batch(schema)] for p in range(P)}
@@ -2593,8 +2435,9 @@ class TpuStageExec(ExecutionPlan):
             pid_out = data_outs[-1]
             data_outs = data_outs[:-1]
         cp = min(_pow2(n), C)  # sliced fetch: pay for actual groups only
-        host = jax.device_get([o[:cp] for o in data_outs])
-        pid_host = jax.device_get(pid_out[:cp]) if pid_out is not None else None
+        with RUN_STATS.span("bt.device.fetch", rows=n):
+            host = jax.device_get([o[:cp] for o in data_outs])
+            pid_host = jax.device_get(pid_out[:cp]) if pid_out is not None else None
         nullcnt_map = meta.get("nullcnt_map", {})
         n_aggs = len(meta["out"])
         ncnt_host = host[n_keyops + n_aggs:]
@@ -2838,6 +2681,19 @@ def _pow2(n: int) -> int:
     while p < max(n, 1):
         p *= 2
     return p
+
+
+def _scoped(name: str, fn):
+    """`fn` traced under `jax.named_scope(name)`. Metadata only: every device
+    operation then says which operator span of the stage it came from
+    (`scan_decode`, `filter`, `project`, `join_probe_<i>`, `partial_agg`,
+    `sorted_agg`, `emit`), in the profiler's trace and in HLO dumps."""
+
+    def run(*args):
+        with ensure_jax().named_scope(name):
+            return fn(*args)
+
+    return run
 
 
 def _mk_col_reader(i: int, kind: str, scale: int, dictionary, valid_idx=None):

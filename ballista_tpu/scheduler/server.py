@@ -66,6 +66,7 @@ from ballista_tpu.serving.normalize import (
     config_fingerprint,
     lift_parameters,
 )
+from ballista_tpu.tracing import RUN_STATS, now_ns
 from ballista_tpu.serving.tier import (
     PlanTemplate,
     PreparedStatement,
@@ -124,6 +125,9 @@ class Event:
     # stamped at post time; dequeue-time minus this is the event-loop lag
     # that feeds the overload state machine
     posted_at: float = field(default_factory=time.monotonic)
+    # the same moment on the span clock: a task_update's `bt.task.report`
+    # starts when the executor handed the result over, not at the dequeue
+    posted_ns: int = field(default_factory=now_ns)
 
 
 @dataclass
@@ -281,7 +285,8 @@ class SchedulerServer:
                 by_shard.setdefault(shard_of(r.job_id, self.num_shards), []).append(r)
             for idx, rs in by_shard.items():
                 self._shards[idx].post(
-                    Event("task_update", (executor_id, rs), posted_at=ev.posted_at))
+                    Event("task_update", (executor_id, rs), posted_at=ev.posted_at,
+                          posted_ns=ev.posted_ns))
         else:
             self._fanin["events_multicast"] += 1
             for sh in self._shards:
@@ -299,8 +304,13 @@ class SchedulerServer:
             self._offer_reservation(shard)
         elif ev.kind == "task_update":
             executor_id, results = ev.payload
-            self._apply_task_updates(executor_id, results)
-            self._offer_reservation(shard)
+            # executor -> scheduler: from the result handed over until the
+            # next tasks are offered (an event carries one result, rarely more)
+            r = results[0]
+            with RUN_STATS.span("bt.task.report", start_ns=ev.posted_ns, job=r.job_id,
+                                stage=r.stage_id, task=r.task_id, results=len(results)):
+                self._apply_task_updates(executor_id, results)
+                self._offer_reservation(shard)
             # the completions above freed slots OTHER shards' starved jobs
             # may be waiting on, and those shards see no event for it.
             # Nudge idle peers ONLY while slots stay free after our own
@@ -399,68 +409,69 @@ class SchedulerServer:
             lane = LANE_INTERACTIVE
         self._admit_or_shed(session_id, job_id, lane=lane)
         self.metrics.record_submitted(job_id)
-        t0 = time.time()
         try:
-            if hit is not None:
-                key, values, template = hit
-                self.metrics.record_plan_cache(True)
-                template.hits += 1
-            else:
-                stmt = parse_sql(sql)
-                if not isinstance(stmt, SelectStmt):
-                    # DDL / utility statements take the legacy queued path
-                    # (the planning context handles them); catalog-visible
-                    # DDL orphans the table's cached results
-                    if isinstance(stmt, (CreateExternalTable, DropTable)):
-                        self._on_catalog_change(stmt.name.lower())
-                    return self._enqueue_legacy_sql(job_id, sql, session_id, job_name)
-                ctx = self.sessions.create_planning_context(session_id)
-                optimized = optimize(SqlPlanner(ctx.catalog).plan_query(stmt))
-                lift = lift_parameters(optimized)
-                if not lift.cacheable:
-                    self.serving.note_uncacheable()
-                    log.debug("job %s uncacheable (%s); planning directly", job_id, lift.reason)
-                    physical = PhysicalPlanner(cfg).plan(optimized)
-                    self.metrics.record_planning_ms(job_id, (time.time() - t0) * 1000)
-                    return self._dispatch_serving(job_id, job_name, session_id, cfg,
-                                                  physical, None, (), inline_results)
-                key = f"{lift.key}:{cfg_fp}"
-                values = lift.values
-                template = self.serving.lookup_template(key, values)
-                self.metrics.record_plan_cache(template is not None)
-                if template is None:
-                    tagged_physical = PhysicalPlanner(cfg).plan(lift.tagged)
-                    bindable = set(range(len(values))) <= collect_physical_params(tagged_physical)
-                    template = PlanTemplate(key=key, physical=tagged_physical,
-                                            type_tags=lift.type_tags, values=values,
-                                            tables=lift.tables, bindable=bindable)
-                    self.serving.store_template(template)
-                self.serving.remember_text(sql, cfg_fp, key, values)
-            if (bool(cfg.get(SERVING_RESULT_CACHE)) and inline_results):
-                rkey = self.serving.result_key(template.key, values, template.tables)
-                cached = self.serving.lookup_result(rkey)
-                self.metrics.record_result_cache(cached is not None)
-                if cached is not None:
-                    job = FastJob(job_id, job_name, session_id, cfg, inline_result=cached)
-                    with self._jobs_lock:
-                        self.jobs[job_id] = job
-                    self.metrics.record_completed(job_id, 0.0)
-                    self._notify(job_id)
-                    return job_id
-            else:
-                rkey = None
-            bound = bind_physical(template.physical, values)
-            physical, fill = self._incremental_or_plain(template, values, bound,
-                                                        rkey, cfg)
-            self.metrics.record_planning_ms(job_id, (time.time() - t0) * 1000)
-            if physical is None:
-                # cached state already covers the current versions
-                self.serving.store_result(rkey, fill.inline_result)
-                return self._serve_inline(job_id, job_name, session_id, cfg,
-                                          fill.inline_result)
-            return self._dispatch_serving(job_id, job_name, session_id, cfg,
-                                          physical, template, values,
-                                          inline_results, fill=fill)
+            with RUN_STATS.span("bt.sched.plan", job=job_id,
+                                plan_cache_hit=int(hit is not None)) as plan_span:
+                if hit is not None:
+                    key, values, template = hit
+                    self.metrics.record_plan_cache(True)
+                    template.hits += 1
+                else:
+                    stmt = parse_sql(sql)
+                    if not isinstance(stmt, SelectStmt):
+                        # DDL / utility statements take the legacy queued path
+                        # (the planning context handles them); catalog-visible
+                        # DDL orphans the table's cached results
+                        if isinstance(stmt, (CreateExternalTable, DropTable)):
+                            self._on_catalog_change(stmt.name.lower())
+                        return self._enqueue_legacy_sql(job_id, sql, session_id, job_name)
+                    ctx = self.sessions.create_planning_context(session_id)
+                    optimized = optimize(SqlPlanner(ctx.catalog).plan_query(stmt))
+                    lift = lift_parameters(optimized)
+                    if not lift.cacheable:
+                        self.serving.note_uncacheable()
+                        log.debug("job %s uncacheable (%s); planning directly", job_id, lift.reason)
+                        physical = PhysicalPlanner(cfg).plan(optimized)
+                        self.metrics.record_planning_ms(job_id, plan_span.seconds * 1000)
+                        return self._dispatch_serving(job_id, job_name, session_id, cfg,
+                                                      physical, None, (), inline_results)
+                    key = f"{lift.key}:{cfg_fp}"
+                    values = lift.values
+                    template = self.serving.lookup_template(key, values)
+                    self.metrics.record_plan_cache(template is not None)
+                    if template is None:
+                        tagged_physical = PhysicalPlanner(cfg).plan(lift.tagged)
+                        bindable = set(range(len(values))) <= collect_physical_params(tagged_physical)
+                        template = PlanTemplate(key=key, physical=tagged_physical,
+                                                type_tags=lift.type_tags, values=values,
+                                                tables=lift.tables, bindable=bindable)
+                        self.serving.store_template(template)
+                    self.serving.remember_text(sql, cfg_fp, key, values)
+                if (bool(cfg.get(SERVING_RESULT_CACHE)) and inline_results):
+                    rkey = self.serving.result_key(template.key, values, template.tables)
+                    cached = self.serving.lookup_result(rkey)
+                    self.metrics.record_result_cache(cached is not None)
+                    if cached is not None:
+                        job = FastJob(job_id, job_name, session_id, cfg, inline_result=cached)
+                        with self._jobs_lock:
+                            self.jobs[job_id] = job
+                        self.metrics.record_completed(job_id, 0.0)
+                        self._notify(job_id)
+                        return job_id
+                else:
+                    rkey = None
+                bound = bind_physical(template.physical, values)
+                physical, fill = self._incremental_or_plain(template, values, bound,
+                                                            rkey, cfg)
+                self.metrics.record_planning_ms(job_id, plan_span.seconds * 1000)
+                if physical is None:
+                    # cached state already covers the current versions
+                    self.serving.store_result(rkey, fill.inline_result)
+                    return self._serve_inline(job_id, job_name, session_id, cfg,
+                                              fill.inline_result)
+                return self._dispatch_serving(job_id, job_name, session_id, cfg,
+                                              physical, template, values,
+                                              inline_results, fill=fill)
         except BaseException as e:  # noqa: BLE001 — same contract as _plan_job
             log.warning("serving submit failed for %s: %s", job_id, e, exc_info=True)
             with self._jobs_lock:
@@ -827,53 +838,54 @@ class SchedulerServer:
         lane = LANE_INTERACTIVE if (peek is not None and peek.single_stage) else LANE_BATCH
         self._admit_or_shed(sid, job_id, lane=lane)
         self.metrics.record_submitted(job_id)
-        t0 = time.time()
         try:
-            template = self.serving.lookup_template(stmt.key, values)
-            self.metrics.record_plan_cache(template is not None)
-            if (bool(cfg.get(SERVING_RESULT_CACHE)) and inline_results
-                    and template is not None):
-                rkey = self.serving.result_key(stmt.key, values, template.tables)
-                cached = self.serving.lookup_result(rkey)
-                self.metrics.record_result_cache(cached is not None)
-                if cached is not None:
-                    job = FastJob(job_id, job_name, sid, cfg, inline_result=cached)
-                    with self._jobs_lock:
-                        self.jobs[job_id] = job
-                    self.metrics.record_completed(job_id, 0.0)
-                    self._notify(job_id)
-                    return job_id
-            else:
-                rkey = None
-            if template is not None:
-                bound = bind_physical(template.physical, values)
-            else:
-                # evicted, or non-bindable with new values: re-lift from
-                # the retained SQL and bind at the logical level
-                ctx = self.sessions.create_planning_context(sid)
-                lift = lift_parameters(optimize(
-                    SqlPlanner(ctx.catalog).plan_query(parse_sql(stmt.sql))))
-                if not lift.cacheable or len(lift.values) != len(values):
-                    raise PlanningError(
-                        f"statement {statement_id} no longer parameterizes "
-                        f"the same way ({lift.reason or 'slot count changed'})")
-                bound = PhysicalPlanner(cfg).plan(bind_logical(lift.tagged, values))
-                physical = PhysicalPlanner(cfg).plan(lift.tagged)
-                bindable = set(range(len(values))) <= collect_physical_params(physical)
-                template = PlanTemplate(
-                    key=stmt.key, physical=physical, type_tags=lift.type_tags,
-                    values=lift.values, tables=lift.tables, bindable=bindable)
-                self.serving.store_template(template)
-            physical, fill = self._incremental_or_plain(template, values, bound,
-                                                        rkey, cfg)
-            self.metrics.record_planning_ms(job_id, (time.time() - t0) * 1000)
-            if physical is None:
-                self.serving.store_result(rkey, fill.inline_result)
-                return self._serve_inline(job_id, job_name, sid, cfg,
-                                          fill.inline_result)
-            return self._dispatch_serving(job_id, job_name, sid, cfg, physical,
-                                          template, values, inline_results,
-                                          fill=fill)
+            with RUN_STATS.span("bt.sched.plan", job=job_id) as plan_span:
+                template = self.serving.lookup_template(stmt.key, values)
+                self.metrics.record_plan_cache(template is not None)
+                plan_span.set(plan_cache_hit=int(template is not None))
+                if (bool(cfg.get(SERVING_RESULT_CACHE)) and inline_results
+                        and template is not None):
+                    rkey = self.serving.result_key(stmt.key, values, template.tables)
+                    cached = self.serving.lookup_result(rkey)
+                    self.metrics.record_result_cache(cached is not None)
+                    if cached is not None:
+                        job = FastJob(job_id, job_name, sid, cfg, inline_result=cached)
+                        with self._jobs_lock:
+                            self.jobs[job_id] = job
+                        self.metrics.record_completed(job_id, 0.0)
+                        self._notify(job_id)
+                        return job_id
+                else:
+                    rkey = None
+                if template is not None:
+                    bound = bind_physical(template.physical, values)
+                else:
+                    # evicted, or non-bindable with new values: re-lift from
+                    # the retained SQL and bind at the logical level
+                    ctx = self.sessions.create_planning_context(sid)
+                    lift = lift_parameters(optimize(
+                        SqlPlanner(ctx.catalog).plan_query(parse_sql(stmt.sql))))
+                    if not lift.cacheable or len(lift.values) != len(values):
+                        raise PlanningError(
+                            f"statement {statement_id} no longer parameterizes "
+                            f"the same way ({lift.reason or 'slot count changed'})")
+                    bound = PhysicalPlanner(cfg).plan(bind_logical(lift.tagged, values))
+                    physical = PhysicalPlanner(cfg).plan(lift.tagged)
+                    bindable = set(range(len(values))) <= collect_physical_params(physical)
+                    template = PlanTemplate(
+                        key=stmt.key, physical=physical, type_tags=lift.type_tags,
+                        values=lift.values, tables=lift.tables, bindable=bindable)
+                    self.serving.store_template(template)
+                physical, fill = self._incremental_or_plain(template, values, bound,
+                                                            rkey, cfg)
+                self.metrics.record_planning_ms(job_id, plan_span.seconds * 1000)
+                if physical is None:
+                    self.serving.store_result(rkey, fill.inline_result)
+                    return self._serve_inline(job_id, job_name, sid, cfg,
+                                              fill.inline_result)
+                return self._dispatch_serving(job_id, job_name, sid, cfg, physical,
+                                              template, values, inline_results,
+                                              fill=fill)
         except BaseException as e:  # noqa: BLE001 — same contract as _plan_job
             log.warning("execute_prepared failed for %s: %s", job_id, e, exc_info=True)
             with self._jobs_lock:
@@ -902,32 +914,32 @@ class SchedulerServer:
 
     def _plan_job(self, payload) -> None:
         job_id, kind, body, session_id = payload
-        t0 = time.time()
         try:
-            ctx = self.sessions.create_planning_context(session_id)
-            if kind == "sql":
-                df = ctx.sql(body)
-                physical = ctx.create_physical_plan(df.plan)
-            else:
-                physical = body
-            physical = self._graft_deltas(physical)
-            stages = DistributedPlanner(job_id).plan_query_stages(physical)
-            cfg = self.sessions.get(session_id) or BallistaConfig()
-            from ballista_tpu.scheduler.planner import merge_mesh_stages
+            with RUN_STATS.span("bt.sched.plan", job=job_id, plan_cache_hit=0) as plan_span:
+                ctx = self.sessions.create_planning_context(session_id)
+                if kind == "sql":
+                    df = ctx.sql(body)
+                    physical = ctx.create_physical_plan(df.plan)
+                else:
+                    physical = body
+                physical = self._graft_deltas(physical)
+                stages = DistributedPlanner(job_id).plan_query_stages(physical)
+                cfg = self.sessions.get(session_id) or BallistaConfig()
+                from ballista_tpu.scheduler.planner import merge_mesh_stages
 
-            stages = merge_mesh_stages(stages, cfg)
-            self._maybe_verify_stages(stages, cfg, job_id)
-            old = self.jobs.get(job_id)
-            graph = ExecutionGraph(job_id, old.job_name if old else "", session_id, stages, cfg)
-            with self._jobs_lock:
-                self.jobs[job_id] = graph
-            if self.job_state.acquire(job_id, self.scheduler_id):
-                self.job_state.save_graph(graph)
-            else:
-                # never clobber a peer's checkpoint on an id collision
-                log.warning("job %s is owned by another scheduler; not persisting", job_id)
-            self.metrics.record_planning_ms(job_id, (time.time() - t0) * 1000)
-            self.post(Event("revive", job_id))
+                stages = merge_mesh_stages(stages, cfg)
+                self._maybe_verify_stages(stages, cfg, job_id)
+                old = self.jobs.get(job_id)
+                graph = ExecutionGraph(job_id, old.job_name if old else "", session_id, stages, cfg)
+                with self._jobs_lock:
+                    self.jobs[job_id] = graph
+                if self.job_state.acquire(job_id, self.scheduler_id):
+                    self.job_state.save_graph(graph)
+                else:
+                    # never clobber a peer's checkpoint on an id collision
+                    log.warning("job %s is owned by another scheduler; not persisting", job_id)
+                self.metrics.record_planning_ms(job_id, plan_span.seconds * 1000)
+                self.post(Event("revive", job_id))
         except BaseException as e:  # noqa: BLE001
             log.warning("planning failed for %s: %s", job_id, e, exc_info=True)
             with self._jobs_lock:
@@ -1079,7 +1091,10 @@ class SchedulerServer:
             # fast-lane results complete on the reporting thread: the whole
             # point of the lane is that short queries never wait behind the
             # event-loop queue
-            self._fast_update(executor_id, fast)
+            r = fast[0]
+            with RUN_STATS.span("bt.task.report", job=r.job_id, stage=r.stage_id,
+                                task=r.task_id, results=len(fast)):
+                self._fast_update(executor_id, fast)
         if rest:
             self.post(Event("task_update", (executor_id, rest)))
 

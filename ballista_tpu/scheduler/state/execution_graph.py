@@ -29,6 +29,7 @@ from ballista_tpu.config import MAX_PARTITIONS_PER_TASK, BallistaConfig
 from ballista_tpu.scheduler.planner import QueryStage, remove_unresolved_shuffles
 from ballista_tpu.shuffle.reader import ShuffleReaderExec
 from ballista_tpu.shuffle.types import PartitionLocation
+from ballista_tpu.tracing import RUN_STATS, now_ns
 
 log = logging.getLogger(__name__)
 
@@ -70,6 +71,10 @@ class TaskDescription:
     # serving tier: dispatched straight from the submit path (single-stage
     # plan, no execution graph); executors count these for heartbeat gauges
     fast_lane: bool = False
+    # when this description was made (scheduler) or decoded (a remote
+    # executor), on the span clock: `bt.task.queued` runs from here to the
+    # pool thread entering Executor.execute_task. Never on the wire.
+    created_ns: int = field(default_factory=now_ns)
 
 
 @dataclass
@@ -90,6 +95,9 @@ class ExecutionStage:
         self.spec = stage
         self.stage_id = stage.stage_id
         self.state = StageState.UNRESOLVED if stage.input_stage_ids else StageState.RESOLVED
+        # when the stage (this attempt) became runnable: `bt.sched.stage`
+        # runs from here to its last task result applied
+        self.runnable_ns = now_ns() if self.state is StageState.RESOLVED else None
         self.attempt = 0
         self.resolved_plan = stage.plan if not stage.input_stage_ids else None
         self.pending: list[int] = list(range(stage.partitions))
@@ -133,6 +141,7 @@ class ExecutionStage:
         self.task_durations = []
         self.retry_counts = {}
         self.state = StageState.UNRESOLVED if self.spec.input_stage_ids else StageState.RESOLVED
+        self.runnable_ns = now_ns() if self.state is StageState.RESOLVED else None
         if not self.spec.input_stage_ids:
             self.resolved_plan = self.spec.plan
 
@@ -301,6 +310,10 @@ class ExecutionGraph:
                     self.stage_metrics.setdefault(stage_id, []).extend(metrics)
                 if stage.all_done():
                     stage.state = StageState.SUCCESSFUL
+                    if stage.runnable_ns is not None:
+                        RUN_STATS.add_span("bt.sched.stage", stage.runnable_ns,
+                                           job=self.job_id, stage=stage_id,
+                                           partitions=stage.effective_partitions)
                     events.append("stage_completed")
                     self._on_stage_success(stage, events)
             elif state in ("failed", "cancelled"):
@@ -594,6 +607,7 @@ class ExecutionGraph:
             stage.pending = list(range(new_parts))
             stage.effective_partitions = new_parts
         stage.state = StageState.RESOLVED
+        stage.runnable_ns = now_ns()
         self._maybe_verify(f"stage {stage.stage_id} resolution")
 
     def _build_reader(self, inp: ExecutionStage) -> ShuffleReaderExec:

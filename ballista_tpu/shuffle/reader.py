@@ -40,6 +40,7 @@ from ballista_tpu.plan.schema import DFSchema
 from ballista_tpu.shuffle import paths
 from ballista_tpu.shuffle.integrity import INTEGRITY, verify_or_raise
 from ballista_tpu.shuffle.types import PartitionLocation
+from ballista_tpu.tracing import RUN_STATS
 from ballista_tpu.utils.lru import LruDict
 
 
@@ -81,6 +82,9 @@ class ShuffleReaderExec(ExecutionPlan):
         produced = False
         gov = _governor(ctx)
         ctr = _FetchCounters()
+        # the reduce task's span, taken here: a generator may be drained on
+        # another thread (the final stage's read pool)
+        task_span = RUN_STATS.current_span()
         t0 = time.perf_counter_ns()
         if len(locs) > 1:
             stream = _stream_locations(locs, ctx, force_remote, gov, counters=ctr)
@@ -98,7 +102,14 @@ class ShuffleReaderExec(ExecutionPlan):
         finally:
             # data-plane accounting for EXPLAIN ANALYZE / the scheduler's
             # task metrics: RPCs issued and bytes moved by provenance
-            self.metrics.extra.update(ctr.snapshot())
+            counts = ctr.snapshot()
+            self.metrics.extra.update(counts)
+            # first pull to exhaustion, one span a partition read (never one a
+            # batch): it holds what the consumer did between batches too
+            RUN_STATS.add_span(
+                "bt.shuffle.read", t0, parent=task_span, partitions=len(locs),
+                bytes=counts["bytes_read_local"] + counts["bytes_fetched_remote"],
+                local=int(counts["fetch_rpcs"] == 0))
         if not produced:
             yield _empty_batch(self.schema())
 

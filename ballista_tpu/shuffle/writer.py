@@ -50,6 +50,7 @@ from ballista_tpu.plan.physical import ExecutionPlan, TaskContext, _empty_batch
 from ballista_tpu.plan.schema import DFField, DFSchema
 from ballista_tpu.shuffle import paths
 from ballista_tpu.shuffle.types import PartitionStats
+from ballista_tpu.tracing import RUN_STATS
 
 
 METADATA_SCHEMA = DFSchema(
@@ -148,7 +149,14 @@ class ShuffleWriterExec(ExecutionPlan):
         )
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
-        return self._timed(iter([self._write(partition, ctx)]))
+        # the whole partition write: the stage's operators are pulled through
+        # it, so a device stage's spans (and the commit) nest inside and its
+        # self time is the pull, the partitioning and operators with no span
+        with RUN_STATS.span("bt.shuffle.write") as span:
+            meta = self._write(partition, ctx)
+            span.set(rows=sum(meta.column("num_rows").to_pylist()),
+                     bytes=sum(meta.column("num_bytes").to_pylist()))
+        return self._timed(iter([meta]))
 
     # ------------------------------------------------------------------
 
@@ -189,8 +197,9 @@ class ShuffleWriterExec(ExecutionPlan):
                 # not leave its .tmp around — it will never be renamed
                 _unlink_quiet(path + ".tmp")
                 raise
-            _write_crc_sidecar(path, sink.digest())
-            os.replace(path + ".tmp", path)
+            with RUN_STATS.span("bt.shuffle.commit"):
+                _write_crc_sidecar(path, sink.digest())
+                os.replace(path + ".tmp", path)
             return self._meta([(map_partition, path, rows, batches, nbytes, "hash")])
 
         bound = [bind_expr(k, self.input.df_schema) for k in self.keys]
@@ -311,9 +320,11 @@ class ShuffleWriterExec(ExecutionPlan):
                     if not spill_largest():
                         break
 
-            if self.sort_shuffle:
-                return self._finish_sort(map_partition, task_id, schema, buckets, spills, bucket_rows, bucket_batches, ctx)
-            return self._finish_hash(map_partition, task_id, schema, buckets, bucket_rows, bucket_batches, ctx)
+            # the buckets drained to their files, checksummed and renamed
+            with RUN_STATS.span("bt.shuffle.commit"):
+                if self.sort_shuffle:
+                    return self._finish_sort(map_partition, task_id, schema, buckets, spills, bucket_rows, bucket_batches, ctx)
+                return self._finish_hash(map_partition, task_id, schema, buckets, bucket_rows, bucket_batches, ctx)
         except BaseException:
             # consolidation removes spills as it streams them; an aborted
             # attempt has to sweep up whatever it spilled itself

@@ -1,0 +1,413 @@
+"""The program's one recorder: per-stage counters and the spans of a query.
+
+`RUN_STATS` (a `RunStats`) holds what the engine counts per stage dispatch
+(`run(tag)` scopes, read through `snapshot()` / `stages()`) and the spans of
+the served path: client -> scheduler -> task runner -> stage dispatch ->
+shuffle -> result fetch, all named `bt.*` (docs/tpu_engine.md#observability
+has the table of keys and of span names). `STAGE_OUTCOMES` counts where
+device-stage operators ran. jax-free: a scheduler or client process imports
+this module and never jax; `ops/tpu/stage_compiler.py` re-exports the four
+names it used to define.
+
+Spans are always on: no key, no environment variable. One span is two reads
+of `time.perf_counter_ns` and one append under a lock. While a
+`jax.profiler` session is active in this process (and only if jax is already
+imported) a span also opens a `jax.profiler.TraceAnnotation` of the same
+name, so the program's spans lie in the `.xplane.pb` beside the device's
+operations.
+"""
+
+from __future__ import annotations
+
+import collections
+import contextlib
+import itertools
+import sys
+import threading
+import time
+from collections.abc import Mapping
+
+now_ns = time.perf_counter_ns
+
+MAX_SPANS_PER_JOB = 1024
+# jobs whose root span lives in another process (a remote scheduler's or
+# executor's view of a client's query) are kept for a later reader, oldest out
+MAX_OPEN_JOBS = 64
+
+
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation` while a profiler session is tracing
+    this process, else None. Never imports jax."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+        _TRACE_ANNOTATION = profiler.TraceAnnotation
+    return _TRACE_ANNOTATION if _TRACE_ANNOTATION.is_enabled() else None
+
+
+def _given(job, stage, task) -> dict:
+    """The ids that are known, as a trace annotation's metadata."""
+    return {k: v for k, v in (("job", job), ("stage", stage), ("task", task)) if v is not None}
+
+
+class Span:
+    """One open or closed span. `with RUN_STATS.span(...) as s:` opens it on
+    the calling thread; `s.set(job=..., rows=...)` adds ids and numbers
+    while it is open; `s.seconds` is its duration (so far, while open) on the
+    span's monotonic clock."""
+
+    __slots__ = ("_stats", "_up", "_ann", "name", "id", "parent", "start", "end",
+                 "job", "stage", "task", "attrs", "root")
+
+    def __init__(self, stats: "RunStats", name: str, up: "Span | None", job, stage, task,
+                 attrs: dict, root: bool, start: int | None):
+        self._stats = stats
+        self._up = up  # the enclosing span: the thread's, or the one attached
+        self._ann = None
+        self.name = name
+        self.id = next(stats._ids)
+        self.parent = up.id if up is not None else None
+        self.job, self.stage, self.task = job, stage, task
+        self.attrs = attrs
+        self.root = root
+        self.end: int | None = None
+        self.start = now_ns() if start is None else start
+
+    def set(self, *, job=None, stage=None, task=None, **attrs) -> None:
+        if job is not None:
+            self.job = job
+        if stage is not None:
+            self.stage = stage
+        if task is not None:
+            self.task = task
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_given(job, stage, task), **attrs)
+
+    @property
+    def seconds(self) -> float:
+        return ((self.end if self.end is not None else now_ns()) - self.start) / 1e9
+
+    def _inherit(self) -> None:
+        """Ids left unset are the enclosing spans': a dispatch under a task
+        carries the task's job, stage and task without being handed them."""
+        up = self._up
+        while up is not None and (self.job is None or self.stage is None or self.task is None):
+            if up.job is not None:
+                if self.job is None:
+                    self.job = up.job
+                elif up.job != self.job:
+                    break  # another job's span encloses this one: not its ids
+            if self.stage is None:
+                self.stage = up.stage
+            if self.task is None and self.stage == up.stage:
+                self.task = up.task
+            up = up._up
+
+    def __enter__(self) -> "Span":
+        tls = self._stats._tls
+        stack = getattr(tls, "spans", None)
+        if stack is None:
+            stack = tls.spans = []
+        stack.append(self)
+        annotation = _annotation()
+        if annotation is not None:
+            self._inherit()
+            self._ann = annotation(self.name, **_given(self.job, self.stage, self.task),
+                                   **self.attrs)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = now_ns()
+        if self._ann is not None:
+            had_job = self.job is not None
+            self._inherit()
+            if self.job is not None and not had_job:
+                # opened before the job had an id (the client's submit)
+                self._ann.set_metadata(job=self.job)
+            self._ann.__exit__(*exc)
+            self._ann = None
+        self._stats._tls.spans.pop()
+        self._stats._close(self)
+
+
+class RunStats(Mapping):
+    """Per-stage-run diagnostics and the spans of the served path, for
+    chip_smoke.py, the benchmark and the executor heartbeat.
+
+    Counters: every `_tpu_run_all` opens a `run(tag)` scope that collects
+    into a private per-run dict (helper threads write through an explicit
+    `rec=` handle) and publishes atomically on exit: the merged view
+    (`dict(RUN_STATS)`, `snapshot()`) is always a consistent
+    most-recent-run-wins snapshot, and `stages()` keeps the last few
+    per-stage records (merged over a stage's dispatches since the last
+    clear(), most recent value wins, with their number as `dispatches`).
+    Every key is listed in docs/tpu_engine.md#observability (the
+    stats-sync analysis pass holds the table to what the code emits).
+
+    Spans: `span(name, job=, stage=, task=, **numbers)` records name, id,
+    parent, start and end (`time.perf_counter_ns`), the ids and the numbers.
+    A span's parent is the enclosing open span of its thread (or the one a
+    helper thread `attach`ed); the first span of a thread is hung, when its
+    job is published, under the span of the same job with the nearest
+    matching (job, stage) that contains it. `add_span` records an interval
+    that began on another thread (a task's wait in the queue, a stage from
+    runnable to done). Spans are kept per job, at most MAX_SPANS_PER_JOB
+    (more are counted in `spans_dropped`). When a job's root span closes in
+    this process the job's spans become ONE record of `stages()` under the
+    tag `job_<job_id>`: {"spans": [[name, id, parent, start_s, end_s, stage,
+    task, numbers], ...], "spans_dropped": n}, seconds of `perf_counter`
+    rounded to the microsecond. That record never passes through the
+    dispatch-counting `_publish` and carries none of the keys the counters
+    sum. Spans that closed outside any job (cluster start) ride in the next
+    job's record."""
+
+    _MAX_STAGES = 32
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._merged: dict = {}
+        self._stages: "collections.OrderedDict[str, dict]" = collections.OrderedDict()
+        self._tls = threading.local()
+        self._ids = itertools.count(1)
+        self._jobs: "collections.OrderedDict[str, list[Span]]" = collections.OrderedDict()
+        self._loose: list[Span] = []  # closed with no job: carried to the next record
+        self._dropped: dict = {}  # job (None: loose) -> spans over the cap
+
+    # -- counters ----------------------------------------------------------
+
+    @contextlib.contextmanager
+    def run(self, tag: str):
+        rec: dict = {}
+        prev = getattr(self._tls, "rec", None)
+        self._tls.rec = rec
+        try:
+            yield rec
+        finally:
+            self._tls.rec = prev
+            self._publish(tag, rec)
+
+    def _publish(self, tag: str, rec: dict) -> None:
+        if not rec:
+            return
+        with self._lock:
+            self._merged.update(rec)
+            # one record per stage, merged over its dispatches: a stage's
+            # map tasks each dispatch it, and only the first carries the
+            # cold-path keys (fill_s, xla_compile_s, persist_cache_*) — a
+            # later task's record must not erase them
+            prev = self._stages.pop(tag, {})
+            self._keep_stage(tag, {**prev, **rec,
+                                   "dispatches": prev.get("dispatches", 0) + 1})
+
+    def _keep_stage(self, tag: str, record: dict) -> None:
+        self._stages[tag] = record
+        while len(self._stages) > self._MAX_STAGES:
+            self._stages.popitem(last=False)
+
+    def set(self, key: str, value, rec: dict | None = None) -> None:
+        """Record one stat. With `rec` (a run's private dict, threadable to
+        helper threads) the write lands in that run; otherwise in the
+        calling thread's open run scope, else directly in the merged view."""
+        if rec is None:
+            rec = getattr(self._tls, "rec", None)
+        if rec is not None:
+            rec[key] = value
+        else:
+            with self._lock:
+                self._merged[key] = value
+
+    def __setitem__(self, key: str, value) -> None:  # legacy write path
+        self.set(key, value)
+
+    def current(self) -> dict | None:
+        return getattr(self._tls, "rec", None)
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._merged)
+
+    def stages(self) -> dict:
+        with self._lock:
+            return {t: dict(r) for t, r in self._stages.items()}
+
+    def clear(self) -> None:
+        """Forget the published counters and records. Spans of jobs still in
+        flight, and those waiting for the next job's record, stay."""
+        with self._lock:
+            self._merged.clear()
+            self._stages.clear()
+
+    # -- spans -------------------------------------------------------------
+
+    def span(self, name: str, *, job=None, stage=None, task=None, root: bool = False,
+             start_ns: int | None = None, **attrs) -> Span:
+        """A context manager recording one span on the calling thread.
+        `root=True` marks a query's outermost span: its close publishes the
+        job. `start_ns` backdates the start to when the work was handed over
+        (an event posted to a queue)."""
+        stack = getattr(self._tls, "spans", None)
+        return Span(self, name, stack[-1] if stack else None, job, stage, task,
+                    attrs, root, start_ns)
+
+    def add_span(self, name: str, start_ns: int, *, parent: Span | None = None,
+                 job=None, stage=None, task=None, **attrs) -> None:
+        """Record an interval that began at `start_ns` elsewhere and ends now:
+        it never joins a thread's nesting and writes no trace annotation.
+        `parent`, a span that contains it, gives it its ids."""
+        s = Span(self, name, parent, job, stage, task, attrs, False, start_ns)
+        s.end = now_ns()
+        self._close(s)
+
+    def current_span(self) -> Span | None:
+        stack = getattr(self._tls, "spans", None)
+        return stack[-1] if stack else None
+
+    @contextlib.contextmanager
+    def attach(self, span: Span | None):
+        """Make `span` (open on another thread) the enclosing span of this
+        helper thread: what it opens hangs under it and carries its ids."""
+        if span is None:
+            yield
+            return
+        stack = getattr(self._tls, "spans", None)
+        if stack is None:
+            stack = self._tls.spans = []
+        stack.append(span)
+        try:
+            yield
+        finally:
+            stack.pop()
+
+    def job_spans(self, job: str) -> list[Span]:
+        """The closed spans held for a job not yet published: what a process
+        that does not hold the job's root (a remote executor) has of it."""
+        with self._lock:
+            return list(self._jobs.get(job, ()))
+
+    def _close(self, span: Span) -> None:
+        span._inherit()
+        with self._lock:
+            job = span.job
+            if job is None:
+                kept = self._loose
+            else:
+                kept = self._jobs.get(job)
+                if kept is None:
+                    kept = self._jobs[job] = []
+                    while len(self._jobs) > MAX_OPEN_JOBS:
+                        old, _ = self._jobs.popitem(last=False)
+                        self._dropped.pop(old, None)
+            if len(kept) < MAX_SPANS_PER_JOB or span.root:
+                kept.append(span)
+            else:
+                self._dropped[job] = self._dropped.get(job, 0) + 1
+            if span.root and job is not None:
+                self._publish_job(span)
+
+    def _publish_job(self, root: Span) -> None:
+        """Under the lock: the job's spans, and whatever closed outside any
+        job since the last record, as one plain record."""
+        spans = self._jobs.pop(root.job, [])
+        dropped = self._dropped.pop(root.job, 0) + self._dropped.pop(None, 0)
+        for s in self._loose:
+            s._inherit()  # a child that closed before the root had its id
+        spans += self._loose
+        self._loose = []
+        _hang_orphans(spans, root)
+        self._keep_stage(f"job_{root.job}", {
+            "spans": [[s.name, s.id, s.parent, round(s.start / 1e9, 6), round(s.end / 1e9, 6),
+                       s.stage, s.task, s.attrs] for s in spans],
+            "spans_dropped": dropped})
+
+    # Mapping protocol over the merged snapshot (dict(RUN_STATS) works)
+    def __getitem__(self, key):
+        with self._lock:
+            return self._merged[key]
+
+    def __iter__(self):
+        with self._lock:
+            return iter(list(self._merged))
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._merged)
+
+
+def _hang_orphans(spans: list[Span], root: Span) -> None:
+    """Give every span of the root's job that has no parent (the first of its
+    thread, or an `add_span`) the span that contains it with the nearest
+    matching ids: same stage before job only, then the shortest. Only spans
+    with no task id (the job's and the stages') can adopt, and only one
+    that starts no later and ends no earlier, so no span adopts its parent."""
+    def outer(s: Span) -> tuple:
+        return (s.start, -s.end, s.id)
+
+    holders = [s for s in spans if s.task is None and s.job == root.job]
+    for o in spans:
+        if o.parent is not None or o is root or o.job != root.job:
+            continue
+        best = None
+        for c in holders:
+            if (c.start <= o.start and c.end >= o.end and outer(c) < outer(o)
+                    and (c.stage is None or c.stage == o.stage)):
+                rank = (c.stage is not None, c.start - c.end)
+                if best is None or rank > best[0]:
+                    best = (rank, c)
+        if best is not None:
+            o.parent = best[1].id
+
+
+RUN_STATS = RunStats()
+
+
+class StageOutcomes:
+    """Process-wide, cumulative ledger of where device-stage operators ran.
+
+    Every stage family (partial / final / sort / window) notes one outcome
+    per dispatch attempt: `device` (ran on the device), `below_row_floor`
+    (stayed on the CPU under ballista.tpu.min.rows, by policy), `declined`
+    (any other Unsupported — the documented per-subtree fallback) or `error`
+    (a non-Unsupported exception demoted to the CPU engine: the query still
+    answers, but this is the fallback that would hide a broken device path).
+    The operators' own tpu_count / fallback_count live on per-task plan
+    objects nobody keeps; this is what chip_smoke.py, tests and the executor
+    heartbeat (`tpu_stage_*` gauges) read instead."""
+
+    KINDS = ("device", "below_row_floor", "declined", "error")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts = dict.fromkeys(self.KINDS, 0)
+        self._recent: "collections.deque[tuple]" = collections.deque(maxlen=64)
+
+    def note(self, family: str, kind: str, detail: str = "") -> None:
+        with self._lock:
+            self._counts[kind] += 1
+            self._recent.append((family, kind, detail))
+
+    def note_fallback(self, family: str, exc: BaseException) -> None:
+        from ballista_tpu.ops.tpu.kernels import BelowRowFloor, Unsupported
+
+        kind = ("below_row_floor" if isinstance(exc, BelowRowFloor)
+                else "declined" if isinstance(exc, Unsupported) else "error")
+        self.note(family, kind, f"{type(exc).__name__}: {exc}"[:300])
+
+    def snapshot(self) -> dict:
+        """Counts per kind and the last 64 (family, kind, detail) notes."""
+        with self._lock:
+            return {**self._counts, "recent": list(self._recent)}
+
+    def clear(self) -> None:
+        with self._lock:
+            self._counts = dict.fromkeys(self.KINDS, 0)
+            self._recent.clear()
+
+
+STAGE_OUTCOMES = StageOutcomes()
