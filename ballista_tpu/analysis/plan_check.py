@@ -16,6 +16,11 @@ stage-list invariants (`verify_stages`):
   plan contains a MeshExchangeExec; a mesh stage is never a broadcast
   producer; the exchange's device bucket count equals the stage's task
   span
+- device tasking (`device-slice`): the scheduler's static predicate
+  (`tpu_engine.whole_stage_scans`, which decides that a stage goes out as
+  one task per executor) names exactly the scans under the TpuStageExec
+  nodes `maybe_compile_tpu` builds for the stage's plan; `verify_graph`
+  also holds each stage's derived `whole_stage_device` to its resolved plan
 
 graph invariants (`verify_graph`): all of the above on the stage specs,
 plus `effective_partitions <= spec.partitions + skew growth` (AQE may
@@ -97,11 +102,11 @@ def _shuffle_leaves(plan) -> list:
     return out
 
 
-def _mesh_exchanges(plan) -> list[MeshExchangeExec]:
+def _nodes_of(plan, kind) -> list:
     out = []
 
     def walk(n):
-        if isinstance(n, MeshExchangeExec):
+        if isinstance(n, kind):
             out.append(n)
         for c in n.children():
             walk(c)
@@ -110,8 +115,35 @@ def _mesh_exchanges(plan) -> list[MeshExchangeExec]:
     return out
 
 
-def verify_stages(stages) -> list[PlanViolation]:
-    """Invariants over a list of QueryStage (pre-graph, post-merge)."""
+def _mesh_exchanges(plan) -> list[MeshExchangeExec]:
+    return _nodes_of(plan, MeshExchangeExec)
+
+
+def _device_slice_violation(stage_id: int, plan, config) -> PlanViolation | None:
+    """The scheduler's predicate against the executor's own compilation of
+    the same plan: the scans they name must be the same, one for one."""
+    from ballista_tpu.engine import tpu_engine
+    from ballista_tpu.ops.tpu.stage_compiler import TpuStageExec
+
+    compiled = [n.scan for n in
+                _nodes_of(tpu_engine.maybe_compile_tpu(plan, config), TpuStageExec)]
+    predicted = tpu_engine.whole_stage_scans(plan)
+    if sorted(map(id, predicted)) == sorted(map(id, compiled)):
+        return None
+    return PlanViolation(
+        "device-slice", stage_id,
+        f"the scheduler's predicate names {len(predicted)} partial device "
+        f"stage(s) but maybe_compile_tpu builds {len(compiled)} TpuStageExec "
+        f"node(s); the two must match scan for scan (pop_next_task hands a "
+        f"whole-stage device stage out as one task per executor)")
+
+
+def verify_stages(stages, config=None) -> list[PlanViolation]:
+    """Invariants over a list of QueryStage (pre-graph, post-merge);
+    `config` is the session's (defaults when a caller has none)."""
+    from ballista_tpu.config import BallistaConfig
+
+    config = config or BallistaConfig()
     v: list[PlanViolation] = []
     by_id = {}
     for s in stages:
@@ -171,6 +203,10 @@ def verify_stages(stages) -> list[PlanViolation]:
                         f"reads stage {prod.stage_id} expecting fields "
                         f"{expected} but the producer emits {produced}"))
 
+        bad = _device_slice_violation(s.stage_id, plan, config)
+        if bad is not None:
+            v.append(bad)
+
         # mesh gating postconditions
         exchanges = _mesh_exchanges(plan)
         if bool(s.mesh) != bool(exchanges):
@@ -217,8 +253,12 @@ def verify_graph(graph) -> list[PlanViolation]:
     """verify_stages over the specs, plus runtime-state invariants."""
     from ballista_tpu.serving.fast_lane import FAST_TASK_ID_BASE
 
+    from ballista_tpu.config import EXECUTOR_ENGINE
+    from ballista_tpu.engine.tpu_engine import is_whole_stage_device
+
     stages = [st.spec for st in graph.stages.values()]
-    v = verify_stages(stages)
+    v = verify_stages(stages, graph.config)
+    tpu = str(graph.config.get(EXECUTOR_ENGINE)) == "tpu"
     if graph.next_task_id >= FAST_TASK_ID_BASE:
         v.append(PlanViolation(
             "task-id-band", 0,
@@ -238,6 +278,18 @@ def verify_graph(graph) -> list[PlanViolation]:
                 f"slice partitions the skew report accounts for; AQE growth "
                 f"must be backed by a SkewSplitReport"))
         v.extend(_verify_skew_splits(graph, st))
+        if st.resolved_plan is not None:
+            want = tpu and is_whole_stage_device(st.resolved_plan, graph.config)
+            if bool(st.whole_stage_device) != want:
+                v.append(PlanViolation(
+                    "device-slice", st.stage_id,
+                    f"whole_stage_device={st.whole_stage_device} but the "
+                    f"resolved plan says {want}; the flag is derived where "
+                    f"resolved_plan is set and must follow it"))
+            elif st.resolved_plan is not st.spec.plan:
+                bad = _device_slice_violation(st.stage_id, st.resolved_plan, graph.config)
+                if bad is not None:
+                    v.append(bad)
         for task_id in st.running:
             if task_id >= DIRECT_TASK_ID_BASE:
                 v.append(PlanViolation(
@@ -435,8 +487,8 @@ def check_lease_bands(leases) -> None:
         raise PlanVerificationError(violations)
 
 
-def check_stages(stages) -> None:
-    violations = verify_stages(stages)
+def check_stages(stages, config=None) -> None:
+    violations = verify_stages(stages, config)
     if violations:
         raise PlanVerificationError(violations)
 

@@ -299,7 +299,7 @@ _ENTRIES: list[ConfigEntry] = [
     ConfigEntry(BROADCAST_JOIN_THRESHOLD, "Max build-side bytes to lower a join to a broadcast exchange.", int, 10 * 1024 * 1024, _nonneg),
     ConfigEntry(BROADCAST_JOIN_ROWS_THRESHOLD, "Max build-side rows to lower a join to a broadcast exchange.", int, 1_000_000, _nonneg),
     ConfigEntry(BROADCAST_SEMI_KEYS_THRESHOLD, "Max build-side rows to collect a filterless semi/anti join's membership keys instead of co-partitioning (the build ships join keys only, so the collect threshold relaxes past the row-broadcast one).", int, 8_000_000, _nonneg),
-    ConfigEntry(MAX_PARTITIONS_PER_TASK, "Group up to N partitions into one task (partition slices).", int, 1, _pos),
+    ConfigEntry(MAX_PARTITIONS_PER_TASK, "Group up to N partitions into one task (partition slices). Not read for a whole-stage device stage (engine tpu, the plan holds a partial TpuStageExec: one task per executor alive) nor for a mesh stage (one task): their slice is decided from the plan.", int, 1, _pos),
     ConfigEntry(JOB_RESUBMIT_INTERVAL_MS, "Periodically re-offer jobs holding runnable-but-unscheduled tasks (0 = off; offers otherwise fire on task/executor events only).", int, 0, _nonneg),
     ConfigEntry(SCHEDULER_SHARDS, "Scheduler event-loop shards: jobs partition by crc32(job_id) mod N, each shard running its own event loop and admission-lag EWMA.", int, 1, _pos),
     ConfigEntry(SCHEDULER_LEASE_ENABLED, "Direct-dispatch leases: mint revocable executor capacity slices so prepared-statement clients can skip the scheduler on the hot path.", bool, False),

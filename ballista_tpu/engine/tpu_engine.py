@@ -78,25 +78,13 @@ def maybe_compile_tpu(physical: ExecutionPlan, config: BallistaConfig) -> Execut
             sort, post_ops, agg, child, coalesce = fs
             return TpuFinalStageExec(sort, post_ops, agg, walk(child), config, coalesce)
         if isinstance(node, HashAggregateExec) and node.mode == "partial":
-            chain = _match_chain(node.input)
-            if chain is not None:
-                ops, scan = chain
-                if _static_ok(node):
-                    return TpuStageExec(node, ops, scan, config)
-                hoisted = _hoist_expr_group_keys(node)
-                if hoisted is not None and _static_ok(hoisted.input):
-                    inner = TpuStageExec(hoisted.input, ops, scan, config)
-                    return hoisted.with_children([inner])
-            elif _static_ok(node):
-                # a UNION on the probe chain (TPC-DS cross-channel shapes:
-                # q2/q5/q71/q75/q76) blocks the single-scan stage form —
-                # push the partial agg through the union so each branch
-                # compiles its own device chain. Per-partition outputs are
-                # identical: union partitions map 1:1 onto branch
-                # partitions, and partials merge downstream either way.
-                pushed = _push_agg_through_union(node)
-                if pushed is not None:
-                    return walk(pushed)
+            form = _lower_partial(node)
+            if isinstance(form, tuple):
+                agg, ops, scan, hoisted = form
+                inner = TpuStageExec(agg, ops, scan, config)
+                return inner if hoisted is None else hoisted.with_children([inner])
+            if form is not None:
+                return walk(form)
         if (sort_on and isinstance(node, SortExec)
                 and sort_static_ok(node.keys, node.input.df_schema)):
             # standalone ORDER BY [LIMIT] (final-stage shapes were claimed
@@ -120,6 +108,75 @@ def maybe_compile_tpu(physical: ExecutionPlan, config: BallistaConfig) -> Execut
     _wire_device_routing(out)
     _wire_observed_bytes(observed_bytes, out)
     return out
+
+
+def _lower_partial(node: HashAggregateExec):
+    """How a partial aggregate goes to the device, decided from the plan
+    alone: `(device agg, ops, scan, hoisted projection or None)` for one
+    TpuStageExec, a UnionExec of per-branch partial aggregates to lower in
+    turn, or None (stays on the CPU engine). The ONE matcher behind both
+    `maybe_compile_tpu` (the executor) and `whole_stage_scans` (the
+    scheduler), so the two cannot disagree."""
+    chain = _match_chain(node.input)
+    if chain is not None:
+        ops, scan = chain
+        if _static_ok(node):
+            return node, ops, scan, None
+        hoisted = _hoist_expr_group_keys(node)
+        if hoisted is not None and _static_ok(hoisted.input):
+            return hoisted.input, ops, scan, hoisted
+    elif _static_ok(node):
+        # a UNION on the probe chain (TPC-DS cross-channel shapes:
+        # q2/q5/q71/q75/q76) blocks the single-scan stage form —
+        # push the partial agg through the union so each branch
+        # compiles its own device chain. Per-partition outputs are
+        # identical: union partitions map 1:1 onto branch
+        # partitions, and partials merge downstream either way.
+        return _push_agg_through_union(node)
+    return None
+
+
+def whole_stage_scans(physical: ExecutionPlan) -> list[ExecutionPlan]:
+    """The scan under every TpuStageExec `maybe_compile_tpu` would build for
+    this plan, without building one (and without jax). Static: it cannot
+    see what the executor learns at run time (row floor on a parquet table,
+    Unsupported at lowering, a second OOM)."""
+    scans: list[ExecutionPlan] = []
+
+    def visit(node: ExecutionPlan) -> None:
+        if isinstance(node, HashAggregateExec) and node.mode == "partial":
+            form = _lower_partial(node)
+            if isinstance(form, tuple):
+                scans.append(form[2])
+                return
+            if form is not None:
+                node = form
+        # the final, sort and window families wrap a node and lower its
+        # input in turn: no partial aggregate hides from this descent
+        for c in node.children():
+            visit(c)
+
+    visit(_concretize_dynamic_joins(physical))
+    return scans
+
+
+def _under_row_floor(scan: ExecutionPlan, config: BallistaConfig) -> bool:
+    """True when the scan's own row count already proves the stage will
+    raise BelowRowFloor. Only an in-memory table states its rows in the
+    plan; a parquet scan's are known after the fill."""
+    from ballista_tpu.config import TPU_MIN_ROWS
+
+    return (isinstance(scan, MemoryScanExec)
+            and sum(b.num_rows for b in scan.batches) < int(config.get(TPU_MIN_ROWS)))
+
+
+def is_whole_stage_device(physical: ExecutionPlan, config: BallistaConfig) -> bool:
+    """The scheduler's question (ExecutionGraph.pop_next_task): will this
+    stage's plan hold a TpuStageExec — the partial family, whose ONE
+    dispatch computes every partition of the stage — that is not already
+    known to stay under the row floor? Such a stage is handed out as one
+    task per executor, not one per partition."""
+    return any(not _under_row_floor(s, config) for s in whole_stage_scans(physical))
 
 
 def _wire_observed_bytes(observed: int, out: ExecutionPlan) -> None:

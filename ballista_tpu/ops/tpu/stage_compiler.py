@@ -768,22 +768,33 @@ class TpuStageExec(ExecutionPlan):
         return node
 
     def _fallback(self, partition: int, ctx: TaskContext) -> list[pa.RecordBatch]:
-        """Re-run the original CPU subtree (scan filters applied on host)."""
+        """Re-run the original CPU subtree (scan filters applied on host).
+
+        The fallback contract for a slice. A task carries a slice of the
+        stage's partitions (all of them with one executor) and runs them on
+        this ONE instance, whose first `_run` decides device-or-CPU for the
+        whole slice: a dispatch that raises (Unsupported, BelowRowFloor, a
+        second OOM, anything else) leaves `_results` empty and `_device_ok`
+        false, and every partition of the slice comes here, one after
+        another. With `emit_pid` set the layout is the same whichever
+        engine produced it: partition 0 carries EVERY group of the stage
+        and the other partitions are empty — so the slices of two
+        executors, one on the device and one demoted, neither lose a group
+        nor count one twice."""
         from ballista_tpu.plan.physical import CoalescePartitionsExec
 
         self.fallback_count += 1
         node = self._raw_chain()
         if self.emit_pid is not None:
-            # device-routed layout contract: the device path ships EVERY
-            # group through map task 0 (__pid routing) and empties the other
-            # map outputs. Tasks decide device-vs-CPU independently (a
-            # runtime OOM can demote ONE task after its peers served the
-            # routed layout), so a classic partition-p partial here would
-            # double-count surviving device outputs — or, demoting task 0,
-            # silently drop every other partition's groups. Keep the shape:
-            # task 0 aggregates the WHOLE input; the shuffle writer's host
-            # hash is the device routing's bit-exact twin, so each group
-            # still meets its partials in the same reduce partition.
+            # the device path ships every group through map partition 0
+            # (__pid routing) and empties the others; a classic
+            # partition-p partial here would double-count what a device
+            # slice on another executor already wrote through partition 0 —
+            # or, demoting the slice that holds partition 0, drop every
+            # other partition's groups. Keep the shape: partition 0
+            # aggregates the WHOLE input; the shuffle writer's host hash is
+            # the device routing's bit-exact twin, so each group still
+            # meets its partials in the same reduce partition.
             if partition != 0:
                 return [_empty_batch(self.schema())]
             node = CoalescePartitionsExec(node)
@@ -975,8 +986,10 @@ class TpuStageExec(ExecutionPlan):
 
     def _tpu_run_all(self, ctx: TaskContext) -> dict[int, list[pa.RecordBatch]]:
         tag = f"stage_{zlib.crc32(self.fingerprint.encode()):08x}"
-        # one span per dispatch, never merged: a stage's map tasks each
-        # dispatch it, and RunStats keeps only the last of their records
+        # one span per dispatch, never merged: the scheduler hands a stage
+        # like this out as one task per executor (pop_next_task), so an
+        # executor dispatches it once; RunStats keeps the last record of a
+        # tag, which on several executors is one of theirs
         with RUN_STATS.run(tag) as rec, \
                 RUN_STATS.span("bt.stage.dispatch", family="partial"):
             try:

@@ -525,7 +525,7 @@ class SchedulerServer:
             from ballista_tpu.analysis.plan_check import check_stages
 
             log.debug("plan verify: %d stages of %s", len(stages), job_id)
-            check_stages(stages)
+            check_stages(stages, cfg)
 
     def _try_fast_lane(self, job_id: str, job_name: str, session_id: str,
                        cfg: BallistaConfig, stages, fill) -> bool:
@@ -979,19 +979,20 @@ class SchedulerServer:
         if self.launcher is None:
             return
         running = self._running_jobs_rotated(shard)
-        demand = sum(g.available_task_count() for g in running)
+        alive = len(self.executors.alive_executors())
+        demand = sum(g.available_task_count(alive) for g in running)
         if demand == 0:
             return
-        self._offer_probes(running)
+        self._offer_probes(running, alive)
         if self.executors.task_distribution == "consistent-hash":
-            self._offer_consistent(running)
+            self._offer_consistent(running, alive)
             return
         reservations = self.executors.reserve_slots(demand)
         for executor_id, count in reservations:
             tasks: list[TaskDescription] = []
             for g in running:
                 while len(tasks) < count:
-                    t = g.pop_next_task(executor_id)
+                    t = g.pop_next_task(executor_id, alive)
                     if t is None:
                         break
                     tasks.append(t)
@@ -1003,13 +1004,13 @@ class SchedulerServer:
             if tasks:
                 self._spawn_launch(executor_id, tasks)
 
-    def _offer_probes(self, running: list) -> None:
+    def _offer_probes(self, running: list, alive: int) -> None:
         """Bind ONE real task to each quarantined executor whose probe
         backoff elapsed; its outcome decides re-admission vs re-quarantine."""
         for executor_id, _count in self.executors.probe_reservations():
             probe: list[TaskDescription] = []
             for g in running:
-                t = g.pop_next_task(executor_id)
+                t = g.pop_next_task(executor_id, alive)
                 if t is not None:
                     probe.append(t)
                     break
@@ -1020,13 +1021,13 @@ class SchedulerServer:
                 # nothing to bind: cancel_probe returns the slot itself
                 self.executors.cancel_probe(executor_id)
 
-    def _offer_consistent(self, running: list) -> None:
+    def _offer_consistent(self, running: list, alive: int) -> None:
         """Consistent-hash binding: each task's (job, stage, partition)
         identity picks its executor on the ring — sticky placement."""
         by_exec: dict[str, list[TaskDescription]] = {}
         for g in running:
             while True:
-                peek = g.pop_next_task("")  # bound to a concrete executor below
+                peek = g.pop_next_task("", alive)  # bound to a concrete executor below
                 if peek is None:
                     break
                 key = f"{peek.job_id}/{peek.stage_id}/{peek.partitions[0] if peek.partitions else 0}"
@@ -1071,9 +1072,10 @@ class SchedulerServer:
             # push+pull cluster double-books the same vcores
             granted = self.executors.take_slots(metadata.id, free_slots)
             running = self._running_jobs_rotated()
+            alive = len(self.executors.alive_executors())
             for g in running:
                 while len(out) < granted:
-                    t = g.pop_next_task(metadata.id)
+                    t = g.pop_next_task(metadata.id, alive)
                     if t is None:
                         break
                     out.append(t)

@@ -8,7 +8,8 @@ stage lifecycle  UNRESOLVED → RESOLVED → RUNNING → SUCCESSFUL | FAILED
   UnresolvedShuffleExec leaves are swapped for ShuffleReaderExec carrying
   the input stages' partition locations (remove_unresolved_shuffles)
 - tasks are handed out per partition SLICE (PendingPartitions::next_slice,
-  max_partitions_per_task)
+  max_partitions_per_task); the slice of a mesh stage and of a whole-stage
+  device stage is decided from the stage's plan instead (`_slice_size`)
 - failure handling: bounded per-stage retries with attempt counters and
   failure dedup (execution_stage.rs:142); executor loss rolls running
   stages back and reruns successful stages whose shuffle outputs were on
@@ -25,7 +26,8 @@ from dataclasses import dataclass, field
 from enum import Enum
 from typing import Optional
 
-from ballista_tpu.config import MAX_PARTITIONS_PER_TASK, BallistaConfig
+from ballista_tpu.config import EXECUTOR_ENGINE, MAX_PARTITIONS_PER_TASK, BallistaConfig
+from ballista_tpu.engine.tpu_engine import is_whole_stage_device
 from ballista_tpu.scheduler.planner import QueryStage, remove_unresolved_shuffles
 from ballista_tpu.shuffle.reader import ShuffleReaderExec
 from ballista_tpu.shuffle.types import PartitionLocation
@@ -91,8 +93,9 @@ class RunningTask:
 
 
 class ExecutionStage:
-    def __init__(self, stage: QueryStage):
+    def __init__(self, stage: QueryStage, config: BallistaConfig | None = None):
         self.spec = stage
+        self.config = config or BallistaConfig()
         self.stage_id = stage.stage_id
         self.state = StageState.UNRESOLVED if stage.input_stage_ids else StageState.RESOLVED
         # when the stage (this attempt) became runnable: `bt.sched.stage`
@@ -123,6 +126,26 @@ class ExecutionStage:
         # delays attempt 0 — a retry must be able to escape the injected
         # fault, same as a speculative duplicate)
         self.retry_counts: dict[int, int] = {}
+
+    @property
+    def resolved_plan(self):
+        return self._resolved_plan
+
+    @resolved_plan.setter
+    def resolved_plan(self, plan) -> None:
+        """Every write (initial, AQE re-resolution, retry, recovery from
+        proto) re-derives what the scheduler reads off the plan, so neither
+        can go stale."""
+        self._resolved_plan = plan
+        # the plan's partial device stage computes EVERY partition in one
+        # dispatch (TpuStageExec): hand the stage out a task an executor
+        self.whole_stage_device = (
+            plan is not None
+            and str(self.config.get(EXECUTOR_ENGINE)) == "tpu"
+            and is_whole_stage_device(plan, self.config))
+        # partitions a task of such a stage takes; fixed at its first
+        # hand-out from the executors alive then
+        self.task_slice: int | None = None
 
     @property
     def is_runnable(self) -> bool:
@@ -159,7 +182,8 @@ class ExecutionGraph:
         self.job_name = job_name
         self.session_id = session_id
         self.config = config or BallistaConfig()
-        self.stages: dict[int, ExecutionStage] = {s.stage_id: ExecutionStage(s) for s in stages}
+        self.stages: dict[int, ExecutionStage] = {
+            s.stage_id: ExecutionStage(s, self.config) for s in stages}
         self.final_stage_id = max(self.stages) if self.stages else 0
         self.status = JobState.RUNNING
         self.error: str = ""
@@ -191,25 +215,44 @@ class ExecutionGraph:
 
     # ------------------------------------------------------------------
 
-    def available_task_count(self) -> int:
+    def _slice_size(self, stage: ExecutionStage, executors: int) -> int:
+        """How many pending partitions one task of this stage takes —
+        decided from the stage's plan, not by a knob, where the plan says
+        one dispatch serves every partition."""
+        if stage.spec.mesh:
+            # a mesh stage's exchange runs ONCE and serves every reduce
+            # bucket from one device dispatch — it must ship as a single
+            # mesh-wide task, never be sliced across executors
+            return max(1, len(stage.pending))
+        if stage.whole_stage_device:
+            # the partial device stage computes every partition whichever
+            # it is asked for: one task per executor, so each executor
+            # dispatches the stage once
+            return stage.task_slice or -(-stage.effective_partitions // max(1, executors))
+        return max(1, int(self.config.get(MAX_PARTITIONS_PER_TASK)))
+
+    def available_task_count(self, executors: int = 1) -> int:
+        """Tasks (not partitions) the runnable stages would hand out now,
+        with `executors` alive: what an offer reserves slots for."""
         with self._lock:
             if self.status is not JobState.RUNNING:
                 return 0
-            return sum(len(s.pending) for s in self.stages.values() if s.is_runnable)
+            return sum(-(-len(s.pending) // self._slice_size(s, executors))
+                       for s in self.stages.values() if s.is_runnable)
 
-    def pop_next_task(self, executor_id: str) -> Optional[TaskDescription]:
-        """Hand out one task (a slice of a runnable stage's partitions)."""
+    def pop_next_task(self, executor_id: str, executors: int = 1) -> Optional[TaskDescription]:
+        """Hand out one task (a slice of a runnable stage's partitions);
+        `executors` is how many are alive to share a whole-stage device
+        stage."""
         with self._lock:
             if self.status is not JobState.RUNNING:
                 return None
-            slice_size = max(1, int(self.config.get(MAX_PARTITIONS_PER_TASK)))
             for stage in sorted(self.stages.values(), key=lambda s: s.stage_id):
                 if not stage.is_runnable:
                     continue
-                # a mesh stage's exchange runs ONCE and serves every reduce
-                # bucket from one device dispatch — it must ship as a single
-                # mesh-wide task, never be sliced across executors
-                n = len(stage.pending) if stage.spec.mesh else slice_size
+                n = self._slice_size(stage, executors)
+                if stage.whole_stage_device:
+                    stage.task_slice = n
                 parts = stage.pending[:n]
                 stage.pending = stage.pending[n:]
                 self.next_task_id += 1

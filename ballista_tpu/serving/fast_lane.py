@@ -158,10 +158,10 @@ class FastJob:
                 self.ended_at = time.time()
 
     # no stage state to offer, expire, speculate on, or roll back
-    def available_task_count(self) -> int:
+    def available_task_count(self, executors: int = 1) -> int:
         return 0
 
-    def pop_next_task(self, executor_id: str):
+    def pop_next_task(self, executor_id: str, executors: int = 1):
         return None
 
     def return_task(self, task) -> None:

@@ -198,10 +198,10 @@ class RunStats(Mapping):
             return
         with self._lock:
             self._merged.update(rec)
-            # one record per stage, merged over its dispatches: a stage's
-            # map tasks each dispatch it, and only the first carries the
-            # cold-path keys (fill_s, xla_compile_s, persist_cache_*) — a
-            # later task's record must not erase them
+            # one record per stage, merged over its dispatches (one an
+            # executor, and one more for a re-read): only the first carries
+            # the cold-path keys (fill_s, xla_compile_s, persist_cache_*) —
+            # a later dispatch's record must not erase them
             prev = self._stages.pop(tag, {})
             self._keep_stage(tag, {**prev, **rec,
                                    "dispatches": prev.get("dispatches", 0) + 1})

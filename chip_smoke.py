@@ -58,13 +58,14 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 DEPLOYMENT_SCALE = 10.0
 # ...and the scale the whole script fits its 1200 s limit at, compilation
 # included (PERF.md, PR 21: 560 s at SF1 on a v5e chip, ~980 s estimated at
-# SF2). Two engine limits, not the chip, set it: a stage's P map tasks each
-# dispatch the whole-stage kernel (8 dispatches of q3's 4.4 s sorted-path
-# kernel per run at SF1), and the sorted path holds at most 2^22 groups, so
-# q18's GROUP BY l_orderkey leaves the device from SF3 up.
+# SF2 — timed when each of a stage's 8 map tasks dispatched the whole-stage
+# kernel; since PR 26 an executor dispatches a stage once and the script has
+# not been re-timed at a larger scale). The engine limit that stays: the
+# sorted path holds at most 2^22 groups, so q18's GROUP BY l_orderkey leaves
+# the device from SF3 up.
 DEFAULT_SCALE = 1.0
-REDUCED_WHY = ("whole script must fit 1200 s with compilation: every map task "
-               "re-dispatches its stage's kernel (8x per stage run) and the "
+REDUCED_WHY = ("whole script must fit 1200 s with compilation (last timed at "
+               "PR 21, with eight dispatches a stage; one since PR 26) and the "
                "sorted path's 2^22 group capacity overflows on q18 from SF3")
 LABEL = "smoke timing, not a benchmark result"
 HEARTBEAT_WAIT_S = 15  # > the executor's heartbeat interval

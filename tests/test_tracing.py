@@ -292,8 +292,10 @@ def test_one_collect_leaves_one_job_record_covering_the_served_path(standalone):
     counted = sum(r.get("dispatches", 0) for t, r in stages.items() if not t.startswith("job_"))
     if engine == "tpu":
         assert DEVICE <= names and {"bt.device.fill", "bt.compile.trace", "bt.compile.xla"} <= cold
-        # one span a dispatch: each of the stage's map tasks dispatches it
-        assert len(dispatches) == counted == 8
+        # one span a dispatch, one dispatch a device stage: the scheduler
+        # hands the stage's eight partitions to the one executor as one task
+        assert len(dispatches) == counted == 1
+        assert sorted(t[NUMBERS]["partitions"] for t in tasks)[-1] == 8
         for d in dispatches:
             kids = {s[NAME] for s in spans if s[PARENT] == d[ID]}
             assert {"bt.device.exec", "bt.device.fetch", "bt.decode"} <= kids
