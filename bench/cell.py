@@ -1,19 +1,24 @@
 #!/usr/bin/env python3
-"""bench/cell.py — the child of bench/run.py: the process that holds the chip.
+"""bench/cell.py — the child of bench/run.py: the process that runs the queries.
+
+Whether it also holds the chips is the topology's business (lib/topology_*):
+this file imports no jax and asks the topology for everything that is bound
+to the process that does — the devices, the profiler session, the memory.
 
 Opens the configuration's topology over the data the parent generated, runs
 the cell's queries once (the first round: fill, compile or persistent-cache
 load, one execution), the cell's untimed warm-up rounds, then the window: one
 client, closed loop, the queries round-robin, ending at the first round
 boundary at or after --seconds. With --trace 1 the first `trace_rounds` rounds
-of the window run under `jax.profiler.trace`, every query under a
-`TraceAnnotation` of its own. Everything it saw goes to <out>/cell.json, every
+of the window run under the topology's profiler session(s), every query inside
+the context manager the topology hands back and with its start and end on the
+wall clock in the record. Everything it saw goes to <out>/cell.json, every
 answer to <out>/results/, the trace to <out>/trace/; the parent judges them.
 
-Fails (exit 2, nothing written) unless jax's default backend is a TPU listed
-in bench/lib/peaks.json with as many chips as the cell asks for. --rehearse
-lifts that for the CPU rehearsal, whose record is stamped with the platform it
-ran on and which the parent never prints as a result.
+Fails (exit 2, nothing written) unless the topology's devices are TPUs listed
+in bench/lib/peaks.json, as many as the cell asks for. --rehearse lifts that
+for the CPU rehearsal, whose record is stamped with the platform it ran on and
+which the parent never prints as a result.
 """
 
 from __future__ import annotations
@@ -35,22 +40,16 @@ from lib import load_json  # noqa: E402
 from lib.window import run_window  # noqa: E402
 
 
-def require_device(chips: int, peaks: dict, rehearse: bool) -> dict:
-    """The device as jax reports it. Exits 2 unless it is a TPU the table
-    of peaks knows, with `chips` chips: an unknown device is an error, not a
-    default."""
-    import jax
-
-    devs = jax.devices()
-    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs)}
+def require_device(device: dict, chips: int, peaks: dict, rehearse: bool) -> None:
+    """Exits 2 unless `device`, as the topology reports what its chip-holding
+    processes see, is a TPU the table of peaks knows, with `chips` chips: an
+    unknown device is an error, not a default."""
     if rehearse:
-        return device
-    if device["platform"] != "tpu" or device["kind"] not in peaks or len(devs) < chips:
+        return
+    if device["platform"] != "tpu" or device["kind"] not in peaks or device["count"] < chips:
         print(f"bench: needs {chips} TPU chip(s) of a kind in lib/peaks.json; jax's "
               f"default backend gives {device}. No CPU fallback.", file=sys.stderr)
         sys.exit(2)
-    return device
 
 
 def run_query(session, probes, name: str, sql: str, annotate) -> tuple[dict, object]:
@@ -59,12 +58,14 @@ def run_query(session, probes, name: str, sql: str, annotate) -> tuple[dict, obj
     before = probes.outcomes()
     rec: dict = {"query": name, "failed": False}
     out = None
+    wall = [time.time_ns()]  # start, end of sql, end: the marks of a trace that has no annotations
     t0 = time.perf_counter()
     try:
         with annotate(name):
             with annotate("sql"):
                 frame = session.sql(sql)
             t1 = time.perf_counter()
+            wall.append(time.time_ns())
             with annotate("collect"):
                 out = frame.collect()
         t2 = time.perf_counter()
@@ -72,6 +73,7 @@ def run_query(session, probes, name: str, sql: str, annotate) -> tuple[dict, obj
     except Exception:  # noqa: BLE001 — a query that raises is counted, not fatal
         rec.update(failed=True, error=traceback.format_exc(limit=6)[-1500:])
     rec["t0"], rec["seconds"] = t0, time.perf_counter() - t0
+    rec["wall_ns"] = wall + [time.time_ns()]
     after = probes.outcomes()
     rec["outcomes"] = {k: after[k] - before[k] for k in before}
     rec["stages"] = probes.run_stats_stages()
@@ -95,16 +97,12 @@ def main(argv=None) -> int:
     peaks = load_json(os.path.join(BENCH, "lib", "peaks.json"))
     sys.path.insert(0, ROOT)
     topology = importlib.import_module(f"lib.topology_{config['topology']}")
-    device = require_device(config["chips"], peaks, args.rehearse)
-
-    import jax
 
     queries = [(q, open(os.path.join(BENCH, "queries", f"{q}.sql")).read())
                for q in workload["queries"]]
     results_dir = os.path.join(args.out_dir, "results")
     trace_dir = os.path.join(args.out_dir, "trace")
-    os.makedirs(results_dir, exist_ok=True)
-    record: dict = {"device": device, "executions": [], "traced": None}
+    record: dict = {"executions": [], "traced": None}
     answers: list = []
 
     def write_record() -> None:
@@ -122,9 +120,15 @@ def main(argv=None) -> int:
                 done += 1
         return done
 
-    probes = topology.Probes()
-    session = topology.open_session(config, args.data_dir)
+    # from here on the topology may have processes of its own running (its
+    # chip holders): close_session ends them, with no session where none opened
+    record["device"] = topology.devices(config)
+    session = None
     try:
+        require_device(record["device"], config["chips"], peaks, args.rehearse)
+        os.makedirs(results_dir, exist_ok=True)
+        probes = topology.Probes()
+        session = topology.open_session(config, args.data_dir)
         cache0 = probes.compile_cache()
         t0 = time.perf_counter()
         one_round("first", 0)
@@ -143,12 +147,9 @@ def main(argv=None) -> int:
 
         def window_round(rnd: int) -> int:
             if args.trace and rnd == 0:
-                options = jax.profiler.ProfileOptions()
-                options.python_tracer_level = 0
-                options.enable_hlo_proto = False
-                jax.profiler.start_trace(trace_dir, profiler_options=options)
-                tracing.update(on=True, t0=time.perf_counter())
-            annotate = jax.profiler.TraceAnnotation if tracing["on"] else contextlib.nullcontext
+                tracing.update(annotate=topology.start_trace(session, trace_dir), on=True,
+                               t0=time.perf_counter())
+            annotate = tracing["annotate"] if tracing["on"] else contextlib.nullcontext
             done = one_round("window", rnd, annotate)
             if tracing["on"] and rnd + 1 >= workload["trace_rounds"]:
                 stop_tracing(rnd + 1)
@@ -156,7 +157,7 @@ def main(argv=None) -> int:
 
         def stop_tracing(rounds: int) -> None:
             traced_s = time.perf_counter() - tracing["t0"]
-            jax.profiler.stop_trace()
+            topology.stop_trace(session)
             tracing["on"] = False
             record["traced"] = {"rounds": rounds, "seconds": traced_s,
                                 "stop_s": time.perf_counter() - tracing["t0"] - traced_s}
@@ -169,9 +170,7 @@ def main(argv=None) -> int:
         cache3 = probes.compile_cache()
         record["window_cache"] = {k: cache3[k] - cache2[k]
                                   for k in ("requests", "hits", "misses")}
-        record["memory_stats"] = {
-            k: v for k, v in (jax.devices()[0].memory_stats() or {}).items()
-            if isinstance(v, (int, float))}
+        record["memory_stats"], record["memory_peaks"] = probes.memory_stats()
         record["outcomes_recent"] = probes.outcomes_recent()
     finally:
         topology.close_session(session)

@@ -18,6 +18,7 @@ class Run:
     trace: dict | None    # lib/trace_reduce.reduce_trace's numbers
     round_bytes: float    # lib/work.py: bytes one round's queries must read once
     peaks: dict           # this device's row of lib/peaks.json
+    chips: int = 1        # the chips the cell asked for
 
     def executions(self, phase: str, query: str | None = None) -> list[dict]:
         return [e for e in self.record["executions"]
@@ -96,10 +97,12 @@ def trace_idle(run: Run) -> float | None:
 
 
 def trace_roofline(run: Run, peak: str = "hbm_bytes_per_s") -> float | None:
-    """The least time the chip could take for the traced rounds (their bytes
-    read once over the peak) as a share of the time the device was busy."""
+    """The least time the cell's chips could take for the traced rounds (their
+    bytes read once over the chips' peak together) as a share of the time a
+    chip was busy: the same bytes over the chips the cell pays for, whatever
+    the program does with them."""
     traced = run.record.get("traced")
     if not run.trace or not traced or not run.trace["busy_s"]:
         return None
-    least_s = traced["rounds"] * run.round_bytes / run.peaks[peak]
+    least_s = traced["rounds"] * run.round_bytes / (run.chips * run.peaks[peak])
     return 100.0 * least_s / run.trace["busy_s"]

@@ -1,13 +1,23 @@
 """Topology `standalone_1chip`: scheduler, one executor and the Flight server
 in this process (`SessionContext.standalone`), the TPU engine in-process, so
-the process that runs the queries is the one that holds the chip and can
-trace it. Everything the benchmark takes from the program is in this file:
-the system under test and its counters.
+the process that runs the queries is the one that holds the chip: it reports
+jax's devices, traces itself and reads its own device's memory. Everything the
+benchmark takes from the program is in this file: the system under test and
+its counters. The contract a topology keeps is in bench/README.md.
 """
 
 from __future__ import annotations
 
 import os
+
+
+def devices(config: dict) -> dict:
+    """The devices this process sees; the first thing a run asks, and what
+    initialises jax's backend."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
 
 
 def open_session(config: dict, data_dir: str):
@@ -27,7 +37,26 @@ def open_session(config: dict, data_dir: str):
 
 
 def close_session(session) -> None:
-    session.shutdown()
+    if session is not None:
+        session.shutdown()
+
+
+def start_trace(session, trace_dir: str):
+    """This process's profiler session into `trace_dir`; hands back what a
+    query and its parts are wrapped in, which writes the trace's own marks."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    return jax.profiler.TraceAnnotation
+
+
+def stop_trace(session) -> None:
+    import jax
+
+    jax.profiler.stop_trace()
 
 
 class Probes:
@@ -59,3 +88,13 @@ class Probes:
 
     def compile_cache(self) -> dict:
         return self._runtime.compile_cache_stats()
+
+    def memory_stats(self) -> tuple[dict, dict]:
+        """The numbers of the fullest device's `memory_stats()` (here: the one
+        device the executor uses) and every device's peak bytes by its name."""
+        import jax
+
+        device = jax.devices()[0]
+        stats = {k: v for k, v in (device.memory_stats() or {}).items()
+                 if isinstance(v, (int, float))}
+        return stats, {f"{device.platform}:{device.id}": stats.get("peak_bytes_in_use", 0)}

@@ -100,6 +100,14 @@ def _take(choices: list[str], idx: np.ndarray) -> pa.Array:
     ).cast(pa.string())
 
 
+def _date32(days: np.ndarray) -> pa.Array:
+    """A datetime64[D] column as date32, by way of its int32 day numbers:
+    `pa.array` over a datetime64 array, run beside numpy work on other
+    threads, corrupted the heap (pyarrow 25.0.0, numpy 2.0.2: one generation
+    of the whole orders table in about 150 died; PERF.md, PR 27)."""
+    return pa.array(days.astype("datetime64[D]").astype(np.int32)).cast(pa.date32())
+
+
 def _retail_cents(pk: np.ndarray) -> np.ndarray:
     return 90000 + ((pk // 10) % 20001) + 100 * (pk % 1000)
 
@@ -306,7 +314,7 @@ class _Columns:
         return np.bincount(self.shared("line_order"), weights=charge,
                            minlength=self.n["orders"]) / 100.0
 
-    def o_orderdate(self): return pa.array(self.shared("o_date"))
+    def o_orderdate(self): return _date32(self.shared("o_date"))
 
     def o_orderpriority(self):
         return _take(PRIORITIES, self.ints("orders.o_orderpriority", 0, 5, self.n["orders"]))
@@ -345,13 +353,13 @@ class _Columns:
             self.shared("l_receipt") <= CURRENTDATE, np.where(returned, 0, 1), 2))
 
     def l_linestatus(self): return _take(["F", "O"], self.shared("open_line"))
-    def l_shipdate(self): return pa.array(self.shared("l_ship"))
+    def l_shipdate(self): return _date32(self.shared("l_ship"))
 
     def l_commitdate(self):
-        return pa.array(self.shared("l_odate") + self.ints(
+        return _date32(self.shared("l_odate") + self.ints(
             "lineitem.l_commitdate", 30, 91, self.shared("n_li")).astype("timedelta64[D]"))
 
-    def l_receiptdate(self): return pa.array(self.shared("l_receipt"))
+    def l_receiptdate(self): return _date32(self.shared("l_receipt"))
 
     def l_shipinstruct(self):
         return _take(INSTRUCTS, self.ints("lineitem.l_shipinstruct", 0, 4, self.shared("n_li")))

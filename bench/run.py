@@ -5,12 +5,14 @@
 
 A parent that never touches a device: it generates the configuration's tables
 from --seed (only the columns the configuration lists) under
-data/bench/<config>_seed<n>/, runs the cell in a child process that holds the
-chip (bench/cell.py: topology up, first round, warm-up, window), then — the
-child gone and the device free — computes the plain reference's answers over
-the same files, compares every answer the child got with them, reduces the
-trace, and prints the contract's one JSON object as the last line of standard
-output. Earlier lines (JSON, one object each) are for people.
+data/bench/<config>_seed<n>/, runs the cell in a child process (bench/cell.py:
+topology up, first round, warm-up, window; the chips are held by that child or
+by processes its topology starts), then — the child and its processes gone and
+the device free — computes the plain reference's answers over the same files,
+compares every answer the child got with them, reduces the trace (every file
+the run's processes wrote, as one), and prints the contract's one JSON object
+as the last line of standard output. Earlier lines (JSON, one object each) are
+for people.
 
 A child whose first round missed the persistent compile cache compiled inside
 `first_query_s`; its numbers are thrown away and the cell runs once more in a
@@ -125,17 +127,41 @@ def judge(out_dir: str, wants: dict, limits: dict, generator, attempted: int) ->
     return checks, all(c["value"] <= c["limit"] for c in checks.values())
 
 
-def reduce_traces(out_dir: str, queries: list[str], traced: dict | None) -> dict | None:
-    """The traced rounds' numbers from the profiler's file, which is then
-    removed (tens of MB a run otherwise); None when the trace holds nothing."""
-    trace = None
-    for path in glob.glob(os.path.join(out_dir, "trace", "**", "*.xplane.pb"), recursive=True):
-        t0 = time.time()
-        raw = trace_reduce.read_xplane(path, queries)
-        trace = trace_reduce.reduce_trace(raw["devices"], raw["spans"], queries)
-        emit({"phase": "trace", "xplane_bytes": os.path.getsize(path),
-              "reduce_s": time.time() - t0, "traced": traced, "reduced": trace})
-    shutil.rmtree(os.path.join(out_dir, "trace"), ignore_errors=True)
+def wall_spans(record: dict) -> list:
+    """The traced rounds' queries and their parts as the record has them on
+    the wall clock: (start_ns, duration_ns, name), named as the annotations
+    of a trace are."""
+    rounds = (record.get("traced") or {}).get("rounds", 0)
+    spans = []
+    for e in record["executions"]:
+        if e["phase"] == "window" and e["round"] < rounds:
+            wall = e["wall_ns"]
+            spans.append((wall[0], wall[-1] - wall[0], e["query"]))
+            if len(wall) == 3:
+                spans += [(wall[0], wall[1] - wall[0], "sql"), (wall[1], wall[2] - wall[1], "collect")]
+    return spans
+
+
+def reduce_traces(out_dir: str, queries: list[str], record: dict, chips: int) -> dict | None:
+    """The traced rounds' numbers from the profiler's files, every process's
+    as one trace; the files are then removed (tens of MB a run otherwise).
+    None when the trace holds nothing."""
+    t0 = time.time()
+    trace_dir = os.path.join(out_dir, "trace")
+    files = trace_reduce.read_files(trace_dir, queries)
+    wall = wall_spans(record)
+    merged = trace_reduce.merge_files(files, wall, queries)
+    trace = trace_reduce.reduce_trace(merged["devices"], merged["spans"], queries, chips)
+    skew_ms = None
+    if merged["marks"] == "annotations" and merged["base_wall_ns"] is not None and wall:
+        # a trace with marks of its own that says when it starts: how far the record's
+        # wall-clock marks lie from them — the clock rule, checked in every traced run
+        first = [min(s for s, _, n in spans if n in queries) for spans in (wall, merged["spans"])]
+        skew_ms = (first[0] - merged["base_wall_ns"] - first[1]) / 1e6
+    emit({"phase": "trace", "xplane_bytes": sum(raw["bytes"] for raw in files.values()),
+          "files": len(files), "marks": merged["marks"], "record_marks_skew_ms": skew_ms,
+          "reduce_s": time.time() - t0, "traced": record["traced"], "reduced": trace})
+    shutil.rmtree(trace_dir, ignore_errors=True)
     return trace
 
 
@@ -212,7 +238,7 @@ def main(argv=None, run_child=spawn_child) -> int:
         device["memory_peak_bytes"] = record["memory_stats"].get("peak_bytes_in_use", 0)
         line: dict = {"correct": correct, "attempted": attempted, "failed": failed}
         if args.trace:
-            trace = reduce_traces(out_dir, traffic["queries"], record["traced"])
+            trace = reduce_traces(out_dir, traffic["queries"], record, cell["chips"])
             schema = load_json(os.path.join(BENCH, "lib", generator.SCHEMA_FILE))
             peaks = load_json(os.path.join(BENCH, "lib", "peaks.json"))
             round_bytes = sum(
@@ -220,7 +246,7 @@ def main(argv=None, run_child=spawn_child) -> int:
                                  config["tables"], rows, schema) for q in traffic["queries"])
             line["metrics"] = per_layer(bench, cell, readers.Run(
                 record=record, trace=trace, round_bytes=round_bytes,
-                peaks=peaks.get(device["kind"], {})))
+                peaks=peaks.get(device["kind"], {}), chips=cell["chips"]))
             if trace:
                 device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
                 line["breakdown"] = {"device_ops": trace["device_ops"],

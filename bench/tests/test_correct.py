@@ -42,14 +42,18 @@ def test_a_sound_run_is_correct(workload, capsys):
 def half_the_rows(monkeypatch, tmp_path):
     """The engine sees half of lineitem's files; the reference sees them all."""
     real = topology.open_session
+    calls = []
 
     def broken(config, data_dir):
+        # a directory a call: on a cold compile cache the first child is
+        # thrown away and the cell opens its session a second time
+        calls.append(tmp_path / f"call{len(calls)}")
         for table in config["tables"]:
             files = sorted(os.listdir(os.path.join(data_dir, table)))
-            os.makedirs(tmp_path / table)
+            os.makedirs(calls[-1] / table)
             for f in files[:len(files) // 2] if table == "lineitem" else files:
-                os.symlink(os.path.join(data_dir, table, f), tmp_path / table / f)
-        return real(config, str(tmp_path))
+                os.symlink(os.path.join(data_dir, table, f), calls[-1] / table / f)
+        return real(config, str(calls[-1]))
 
     monkeypatch.setattr(topology, "open_session", broken)
 

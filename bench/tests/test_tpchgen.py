@@ -52,6 +52,15 @@ def test_shapes_and_types(full):
         tpchgen.generate_tpch(full[0], SCALE, SEED, tables={"lineitem": ["l_nosuch"]})
 
 
+def test_dates_are_what_pyarrow_makes_of_datetime64():
+    # the generator goes by way of int32 day numbers (tpchgen._date32 says why)
+    import numpy as np
+
+    days = np.datetime64("1992-01-01") + np.arange(-400, 2600, 7).astype("timedelta64[D]")
+    made = tpchgen._date32(days)
+    assert made.type == pa.date32() and made.equals(pa.array(days))
+
+
 def test_another_seed_gives_other_data(full, tmp_path):
     tpchgen.generate_tpch(str(tmp_path), SCALE, SEED + 1, 2, tables={"lineitem": ["l_quantity"]})
     assert not read(str(tmp_path), "lineitem").equals(read(full[0], "lineitem").select(["l_quantity"]))
