@@ -65,6 +65,18 @@ class SessionContext:
         (reference: SessionContextExt::remote())."""
         return cls(config, mode="remote", scheduler_url=scheduler_url)
 
+    def job_diagnostics(self, job_id: str = "") -> dict:
+        """Remote mode: ONE `job_<id>` record of a query this context
+        collected (default: the last), this process's spans joined with the
+        scheduler's and every executor's, and their counters beside it
+        (client/remote.py `job_diagnostics`; docs/tpu_engine.md
+        #observability). In the other modes every span is already in this
+        process: `RUN_STATS.stages()` holds the record."""
+        if self.mode != "remote":
+            raise ValueError("job_diagnostics asks a remote scheduler; in this mode "
+                             "tracing.RUN_STATS.stages() already holds the job's record")
+        return self._ensure_remote().job_diagnostics(job_id)
+
     def _ensure_cluster(self):
         if self._cluster is None:
             from ballista_tpu.executor.standalone import StandaloneCluster

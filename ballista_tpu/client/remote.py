@@ -36,7 +36,7 @@ from ballista_tpu.errors import ClusterOverloaded, ExecutionError, GrpcError
 from ballista_tpu.proto import pb
 from ballista_tpu.scheduler.grpc_service import scheduler_stub
 from ballista_tpu.serde import encode_plan
-from ballista_tpu.serde_control import decode_job_status
+from ballista_tpu.serde_control import decode_diagnostics, decode_job_status
 
 log = logging.getLogger(__name__)
 
@@ -46,6 +46,13 @@ log = logging.getLogger(__name__)
 # p99 honest; the exponential growth still backs long jobs off to the cap.
 POLL_INTERVAL_S = 0.01
 POLL_INTERVAL_MAX_S = 2.0
+# the growth is also held to a share of the time already waited: a poll
+# interval is what the client adds to a query that finished just after the
+# last poll, so an interval that grew to 2 s put 0-2 s, at random, on a 5 s
+# query (PERF.md, PR 28: q3 read 5.84 s through a remote client where the
+# executors needed 4.7). A twentieth bounds the added wait at 5 % of the
+# query and still reaches the 2 s cap for jobs of 40 s and more.
+POLL_LAG_SHARE = 0.05
 
 # transient codes worth retrying on idempotent rpcs
 _TRANSIENT = (grpc.StatusCode.UNAVAILABLE, grpc.StatusCode.DEADLINE_EXCEEDED)
@@ -76,6 +83,7 @@ class RemoteSchedulerClient:
         self.config = config
         self.session_id: str = ""
         self.submit_retries = 0  # observability: backoffs taken on submit
+        self.last_job_id: str = ""  # the job of the last collect()
 
     def _settings(self) -> list[pb.KeyValuePair]:
         return [pb.KeyValuePair(key=k, value=v) for k, v in self.config.to_key_value_pairs()]
@@ -187,7 +195,8 @@ class RemoteSchedulerClient:
         return last
 
     def wait_for_job(self, job_id: str, timeout: float = 600.0) -> dict:
-        deadline = time.time() + timeout
+        started = time.time()
+        deadline = started + timeout
         # jittered floor: a herd of clients submitting together must not
         # poll in lockstep — each client's cadence starts (and grows) at a
         # random phase, so the scheduler sees a smear instead of spikes
@@ -202,7 +211,8 @@ class RemoteSchedulerClient:
             # exponential poll growth: fast feedback on short jobs, gentle
             # on the scheduler for long ones; jittering the factor keeps
             # initially-synchronized clients from re-converging
-            poll = min(POLL_INTERVAL_MAX_S, poll * (1.25 + 0.5 * random.random()))
+            poll = min(POLL_INTERVAL_MAX_S, poll * (1.25 + 0.5 * random.random()),
+                       max(POLL_INTERVAL_S, POLL_LAG_SHARE * (time.time() - started)))
         raise ExecutionError(f"job {job_id} timed out")
 
     # -- prepared statements -------------------------------------------------
@@ -305,6 +315,49 @@ class RemoteSchedulerClient:
     def job_metrics(self, job_id: str):
         return self.stub.GetJobMetrics(pb.GetJobMetricsParams(job_id=job_id), timeout=10)
 
+    # -- diagnostics: other processes' spans, counters, memory, profiler -------
+
+    def diagnostics(self, job_id: str = "", clear: bool = False) -> dict:
+        """The scheduler's `GetDiagnostics` answer as it came: {"scheduler":
+        part, "executors": [part by executor id and ordinal, ...]}, every
+        alive executor asked side by side (tracing.process_diagnostics says
+        what a part holds). With `job_id`, each process hands out the closed
+        spans it holds of that job and forgets them; `clear` does
+        `RUN_STATS.clear()` in every process. One rpc, outside any job."""
+        from ballista_tpu.tracing import RUN_STATS
+
+        with RUN_STATS.span("bt.diag.fetch", spans=int(bool(job_id)), clear=int(clear)):
+            return decode_diagnostics(self.stub.GetDiagnostics(
+                pb.DiagnosticsParams(job_id=job_id, clear=clear), timeout=60))
+
+    def profile(self, start: bool, trace_dir: str = "") -> dict:
+        """Start (each into a directory of its own under `trace_dir`) or stop
+        a `jax.profiler` session in every executor; a stop returns when every
+        `.xplane.pb` is complete. This process traces nothing."""
+        return decode_diagnostics(self.stub.Profile(
+            pb.ProfileParams(start=start, dir=trace_dir), timeout=600))
+
+    def job_diagnostics(self, job_id: str = "") -> dict:
+        """ONE `job_<id>` record of a query this client collected (default:
+        the last): this process's published record joined with the
+        scheduler's and every executor's spans of the job, on this process's
+        `perf_counter`, ids unique, orphans hung (tracing.join_job_parts).
+        Beside the spans: "parts", the scheduler's and the executors'
+        counters, devices and memory as `diagnostics()` returns them. The
+        other processes drop their spans of the job: a second call gets
+        this process's part alone."""
+        from ballista_tpu.tracing import RUN_STATS, clock_pair, join_job_parts
+
+        job_id = job_id or self.last_job_id
+        answer = self.diagnostics(job_id)
+        own = RUN_STATS.stages().get(f"job_{job_id}", {})
+        parts = [{"process": "client", "clock": clock_pair(), **own}]
+        for part in (answer.get("scheduler"), *answer.get("executors", ())):
+            job = part.pop("job", None) if part else None  # handed over to the one record
+            if job and part.get("clock"):
+                parts.append({"process": part.get("process", ""), "clock": part["clock"], **job})
+        return {**join_job_parts(job_id, parts), "job_id": job_id, "parts": answer}
+
     def collect(self, df) -> pa.Table:
         from ballista_tpu.client.context import fetch_job_results
         from ballista_tpu.config import PUSH_STATUS
@@ -320,6 +373,7 @@ class RemoteSchedulerClient:
                 with RUN_STATS.span("bt.client.wait"):
                     status = self.execute_sql_push(df.sql_text, timeout=timeout)
                 root.set(job=status.get("job_id"))
+                self.last_job_id = status.get("job_id") or ""
             else:
                 with RUN_STATS.span("bt.client.submit"):
                     if sql_ok:
@@ -331,6 +385,7 @@ class RemoteSchedulerClient:
                         physical = df.ctx.create_physical_plan(df.plan)
                         job_id = self.execute_physical(physical)
                     root.set(job=job_id)
+                    self.last_job_id = job_id
                 with RUN_STATS.span("bt.client.wait"):
                     status = self.wait_for_job(job_id, timeout=timeout)
             if status["state"] != "successful":

@@ -17,7 +17,11 @@ import grpc
 
 from ballista_tpu.executor.executor import Executor
 from ballista_tpu.proto import pb
-from ballista_tpu.serde_control import decode_task_definition, encode_task_status
+from ballista_tpu.serde_control import (
+    decode_task_definition,
+    encode_diagnostics,
+    encode_task_status,
+)
 
 log = logging.getLogger(__name__)
 
@@ -119,8 +123,34 @@ class ExecutorGrpcService:
         return pb.RemoveJobDataResult()
 
 
+    def GetDiagnostics(self, request: pb.DiagnosticsParams, context) -> pb.DiagnosticsResult:
+        """This process's spans of a job, counters, devices, memory and clock
+        (tracing.process_diagnostics): pull only, asked by the scheduler on
+        a client's behalf. Nothing on the served path pays for it."""
+        from ballista_tpu.tracing import process_diagnostics
+
+        meta = self.executor.metadata
+        body = process_diagnostics(request.job_id, request.clear, meta.device_ordinal)
+        body.update(process=f"executor:{meta.id}", executor_id=meta.id,
+                    ordinal=meta.device_ordinal)
+        return encode_diagnostics(body)
+
+    def Profile(self, request: pb.ProfileParams, context) -> pb.ProfileResult:
+        """Start (into `dir`) or stop this process's `jax.profiler` session;
+        the stop returns when the `.xplane.pb` is complete."""
+        from ballista_tpu.ops.tpu import runtime
+
+        try:
+            body = runtime.start_profile(request.dir) if request.start else runtime.stop_profile()
+        except RuntimeError as e:
+            context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
+        return encode_diagnostics(body, pb.ProfileResult)
+
+
 _RPCS = {
     "LaunchMultiTask": (pb.LaunchMultiTaskParams, pb.LaunchMultiTaskResult),
+    "GetDiagnostics": (pb.DiagnosticsParams, pb.DiagnosticsResult),
+    "Profile": (pb.ProfileParams, pb.ProfileResult),
     "StopExecutor": (pb.StopExecutorParams, pb.StopExecutorResult),
     "CancelTasks": (pb.CancelTasksParams, pb.CancelTasksResult),
     "RemoveJobData": (pb.RemoveJobDataParams, pb.RemoveJobDataResult),

@@ -183,6 +183,16 @@ def bind_process_ordinal(ordinal: int) -> None:
     os.environ["CLOUD_TPU_TASK_ID"] = "0"
 
 
+def backend_is_up() -> bool:
+    """Whether this process already runs a jax backend (and so holds the
+    chips it can see). Never imports jax and never initialises one."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
 def bound_device(ordinal: int):
     """Resolve the jax.Device for an ordinal. After process-level filtering
     only one device is visible and it wins regardless of ordinal; otherwise
@@ -229,3 +239,44 @@ def platform() -> str:
     """Platform ("tpu", "cpu", ...) of current_device(). The cost model and
     the Pallas interpret switch decide on this."""
     return current_device().platform
+
+
+# ---------------------------------------------------------------- profiler
+# One jax.profiler session a process, started and stopped from outside (the
+# `Profile` rpc): the process that holds a chip is the only one that can
+# trace it. `python_tracer_level = 0` and no HLO protos: the file holds the
+# device's operations and the program's own `bt.*` annotations.
+
+_profile_lock = threading.Lock()
+_profile_dir: str | None = None
+
+
+def start_profile(trace_dir: str) -> dict:
+    """Start this process's profiler session into `trace_dir` (made if it
+    is not there). One session at a time: a second start is an error."""
+    global _profile_dir
+    jax = ensure_jax()
+    with _profile_lock:
+        if _profile_dir is not None:
+            raise RuntimeError(f"a profiler session into {_profile_dir} is already running")
+        os.makedirs(trace_dir, exist_ok=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        _profile_dir = trace_dir
+    return {"dir": trace_dir}
+
+
+def stop_profile() -> dict:
+    """End the session; the `.xplane.pb` is complete when this returns."""
+    global _profile_dir
+    import glob
+
+    with _profile_lock:
+        if _profile_dir is None:
+            raise RuntimeError("no profiler session is running")
+        trace_dir, _profile_dir = _profile_dir, None
+        sys.modules["jax"].profiler.stop_trace()
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    return {"dir": trace_dir, "files": sorted(files)}

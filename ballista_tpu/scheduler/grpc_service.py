@@ -19,6 +19,7 @@ from ballista_tpu.scheduler.server import SchedulerServer
 from ballista_tpu.serde_control import (
     decode_executor_metadata,
     decode_task_status,
+    encode_diagnostics,
     encode_job_status,
     encode_task_definition,
 )
@@ -273,6 +274,69 @@ class SchedulerGrpcService:
                     )
         return out
 
+    # -- diagnostics (pull only: nothing rides the heartbeat) ------------------
+
+    def _ask_executors(self, ask) -> list[dict]:
+        """`ask(slot)` starts a call to an alive executor and hands back what
+        waits for its answer: all are started, then all awaited, so they are
+        asked side by side. Each answer (or the error that took its place)
+        is named by executor id and ordinal, in ordinal order. A client knows
+        only the scheduler's address: the executors' ports are the
+        scheduler's knowledge."""
+        slots = sorted(self.scheduler.executors.alive_executors(),
+                       key=lambda e: (e.metadata.device_ordinal, e.metadata.id))
+
+        def failed(e: Exception) -> dict:  # a lost executor is an answer too
+            return {"error": f"{type(e).__name__}: {e}"[:300]}
+
+        started = []
+        for slot in slots:
+            try:
+                started.append(ask(slot))
+            except Exception as e:  # noqa: BLE001
+                started.append(lambda e=e: failed(e))
+        out = []
+        for slot, wait in zip(slots, started):
+            try:
+                answer = wait() or {}
+            except Exception as e:  # noqa: BLE001
+                answer = failed(e)
+            out.append({**answer, "executor_id": slot.metadata.id,
+                        "ordinal": slot.metadata.device_ordinal})
+        return out
+
+    def GetDiagnostics(self, request: pb.DiagnosticsParams, context) -> pb.DiagnosticsResult:
+        """The scheduler's own part (its `bt.sched.*` / `bt.task.*` spans of
+        the job, its clock; it holds no chip) and every alive executor's,
+        asked side by side, in one answer: {"scheduler": part, "executors":
+        [part, ...]} (tracing.process_diagnostics says what a part holds)."""
+        from ballista_tpu.tracing import process_diagnostics
+
+        launcher, server = self.scheduler.launcher, self.scheduler
+        parts = self._ask_executors(lambda slot: launcher.diagnostics(
+            slot.metadata.id, request.job_id, request.clear, server))
+        own = process_diagnostics(request.job_id, request.clear)
+        own["process"] = f"scheduler:{server.scheduler_id}"
+        return encode_diagnostics({"scheduler": own, "executors": parts})
+
+    def Profile(self, request: pb.ProfileParams, context) -> pb.ProfileResult:
+        """Start or stop a profiler session in every alive executor, each
+        into a directory of its own under `dir` (`executor<ordinal>`, or the
+        executor's id where it is not pinned). The scheduler holds no chip
+        and traces nothing. A stop returns when every file is complete."""
+        import os
+
+        launcher, server = self.scheduler.launcher, self.scheduler
+
+        def ask(slot):
+            m = slot.metadata
+            name = f"executor{m.device_ordinal}" if m.device_ordinal >= 0 else f"executor-{m.id}"
+            return launcher.profile(m.id, request.start,
+                                    os.path.join(request.dir, name) if request.start else "",
+                                    server)
+
+        return encode_diagnostics({"executors": self._ask_executors(ask)}, pb.ProfileResult)
+
     # -- executor-facing -----------------------------------------------------
 
     def RegisterExecutor(self, request: pb.RegisterExecutorParams, context) -> pb.RegisterExecutorResult:
@@ -330,6 +394,8 @@ _RPCS = {
     "CancelJob": (pb.CancelJobParams, pb.CancelJobResult),
     "CleanJobData": (pb.CleanJobDataParams, pb.CleanJobDataResult),
     "GetJobMetrics": (pb.GetJobMetricsParams, pb.GetJobMetricsResult),
+    "GetDiagnostics": (pb.DiagnosticsParams, pb.DiagnosticsResult),
+    "Profile": (pb.ProfileParams, pb.ProfileResult),
     "RegisterExecutor": (pb.RegisterExecutorParams, pb.RegisterExecutorResult),
     "HeartBeatFromExecutor": (pb.HeartBeatParams, pb.HeartBeatResult),
     "UpdateTaskStatus": (pb.UpdateTaskStatusParams, pb.UpdateTaskStatusResult),

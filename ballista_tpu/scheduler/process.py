@@ -22,7 +22,7 @@ from ballista_tpu.scheduler.grpc_service import SchedulerGrpcService, add_schedu
 from ballista_tpu.scheduler.metrics import InMemoryMetricsCollector
 from ballista_tpu.scheduler.server import SchedulerServer, TaskLauncher
 from ballista_tpu.scheduler.state.execution_graph import TaskDescription
-from ballista_tpu.serde_control import encode_task_definition
+from ballista_tpu.serde_control import decode_diagnostics, encode_task_definition
 
 log = logging.getLogger(__name__)
 
@@ -83,6 +83,25 @@ class GrpcTaskLauncher(TaskLauncher):
         addr = f"{slot.metadata.host}:{slot.metadata.grpc_port}"
         stub = self._stub_for(addr)
         stub.RemoveJobData(pb.RemoveJobDataParams(job_id=job_id), timeout=10)
+
+    def _ask(self, executor_id: str, rpc: str, request, timeout: float, server):
+        """Start `rpc` on the executor; the callable handed back waits for
+        its answer (one bytes field of JSON) and decodes it."""
+        slot = server.executors.get(executor_id)
+        if slot is None:
+            return lambda: None
+        stub = self._stub_for(f"{slot.metadata.host}:{slot.metadata.grpc_port}")
+        call = getattr(stub, rpc).future(request, timeout=timeout)
+        return lambda: decode_diagnostics(call.result())
+
+    def diagnostics(self, executor_id: str, job_id: str, clear: bool, server):
+        return self._ask(executor_id, "GetDiagnostics",
+                         pb.DiagnosticsParams(job_id=job_id, clear=clear), 30, server)
+
+    def profile(self, executor_id: str, start: bool, trace_dir: str, server):
+        # a stop writes the whole trace before it answers
+        return self._ask(executor_id, "Profile",
+                         pb.ProfileParams(start=start, dir=trace_dir), 300, server)
 
 
 class SchedulerProcess:

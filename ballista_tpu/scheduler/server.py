@@ -117,6 +117,22 @@ class TaskLauncher:
         the backstop when this never arrives."""
         return
 
+    def diagnostics(self, executor_id: str, job_id: str, clear: bool,
+                    server: "SchedulerServer"):
+        """Ask the executor for its own answer to `GetDiagnostics` (its spans
+        of the job, counters, devices, memory, clock). The call is started
+        here; what comes back is a callable that waits for the answer, so
+        several executors are asked side by side without a thread each. It
+        returns None where the executor lives in the scheduler's process and
+        has no answer of its own."""
+        return lambda: None
+
+    def profile(self, executor_id: str, start: bool, trace_dir: str,
+                server: "SchedulerServer"):
+        """Start or stop the executor's profiler session (`Profile`); started
+        and answered as `diagnostics` is."""
+        return lambda: None
+
 
 @dataclass
 class Event:
@@ -1044,7 +1060,14 @@ class SchedulerServer:
     def _spawn_launch(self, executor_id: str, tasks: list[TaskDescription]) -> None:
         def run():
             try:
-                self.launcher.launch(executor_id, tasks, self)
+                # scheduler -> executor: encoding the tasks and the launch
+                # call itself (one rpc where the executor is another process)
+                first = tasks[0]
+                slot = self.executors.get(executor_id)
+                with RUN_STATS.span("bt.task.launch", job=first.job_id, stage=first.stage_id,
+                                    task=first.task_id, tasks=len(tasks),
+                                    executor=slot.metadata.device_ordinal if slot else -1):
+                    self.launcher.launch(executor_id, tasks, self)
             except Exception as e:  # noqa: BLE001
                 log.warning("launch to %s failed: %s", executor_id, e)
                 self.post(Event("executor_lost", executor_id))

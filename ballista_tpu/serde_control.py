@@ -201,3 +201,30 @@ def decode_job_status(p: pb.JobStatusProto) -> dict:
     if p.HasField("schema"):
         out["schema"] = decode_schema(p.schema)
     return out
+
+
+# -- diagnostics (GetDiagnostics / Profile, both services) --------------------
+# A process's answer is a dictionary of numbers, short strings and span rows
+# (docs/tpu_engine.md#observability lists the keys); it travels as compact
+# JSON in one bytes field.
+
+
+def _plain(obj):
+    """What JSON cannot say (a numpy scalar in a span's numbers) as a number
+    or a string."""
+    try:
+        return float(obj)
+    except (TypeError, ValueError):
+        return str(obj)
+
+
+def encode_diagnostics(body: dict, result=pb.DiagnosticsResult):
+    import json
+
+    return result(body=json.dumps(body, separators=(",", ":"), default=_plain).encode())
+
+
+def decode_diagnostics(p) -> dict:
+    import json
+
+    return json.loads(p.body) if p.body else {}

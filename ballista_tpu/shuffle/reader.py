@@ -106,10 +106,15 @@ class ShuffleReaderExec(ExecutionPlan):
             self.metrics.extra.update(counts)
             # first pull to exhaustion, one span a partition read (never one a
             # batch): it holds what the consumer did between batches too
-            RUN_STATS.add_span(
+            read = RUN_STATS.add_span(
                 "bt.shuffle.read", t0, parent=task_span, partitions=len(locs),
                 bytes=counts["bytes_read_local"] + counts["bytes_fetched_remote"],
                 local=int(counts["fetch_rpcs"] == 0))
+            # what came from another executor over Flight, one span a location,
+            # inside the read (its fetch threads have no span of their own)
+            for start, end, nbytes in ctr.fetches():
+                RUN_STATS.add_span("bt.flight.fetch", start, end_ns=end, parent=read,
+                                   bytes=nbytes)
         if not produced:
             yield _empty_batch(self.schema())
 
@@ -206,6 +211,7 @@ class _FetchCounters:
         self._lock = threading.Lock()
         self._data = {"fetch_rpcs": 0, "bytes_fetched_remote": 0, "bytes_read_local": 0,
                       "checksum_failures": 0, "corruption_retries": 0}
+        self._fetches: list[tuple[int, int, int]] = []
 
     def add(self, key: str, n: int = 1) -> None:
         with self._lock:
@@ -214,6 +220,28 @@ class _FetchCounters:
     def snapshot(self) -> dict:
         with self._lock:
             return dict(self._data)
+
+    def fetched(self, start_ns: int, end_ns: int, nbytes: int) -> None:
+        """One location's fetch over Flight: (start, end, bytes)."""
+        with self._lock:
+            self._fetches.append((start_ns, end_ns, nbytes))
+
+    def fetches(self) -> list[tuple[int, int, int]]:
+        with self._lock:
+            return list(self._fetches)
+
+
+def _note_flight_fetch(counters: "_FetchCounters | None", start_ns: int, nbytes: int) -> None:
+    """`bt.flight.fetch`: one location fetched from another executor over
+    Flight, `start_ns` until now. A shuffle read keeps the interval and
+    records it inside its `bt.shuffle.read`; a fetch outside one (the
+    client's result fetch) records it under the calling thread's open span."""
+    end_ns = time.perf_counter_ns()
+    if counters is not None:
+        counters.fetched(start_ns, end_ns, nbytes)
+    else:
+        RUN_STATS.add_span("bt.flight.fetch", start_ns, end_ns=end_ns,
+                           parent=RUN_STATS.current_span(), bytes=nbytes)
 
 
 class FetchGovernor:
@@ -449,10 +477,14 @@ def _fetch_unit_coalesced(unit: list[int], locs: list[PartitionLocation],
             if counters:
                 counters.add("fetch_rpcs")
             try:
+                t_loc = time.perf_counter_ns()
                 for j, batches, nbytes in fetch_partitions_flight(
                         [locs[i] for i in sub], ctx):
+                    got = sum(b.nbytes for b in batches)
                     if counters:
-                        counters.add("bytes_fetched_remote", sum(b.nbytes for b in batches))
+                        counters.add("bytes_fetched_remote", got)
+                    _note_flight_fetch(counters, t_loc, got)
+                    t_loc = time.perf_counter_ns()
                     publish(sub[j], batches)
                     remaining.remove(sub[j])
                 return []
@@ -533,7 +565,10 @@ def fetch_partition(loc: PartitionLocation, ctx: TaskContext, force_remote: bool
             # incrementally, so a retry around a half-yielded stream would
             # duplicate the first attempt's rows downstream (the
             # reference's fetch_partition_buffered, shuffle_reader.rs:975)
+            t_loc = time.perf_counter_ns()
             batches = list(fetch_partition_flight(loc, ctx))
+            got = sum(b.nbytes for b in batches)
+            _note_flight_fetch(counters, t_loc, got)
         except DataCorrupted as e:
             last = e
             first = not corrupt_seen
@@ -551,7 +586,7 @@ def fetch_partition(loc: PartitionLocation, ctx: TaskContext, force_remote: bool
             if governor:
                 governor.release(addr, token)
         if counters:
-            counters.add("bytes_fetched_remote", sum(b.nbytes for b in batches))
+            counters.add("bytes_fetched_remote", got)
         yield from batches
         return
     cause = "corruption" if isinstance(last, DataCorrupted) else ""

@@ -256,14 +256,17 @@ class RunStats(Mapping):
         return Span(self, name, stack[-1] if stack else None, job, stage, task,
                     attrs, root, start_ns)
 
-    def add_span(self, name: str, start_ns: int, *, parent: Span | None = None,
-                 job=None, stage=None, task=None, **attrs) -> None:
-        """Record an interval that began at `start_ns` elsewhere and ends now:
-        it never joins a thread's nesting and writes no trace annotation.
-        `parent`, a span that contains it, gives it its ids."""
+    def add_span(self, name: str, start_ns: int, *, end_ns: int | None = None,
+                 parent: Span | None = None, job=None, stage=None, task=None,
+                 **attrs) -> Span:
+        """Record an interval that began at `start_ns` elsewhere and ends now
+        (or ended at `end_ns`): it never joins a thread's nesting and writes
+        no trace annotation. `parent`, a span that contains it, gives it its
+        ids. Returns the span, so a later interval can name it as parent."""
         s = Span(self, name, parent, job, stage, task, attrs, False, start_ns)
-        s.end = now_ns()
+        s.end = now_ns() if end_ns is None else end_ns
         self._close(s)
+        return s
 
     def current_span(self) -> Span | None:
         stack = getattr(self._tls, "spans", None)
@@ -291,6 +294,28 @@ class RunStats(Mapping):
         with self._lock:
             return list(self._jobs.get(job, ()))
 
+    def take_job_spans(self, job: str) -> dict:
+        """The closed spans this process holds for `job`, and whatever closed
+        outside any job since the last record, as the rows of a `job_<id>`
+        record — and forgets them: what a process that does not hold the
+        job's root (a remote scheduler or executor) hands to whoever asks
+        (`join_job_parts` makes one record of several processes' answers)."""
+        with self._lock:
+            spans, dropped = self._take(job)
+        return {"spans": span_rows(spans), "spans_dropped": dropped}
+
+    def _take(self, job: str) -> tuple[list[Span], int]:
+        """Under the lock: the job's spans and whatever closed outside any job
+        since the last record, no longer held here, and how many went over
+        the cap."""
+        spans = self._jobs.pop(job, [])
+        dropped = self._dropped.pop(job, 0) + self._dropped.pop(None, 0)
+        for s in self._loose:
+            s._inherit()  # a child that closed before the root had its id
+        spans += self._loose
+        self._loose = []
+        return spans, dropped
+
     def _close(self, span: Span) -> None:
         span._inherit()
         with self._lock:
@@ -314,17 +339,10 @@ class RunStats(Mapping):
     def _publish_job(self, root: Span) -> None:
         """Under the lock: the job's spans, and whatever closed outside any
         job since the last record, as one plain record."""
-        spans = self._jobs.pop(root.job, [])
-        dropped = self._dropped.pop(root.job, 0) + self._dropped.pop(None, 0)
-        for s in self._loose:
-            s._inherit()  # a child that closed before the root had its id
-        spans += self._loose
-        self._loose = []
+        spans, dropped = self._take(root.job)
         _hang_orphans(spans, root)
-        self._keep_stage(f"job_{root.job}", {
-            "spans": [[s.name, s.id, s.parent, round(s.start / 1e9, 6), round(s.end / 1e9, 6),
-                       s.stage, s.task, s.attrs] for s in spans],
-            "spans_dropped": dropped})
+        self._keep_stage(f"job_{root.job}", {"spans": span_rows(spans),
+                                             "spans_dropped": dropped})
 
     # Mapping protocol over the merged snapshot (dict(RUN_STATS) works)
     def __getitem__(self, key):
@@ -340,18 +358,21 @@ class RunStats(Mapping):
             return len(self._merged)
 
 
-def _hang_orphans(spans: list[Span], root: Span) -> None:
-    """Give every span of the root's job that has no parent (the first of its
-    thread, or an `add_span`) the span that contains it with the nearest
+def _hang_orphans(spans: list, root) -> None:
+    """Give every span of the root's job (with no root: of the spans' job)
+    that has no parent (the first of its thread, or an `add_span`; in a
+    record joined from several processes, each process's own roots) the
+    span that contains it with the nearest
     matching ids: same stage before job only, then the shortest. Only spans
     with no task id (the job's and the stages') can adopt, and only one
     that starts no later and ends no earlier, so no span adopts its parent."""
     def outer(s: Span) -> tuple:
         return (s.start, -s.end, s.id)
 
-    holders = [s for s in spans if s.task is None and s.job == root.job]
+    job = root.job if root is not None else (spans[0].job if spans else None)
+    holders = [s for s in spans if s.task is None and s.job == job]
     for o in spans:
-        if o.parent is not None or o is root or o.job != root.job:
+        if o.parent is not None or o is root or o.job != job:
             continue
         best = None
         for c in holders:
@@ -364,7 +385,114 @@ def _hang_orphans(spans: list[Span], root: Span) -> None:
             o.parent = best[1].id
 
 
+def span_rows(spans) -> list[list]:
+    """Spans as the eight-field rows of a `job_<id>` record: name, id,
+    parent, start and end (seconds of `perf_counter`, to the microsecond),
+    stage, task, numbers."""
+    return [[s.name, s.id, s.parent, round(s.start / 1e9, 6), round(s.end / 1e9, 6),
+             s.stage, s.task, s.attrs] for s in spans]
+
+
+def clock_pair() -> list[int]:
+    """This process's span clock and the wall clock, read together (ns):
+    what puts another process's spans on the reader's `perf_counter`."""
+    return [now_ns(), time.time_ns()]
+
+
+class _Row:
+    """A record's row with the attributes `_hang_orphans` reads."""
+
+    __slots__ = ("row", "job", "id", "parent", "start", "end", "stage", "task")
+
+    def __init__(self, row: list, job: str):
+        self.row, self.job = row, job
+        _, self.id, self.parent, self.start, self.end, self.stage, self.task = row[:7]
+
+
+def join_job_parts(job_id: str, parts: list[dict]) -> dict:
+    """ONE `job_<id>` record out of several processes' parts of a job, in
+    the shape `RunStats._publish_job` writes. A part is {"process": name,
+    "clock": `clock_pair()` of that process, "spans": rows, "spans_dropped":
+    n}; the first is the asking process's own (the client's published
+    record with its clock), whose `perf_counter` the record keeps: every
+    other part is shifted onto it through the two clock pairs (on one host
+    processes share CLOCK_MONOTONIC, but nothing here depends on that).
+    Ids are made unique over the parts (a part's ids are offset past every
+    earlier part's, its parents with them). A span without a parent is hung
+    by the rule of `_hang_orphans`: under the span of the job with the
+    nearest matching stage that contains it — an executor's `bt.task.run`
+    under the scheduler's `bt.sched.stage`, the scheduler's own roots under
+    the client's `bt.client.wait`; one that nothing contains (its part's
+    parent process is missing) stays a root. Every row of a part but the
+    first carries the part's index in its numbers as `proc`, and
+    "processes" names them in order. Works on plain lists."""
+    spans: list[list] = []
+    dropped = 0
+    base = None
+    next_id = 0
+    for index, part in enumerate(parts):
+        perf_ns, wall_ns = part["clock"]
+        if base is None:
+            base = wall_ns - perf_ns
+        shift = ((wall_ns - perf_ns) - base) / 1e9
+        rows = part.get("spans") or []
+        offset = next_id
+        for row in rows:
+            name, sid, parent, start, end, stage, task = row[:7]
+            numbers = dict(row[7]) if len(row) > 7 and row[7] else {}
+            if index:
+                numbers["proc"] = index
+            spans.append([name, sid + offset, None if parent is None else parent + offset,
+                          round(start + shift, 6), round(end + shift, 6), stage, task, numbers])
+            next_id = max(next_id, sid + offset)
+        dropped += part.get("spans_dropped", 0)
+    held = [_Row(row, job_id) for row in spans]
+    ids = {r.id for r in held}
+    for r in held:
+        if r.parent is not None and r.parent not in ids:
+            r.parent = None  # its parent went over the cap, or closed after the fetch
+    roots = [r for r in held if r.parent is None and r.row[0] == "bt.client.collect"]
+    root = max(roots, key=lambda r: r.end - r.start) if roots else None
+    _hang_orphans(held, root)
+    for r in held:
+        r.row[2] = r.parent
+    return {"spans": spans, "spans_dropped": dropped,
+            "processes": [part.get("process", str(i)) for i, part in enumerate(parts)]}
+
+
 RUN_STATS = RunStats()
+
+
+def _plain_values(rec: dict) -> dict:
+    return {k: v for k, v in rec.items() if isinstance(v, (int, float, str, list, tuple))}
+
+
+def process_diagnostics(job_id: str = "", clear: bool = False,
+                        device_ordinal: int = -1) -> dict:
+    """What THIS process answers to `GetDiagnostics`: its clock pair; the
+    closed spans it holds for `job_id` (handed out once: fetching drops
+    them); `RUN_STATS.stages()` as numbers and short strings;
+    `STAGE_OUTCOMES.snapshot()`; and, only where the process already runs a
+    jax backend (an executor that holds a chip; never a scheduler or a
+    client), the devices it sees, its device's `memory_stats()` and
+    `runtime.compile_cache_stats()`. `clear` then does `RUN_STATS.clear()`.
+    Never imports jax and never initialises a backend."""
+    out: dict = {"clock": clock_pair(),
+                 "job": RUN_STATS.take_job_spans(job_id) if job_id else None,
+                 "stages": {tag: _plain_values(rec) for tag, rec in RUN_STATS.stages().items()},
+                 "outcomes": STAGE_OUTCOMES.snapshot(),
+                 "devices": None, "memory": None, "compile_cache": None}
+    runtime = sys.modules.get("ballista_tpu.ops.tpu.runtime")
+    if runtime is not None and runtime.backend_is_up():
+        device = runtime.bound_device(device_ordinal) or runtime.current_device()
+        out["devices"] = {"platform": device.platform, "kind": device.device_kind,
+                          "count": len(device.client.local_devices()),
+                          "ordinal": device_ordinal, "id": device.id}
+        out["memory"] = _plain_values(device.memory_stats() or {})
+        out["compile_cache"] = runtime.compile_cache_stats()
+    if clear:
+        RUN_STATS.clear()
+    return out
 
 
 class StageOutcomes:

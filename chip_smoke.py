@@ -26,8 +26,10 @@ one chip   TPC-H at --scale is generated from --seed, registered on
 --chips 4  only this: the parent stays off jax, starts a scheduler and four
            `python -m ballista_tpu.executor --engine tpu --device-ordinal i`
            processes, runs q3 and q5 through `SessionContext.remote`, compares
-           with the oracle, and checks from each executor's heartbeat gauges
-           that its stages ran on a TPU it alone holds, with no fallbacks.
+           with the oracle, and checks from each executor's own answer to the
+           `GetDiagnostics` rpc (asked of the scheduler: the device it holds,
+           its cumulative stage ledger) that its stages ran on a TPU it alone
+           holds, with no fallbacks.
 
 Earlier lines of standard output are one JSON object each (smoke timings, not
 benchmark results). The LAST line is
@@ -51,7 +53,6 @@ import subprocess
 import sys
 import time
 import traceback
-import urllib.request
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 # the scale one chip holds of the SF100 / v5e-8 deployment in BASELINE.json
@@ -68,7 +69,6 @@ REDUCED_WHY = ("whole script must fit 1200 s with compilation (last timed at "
                "PR 21, with eight dispatches a stage; one since PR 26) and the "
                "sorted path's 2^22 group capacity overflows on q18 from SF3")
 LABEL = "smoke timing, not a benchmark result"
-HEARTBEAT_WAIT_S = 15  # > the executor's heartbeat interval
 
 TPCH_QUERIES = (1, 6, 3, 5, 18)
 FOUR_CHIP_QUERIES = (3, 5)
@@ -392,11 +392,6 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def get_json(url: str):
-    with urllib.request.urlopen(url, timeout=5) as r:
-        return json.load(r)
-
-
 def stop_cluster(procs: dict) -> None:
     """Stop every process this script started, children included.
     Executors go first, while the scheduler still answers: SIGTERM is their
@@ -428,9 +423,9 @@ def stop_cluster(procs: dict) -> None:
 
 
 def holds_one_tpu(executor: dict) -> bool:
-    """From an executor's heartbeat gauges: the device it claimed at start-up
-    is a TPU and the only one its process sees."""
-    return executor["is_tpu"] == 1.0 and executor["local_device_count"] == 1.0
+    """From an executor's own answer to `GetDiagnostics`: the device it
+    claimed at start-up is a TPU and the only one its process sees."""
+    return executor["platform"] == "tpu" and executor["local_device_count"] == 1
 
 
 def four_chips(args) -> tuple[bool, dict]:
@@ -443,7 +438,7 @@ def four_chips(args) -> tuple[bool, dict]:
     os.makedirs(out_dir)
     env = dict(os.environ)
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    port, rest = free_port(), free_port()
+    port = free_port()
     procs: dict[str, subprocess.Popen] = {}
     logs: list[str] = []
 
@@ -459,21 +454,21 @@ def four_chips(args) -> tuple[bool, dict]:
                 start_new_session=True)
 
     def placement() -> tuple[list, bool]:
-        """Each executor's heartbeat gauges: the device it claimed at start
-        and its cumulative stage ledger. One chip each: every process holds
-        exactly one TPU device under its own ordinal, and a chip cannot
-        belong to two live processes at once."""
+        """Each executor's own answer, asked of the scheduler in one rpc:
+        the device it claimed at start and its cumulative stage ledger. One
+        chip each: every process holds exactly one TPU device under its own
+        ordinal, and a chip cannot belong to two live processes at once."""
         per = []
-        for e in sorted(get_json(api), key=lambda e: e["device_ordinal"]):
-            g = e.get("tpu_stages", {})
+        for e in client.diagnostics()["executors"]:
+            d, led = e.get("devices") or {}, e.get("outcomes") or {}
             per.append({
-                "ordinal": e["device_ordinal"], "id": e["id"],
-                "is_tpu": g.get("tpu_device_is_tpu"),
-                "local_device_count": g.get("tpu_local_device_count"),
-                "device_runs": g.get("tpu_stage_device_runs", 0.0),
-                "below_row_floor": g.get("tpu_stage_below_row_floor", 0.0),
-                "declined": g.get("tpu_stage_declined", 0.0),
-                "errors": g.get("tpu_stage_errors", 0.0)})
+                "ordinal": e["ordinal"], "id": e["executor_id"],
+                "platform": d.get("platform"), "kind": d.get("kind"),
+                "local_device_count": d.get("count"),
+                "device_runs": led.get("device", 0),
+                "below_row_floor": led.get("below_row_floor", 0),
+                "declined": led.get("declined", 0), "errors": led.get("error", 0),
+                "stages": sorted(e.get("stages") or ())})
         good = (sorted(r["ordinal"] for r in per) == list(range(n))
                 and all(holds_one_tpu(r) for r in per)
                 and all(p.poll() is None for p in procs.values()))
@@ -489,11 +484,10 @@ def four_chips(args) -> tuple[bool, dict]:
     ok = True
     device = {"platform": "unknown", "kind": "unknown", "count": 0}
     data_dir = None
-    api = f"http://127.0.0.1:{rest}/api/executors"
     t_start = time.time()
     try:
         spawn("scheduler", ["ballista_tpu.scheduler", "--bind-host", "127.0.0.1",
-                            "--port", str(port), "--rest-port", str(rest),
+                            "--port", str(port), "--rest-port", "-1",
                             "--flight-proxy-port", "0", "--log-level", "WARNING"])
         for i in range(n):
             spawn(f"executor{i}", [
@@ -502,17 +496,22 @@ def four_chips(args) -> tuple[bool, dict]:
                 "--engine", "tpu", "--device-ordinal", str(i),
                 "--work-dir", os.path.join(out_dir, f"work{i}"),
                 "--log-level", "INFO"])
+        import grpc
+
+        from ballista_tpu.client.remote import RemoteSchedulerClient
+        from ballista_tpu.config import BallistaConfig
+
+        client = RemoteSchedulerClient(f"127.0.0.1:{port}", BallistaConfig())
         deadline = time.time() + 240
         per: list = []
         while time.time() < deadline:
             dead = [k for k, p in procs.items() if p.poll() is not None]
             if dead:
                 raise RuntimeError(f"exited during start-up: {dead}")
-            with contextlib.suppress(OSError):
+            with contextlib.suppress(grpc.RpcError):
                 per, _ = placement()
-            # registered AND past the first heartbeat (which carries the
-            # device each executor claimed at start-up)
-            if len(per) == n and all(r["is_tpu"] is not None for r in per):
+            # registered, and each says what device it claimed at start-up
+            if len(per) == n and all(r["platform"] is not None for r in per):
                 break
             time.sleep(1.0)
         per, good = placement()
@@ -530,7 +529,7 @@ def four_chips(args) -> tuple[bool, dict]:
         emit(line)
 
         from ballista_tpu.client.context import SessionContext
-        from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
+        from ballista_tpu.config import EXECUTOR_ENGINE
         from ballista_tpu.testing.reference import compare_results
         from ballista_tpu.testing.tpchgen import register_tpch
 
@@ -554,21 +553,16 @@ def four_chips(args) -> tuple[bool, dict]:
             ok &= not problems
         assert "jax" not in sys.modules, "the --chips 4 parent touched jax"
 
-        # wait for a heartbeat after the last query, then read the ledgers
-        time.sleep(HEARTBEAT_WAIT_S)
+        # the ledgers as the executors hold them now: no heartbeat to wait for
         per, good = placement()
         placed = good and all(r["device_runs"] > 0 and r["errors"] == 0
                               and r["declined"] == 0 for r in per)
         emit({"phase": "placement", "executors": per, "ok": placed})
         ok &= placed
         if placed:
-            kind = ""
-            for path in logs[1:]:
-                with open(path, "rb") as f:
-                    for ln in f.read().decode(errors="replace").splitlines():
-                        if "device_kind=" in ln:
-                            kind = ln.split("device_kind=", 1)[1].strip()
-            device = {"platform": "tpu", "kind": kind or "unknown", "count": n}
+            kinds = sorted({r["kind"] for r in per})
+            device = {"platform": "tpu", "kind": kinds[0] if len(kinds) == 1 else str(kinds),
+                      "count": n}
     except Exception:  # noqa: BLE001 — a failed phase, reported
         emit({"phase": "four_chips", "ok": False,
               "problems": [traceback.format_exc(limit=8)], "logs": tails()})
