@@ -169,20 +169,54 @@ def lex_order(keys):
     return perm
 
 
+# rows this short are summed as a masked triangle, not scanned (int_cumsum)
+CUMSUM_TRIANGLE = 64
+
+
 def int_cumsum(x, block: int = 2048):
     """Inclusive cumulative sum of a 1-D INTEGER array in its own dtype, as
     per-block cumsums plus the running block totals. Exact (integer addition
     is associative) and far cheaper for the chip's compiler than one flat
     scan: compiling for a v5e at 2^26 int64 rows, `jnp.cumsum` takes 77 s,
-    this form 3 s. Lengths the block does not divide take the flat scan."""
+    this form 3 s. A length the block does not divide is padded with zeros
+    to the next one it does. A length within one block holds no scan at all:
+    inside a conditional's branch or a loop's body the chip's compiler
+    cannot place a 64-bit scan of 256 to 1024 elements (it runs out of
+    scoped vmem), and the sorted path's tiers are conditionals. Its blocks
+    are CUMSUM_TRIANGLE long and each is summed as a masked triangle (64
+    additions an element)."""
     jnp = _jnp()
     M = x.shape[0]
-    if M <= block or M % block:
-        return jnp.cumsum(x, dtype=x.dtype)
-    inner = jnp.cumsum(x.reshape(-1, block), axis=1, dtype=x.dtype)
+    if M == 0:
+        return x
+    width = block if M > block else min(M, CUMSUM_TRIANGLE)
+    if M % width:
+        return int_cumsum(jnp.pad(x, (0, -M % width)), block)[:M]
+    rows = x.reshape(-1, width)
+    if width == block:
+        inner = jnp.cumsum(rows, axis=1, dtype=x.dtype)
+    else:
+        at = jnp.arange(width, dtype=jnp.int32)
+        inner = jnp.sum(jnp.where(at[None, :] <= at[:, None], rows[:, None, :], 0),
+                        axis=2, dtype=x.dtype)
+    if M == width:
+        return inner[0]
     totals = inner[:, -1]
-    before = jnp.cumsum(totals, dtype=x.dtype) - totals
+    before = int_cumsum(totals, block) - totals
     return (inner + before[:, None]).reshape(-1)
+
+
+def live_slots(valid, cap: int):
+    """Slots (int32 [cap]) of the first `cap` True rows of the 1-D mask
+    `valid`, in slot order; past the live count, the last slot. The j-th
+    live row is where the running count of live rows first reaches j + 1: a
+    binary search of the prefix sum — log2(M) steps of cap-row gathers, and
+    no M-row gather, sort or scatter, which the TPU does slowly."""
+    jnp = _jnp()
+    seen = int_cumsum(valid.astype(jnp.int32))
+    nth = jnp.arange(1, cap + 1, dtype=jnp.int32)
+    return jnp.minimum(jnp.searchsorted(seen, nth, side="left"),
+                       valid.shape[0] - 1).astype(jnp.int32)
 
 
 # -- bit-exact twin of ops/hashing.py ---------------------------------------

@@ -61,6 +61,7 @@ from ballista_tpu.ops.tpu.kernels import (
     Unsupported,
     int_cumsum,
     lex_order,
+    live_slots,
     lower_expr,
     true_mask,
 )
@@ -82,6 +83,12 @@ from ballista_tpu.plan.schema import DFSchema
 log = logging.getLogger(__name__)
 
 MAX_SEGMENTS = 1 << 16
+# the sorted path holds at most this many groups a dispatch, and orders its
+# LIVE rows only: of a stage's M row slots it takes M / 64 where that holds
+# them, else M (_compile_sorted). Every tier is one more ordering to trace,
+# compile and load: add one only for a measured workload that lands in it
+SORTED_MAX_GROUPS = 1 << 22
+SORTED_LIVE_TIERS = (64,)
 
 # LruDict moved to utils/lru.py (PR 9) so CPU-side modules can bound their
 # caches without importing this module — the executor heartbeat keys TPU
@@ -2085,6 +2092,17 @@ class TpuStageExec(ExecutionPlan):
         sliced to pow2(actual segment count), so a 4M-slot capacity costs
         nothing when a query yields 10k groups. Overflow (> C distinct
         groups) raises and the stage re-runs on the CPU engine.
+
+        Gathers and scatters are what the chip does slowly, and all of the
+        above is gathers and scatters over every row slot. So the program
+        counts its live rows (the slots the filters and join matches left
+        valid) and a `lax.switch` on the count runs all of it over the
+        smaller capacity of `M / 64` and `M` (SORTED_LIVE_TIERS) that holds
+        them: below `M`, over the live rows gathered, in slot order, through
+        `kernels.live_slots`; at `M`, over the slots as they are. One body
+        (`reduce_rows`) serves every tier, each padding its outputs to [C].
+        The last output is int32 (groups, live rows, slots ordered): RunStats
+        `sorted_rows_live` / `sorted_rows_ordered`.
         """
         jax = ensure_jax()
         jnp = jax.numpy
@@ -2094,7 +2112,10 @@ class TpuStageExec(ExecutionPlan):
         lane_sets = ctx.lane_sets
         lane_cells = ctx.lane_cells
         M = P * N * len(lane_sets)
-        C = min(_pow2(M), 1 << 22)
+        C = min(_pow2(M), SORTED_MAX_GROUPS)
+        # the capacities a dispatch may order its live rows at, from M alone
+        # (so the compile key and `meta` stay what they are)
+        capacities = sorted({-(-M // d) for d in SORTED_LIVE_TIERS} - {M}) + [M]
         meta_holder: dict = {}
         # device-side shuffle routing: emit a __pid column over the
         # compacted output rows (bit-exact twin of ops/hashing.py — string
@@ -2250,6 +2271,162 @@ class TpuStageExec(ExecutionPlan):
                     meta_holder["pay_plan"] = pay_plan
                     lane_pays.append(pays)
 
+            def reduce_rows(valid, keys, pays):
+                """The ordering, the segmented reduction and the routing hash
+                over ONE row set of any static length: the whole stage's M
+                slots, or its live rows compacted into a tier's capacity.
+                Outputs are padded to the stage's [C], so every tier has the
+                one signature."""
+                Mt = valid.shape[0]
+                Ct = min(_pow2(Mt), SORTED_MAX_GROUPS)
+                with jax.named_scope("sorted_agg"):
+                    perm = lex_order([~valid] + [
+                        k.astype(jnp.int32) if fits and k.dtype == jnp.int64 else k
+                        for k, fits in zip(keys, key_narrow)])
+                    svalid = valid[perm]
+                    skeys = [k[perm] for k in keys]
+                    spays = [p[perm] for p in pays]
+
+                    diff = jnp.zeros((Mt,), bool).at[0].set(True)
+                    for k in skeys:
+                        diff = diff | jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+                    boundary = svalid & diff
+                    seg = int_cumsum(boundary.astype(jnp.int32)) - 1
+                    bor_inv = boundary | ~svalid
+                    is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
+                    n_seg = boundary.sum().astype(jnp.int32)
+
+                    arange = jnp.arange(Mt, dtype=jnp.int32)
+                    # segment-start position of each row's segment, via one scatter
+                    # + gather (indices unique: one boundary row per segment)
+                    spos = (
+                        jnp.zeros((Ct,), jnp.int32)
+                        .at[jnp.where(boundary, seg, Ct)]
+                        .set(arange, mode="drop", unique_indices=True)
+                    )
+                    start = spos[jnp.clip(seg, 0, Ct - 1)]
+                    end_idx = jnp.where(is_end, seg, Ct)
+
+                    def compact(src):
+                        if src.dtype == jnp.int64:
+                            # as two 32-bit scatters: the chip scatters a 64-bit
+                            # lane as one two-operand scatter that takes ~25x a
+                            # 32-bit one (0.94 s against 0.041 s for 2^23 updates)
+                            lo = compact((src & 0xFFFFFFFF).astype(jnp.uint32))
+                            hi = compact((src >> 32).astype(jnp.int32))
+                            return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+                        return (
+                            jnp.zeros((Ct,), src.dtype)
+                            .at[end_idx]
+                            .set(src, mode="drop", unique_indices=True)
+                        )
+
+                    def int_segsum(sv):
+                        # exact int64: global cumsum minus prefix-at-segment-start
+                        w = sv.astype(jnp.int64)
+                        csum = int_cumsum(w)
+                        presum = csum - w  # exclusive
+                        return compact(csum - presum[start])
+
+                    key_outs = [compact(k) for k in skeys]
+                    agg_outs = []
+                    ncnt_outs = []
+                    ncnt_map: dict[int, int] = {}
+                    welford_stats: dict[int, tuple] = {}  # pay_idx → (c_c, mean_c, ncnt_pos)
+                    for ai, (d, (pay_idx, ncnt_idx)) in enumerate(
+                        zip(aggs, meta_holder["pay_plan"])
+                    ):
+                        if pay_idx is None:
+                            agg_outs.append(compact((arange - start + 1).astype(jnp.int64)))
+                            continue
+                        sv = spays[pay_idx]
+                        if d.func in ("welford_mean", "welford_m2"):
+                            # two-pass variance partial over sorted segments: segment
+                            # mean via float segscan, then gather the mean back per
+                            # row (seg indexes the compacted [Ct] space) for the
+                            # centered square sum — stable, no cancellation. The
+                            # (mean, m2) pair shares payload lanes and stats.
+                            if pay_idx in welford_stats:
+                                c_c, mean_c, ncnt_pos = welford_stats[pay_idx]
+                            else:
+                                if ncnt_idx is not None:
+                                    c_c = int_segsum(spays[ncnt_idx])
+                                else:
+                                    c_c = compact((arange - start + 1).astype(jnp.int64))
+                                s1_c = compact(_segscan(jnp, sv, boundary, "sum"))
+                                mean_c = s1_c / jnp.maximum(c_c, 1).astype(sv.dtype)
+                                ncnt_pos = None
+                                if ncnt_idx is not None:
+                                    ncnt_pos = len(ncnt_outs)
+                                    ncnt_outs.append(c_c)
+                                welford_stats[pay_idx] = (c_c, mean_c, ncnt_pos)
+                            if d.func == "welford_mean":
+                                agg_outs.append(mean_c)
+                            else:
+                                mean_row = mean_c[jnp.clip(seg, 0, Ct - 1)]
+                                d2 = (sv - mean_row) ** 2
+                                if ncnt_idx is not None:
+                                    # null x slots were sum-neutralized to 0; keep
+                                    # them out of the square sum too
+                                    d2 = jnp.where(spays[ncnt_idx] > 0, d2, 0.0)
+                                agg_outs.append(compact(_segscan(jnp, d2, boundary, "sum")))
+                            if ncnt_pos is not None:
+                                ncnt_map[ai] = ncnt_pos
+                            continue
+                        fname = "sum" if d.func in ("count", "count_all") else d.func
+                        if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
+                            agg_outs.append(int_segsum(sv))
+                        else:
+                            # float sums use the segmented scan too: cumsum-subtract
+                            # would difference two near-equal whole-table totals
+                            # (catastrophic cancellation for small late segments)
+                            agg_outs.append(compact(_segscan(jnp, sv, boundary, fname)))
+                        if ncnt_idx is not None:
+                            ncnt_map[ai] = len(ncnt_outs)
+                            ncnt_outs.append(int_segsum(spays[ncnt_idx]))
+                    meta_holder["nullcnt_map"] = ncnt_map
+
+                outs = key_outs + agg_outs + ncnt_outs
+                if emit_keys is not None:
+                    from ballista_tpu.ops.tpu.kernels import hash64, hash_combine_jax
+
+                    with jax.named_scope("emit"):
+                        # key_outs layout: optional marker precedes each nullable
+                        # key's value — build a key→(marker, value) position map
+                        pos = 0
+                        key_pos = []
+                        for (_k, _s, _slot, hn) in meta_holder["key_meta"]:
+                            key_pos.append((pos if hn else None, pos + (1 if hn else 0)))
+                            pos += 2 if hn else 1
+                        _NULL_TAG = jnp.uint64(0x9E3779B97F4A7C15)
+                        h = jnp.zeros((Ct,), jnp.uint64)
+                        for ki in emit_keys:
+                            kind, scale, slot, _hn = meta_holder["key_meta"][ki]
+                            mpos, vpos = key_pos[ki]
+                            arr = key_outs[vpos]
+                            if kind == "code":
+                                enc = luts[emit_luts[ki]][arr]
+                            else:  # i64 / date / bool — value-preserving int64 bits
+                                enc = arr.astype(jnp.int64).astype(jnp.uint64)
+                            hv = hash64(enc)
+                            if mpos is not None:
+                                hv = jnp.where(key_outs[mpos] != 0, _NULL_TAG, hv)
+                            h = hash_combine_jax(h, hv)
+                        pid = (h % jnp.uint64(emit_k)).astype(jnp.int32)
+                    outs.append(pid)
+                return tuple(jnp.pad(o, (0, C - Ct)) for o in outs) + (n_seg,)
+
+            def at_capacity(cap):
+                def branch(valid, keys, pays, n_live):
+                    if cap == M:  # mostly alive: over the slots as they are
+                        return reduce_rows(valid, keys, pays)
+                    with jax.named_scope("compact_live"):
+                        src = live_slots(valid, cap)
+                        live = (jnp.arange(cap, dtype=jnp.int32) < n_live,
+                                [k[src] for k in keys], [p[src] for p in pays])
+                    return reduce_rows(*live)
+                return branch
+
             with jax.named_scope("sorted_agg"):
                 valid = jnp.concatenate(lane_valid)
                 n_keyops = len(lane_keyops[0])
@@ -2260,133 +2437,13 @@ class TpuStageExec(ExecutionPlan):
                     jnp.concatenate([lp[i] for lp in lane_pays])
                     for i in range(len(lane_pays[0]))
                 ]
-                perm = lex_order([~valid] + [
-                    k.astype(jnp.int32) if fits and k.dtype == jnp.int64 else k
-                    for k, fits in zip(cat_keys, key_narrow)])
-                svalid = valid[perm]
-                skeys = [k[perm] for k in cat_keys]
-                spays = [p[perm] for p in cat_pays]
-
-                diff = jnp.zeros((M,), bool).at[0].set(True)
-                for k in skeys:
-                    diff = diff | jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
-                boundary = svalid & diff
-                seg = int_cumsum(boundary.astype(jnp.int32)) - 1
-                bor_inv = boundary | ~svalid
-                is_end = svalid & jnp.concatenate([bor_inv[1:], jnp.ones((1,), bool)])
-                n_seg = boundary.sum().astype(jnp.int32)
-
-                arange = jnp.arange(M, dtype=jnp.int32)
-                # segment-start position of each row's segment, via one scatter
-                # + gather (indices unique: one boundary row per segment)
-                spos = (
-                    jnp.zeros((C,), jnp.int32)
-                    .at[jnp.where(boundary, seg, C)]
-                    .set(arange, mode="drop", unique_indices=True)
-                )
-                start = spos[jnp.clip(seg, 0, C - 1)]
-                end_idx = jnp.where(is_end, seg, C)
-
-                def compact(src):
-                    return (
-                        jnp.zeros((C,), src.dtype)
-                        .at[end_idx]
-                        .set(src, mode="drop", unique_indices=True)
-                    )
-
-                def int_segsum(sv):
-                    # exact int64: global cumsum minus prefix-at-segment-start
-                    w = sv.astype(jnp.int64)
-                    csum = int_cumsum(w)
-                    presum = csum - w  # exclusive
-                    return compact(csum - presum[start])
-
-                key_outs = [compact(k) for k in skeys]
-                agg_outs = []
-                ncnt_outs = []
-                ncnt_map: dict[int, int] = {}
-                welford_stats: dict[int, tuple] = {}  # pay_idx → (c_c, mean_c, ncnt_pos)
-                for ai, (d, (pay_idx, ncnt_idx)) in enumerate(
-                    zip(aggs, meta_holder["pay_plan"])
-                ):
-                    if pay_idx is None:
-                        agg_outs.append(compact((arange - start + 1).astype(jnp.int64)))
-                        continue
-                    sv = spays[pay_idx]
-                    if d.func in ("welford_mean", "welford_m2"):
-                        # two-pass variance partial over sorted segments: segment
-                        # mean via float segscan, then gather the mean back per
-                        # row (seg indexes the compacted [C] space) for the
-                        # centered square sum — stable, no cancellation. The
-                        # (mean, m2) pair shares payload lanes and stats.
-                        if pay_idx in welford_stats:
-                            c_c, mean_c, ncnt_pos = welford_stats[pay_idx]
-                        else:
-                            if ncnt_idx is not None:
-                                c_c = int_segsum(spays[ncnt_idx])
-                            else:
-                                c_c = compact((arange - start + 1).astype(jnp.int64))
-                            s1_c = compact(_segscan(jnp, sv, boundary, "sum"))
-                            mean_c = s1_c / jnp.maximum(c_c, 1).astype(sv.dtype)
-                            ncnt_pos = None
-                            if ncnt_idx is not None:
-                                ncnt_pos = len(ncnt_outs)
-                                ncnt_outs.append(c_c)
-                            welford_stats[pay_idx] = (c_c, mean_c, ncnt_pos)
-                        if d.func == "welford_mean":
-                            agg_outs.append(mean_c)
-                        else:
-                            mean_row = mean_c[jnp.clip(seg, 0, C - 1)]
-                            d2 = (sv - mean_row) ** 2
-                            if ncnt_idx is not None:
-                                # null x slots were sum-neutralized to 0; keep
-                                # them out of the square sum too
-                                d2 = jnp.where(spays[ncnt_idx] > 0, d2, 0.0)
-                            agg_outs.append(compact(_segscan(jnp, d2, boundary, "sum")))
-                        if ncnt_pos is not None:
-                            ncnt_map[ai] = ncnt_pos
-                        continue
-                    fname = "sum" if d.func in ("count", "count_all") else d.func
-                    if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
-                        agg_outs.append(int_segsum(sv))
-                    else:
-                        # float sums use the segmented scan too: cumsum-subtract
-                        # would difference two near-equal whole-table totals
-                        # (catastrophic cancellation for small late segments)
-                        agg_outs.append(compact(_segscan(jnp, sv, boundary, fname)))
-                    if ncnt_idx is not None:
-                        ncnt_map[ai] = len(ncnt_outs)
-                        ncnt_outs.append(int_segsum(spays[ncnt_idx]))
-                meta_holder["nullcnt_map"] = ncnt_map
-
-            if emit_keys is not None:
-                from ballista_tpu.ops.tpu.kernels import hash64, hash_combine_jax
-
-                with jax.named_scope("emit"):
-                    # key_outs layout: optional marker precedes each nullable
-                    # key's value — build a key→(marker, value) position map
-                    pos = 0
-                    key_pos = []
-                    for (_k, _s, _slot, hn) in meta_holder["key_meta"]:
-                        key_pos.append((pos if hn else None, pos + (1 if hn else 0)))
-                        pos += 2 if hn else 1
-                    _NULL_TAG = jnp.uint64(0x9E3779B97F4A7C15)
-                    h = jnp.zeros((C,), jnp.uint64)
-                    for ki in emit_keys:
-                        kind, scale, slot, _hn = meta_holder["key_meta"][ki]
-                        mpos, vpos = key_pos[ki]
-                        arr = key_outs[vpos]
-                        if kind == "code":
-                            enc = luts[emit_luts[ki]][arr]
-                        else:  # i64 / date / bool — value-preserving int64 bits
-                            enc = arr.astype(jnp.int64).astype(jnp.uint64)
-                        hv = hash64(enc)
-                        if mpos is not None:
-                            hv = jnp.where(key_outs[mpos] != 0, _NULL_TAG, hv)
-                        h = hash_combine_jax(h, hv)
-                    pid = (h % jnp.uint64(emit_k)).astype(jnp.int32)
-                return tuple(key_outs) + tuple(agg_outs) + tuple(ncnt_outs) + (pid, n_seg)
-            return tuple(key_outs) + tuple(agg_outs) + tuple(ncnt_outs) + (n_seg,)
+                # a scalar predicate outside any vmap: only the taken tier runs
+                n_live = valid.sum(dtype=jnp.int32)
+                tier = sum((n_live > cap).astype(jnp.int32) for cap in capacities[:-1])
+            *outs, n_seg = jax.lax.switch(tier, [at_capacity(cap) for cap in capacities],
+                                          valid, cat_keys, cat_pays, n_live)
+            counts = jnp.stack([n_seg, n_live, jnp.asarray(capacities, jnp.int32)[tier]])
+            return tuple(outs) + (counts,)
 
         raw.__name__ = raw.__qualname__ = "stage_partial_sorted_fused_xla"
         jitted = jax.jit(raw)
@@ -2416,19 +2473,21 @@ class TpuStageExec(ExecutionPlan):
         """Device outputs to Arrow batches per partition. The sorted path
         fetches inside its decode (a count first, then a sliced fetch)."""
         if meta["mode"] == "sorted":
-            with RUN_STATS.span("bt.decode", mode="sorted"):
-                return self._decode_sorted(outs, meta, P, dicts, build_dicts)
+            with RUN_STATS.span("bt.decode", mode="sorted") as span:
+                return self._decode_sorted(outs, meta, P, dicts, build_dicts, span)
         with RUN_STATS.span("bt.device.fetch"):
             outs = ensure_jax().device_get(list(outs))  # ONE batched fetch
         with RUN_STATS.span("bt.decode"):
             return self._decode_all(outs, meta, P, dicts, build_dicts)
 
     def _decode_sorted(self, outs, meta: dict, P: int, dicts,
-                       build_dicts: list) -> dict[int, list[pa.RecordBatch]]:
+                       build_dicts: list, span) -> dict[int, list[pa.RecordBatch]]:
         """Decode the sorted-path compacted outputs. Partial-agg results are
         mergeable, so all segments land in output partition 0 (globally
         deduplicated across input partitions — strictly better reduction
-        than per-partition partials); other partitions emit empty."""
+        than per-partition partials); other partitions emit empty. The
+        program's three counts come in one fetch: the groups, the live rows
+        and the row slots it ordered them at (RunStats, and on `span`)."""
         jax = ensure_jax()
         schema = self.schema()
         key_meta = meta["key_meta"]
@@ -2436,7 +2495,10 @@ class TpuStageExec(ExecutionPlan):
         n_keyops = sum(2 if km[3] else 1 for km in key_meta)
         C = meta["C"]
         with RUN_STATS.span("bt.device.fetch", what="count"):
-            n = int(jax.device_get(outs[-1]))
+            n, n_live, n_ordered = (int(x) for x in jax.device_get(outs[-1]))
+        RUN_STATS.set("sorted_rows_live", n_live)
+        RUN_STATS.set("sorted_rows_ordered", n_ordered)
+        span.set(sorted_rows_live=n_live, sorted_rows_ordered=n_ordered)
         if n > C:
             raise Unsupported(f"group capacity overflow ({n} > {C})")
         results = {p: [_empty_batch(schema)] for p in range(P)}

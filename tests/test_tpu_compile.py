@@ -146,6 +146,43 @@ def test_lex_order_and_int_cumsum_compile_fast_for_v5e(one_chip):
     assert secs < 60, f"int_cumsum took {secs:.0f}s to compile"
 
 
+@pytest.mark.parametrize("rows", [512, 1 << 20, 3 << 10])
+def test_int_cumsum_compiles_inside_a_conditional_for_v5e(one_chip, rows):
+    """The sorted path's tiers are branches of a `lax.switch`, and inside a
+    conditional the chip's compiler cannot place a 64-bit scan of 256 to
+    1024 elements (it runs out of scoped vmem: q3's 2^20-row tier has 512
+    block totals, and a small table's tier is itself that short). So
+    `int_cumsum` holds no such scan: a length within a block is summed in
+    masked triangles of 64, one the block does not divide is padded."""
+    import jax
+    import jax.numpy as jnp
+
+    from ballista_tpu.ops.tpu.kernels import int_cumsum
+
+    def tiers(x, tier):
+        return jax.lax.switch(tier, [lambda x: int_cumsum(x[:8])[-1],
+                                     lambda x: int_cumsum(x)[-1]], x)
+
+    _compile(tiers, _spec(one_chip, (rows,), jnp.int64), _spec(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", ["int32", "int64"])
+def test_int_cumsum_of_one_block_is_fused_triangles_for_v5e(one_chip, dtype):
+    """Outside any conditional too — `final_stage`'s segmented reduction and
+    output compaction and `_float_rank` sum C-sized inputs through it — a
+    length within one block (here the whole 2048-row block) compiles to
+    masked triangles fused into their row sums: no scan, and nothing of the
+    [32, 64, 64] triangles ever held in memory."""
+    import jax.numpy as jnp
+
+    from ballista_tpu.ops.tpu.kernels import int_cumsum
+
+    compiled, secs = _compile(int_cumsum, _spec(one_chip, (2048,), jnp.dtype(dtype)))
+    assert secs < 60, f"int_cumsum took {secs:.0f}s to compile"
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 64 * 64
+    assert "reduce-window" not in compiled.as_text(), "a scan, not the triangle"
+
+
 # ------------------------------------------------------ fused_xla stages
 
 

@@ -64,6 +64,62 @@ def test_tpch_tpu_engine(q, tpu_ctx, tpch_ref_tables):
     assert not problems, "\n".join(problems)
 
 
+# -- the sorted path orders its live rows at a tier of the stage's row slots --
+# (the share of the table's rows that is alive, the divisor of the ladder the
+# stage should take: a sixty-fourth of its row slots where that holds the live
+# rows, else all of them). The tier is chosen by the data alone — the same live
+# rows among more or fewer dead ones — never by a switch.
+LIVE_SHARES = [
+    pytest.param(None, 1, id="unfiltered"),
+    pytest.param(0.10, 1, id="tenth_alive"),
+    pytest.param(0.005, 64, id="half_percent_alive"),
+]
+
+
+def _among_dead_rows(live: pa.Table, share, seed=3) -> pa.Table:
+    """`live` with an `alive` column of ones and, given a `share`, among so
+    many rows of alive = 0 that the live ones are that share of the table,
+    in their own order. A dead row is a copy of a live one: let past the
+    filter it would join a real group and move its aggregates."""
+    n = live.num_rows
+    total = n if share is None else int(round(n / share))
+    rng = np.random.default_rng(seed)
+    is_live = np.zeros(total, bool)
+    is_live[rng.choice(total, n, replace=False)] = True
+    take = rng.integers(0, n, total)
+    take[is_live] = np.arange(n)
+    tbl = live.take(pa.array(take)).append_column(
+        "alive", pa.array(is_live.astype("int64")))
+    return _in_batches(tbl, 4)
+
+
+def _in_batches(tbl: pa.Table, k: int) -> pa.Table:
+    """`tbl` as k equal batches: a memory table deals its batches out to its
+    partitions, and one batch would be one partition's rows."""
+    return pa.Table.from_batches(tbl.to_batches(max_chunksize=-(-tbl.num_rows // k)))
+
+
+def _sorted_stage_counts() -> tuple:
+    """(table shape, live rows, row slots ordered) as the ONE stage that took
+    the sorted path since the last `RUN_STATS.clear()` recorded them."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+
+    recs = [r for r in sc.RUN_STATS.stages().values() if "sorted_rows_ordered" in r]
+    assert len(recs) == 1, "exactly one stage should have taken the sorted path"
+    return (recs[0]["table_shape"], recs[0]["sorted_rows_live"],
+            recs[0]["sorted_rows_ordered"])
+
+
+def _assert_sorted_tier(divisor, live_rows, lanes=1):
+    """The sorted-path stage found `live_rows` alive and ordered them at its
+    row slots (P x N x `lanes`; None: some number of expansion lanes over
+    one) over `divisor`."""
+    (P, N), live, ordered = counts = _sorted_stage_counts()
+    assert live == live_rows, counts
+    got_lanes, rest = divmod(ordered * divisor, P * N)
+    assert rest == 0 and (got_lanes > 1 if lanes is None else got_lanes == lanes), counts
+
+
 def test_large_domain_groupby_on_device(tpu_ctx):
     """q3's group-by (l_orderkey × build-side keys — thousands of groups)
     must take the sort-based segmented-reduction path, not fall back."""
@@ -83,33 +139,45 @@ def test_large_domain_groupby_on_device(tpu_ctx):
     assert sum(s.fallback_count for s in stages) == 0
 
 
-def test_sorted_path_min_max_sum_count_oracle():
+def _min_max_sum_count_table(share):
+    rng = np.random.default_rng(7)
+    n = 3_000
+    return _among_dead_rows(pa.table({
+        "k": rng.integers(0, 3000, n),
+        "price": np.round(rng.uniform(1, 100, n), 2),   # money (int64 cents)
+        "weight": rng.uniform(0.0, 1.0, n),              # true f64
+        "qty": rng.integers(1, 50, n),
+    }), share)
+
+
+MIN_MAX_SUM_COUNT_SQL = (
+    "SELECT k, sum(price) AS s, sum(weight) AS w, count(*) AS c, "
+    "min(qty) AS mn, max(qty) AS mx FROM t WHERE qty > 5 AND alive = 1 "
+    "GROUP BY k ORDER BY k"
+)
+
+
+@pytest.mark.parametrize("share,divisor", LIVE_SHARES)
+def test_sorted_path_min_max_sum_count_oracle(share, divisor):
     """Synthetic large-domain aggregation: every agg func through the
     sorted path must match pandas (int money math exact, f64 sums via the
-    segmented scan) — and must actually run on the device path."""
+    segmented scan) — and must actually run on the device path, at the tier
+    its live rows call for."""
     import ballista_tpu.ops.tpu.stage_compiler as sc
     from ballista_tpu.client.context import SessionContext
     from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
     from ballista_tpu.plan.physical import TaskContext
 
-    rng = np.random.default_rng(7)
-    n = 20_000
-    tbl = pa.table({
-        "k": rng.integers(0, 3000, n),
-        "price": np.round(rng.uniform(1, 100, n), 2),   # money (int64 cents)
-        "weight": rng.uniform(0.0, 1.0, n),              # true f64
-        "qty": rng.integers(1, 50, n),
-    })
+    tbl = _min_max_sum_count_table(share)
     cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0})
     ctx = SessionContext(cfg)
     ctx.register_arrow_table("t", tbl, partitions=4)
-    sql = (
-        "SELECT k, sum(price) AS s, sum(weight) AS w, count(*) AS c, "
-        "min(qty) AS mn, max(qty) AS mx FROM t WHERE qty > 5 GROUP BY k ORDER BY k"
-    )
+    sql = MIN_MAX_SUM_COUNT_SQL
+    sc.RUN_STATS.clear()
     out = ctx.sql(sql).collect().to_pandas()
     df = tbl.to_pandas()
-    df = df[df.qty > 5]
+    df = df[(df.qty > 5) & (df.alive == 1)]
+    _assert_sorted_tier(divisor, len(df))
     g = (
         df.groupby("k")
         .agg(s=("price", "sum"), w=("weight", "sum"), c=("price", "size"),
@@ -135,6 +203,82 @@ def test_sorted_path_min_max_sum_count_oracle():
         list(phys.execute(p, tc))
     assert sum(s.tpu_count for s in stages) >= 1
     assert sum(s.fallback_count for s in stages) == 0
+
+
+def test_sorted_path_tiers_agree_bit_for_bit():
+    """The same live rows among no, nine times and 199 times as many dead
+    ones are ordered at every slot twice and at the lower tier once, and
+    every exact kind (keys, money sums in int64 cents, counts, integer min /
+    max) comes out the same to the bit; float sums to their rounding (a
+    segment's association depends on where it lands)."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.client.context import SessionContext
+
+    outs = []
+    for share, divisor in (case.values for case in LIVE_SHARES):
+        ctx = SessionContext(BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0}))
+        ctx.register_arrow_table("t", _min_max_sum_count_table(share), partitions=4)
+        sc.RUN_STATS.clear()
+        outs.append(ctx.sql(MIN_MAX_SUM_COUNT_SQL).collect().to_pandas())
+        _assert_sorted_tier(divisor, int(outs[-1].c.sum()))
+    for other in outs[1:]:
+        for col in ("k", "s", "c", "mn", "mx"):
+            assert (other[col].values == outs[0][col].values).all(), col
+        assert np.allclose(other.w.values, outs[0].w.values, rtol=1e-12)
+
+
+# (live rows of the stage's 8192 row slots, the slots they are ordered at): none
+# alive; one below the lower tier's capacity, exactly at it and one above it;
+# nearly all alive
+@pytest.mark.parametrize("live,ordered", [
+    (0, 128), (127, 128), (128, 128), (129, 8192), (7999, 8192)])
+def test_sorted_path_live_rows_at_a_tier_edge(live, ordered):
+    """Every live row its own group, so a tier's group capacity fills with
+    its row capacity."""
+    rng = np.random.default_rng(23)
+    n = 8000  # two partitions of 4000 rows: 2 x 4096 row slots
+    alive = np.zeros(n, dtype="int64")
+    alive[rng.choice(n, live, replace=False)] = 1
+    tbl = _in_batches(pa.table({"k": rng.permutation(n) * 7, "v": rng.integers(1, 100, n),
+                                "alive": alive}), 2)
+    sql = "SELECT k, sum(v) AS s, count(*) AS c FROM t WHERE alive = 1 GROUP BY k ORDER BY k"
+    tpu, cpu = _device_oracle(sql, {"t": tbl})
+    assert _sorted_stage_counts() == ([2, 4096], live, ordered)
+    want = tbl.to_pandas()
+    want = want[want.alive == 1].sort_values("k")
+    tp = tpu.to_pandas()
+    assert tpu.num_rows == cpu.num_rows == live
+    assert tp.k.tolist() == want.k.tolist()
+    assert tp.s.tolist() == want.v.tolist()
+    assert tp.c.tolist() == [1] * live
+
+
+def test_sorted_path_group_overflow_reruns_on_cpu_engine(monkeypatch):
+    """More distinct groups than the sorted path holds: the stage raises
+    Unsupported after its count fetch and the CPU engine answers. The
+    capacity (1 << 22 groups, whatever the tier) is lowered for the test:
+    100 live rows of 8192 slots are ordered at the 128-slot tier, whose
+    group capacity is then the cap's 64."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+
+    assert sc.SORTED_MAX_GROUPS == 1 << 22
+    monkeypatch.setattr(sc, "SORTED_MAX_GROUPS", 64)
+    n, live = 8000, 100
+    alive = np.zeros(n, dtype="int64")
+    alive[np.random.default_rng(29).choice(n, live, replace=False)] = 1
+    tbl = _in_batches(
+        pa.table({"k": np.arange(n) * 3, "w": np.arange(n) % 11, "alive": alive}), 2)
+    sql = "SELECT k, sum(w) AS s FROM t WHERE alive = 1 GROUP BY k ORDER BY k"
+    before = sc.STAGE_OUTCOMES.snapshot()
+    try:
+        tpu, cpu = _device_oracle(sql, {"t": tbl}, expect_device=False)
+    finally:
+        sc.clear_device_caches()  # no later test may meet a program compiled for 64 groups
+    after = sc.STAGE_OUTCOMES.snapshot()
+    assert after["declined"] > before["declined"]
+    assert any("group capacity overflow (100 > 64)" in str(r) for r in after["recent"])
+    assert tpu.num_rows == live and tpu.equals(cpu)
+    assert _sorted_stage_counts() == ([2, 4096], 100, 128)
 
 
 def test_tpu_stage_actually_ran(tpu_ctx):
@@ -199,36 +343,40 @@ def test_expansion_join_on_device(tpu_ctx, tpch_ref_tables):
     assert sum(s.fallback_count for s in stages) == 0
 
 
-def test_expansion_join_with_large_domain_groupby():
+@pytest.mark.parametrize("share,divisor", LIVE_SHARES)
+def test_expansion_join_with_large_domain_groupby(share, divisor):
     """Duplicate build keys AND a large int group domain: expansion lanes
-    concatenate into the sorted segmented reduction. Oracle = pandas."""
+    concatenate into the sorted segmented reduction, whose live rows are the
+    matched (row, lane) pairs. Oracle = pandas."""
     import ballista_tpu.ops.tpu.stage_compiler as sc
     from ballista_tpu.client.context import SessionContext
     from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
     from ballista_tpu.plan.physical import TaskContext
 
     rng = np.random.default_rng(11)
-    n_fact, n_dim = 30_000, 2_000
-    fact = pa.table({
+    n_fact = 3_600
+    fact = _among_dead_rows(pa.table({
         "fk": rng.integers(0, 500, n_fact),     # join key (dense)
         "gk": rng.integers(0, 4000, n_fact),    # large group domain
         "v": rng.integers(1, 100, n_fact),
-    })
-    dim = pa.table({
-        "dk": rng.integers(0, 500, n_dim),      # ~4 dups per key
-        "w": rng.integers(1, 10, n_dim),
-    })
+    }), share)
+    dk = np.repeat(np.arange(500), rng.integers(2, 5, 500))  # 2 to 4 dups per key
+    dim = pa.table({"dk": dk, "w": rng.integers(1, 10, len(dk))})
     cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0})
     ctx = SessionContext(cfg)
     ctx.register_arrow_table("fact", fact, partitions=4)
     ctx.register_arrow_table("dim", dim, partitions=1)
     sql = (
         "SELECT gk, sum(v * w) AS s, count(*) AS c FROM fact, dim "
-        "WHERE fk = dk GROUP BY gk ORDER BY gk"
+        "WHERE fk = dk AND alive = 1 GROUP BY gk ORDER BY gk"
     )
+    sc.RUN_STATS.clear()
     out = ctx.sql(sql).collect().to_pandas()
-    df = fact.to_pandas().merge(dim.to_pandas(), left_on="fk", right_on="dk")
+    df = fact.to_pandas()
+    df = df[df.alive == 1].merge(dim.to_pandas(), left_on="fk", right_on="dk")
     df["p"] = df.v * df.w
+    if [r for r in sc.RUN_STATS.stages().values() if "table_shape" in r]:
+        _assert_sorted_tier(divisor, len(df), lanes=None)  # collect_left: on the device
     g = (
         df.groupby("gk").agg(s=("p", "sum"), c=("p", "size"))
         .reset_index().sort_values("gk").reset_index(drop=True)
@@ -349,10 +497,12 @@ def test_pallas_fused_aggregation_path():
     assert sum(s.fallback_count for s in stages) == 0
 
 
-def test_device_side_shuffle_routing(tmp_path):
+@pytest.mark.parametrize("share,divisor", LIVE_SHARES)
+def test_device_side_shuffle_routing(tmp_path, share, divisor):
     """ROADMAP device-side shuffle write: the sorted path emits a __pid
-    column (bit-exact hash twin), the shuffle writer consumes it instead of
-    host hashing, and written buckets match host routing exactly."""
+    column (bit-exact hash twin, computed at the tier's group capacity), the
+    shuffle writer consumes it instead of host hashing, and written buckets
+    match host routing exactly."""
     import glob
     import json
 
@@ -368,15 +518,16 @@ def test_device_side_shuffle_routing(tmp_path):
     from ballista_tpu.shuffle import paths as sp
 
     rng = np.random.default_rng(5)
-    n = 30_000
-    pq.write_table(pa.table({
+    n = 3_000
+    tbl = _among_dead_rows(pa.table({
         "k": rng.integers(0, 5000, n),
         "v": rng.integers(1, 100, n),
-    }), str(tmp_path / "t.parquet"))
+    }), share)
+    pq.write_table(tbl, str(tmp_path / "t.parquet"))
     cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0})
     ctx = SessionContext(cfg)
     ctx.register_parquet("t", str(tmp_path / "t.parquet"))
-    sql = "select k, sum(v) s from t where v > 10 group by k"
+    sql = "select k, sum(v) s from t where v > 10 and alive = 1 group by k"
     phys = ctx.create_physical_plan(ctx.sql(sql).plan)
     stages = DistributedPlanner("jpid").plan_query_stages(phys)
     stage1 = stages[0]
@@ -386,12 +537,16 @@ def test_device_side_shuffle_routing(tmp_path):
 
     work = str(tmp_path / "work")
     tc = TaskContext(cfg, task_id="t0", work_dir=work)
+    sc.RUN_STATS.clear()
     for p in range(stage1.partitions):
         list(compiled.execute(p, tc))
     assert tpu[0].pid_emitted >= 1
     assert tpu[0].fallback_count == 0
+    want = tbl.to_pandas()
+    want = want[(want.v > 10) & (want.alive == 1)]
+    _assert_sorted_tier(divisor, len(want))
 
-    checked = 0
+    checked = seen = 0
     for f in glob.glob(f"{work}/jpid/1/*.arrow"):
         idx = json.load(open(sp.index_path(f)))
         for pid_s, entry in idx.items():
@@ -407,7 +562,9 @@ def test_device_side_shuffle_routing(tmp_path):
                 )
                 assert (host == int(pid_s)).all()
                 checked += 1
+                seen += tblx.num_rows
     assert checked > 0
+    assert seen == want.k.nunique()  # every group routed, none twice
 
 
 def test_q22_string_fn_filter_on_device(tpu_ctx, tpch_ref_tables):
@@ -505,6 +662,7 @@ def _device_oracle(sql: str, tables: dict, cfg_extra=None, expect_device=True):
     from ballista_tpu.plan.physical import TaskContext
 
     results = {}
+    sc.RUN_STATS.clear()  # what a caller then reads of the stages is this query's
     for engine in ("tpu", "cpu"):
         cfg = BallistaConfig({EXECUTOR_ENGINE: engine, TPU_MIN_ROWS: 0,
                               **(cfg_extra or {})})
@@ -559,14 +717,17 @@ def test_nullable_filter_and_aggs_on_device():
     assert tp.mn[0] == cp.mn[0] and tp.mx[0] == cp.mx[0]
 
 
-def test_nullable_group_key_on_device():
+@pytest.mark.parametrize("share,divisor", LIVE_SHARES)
+def test_nullable_group_key_on_device(share, divisor):
     """A nullable GROUP BY key: NULL forms its own group (sorted path's
-    null-marker sort operand), matching the CPU engine."""
-    tbl = _null_table()
+    null-marker sort operand), matching the CPU engine — the marker lane
+    compacted with the value lane at every tier."""
+    tbl = _among_dead_rows(_null_table(n=2000), share)
     sql = ("SELECT k, count(*) AS c, sum(price) AS s FROM t "
-           "WHERE qty >= 1 GROUP BY k ORDER BY k NULLS LAST")
+           "WHERE qty >= 1 AND alive = 1 GROUP BY k ORDER BY k NULLS LAST")
     tpu, cpu = _device_oracle(sql, {"t": tbl})
     tp, cp = tpu.to_pandas(), cpu.to_pandas()
+    _assert_sorted_tier(divisor, int(cp.c.sum()))
     assert len(tp) == len(cp)
     # align on key (None sorts last in both by the ORDER BY)
     assert tp.k.isna().tolist() == cp.k.isna().tolist()
@@ -582,24 +743,27 @@ def test_is_null_predicates_on_device():
     assert tpu.to_pandas().c[0] == cpu.to_pandas().c[0]
 
 
-def test_all_null_group_aggregates_to_null_on_device():
+@pytest.mark.parametrize("share,divisor", LIVE_SHARES)
+def test_all_null_group_aggregates_to_null_on_device(share, divisor):
     """A group whose agg inputs are all NULL yields NULL (not 0 / ±inf) —
-    the valid-count companion outputs."""
-    tbl = pa.table({
-        "g": pa.array([1, 1, 2, 2, 3], pa.int64()),
-        "v": pa.array([None, None, 5.25, 7.75, None], pa.float64()),
-        "q": pa.array([None, None, 4, 2, 9], pa.int64()),
-    })
+    the valid-count companion outputs, compacted with their values."""
+    reps = 300  # enough rows for the stage's smallest shape to be mostly alive
+    tbl = _among_dead_rows(pa.table({
+        "g": pa.array([1, 1, 2, 2, 3] * reps, pa.int64()),
+        "v": pa.array([None, None, 5.25, 7.75, None] * reps, pa.float64()),
+        "q": pa.array([None, None, 4, 2, 9] * reps, pa.int64()),
+    }), share)
     sql = ("SELECT g, sum(v) AS s, min(q) AS mn, max(q) AS mx, count(q) AS c "
-           "FROM t GROUP BY g ORDER BY g")
+           "FROM t WHERE alive = 1 GROUP BY g ORDER BY g")
     tpu, cpu = _device_oracle(sql, {"t": tbl})
+    _assert_sorted_tier(divisor, 5 * reps)
     tp, cp = tpu.to_pandas(), cpu.to_pandas()
     assert tp.s.isna().tolist() == cp.s.isna().tolist() == [True, False, True]
     assert tp.mn.isna().tolist() == cp.mn.isna().tolist() == [True, False, False]
-    assert float(tp.s[1]) == 13.0
+    assert float(tp.s[1]) == 13.0 * reps
     assert int(tp.mn[1]) == 2 and int(tp.mx[1]) == 4
     assert int(tp.mn[2]) == 9
-    assert tp.c.tolist() == cp.c.tolist() == [0, 2, 1]
+    assert tp.c.tolist() == cp.c.tolist() == [0, 2 * reps, reps]
 
 
 def test_nullable_probe_key_join_on_device():
@@ -764,12 +928,14 @@ def test_all_22_tpch_queries_run_device_stages(tpch_mid_dir):
     assert not bad, bad
 
 
-def test_variance_on_device_sorted_path():
+@pytest.mark.parametrize("share,divisor", LIVE_SHARES)
+def test_variance_on_device_sorted_path(share, divisor):
     """var/stddev partials (Welford (cnt, mean, M2) triple) computed on
     device via the sorted segmented two-pass, including an all-NULL group
-    and the n<2 sample-variance guard — vs the CPU engine."""
+    and the n<2 sample-variance guard — vs the CPU engine, the segment mean
+    gathered back per row inside the tier's own slots."""
     rng = np.random.default_rng(17)
-    n = 6000
+    n = 2000
     g = rng.integers(0, 40, n).astype("int64")
     v = np.round(rng.normal(1000.0, 25.0, n), 4)
     null_v = rng.random(n) < 0.25
@@ -778,13 +944,14 @@ def test_variance_on_device_sorted_path():
     one = np.nonzero(g == 38)[0]
     null_v[one] = True
     null_v[one[0]] = False
-    tbl = pa.table({
+    tbl = _among_dead_rows(pa.table({
         "g": pa.array(g, pa.int64()),
         "v": pa.array(v, pa.float64(), mask=null_v),
-    })
+    }), share)
     sql = ("SELECT g, var_samp(v) AS vs, var_pop(v) AS vp, "
-           "stddev(v) AS sd, count(v) AS c FROM t GROUP BY g ORDER BY g")
+           "stddev(v) AS sd, count(v) AS c FROM t WHERE alive = 1 GROUP BY g ORDER BY g")
     tpu, cpu = _device_oracle(sql, {"t": tbl})
+    _assert_sorted_tier(divisor, n)
     tp, cp = tpu.to_pandas(), cpu.to_pandas()
     assert tp.g.tolist() == cp.g.tolist()
     assert tp.c.tolist() == cp.c.tolist()
