@@ -141,23 +141,11 @@ EXECUTOR_DRAIN_TIMEOUT_S = "ballista.executor.drain.timeout.seconds"
 # TPU-native knobs
 TPU_SHAPE_BUCKETS = "ballista.tpu.shape.buckets"
 TPU_MAX_DEVICE_BYTES = "ballista.tpu.max.device.bytes"
-TPU_HASH_TABLE_LOAD = "ballista.tpu.hash.table.load.factor"
-TPU_ALLOW_F32_MONEY = "ballista.tpu.allow.f32.money"
 TPU_MIN_ROWS = "ballista.tpu.min.rows"
 TPU_BROADCAST_JOIN_ROWS = "ballista.tpu.broadcast.join.threshold.rows"
 TPU_COLLECTIVE_EXCHANGE = "ballista.tpu.collective.exchange"
-TPU_PALLAS = "ballista.tpu.pallas.enabled"
-# whole-stage fusion (stage_compiler fusion planner + cost model)
-TPU_FUSION_ENABLED = "ballista.tpu.fusion.enabled"
-TPU_FUSION_MODE = "ballista.tpu.fusion.mode"
-TPU_FUSION_MIN_ROWS = "ballista.tpu.fusion.min.rows"
-TPU_FUSION_PALLAS_MAX_GROUPS = "ballista.tpu.fusion.pallas.max.groups"
-TPU_FUSION_PALLAS_MAX_PROBE = "ballista.tpu.fusion.pallas.max.probe.rows"
-# on-device sort / window / top-k stage family
+# on-device sort / window stage family
 TPU_SORT_ENABLED = "ballista.tpu.sort.enabled"
-TPU_SORT_PALLAS_MAX_ROWS = "ballista.tpu.sort.pallas.max.rows"
-TPU_TOPK_ENABLED = "ballista.tpu.topk.enabled"
-TPU_TOPK_MAX_K = "ballista.tpu.topk.max.k"
 # cold-path pipeline (fill/compile overlap + persistent XLA compile cache)
 TPU_FILL_THREADS = "ballista.tpu.fill.threads"
 TPU_FILL_CHUNK_ROWS = "ballista.tpu.fill.chunk_rows"
@@ -798,94 +786,19 @@ _ENTRIES: list[ConfigEntry] = [
         str, "4096,16384,65536,262144,1048576",
     ),
     ConfigEntry(TPU_MAX_DEVICE_BYTES, "Per-stage HBM budget before falling back to cpu/spill.", int, 12 * 1024**3, _pos),
-    ConfigEntry(TPU_HASH_TABLE_LOAD, "Open-addressing hash table load factor for device joins/aggs.", float, 0.5, lambda v: 0.0 < v <= 0.9),
-    ConfigEntry(TPU_ALLOW_F32_MONEY, "Allow lossy float32 for decimal columns (faster, inexact).", bool, False),
     ConfigEntry(TPU_MIN_ROWS, "Below this many input rows a stage stays on cpu (compile cost dominates).", int, 8192, _nonneg),
     ConfigEntry(TPU_BROADCAST_JOIN_ROWS, "With engine=tpu: max build-side rows to collect a join build instead of co-partitioning. Device joins probe an HBM-resident sorted build table, so the collect budget is orders of magnitude past the CPU broadcast threshold; a partitioned join hides the chain from the stage compiler entirely.", int, 16_000_000, _nonneg),
     ConfigEntry(
-        TPU_PALLAS,
-        "Legacy switch predating ballista.tpu.fusion.mode: when true the "
-        "fusion cost model requests fused_pallas for every eligible stage "
-        "(f32 sums / i32 counts; exact int64 money stays on XLA). Prefer "
-        "ballista.tpu.fusion.mode=fused_pallas.",
-        bool, False,
-    ),
-    ConfigEntry(
-        TPU_FUSION_ENABLED,
-        "Whole-stage fusion in the TPU stage compiler. On, the fusion "
-        "planner groups a stage's operator chain into fusible spans "
-        "(predicates, projections, join probe+gather, aggregation) and the "
-        "cost model picks fused-Pallas / fused-XLA / staged per stage "
-        "(RUN_STATS fusion_mode records the choice). Off, every stage "
-        "compiles in staged mode when eligible (per-span sub-kernels with "
-        "HBM intermediates), else fused-XLA.",
-        bool, True,
-    ),
-    ConfigEntry(
-        TPU_FUSION_MODE,
-        "Fusion mode override: auto (cost model decides), staged, "
-        "fused_xla, or fused_pallas. Forced modes are still clamped to "
-        "what the stage supports (the fallback ladder is fused_pallas → "
-        "fused_xla → staged-ineligible → fused_xla; RUN_STATS fusion_mode "
-        "reports the mode that actually ran).",
-        str, "auto", lambda v: v in ("auto", "staged", "fused_xla", "fused_pallas"),
-    ),
-    ConfigEntry(
-        TPU_FUSION_MIN_ROWS,
-        "Cost model: below this many total stage input rows the planner "
-        "prefers the staged path when the stage is staged-eligible "
-        "(per-span dispatch overhead is noise at small sizes and the "
-        "span timings feed the roofline taps).",
-        int, 4096, _nonneg,
-    ),
-    ConfigEntry(
-        TPU_FUSION_PALLAS_MAX_GROUPS,
-        "Cost model / compiler: max group-domain cardinality routed to the "
-        "Pallas hash-aggregate kernel (multi-tile one-hot accumulation). "
-        "Hard kernel ceiling is 4096 lanes; larger domains use the "
-        "fused-XLA sorted segmented reduction.",
-        int, 4096, _pos,
-    ),
-    ConfigEntry(
-        TPU_FUSION_PALLAS_MAX_PROBE,
-        "Cost model / compiler: max direct-mode build table entries routed "
-        "to the Pallas hash-probe kernel (the key→row table must fit "
-        "VMEM-resident per block). Larger tables probe via the XLA gather.",
-        int, 1 << 18, _pos,
-    ),
-    ConfigEntry(
         TPU_SORT_ENABLED,
-        "On-device sort / window / top-k stage family: when true the TPU "
+        "On-device sort / window stage family: when true the TPU "
         "engine wraps eligible SortExec and WindowExec subtrees so ORDER "
         "BY, window-aggregate, and ORDER BY ... LIMIT stages compute their "
         "ordering permutation on device over the int64 lane encoding "
         "(results stay byte-identical to the CPU engine; ineligible shapes "
-        "decline with a recorded reason and run on the host).",
+        "decline with a recorded reason and run on the host). ORDER BY ... "
+        "LIMIT orders every row and slices (RUN_STATS "
+        "sort_full_materializations counts it).",
         bool, True,
-    ),
-    ConfigEntry(
-        TPU_SORT_PALLAS_MAX_ROWS,
-        "Cost model: max padded sort lanes (rows rounded up to a power of "
-        "two) routed to the Pallas bitonic segmented-sort kernel family. "
-        "Larger stages demote to the fused-XLA stable sort with the reason "
-        "recorded in fusion_reason.",
-        int, 1 << 17, _pos,
-    ),
-    ConfigEntry(
-        TPU_TOPK_ENABLED,
-        "Fused top-k for ORDER BY ... LIMIT final stages: select the k "
-        "smallest/largest lanes by chunked bitonic folding without ever "
-        "materializing the full sorted order. Off (or when the shape is "
-        "ineligible), LIMIT stages fall back to full sort + slice and "
-        "RUN_STATS sort_full_materializations counts it.",
-        bool, True,
-    ),
-    ConfigEntry(
-        TPU_TOPK_MAX_K,
-        "Cost model: max LIMIT fetch routed to the fused top-k kernel (the "
-        "kept set must stay a small power-of-two chunk per fold round). "
-        "Larger fetches use full sort + slice.",
-        int, 1024, _pos,
     ),
     ConfigEntry(
         TPU_COLLECTIVE_EXCHANGE,

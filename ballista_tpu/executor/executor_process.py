@@ -373,7 +373,7 @@ class ExecutorProcess:
                     "mesh_devices", "exchange_bytes_on_device", "exchange_s",
                     "hbm_budget_bytes", "hbm_spill_bytes", "hbm_spill_events",
                     "hbm_reupload_events", "grace_splits", "hbm_oom_retries",
-                    "sort_kernel_s", "sort_invocations", "topk_invocations",
+                    "sort_kernel_s", "sort_invocations",
                     "topk_rows_kept", "window_invocations",
                     "window_partitions", "sort_full_materializations",
                     "delta_fill_rows",
@@ -387,11 +387,6 @@ class ExecutorProcess:
             code = {"run_whole": 0.0, "spill_colds": 1.0, "grace_split": 2.0,
                     "cpu_demote": 3.0}
             out.append(("tpu_hbm_plan", code.get(str(stats["hbm_plan"]), -1.0)))
-        if "fusion_mode" in stats:
-            # gauges are floats: staged=0, fused_xla=1, fused_pallas=2
-            code = {"staged": 0.0, "fused_xla": 1.0, "fused_pallas": 2.0}
-            out.append(("tpu_fusion_mode",
-                        code.get(str(stats["fusion_mode"]), -1.0)))
         # AQE decision counters likewise keep their RUN_STATS names (no
         # tpu_ prefix: they count scheduler replans — skew splits, join
         # mode switches, mesh replans — not this executor's device work)

@@ -1,6 +1,6 @@
 """JAX kernels: expression lowering, masked segment aggregation, row hash.
 
-Design rules (pallas_guide / XLA-friendly):
+Design rules (XLA-friendly):
 - no data-dependent shapes: filters produce MASKS, never compaction; the
   aggregation consumes (value, mask) pairs with segment ops
 - string work never reaches the device: predicates over dictionary columns
@@ -255,10 +255,6 @@ class Lowering:
         # change across partitions (padded size is part of the jit key).
         self.lut_builders: list[tuple[int, Any]] = []
         self.slots: list[int] = list(range(len(kinds)))  # field → source slot
-        # fused_pallas stages flip this on: dictionary-code predicates run
-        # through the pallas dict_filter kernel (VMEM-resident LUT) instead
-        # of a plain XLA gather
-        self.pallas_dict_filter = False
         # env indirection (set by the stage compiler): field index → lowered
         # fn, so projections rebind what a Column reference means
         self.env_fns: list | None = None
@@ -403,21 +399,9 @@ def lower_expr(e: Expr, ctx: Lowering) -> LoweredFn:
                         )
                         neg = e.op == "<>"
 
-                        def run(cols, luts, src=src, li=li, neg=neg, ctx=ctx):
+                        def run(cols, luts, src=src, li=li, neg=neg):
                             v = src(cols, luts)
-                            lut = luts[li]
-                            if ctx.pallas_dict_filter and getattr(v.arr, "ndim", 0) == 2:
-                                from ballista_tpu.ops.tpu.pallas_kernels import dict_filter
-
-                                jnp = _jnp()
-                                # the kernel conjoins validity in VMEM; under
-                                # <> keep the raw gather (the valid plane
-                                # handles nulls downstream either way)
-                                mask = v.valid if (v.valid is not None and not neg) \
-                                    else jnp.ones(v.arr.shape, bool)
-                                out = dict_filter(v.arr, lut, mask)
-                            else:
-                                out = lut[v.arr]
+                            out = luts[li][v.arr]
                             return DevVal("bool", ~out if neg else out, valid=v.valid)
 
                         return run
@@ -541,17 +525,9 @@ def lower_expr(e: Expr, ctx: Lowering) -> LoweredFn:
         )
         neg = e.negated
 
-        def run(cols, luts, src=src, li=li, neg=neg, ctx=ctx):
+        def run(cols, luts, src=src, li=li, neg=neg):
             v = src(cols, luts)
-            lut = luts[li]
-            if ctx.pallas_dict_filter and getattr(v.arr, "ndim", 0) == 2:
-                from ballista_tpu.ops.tpu.pallas_kernels import dict_filter
-                jnp = _jnp()
-                mask = (v.valid if (v.valid is not None and not neg)
-                        else jnp.ones(v.arr.shape, bool))
-                out = dict_filter(v.arr, lut, mask)
-            else:
-                out = lut[v.arr]
+            out = luts[li][v.arr]
             return DevVal("bool", ~out if neg else out, valid=v.valid)
 
         return run

@@ -6,7 +6,7 @@ acceptable because the hot paths (masks, money, codes) are integer.
 
 Nothing here guesses. A backend that cannot be asked for its devices is an
 error that propagates: a silent "cpu" answer would run a TPU deployment on
-the host (or Pallas kernels in interpret mode on a chip) with exit code 0.
+the host with exit code 0.
 """
 
 from __future__ import annotations
@@ -233,12 +233,6 @@ def current_device():
     if isinstance(dev, str):  # a platform name is also a legal value
         return jax.local_devices(backend=dev)[0]
     return dev
-
-
-def platform() -> str:
-    """Platform ("tpu", "cpu", ...) of current_device(). The cost model and
-    the Pallas interpret switch decide on this."""
-    return current_device().platform
 
 
 # ---------------------------------------------------------------- profiler

@@ -1,4 +1,4 @@
-"""On-device ORDER BY / window / top-k stage family.
+"""On-device ORDER BY / window stage family.
 
 `maybe_compile_tpu` wraps eligible SortExec / WindowExec subtrees in
 TpuSortStageExec / TpuWindowStageExec (`ballista.tpu.sort.enabled`). The
@@ -12,15 +12,12 @@ split of labor is the parity contract:
   `pa.Table.take` — payload columns never leave the host, so the output
   bytes are the CPU engine's bytes by construction.
 - The DEVICE computes only the permutation (and, for windows, the
-  segmented scans): `fused_pallas` (CPU interpreter only) runs the bitonic
-  `segmented_sort` / `topk_select` / `segmented_scan` kernels; `fused_xla`
-  and `staged` run `kernels.lex_order` (stable LSD radix passes over the
+  segmented scans): `kernels.lex_order` (stable LSD radix passes over the
   keys' 32-bit lanes) and an associative segmented scan, both jitted at
   power-of-two lane counts so one compilation serves every partition of
-  a bucket. `CostModel.choose_sort` picks per shape with the demotion
-  ladder; an ineligible shape raises Unsupported and the operator falls
-  back to the CPU oracle over the SAME materialized input (never
-  re-executing the child).
+  a bucket. ORDER BY ... LIMIT is the full order, sliced. An ineligible
+  shape raises Unsupported and the operator falls back to the CPU oracle
+  over the SAME materialized input (never re-executing the child).
 
 Order-preserving int64 encoding per key kind:
 
@@ -61,7 +58,6 @@ from ballista_tpu.config import (
     BallistaConfig,
     TPU_MIN_ROWS,
     TPU_SORT_ENABLED,
-    TPU_TOPK_ENABLED,
 )
 from ballista_tpu.ops.phys_expr import bind_expr, evaluate_to_array
 from ballista_tpu.ops.tpu.columnar import encode_column
@@ -96,7 +92,6 @@ _WINDOW_DEVICE_FUNCS = ("row_number", "rank", "count", "sum", "min", "max")
 _CTR_LOCK = threading.Lock()
 _COUNTERS = {
     "sort_invocations": 0,
-    "topk_invocations": 0,
     "window_invocations": 0,
     "topk_rows_kept": 0,
     "window_partitions": 0,
@@ -119,7 +114,6 @@ def _publish_counters() -> None:
     with _CTR_LOCK:
         snap = dict(_COUNTERS)
     RUN_STATS.set("sort_invocations", snap["sort_invocations"])
-    RUN_STATS.set("topk_invocations", snap["topk_invocations"])
     RUN_STATS.set("window_invocations", snap["window_invocations"])
     RUN_STATS.set("topk_rows_kept", snap["topk_rows_kept"])
     RUN_STATS.set("window_partitions", snap["window_partitions"])
@@ -215,7 +209,7 @@ def _encode_key_arrays(arrays: list, orders: list) -> tuple[list, list]:
             # directions (placement, not magnitude), so the override goes
             # on top of the flipped lane. I64_MAX-1 needs float bits of a
             # NaN payload to reach → no real value collides, and it stays
-            # strictly below the I64_MAX pad sentinel of the pallas rung.
+            # strictly below the I64_MAX pad sentinel.
             lane = np.where(nan, np.int64(_I64_MAX - 1), lane)
         nrank = None
         if valid is not None:
@@ -229,12 +223,6 @@ def _encode_key_arrays(arrays: list, orders: list) -> tuple[list, list]:
 
 # ---------------------------------------------------------------------------
 # device permutation
-
-
-def _sort_cost_model(config: BallistaConfig):
-    from ballista_tpu.ops.tpu import fusion, runtime
-
-    return fusion.CostModel.from_config(config, runtime.platform())
 
 
 def _admit(est, config: BallistaConfig) -> None:
@@ -264,29 +252,9 @@ class _Uploads:
         return jax.numpy.asarray(arr)
 
 
-def _perm_full(key_ops: list, n: int, mode: str, up: _Uploads) -> np.ndarray:
+def _perm_full(key_ops: list, n: int, up: _Uploads) -> np.ndarray:
     """Full ordering permutation of n rows by the encoded key operands."""
     jax = ensure_jax()
-    jnp = jax.numpy
-    if mode == "fused_pallas":
-        from ballista_tpu.ops.tpu.pallas_kernels import segmented_sort
-
-        L = _pow2(n)
-        pos = jnp.arange(L, dtype=jnp.int32)
-        perm = pos
-        # LSD passes, least-significant key first: the kernel's position
-        # operand makes each pass a stable sort by (null rank, lane), so
-        # earlier passes' order survives ties. Sentinel lanes (i64 max on
-        # BOTH operands) sort strictly after every real row because real
-        # null-rank operands are 0/1.
-        for nrank, lane in reversed(key_ops):
-            a = up.put(_pad_max(nrank if nrank is not None else
-                                np.zeros(n, np.int64), L))
-            b = up.put(_pad_max(lane, L))
-            _, _, p = segmented_sort(a[perm][None, :], b[perm][None, :],
-                                     pos[None, :])
-            perm = perm[p[0]]
-        return np.asarray(jax.device_get(perm))[:n]
     # the stable lexicographic order over every operand, at a power-of-two
     # lane count: max-value sentinels pad the tail and, the order being
     # stable, stay behind every real row — the first n of the permutation
@@ -303,24 +271,6 @@ def _perm_full(key_ops: list, n: int, mode: str, up: _Uploads) -> np.ndarray:
     up.bytes += L * 4
     perm = _lex_order_jit()(*flat)
     return np.asarray(jax.device_get(perm))[:n]
-
-
-def _perm_topk(key_ops: list, n: int, k: int, up: _Uploads) -> np.ndarray:
-    """First-k permutation via the fused top-k kernel (single key only;
-    the full sort is never materialized)."""
-    jax = ensure_jax()
-    jnp = jax.numpy
-    from ballista_tpu.ops.tpu.pallas_kernels import topk_select
-
-    (nrank, lane), = key_ops
-    L = _pow2(n)
-    a = up.put(_pad_max(nrank if nrank is not None else np.zeros(n, np.int64), L))
-    b = up.put(_pad_max(lane, L))
-    pos = jnp.arange(L, dtype=jnp.int32)
-    up.bytes += L * 4
-    kk = min(int(k), n)
-    _, _, sp = topk_select(a[None, :], b[None, :], pos[None, :], kk)
-    return np.asarray(jax.device_get(sp[0]))[:kk]
 
 
 def _pad_max(a: np.ndarray, L: int) -> np.ndarray:
@@ -433,29 +383,16 @@ def _device_sort(tbl: pa.Table, df_schema: DFSchema, keys: list,
     orders = [(k.ascending, k.nulls_first) for k in keys]
     key_ops, key_meta = _encode_key_arrays(arrays, orders)
 
-    topk_wanted = fetch is not None and bool(config.get(TPU_TOPK_ENABLED))
-    est = fusion.estimate_sort_stage(
-        n, key_meta, fetch=fetch if topk_wanted else None)
-    _admit(est, config)
-    cm = _sort_cost_model(config)
-    dec = cm.choose_sort(est)
-    RUN_STATS.set("fusion_mode", dec.mode)
-    RUN_STATS.set("fusion_reason", dec.reason)
+    _admit(fusion.estimate_sort_stage(n, key_meta), config)
 
     up = _Uploads()
     # upload, kernel and the permutation's fetch: the host blocked on the device
     with RUN_STATS.span("bt.device.exec", rows=n) as span:
-        if dec.mode == "fused_pallas" and topk_wanted:
-            # choose_sort only keeps topk_k on the pallas rung when the kernel
-            # can take it (single key, k under the ceiling)
-            perm = _perm_topk(key_ops, n, int(fetch), up)
-            _count("topk_invocations")
-            _count("topk_rows_kept", len(perm))
-        else:
-            perm = _perm_full(key_ops, n, dec.mode, up)
-            _count("sort_invocations")
-            if fetch is not None:
-                _count("sort_full_materializations")
+        perm = _perm_full(key_ops, n, up)
+        _count("sort_invocations")
+        if fetch is not None:
+            # ORDER BY ... LIMIT orders every row, then slices
+            _count("sort_full_materializations")
     _note_kernel_s(span.seconds)
     RUN_STATS.set("device_bytes", up.bytes)
 
@@ -543,7 +480,7 @@ def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
                   config: BallistaConfig, window_funcs: int, up: "_Uploads"):
     """The oracle's _Frame, with the sort permutation computed on device.
     Boundary flags reuse the oracle's `_changes` (nulls equal, NaN splits
-    peers) so peer semantics cannot drift. Returns (_Frame, mode)."""
+    peers) so peer semantics cannot drift."""
     from ballista_tpu.ops.cpu.window import _Frame, _changes, _first_only
     from ballista_tpu.ops.tpu import fusion
     n = batch.num_rows
@@ -556,16 +493,13 @@ def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
         (k.ascending, k.nulls_first) for k in w.order_by
     ]
     key_ops, key_meta = _encode_key_arrays(arrays, orders)
-    est = fusion.estimate_sort_stage(n, key_meta or [("i64", False)],
-                                     window_funcs=max(window_funcs, 1))
-    _admit(est, config)
-    dec = _sort_cost_model(config).choose_sort(est)
-    RUN_STATS.set("fusion_mode", dec.mode)
-    RUN_STATS.set("fusion_reason", dec.reason)
+    _admit(fusion.estimate_sort_stage(n, key_meta or [("i64", False)],
+                                      window_funcs=max(window_funcs, 1)),
+           config)
 
     with RUN_STATS.span("bt.device.exec", rows=n) as span:
         if key_ops:
-            idx = _perm_full(key_ops, n, dec.mode, up).astype(np.int64)
+            idx = _perm_full(key_ops, n, up).astype(np.int64)
         else:
             idx = np.arange(n, dtype=np.int64)
     _note_kernel_s(span.seconds)
@@ -582,33 +516,27 @@ def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
     counts = ends - starts + 1 if len(starts) else np.array([], np.int64)
     seg_end = np.repeat(ends, counts) if len(starts) else np.zeros(n, np.int64)
     _count("window_partitions", int(len(starts)))
-    return _Frame(idx, inv, new_part, new_peer, seg_start, seg_end), dec.mode
+    return _Frame(idx, inv, new_part, new_peer, seg_start, seg_end)
 
 
-def _seg_scan(vals: np.ndarray, boundary: np.ndarray, func: str, mode: str,
+def _seg_scan(vals: np.ndarray, boundary: np.ndarray, func: str,
               up: _Uploads) -> np.ndarray:
     """Device inclusive segmented scan (reset at boundary lanes)."""
     jax = ensure_jax()
     n = len(vals)
-    # power-of-two lanes either way: one compilation per bucket, not per
-    # partition row count
+    # power-of-two lanes: one compilation per bucket, not per partition
+    # row count
     L = _pow2(n)
     v = np.zeros(L, dtype=vals.dtype)
     v[:n] = vals
     f = np.ones(L, dtype=bool)  # padding lanes self-reset
     f[:n] = boundary
-    if mode == "fused_pallas":
-        from ballista_tpu.ops.tpu.pallas_kernels import segmented_scan
-
-        out = segmented_scan(up.put(v)[None, :], up.put(f)[None, :], func)[0]
-    else:
-        out = _segscan_jit(func)(up.put(v), up.put(f))
+    out = _segscan_jit(func)(up.put(v), up.put(f))
     return np.asarray(jax.device_get(out))[:n]
 
 
 def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
-                        schema: DFSchema, fr, mode: str,
-                        up: _Uploads) -> pa.Array:
+                        schema: DFSchema, fr, up: _Uploads) -> pa.Array:
     """One window expression over a shared frame: device segmented scans
     inside the oracle's gather/scatter/emit skeleton."""
     from ballista_tpu.ops.cpu.window import _decimal_prepare, _emit_agg, _peer_last
@@ -623,13 +551,13 @@ def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
 
         arr = None
         if w.func == "row_number":
-            out_sorted = _seg_scan(np.ones(n, np.int64), boundary, "sum", mode, up)
+            out_sorted = _seg_scan(np.ones(n, np.int64), boundary, "sum", up)
         elif w.func == "rank":
             marked = np.where(fr.new_peer, arange, np.int64(_I64_MIN))
-            peer_start = _seg_scan(marked, boundary, "max", mode, up)
+            peer_start = _seg_scan(marked, boundary, "max", up)
             out_sorted = peer_start - fr.seg_start + 1
         else:
-            arr = _emit_scan_agg(batch, w, schema, fr, mode, boundary, up,
+            arr = _emit_scan_agg(batch, w, schema, fr, boundary, up,
                                  out_type, _decimal_prepare, _emit_agg,
                                  _peer_last, n)
     _note_kernel_s(span.seconds)
@@ -640,7 +568,7 @@ def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
     return pa.array(out, out_type)
 
 
-def _emit_scan_agg(batch, w, schema, fr, mode, boundary, up, out_type,
+def _emit_scan_agg(batch, w, schema, fr, boundary, up, out_type,
                    _decimal_prepare, _emit_agg, _peer_last, n):
     import pyarrow.compute as pc  # noqa: F401 — _decimal_prepare path
 
@@ -656,7 +584,7 @@ def _emit_scan_agg(batch, w, schema, fr, mode, boundary, up, out_type,
         valid = np.ones(n, dtype=bool)
     last = _peer_last(fr.new_peer, n)
 
-    seg_cnt = _seg_scan(valid.astype(np.int64), boundary, "sum", mode, up)
+    seg_cnt = _seg_scan(valid.astype(np.int64), boundary, "sum", up)
     if w.func == "count":
         out = np.empty(n, dtype=np.int64)
         out[fr.idx] = seg_cnt[last]
@@ -681,7 +609,7 @@ def _emit_scan_agg(batch, w, schema, fr, mode, boundary, up, out_type,
             m = int(np.abs(v).max())
             if m and m * n >= (1 << 53):
                 raise Unsupported("window sum magnitude beyond exact-f64")
-        out_sorted = _seg_scan(v, boundary, "sum", mode, up)[last]
+        out_sorted = _seg_scan(v, boundary, "sum", up)[last]
     else:  # min / max
         is_f = (np.issubdtype(np.asarray(vals).dtype, np.floating)
                 or pa.types.is_floating(out_type))
@@ -699,7 +627,7 @@ def _emit_scan_agg(batch, w, schema, fr, mode, boundary, up, out_type,
         else:
             v = np.asarray(vals, dtype=np.int64)
         v = np.where(valid, v, np.int64(ident))
-        out_sorted = _seg_scan(v, boundary, w.func, mode, up)[last]
+        out_sorted = _seg_scan(v, boundary, w.func, up)[last]
         if is_f:
             out_sorted = np.where(out_sorted == nan_mark, np.nan,
                                   _ordered_to_f64(out_sorted))
@@ -724,7 +652,7 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
         key = (tuple(str(e) for e in w.partition_by),
                tuple(str(k) for k in w.order_by))
         groups[key] = groups.get(key, 0) + 1
-    frames: dict[tuple, tuple] = {}
+    frames: dict[tuple, object] = {}
     out = []
     up = _Uploads()  # stage-total device bytes: sorts + scans (fill test)
     for w in window_exprs:
@@ -733,8 +661,7 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
         if key not in frames:
             frames[key] = _device_frame(batch, w, schema, config,
                                         groups[key], up)
-        fr, mode = frames[key]
-        out.append(_device_compute_one(batch, w, schema, fr, mode, up))
+        out.append(_device_compute_one(batch, w, schema, frames[key], up))
     RUN_STATS.set("device_bytes", up.bytes)
     _count("window_invocations")
     return out

@@ -40,8 +40,6 @@ from ballista_tpu.config import (
     TPU_COMPILE_OVERLAP,
     TPU_FILL_CHUNK_ROWS,
     TPU_FILL_THREADS,
-    TPU_FUSION_PALLAS_MAX_GROUPS,
-    TPU_FUSION_PALLAS_MAX_PROBE,
     TPU_HBM_GRACE_BUCKETS,
     TPU_HBM_GRACE_DEPTH,
     TPU_HBM_SPILL_DIR,
@@ -52,7 +50,7 @@ from ballista_tpu.config import (
     BallistaConfig,
     _env_int,
 )
-from ballista_tpu.ops.tpu import hbm, runtime
+from ballista_tpu.ops.tpu import fusion, hbm, runtime
 from ballista_tpu.ops.tpu.columnar import encode_column, encode_stacked, next_bucket
 from ballista_tpu.ops.tpu.kernels import (
     BelowRowFloor,
@@ -1030,8 +1028,7 @@ class TpuStageExec(ExecutionPlan):
                         ) from e2
                     raise
 
-    def _compile_key(self, dt: DeviceTable, builds: list[BuildTable],
-                     mode_req: str = "fused_xla") -> tuple:
+    def _compile_key(self, dt: DeviceTable, builds: list[BuildTable]) -> tuple:
         """The compile-cache key. Derivable from a spec DeviceTable (the
         encode metadata alone), which is what makes compile/fill overlap
         possible: tracing starts before the uploads finish."""
@@ -1042,47 +1039,16 @@ class TpuStageExec(ExecutionPlan):
             tuple(str(c.dtype) for c in dt.cols),
             tuple(v is not None for v in dt.valids),
             tuple(_pow2(len(d)) if d else 0 for d in dt.dicts),
-            tuple(b.shape_key() for b in builds), emit_key, mode_req,
+            tuple(b.shape_key() for b in builds), emit_key,
         )
 
-    def _fusion_decision(self, dt: DeviceTable, builds: list[BuildTable]):
-        """Run the fusion cost model over compile-time stage facts. Pure
-        host logic over encode metadata, so the overlap worker and the main
-        thread compute the SAME decision from a spec table and the real
-        table respectively (same kinds/dicts/part_rows/builds/config)."""
-        from ballista_tpu.ops.tpu import fusion
-
-        est = fusion.estimate_stage(self.scan, self.ops, self.partial_agg, dt, builds)
-        cm = fusion.CostModel.from_config(self.config, runtime.platform())
-        dec = cm.choose(est)
-        if dec.mode == "fused_pallas" and _stage_mesh(self.config) is not None:
-            # pallas kernels are single-device (no shard_map wrapping yet)
-            dec = fusion.FusionDecision(
-                "fused_xla", dec.reason + "; clamped: collective-exchange mesh")
-        return dec, est
-
-    def _compile_with_fallback(self, dt: DeviceTable, builds: list[BuildTable],
-                               rec: dict | None, mode_req: str):
-        """The fallback ladder's top rung: a fused_pallas request whose
-        stage turns out kernel-ineligible at trace time (f64-only sums over
-        money columns, validity planes, G past the lane budget) raises
-        Unsupported — retry once as fused_xla instead of knocking the whole
-        stage off the device."""
-        try:
-            return self._compile_locked(dt, builds, rec, mode_req)
-        except Unsupported:
-            if mode_req != "fused_pallas":
-                raise
-            log.info("fused_pallas ineligible at trace time; retrying fused_xla")
-            return self._compile_locked(dt, builds, rec, "fused_xla")
-
     def _compile_locked(self, dt: DeviceTable, builds: list[BuildTable],
-                        rec: dict | None, mode_req: str = "fused_xla"):
+                        rec: dict | None):
         """Look up or create the compiled entry. `dt` may be a spec table
         (ShapeDtypeStruct columns): _compile only consults shapes, dtypes,
         kinds and dictionaries. Returns (entry, fresh, lowered) — `lowered`
         (the jax Lowered, pre-backend-compile) only for fresh entries."""
-        key = self._compile_key(dt, builds, mode_req)
+        key = self._compile_key(dt, builds)
         P, N = dt.shape
         kinds = list(zip(dt.kinds, dt.scales))
         with _COMPILE_LOCK:
@@ -1091,7 +1057,7 @@ class TpuStageExec(ExecutionPlan):
                 return cached, False, None
             with RUN_STATS.span("bt.compile.trace") as span:
                 fn, lowering, meta, lowered = self._compile(
-                    dt, kinds, dt.dicts, P, N, builds, mode_req=mode_req)
+                    dt, kinds, dt.dicts, P, N, builds)
             RUN_STATS.set("trace_s", round(span.seconds, 3), rec=rec)
             # the dispatched flag lives with the entry: the FIRST call of a
             # jitted fn runs the backend compile, so the first dispatcher
@@ -1176,9 +1142,8 @@ class TpuStageExec(ExecutionPlan):
                     bts = [f.result() for f in build_futs]
                     t0 = time.perf_counter()
                     with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch):
-                        dec, _ = self._fusion_decision(sdt, bts)
-                        entry, fresh, lowered = self._compile_with_fallback(
-                            sdt, bts, rec, dec.mode)
+                        entry, fresh, lowered = self._compile_locked(
+                            sdt, bts, rec)
                         if fresh and lowered is not None and mesh is None \
                                 and runtime.compile_cache_dir():
                             # AOT-compile here: backend_compile writes the
@@ -1229,12 +1194,10 @@ class TpuStageExec(ExecutionPlan):
             builds = [self._prepare_build(op, jidx, ctx, table_key, mesh)
                       for jidx, op in enumerate(join_ops)]
 
-        dec, est = self._fusion_decision(dt, builds)
-        rec["fusion_choice"] = dec.mode
-        rec["fusion_reason"] = dec.reason
+        est = fusion.estimate_stage(self.ops, self.partial_agg, dt, builds)
 
         # ---- HBM admission: every stage states its memory plan before the
-        # dispatch, in the demotion-ladder style of fusion_reason. Splitting
+        # dispatch, each demotion with its reason. Splitting
         # is only sound for an INNER join's build: a probe row's whole match
         # set shares its key's sub-bucket, so wrong-bucket runs mask it like
         # any unmatched probe; outer/anti would re-emit it per bucket.
@@ -1275,14 +1238,13 @@ class TpuStageExec(ExecutionPlan):
             try:
                 return self._grace_run(ctx, rec, dt, join_ops, builds, plan,
                                        grace_fanout, grace_depth_cap, mesh,
-                                       table_key, dec)
+                                       table_key)
             finally:
                 _record_spill_stats(rec, spill_pool)
 
         if cached is None:
-            cached, _, _ = self._compile_with_fallback(dt, builds, rec, dec.mode)
+            cached, _, _ = self._compile_locked(dt, builds, rec)
         fn, lowering, meta, state = cached
-        rec["fusion_mode"] = meta.get("fusion_mode", "fused_xla")
         rec["fused_spans"] = meta.get("fused_spans", 0)
         dicts = dt.dicts
         P, N = dt.shape
@@ -1302,23 +1264,17 @@ class TpuStageExec(ExecutionPlan):
         build_args = [b.flat_arrays() for b in builds]
         first_dispatch = not state["dispatched"]
         state["dispatched"] = True
-        span_s: dict[str, float] = {}
         t0 = time.perf_counter()
         # host blocked on the device; a fresh entry's first call compiles
         # (or loads the persistent cache's binary) inside it, and is named so
         with RUN_STATS.span("bt.compile.xla" if first_dispatch else "bt.device.exec") as span:
-            if meta.get("exec") == "staged":
-                outs = fn(dt.flat_cols(), luts, dt.mask, build_args, span_s)
-            else:
-                outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
-                jax.block_until_ready(list(outs))
+            outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
+            jax.block_until_ready(list(outs))
         t_call = span.seconds
-        # host seconds around the synced stage kernel(s): the fused dispatch,
-        # or the per-span sum. The cold call folds the backend compile in;
-        # xla_compile_s below carries the honest attribution
-        rec["fused_kernel_s"] = round(sum(span_s.values()) or t_call, 6)
-        if span_s:
-            rec["span_s"] = {k: round(v, 6) for k, v in span_s.items()}
+        # host seconds around the synced stage kernel. The cold call folds
+        # the backend compile in; xla_compile_s below carries the honest
+        # attribution
+        rec["fused_kernel_s"] = round(t_call, 6)
         if first_dispatch:
             # jit compiles (or fetches from the persistent cache) inside the
             # first call; when the overlap worker already AOT-compiled, the
@@ -1342,8 +1298,8 @@ class TpuStageExec(ExecutionPlan):
 
     def _grace_run(self, ctx: TaskContext, rec: dict, dt: DeviceTable,
                    join_ops: list, builds: list[BuildTable], plan,
-                   fanout: int, depth_cap: int, mesh, table_key,
-                   dec) -> dict[int, list[pa.RecordBatch]]:
+                   fanout: int, depth_cap: int, mesh,
+                   table_key) -> dict[int, list[pa.RecordBatch]]:
         """Grace-partitioned execution of a budget-breaking hash-join stage.
 
         The split join's build side re-splits by a secondary hash of the
@@ -1380,7 +1336,7 @@ class TpuStageExec(ExecutionPlan):
                     buckets_empty.append(b)
                     continue
                 raise
-            cached, _, _ = self._compile_with_fallback(dt, sub_builds, rec, dec.mode)
+            cached, _, _ = self._compile_locked(dt, sub_builds, rec)
             fn, lowering, meta, state = cached
             state["dispatched"] = True
             # LUT cache bypass: sub-build dictionaries are bucket-dependent,
@@ -1388,13 +1344,9 @@ class TpuStageExec(ExecutionPlan):
             luts = [_put(mesh, l)
                     for l in lowering.build_luts(dicts, [sb.dicts for sb in sub_builds])]
             build_args = [sb.flat_arrays() for sb in sub_builds]
-            span_s: dict[str, float] = {}
             with RUN_STATS.span("bt.device.exec", grace_bucket=b):
-                if meta.get("exec") == "staged":
-                    outs = fn(dt.flat_cols(), luts, dt.mask, build_args, span_s)
-                else:
-                    outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
-                    jax.block_until_ready(list(outs))
+                outs = fn(dt.flat_cols(), luts, dt.mask, build_args)
+                jax.block_until_ready(list(outs))
             res = self._fetch_decode(outs, meta, P, dicts,
                                      [sb.dicts for sb in sub_builds])
             for p, bl in res.items():
@@ -1422,30 +1374,18 @@ class TpuStageExec(ExecutionPlan):
     # ------------------------------------------------------------------
 
     def _compile(self, dt: DeviceTable, kinds, dicts, P: int, N: int,
-                 builds: list[BuildTable] | None = None,
-                 mode_req: str = "fused_xla"):
+                 builds: list[BuildTable] | None = None):
         from ballista_tpu.plan.physical import HashJoinExec
-        from ballista_tpu.ops.tpu import fusion as _fusion
-        from ballista_tpu.ops.tpu.pallas_kernels import MAX_GROUPS as _PALLAS_MAX_G
 
         jax = ensure_jax()
         jnp = jax.numpy
         agg = self.partial_agg
         scan_schema = self.scan.df_schema
         builds = builds or []
-        spans = _fusion.plan_spans(
+        spans = fusion.plan_spans(
             len(getattr(self.scan, "filters", []) or []), self.ops, agg)
-        span_meta = [(s.kind, s.ops) for s in spans]
-        # the pallas kernels are single-device (no shard_map wrapping yet):
-        # under a collective-exchange mesh the XLA path handles sharding
-        use_pallas = mode_req == "fused_pallas" and _stage_mesh(self.config) is None
-        pallas_g_cap = min(int(self.config.get(TPU_FUSION_PALLAS_MAX_GROUPS)),
-                           _PALLAS_MAX_G)
-        pallas_probe_max = int(self.config.get(TPU_FUSION_PALLAS_MAX_PROBE))
-        probe_kernel_ok = _fusion.kernel_runs_on("hash_probe", runtime.platform())
 
         ctx = Lowering(scan_schema, kinds, dicts)
-        ctx.pallas_dict_filter = use_pallas
         valid_idx = dt.valid_flat_idx()
         n_flat_cols = len(dt.cols) + sum(1 for v in dt.valids if v is not None)
         env_fns = []
@@ -1503,15 +1443,9 @@ class TpuStageExec(ExecutionPlan):
                 off = n_flat_cols + sum(len(builds[i].flat_arrays()) for i in range(jidx))
                 pay_off = off + (2 if bt.cnt is not None else 1)
                 probe_fns = [lower_expr(r, ctx) for (_, r) in op.on]
-                probe_pallas = (
-                    use_pallas and probe_kernel_ok
-                    and bt.mode == "direct" and bt.cnt is None
-                    and bt.dup == 1
-                    and int(bt.keys.shape[0]) <= pallas_probe_max
-                )
                 probe_scope = f"join_probe_{jidx}"
                 finder = _scoped(probe_scope, _mk_join_finder(
-                    off, probe_fns, bt, lane_cells[jidx], pallas=probe_pallas))
+                    off, probe_fns, bt, lane_cells[jidx]))
                 pv_idx = bt.pay_valid_flat_idx()
                 if op.join_type in ("right_semi", "right_anti"):
                     neg = op.join_type == "right_anti"
@@ -1687,22 +1621,8 @@ class TpuStageExec(ExecutionPlan):
         ):
             # the unrolled form materializes G masked reductions PER
             # expansion lane; beyond this budget the sorted form wins (and
-            # scatter-free unrolling stops scaling) — UNLESS the Pallas
-            # hash-aggregate was requested and the stage fits the kernel
-            # family: its one-hot matmul accumulation carries all G lanes
-            # without per-group unrolling, so the 64-group budget lifts to
-            # the kernel ceiling. If the value lanes turn out ineligible at
-            # trace time (money int64 sums, validity planes), raw() raises
-            # Unsupported and the fallback ladder retries as fused_xla,
-            # landing here again with use_pallas off → sorted path.
-            pallas_agg_ok = (
-                use_pallas and n_lanes == 1 and mult_weight_fn is None
-                and G <= pallas_g_cap and G * P <= 1 << 22
-                and all(d.func in ("sum", "count", "count_all")
-                        for d in agg.aggs)
-            )
-            if not pallas_agg_ok:
-                unrolled = False
+            # scatter-free unrolling stops scaling)
+            unrolled = False
 
         agg_fns = []
         agg_modes = []  # "row" | "build_cnt" (count of a mult-join build col)
@@ -1745,9 +1665,7 @@ class TpuStageExec(ExecutionPlan):
                 dt, ctx, P, N, builds, group_fns, agg_fns, key_slots, key_premeta,
                 agg_modes=agg_modes, mult=mult,
             )
-            meta_s["fusion_mode"] = "fused_xla"
             meta_s["fused_spans"] = len(spans)
-            meta_s["spans"] = span_meta
             return fn_s, ctx_s, meta_s, lowered_s
 
         meta_holder: dict = {}
@@ -1756,11 +1674,7 @@ class TpuStageExec(ExecutionPlan):
         lane_sets = ctx.lane_sets
         lane_cells = ctx.lane_cells
 
-        # --- span closures, shared by the fused and staged executions -----
-        # Fused mode composes these into ONE traced function; staged mode
-        # jits each span separately with HBM intermediates between them.
-        # Either way the SAME jnp expressions run over the same inputs,
-        # which is what makes fused-vs-staged outputs byte-identical.
+        # --- span closures, composed into ONE traced function below -------
 
         def eval_pred(cols, luts, mask):
             """predicate span: scan filters, FilterExec predicates, semi/
@@ -1821,56 +1735,10 @@ class TpuStageExec(ExecutionPlan):
             meta_holder["nullcnt_map"] = nullcnt_map
             return outs_lane, nullcnt_lane, presence_lane
 
-        def pallas_lane(m, gid, vs):
-            """aggregate span, Pallas form: the multi-tile one-hot hash
-            aggregate computes ALL G masked sums + counts in one VMEM pass
-            per float value lane (exact int64 money stays on the XLA
-            reductions in aggregate_lane)."""
-            from ballista_tpu.ops.tpu.pallas_kernels import masked_group_reduce
-
-            # sums first: every sum's kernel call also yields the counts,
-            # so count aggs never need a dedicated pass
-            sum_results: dict[int, object] = {}
-            counts = None
-            for i_, (d, v) in enumerate(zip(aggs, vs)):
-                if d.func == "sum":
-                    arr = jnp.broadcast_to(v.arr, m.shape)
-                    s, c = masked_group_reduce(arr, gid, m, G)
-                    sum_results[i_] = s
-                    counts = c if counts is None else counts
-            if counts is None:  # count-only aggregation
-                _, counts = masked_group_reduce(
-                    jnp.zeros(m.shape, jnp.float32), gid, m, G
-                )
-            outs_lane = []
-            out_meta = []
-            for i_, d in enumerate(aggs):
-                if d.func in ("count", "count_all"):
-                    outs_lane.append(counts.astype(jnp.int64))
-                    out_meta.append(("i64", 0))
-                else:
-                    outs_lane.append(sum_results[i_].astype(jnp.float64))
-                    out_meta.append(("f64", 0))
-            meta_holder["out"] = out_meta
-            meta_holder["nullcnt_map"] = {}
-            meta_holder["pallas_used"] = True
-            return outs_lane, counts
-
         # each device operation's metadata says which operator span it came from
         eval_pred = _scoped("filter", eval_pred)
         eval_proj = _scoped("project", eval_proj)
         aggregate_lane = _scoped("partial_agg", aggregate_lane)
-        pallas_lane = _scoped("partial_agg", pallas_lane)
-
-        staged_ok = (
-            mode_req == "staged" and len(lane_sets) == 1
-            and mult_weight_fn is None
-        )
-        if staged_ok:
-            return self._compile_staged(
-                dt, ctx, dicts, builds, eval_pred, eval_proj, aggregate_lane,
-                meta_holder, span_meta, group_src_slots, pad_sizes, G,
-            )
 
         def raw(cols, luts, mask, build_args):
             # keep [P, N]: partitions are the leading axis, reductions run
@@ -1893,33 +1761,8 @@ class TpuStageExec(ExecutionPlan):
                 if mult_weight_fn is not None:
                     w = jnp.broadcast_to(mult_weight_fn(cols, luts), mask.shape)
                     m_eff = jnp.maximum(w, 1) if mult_outer else w
-                pallas_ok = (
-                    use_pallas and gid is not None and aggs
-                    and G <= pallas_g_cap and mult_weight_fn is None
-                    and all(v is None or v.valid is None for v in vs)
-                    and all(
-                        d.func in ("count", "count_all")
-                        or (d.func == "sum" and v is not None and v.kind == "f64")
-                        for d, v in zip(aggs, vs)
-                    )
-                )
-                if pallas_ok:
-                    outs_lane, presence_lane = pallas_lane(m, gid, vs)
-                    nullcnt_lane = []
-                else:
-                    if use_pallas and (
-                        G * len(lane_sets) > 64
-                        or G * len(lane_sets) * m.shape[0] > MAX_SEGMENTS * 16
-                    ):
-                        # this stage only kept the unrolled form because the
-                        # relaxed Pallas budget admitted it; its value lanes
-                        # turned out kernel-ineligible (money int64 sums,
-                        # validity planes) — refuse the G-wide XLA unroll
-                        # and let the fallback ladder retry as fused_xla
-                        raise Unsupported(
-                            f"pallas-ineligible aggregation at G={G}")
-                    outs_lane, nullcnt_lane, presence_lane = aggregate_lane(
-                        m, gid, vs, w, m_eff)
+                outs_lane, nullcnt_lane, presence_lane = aggregate_lane(
+                    m, gid, vs, w, m_eff)
                 if outs is None:
                     outs, presence, nullcnts = outs_lane, presence_lane, nullcnt_lane
                 else:
@@ -1938,8 +1781,7 @@ class TpuStageExec(ExecutionPlan):
 
         # a stable name from what the stage is, never a plan hash: the trace
         # reads jit_stage_partial_direct_fused_xla(..fingerprint)/fusion.N
-        raw.__name__ = raw.__qualname__ = (
-            "stage_partial_direct_" + ("fused_pallas" if use_pallas else "fused_xla"))
+        raw.__name__ = raw.__qualname__ = "stage_partial_direct_fused_xla"
         jitted = jax.jit(raw)
         cols_spec = [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in dt.flat_cols()]
         luts0 = ctx.build_luts(dicts, [b.dicts for b in builds])
@@ -1954,11 +1796,7 @@ class TpuStageExec(ExecutionPlan):
         lowered = jitted.lower(cols_spec, luts_spec, mask_spec, builds_spec)
         meta = {
             "mode": "unrolled",
-            "fusion_mode": (
-                "fused_pallas" if meta_holder.get("pallas_used") else "fused_xla"
-            ),
             "fused_spans": len(spans),
-            "spans": span_meta,
             "out": meta_holder["out"],
             "nullcnt_map": meta_holder.get("nullcnt_map", {}),
             "group_src_slots": group_src_slots,
@@ -1966,115 +1804,6 @@ class TpuStageExec(ExecutionPlan):
             "G": G,
         }
         return jitted, ctx, meta, lowered
-
-    def _compile_staged(self, dt: DeviceTable, ctx: Lowering, dicts, builds,
-                        eval_pred, eval_proj, aggregate_lane, meta_holder,
-                        span_meta, group_src_slots, pad_sizes, G: int):
-        """Per-span sub-kernels with HBM intermediates — the always-available
-        fallback mode and the roofline instrument.
-
-        Each span (predicate → project → aggregate) is its own jitted
-        function, dispatched with a device sync in between, so `span_s`
-        in RunStats shows where a stage's time actually goes. The spans
-        trace the SAME closures the fused path composes (eval_pred /
-        eval_proj / aggregate_lane), so staged and fused_xla results are
-        byte-identical; the price is materializing the predicate mask and
-        every projected value lane in HBM between dispatches."""
-        jax = ensure_jax()
-        jnp = jax.numpy
-        proj_info: dict = {}
-
-        def pred_raw(cols, luts, mask, build_args):
-            cols = list(cols) + [a for b in build_args for a in b]
-            return eval_pred(cols, luts, mask)
-
-        def proj_raw(cols, luts, mask, build_args):
-            cols = list(cols) + [a for b in build_args for a in b]
-            gid, vs = eval_proj(cols, luts)
-            out = {}
-            if gid is not None:
-                out["gid"] = jnp.broadcast_to(gid, mask.shape)
-            vmeta = []
-            for ai, v in enumerate(vs):
-                if v is None:
-                    vmeta.append(None)
-                    continue
-                out[f"a{ai}"] = jnp.broadcast_to(v.arr, mask.shape)
-                if v.valid is not None:
-                    out[f"v{ai}"] = jnp.broadcast_to(v.valid, mask.shape)
-                vmeta.append((v.kind, v.scale))
-            proj_info["vmeta"] = vmeta
-            return out
-
-        def agg_raw(m, pv):
-            vs = []
-            for ai, vm in enumerate(proj_info["vmeta"]):
-                if vm is None:
-                    vs.append(None)
-                else:
-                    kind, scale = vm
-                    vs.append(DevVal(kind, pv[f"a{ai}"], scale,
-                                     valid=pv.get(f"v{ai}")))
-            outs_lane, nullcnt_lane, presence_lane = aggregate_lane(
-                m, pv.get("gid"), vs, None, None)
-            return tuple(outs_lane) + tuple(nullcnt_lane) + (presence_lane,)
-
-        # single expansion lane (the staged gate): pin the lane cells once
-        for cell, d_ in zip(ctx.lane_cells, ctx.lane_sets[0]):
-            cell["d"] = d_
-        pred_raw.__name__ = pred_raw.__qualname__ = "stage_pred"
-        proj_raw.__name__ = proj_raw.__qualname__ = "stage_proj"
-        agg_raw.__name__ = agg_raw.__qualname__ = "stage_agg"
-        jp = jax.jit(pred_raw)
-        jproj = jax.jit(proj_raw)
-        jagg = jax.jit(agg_raw)
-
-        cols_spec = [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in dt.flat_cols()]
-        luts0 = ctx.build_luts(dicts, [b.dicts for b in builds])
-        luts_spec = [jax.ShapeDtypeStruct(l.shape, l.dtype) for l in luts0]
-        mask_spec = jax.ShapeDtypeStruct(dt.mask.shape, np.bool_)
-        builds_spec = [
-            [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in b.flat_arrays()]
-            for b in builds
-        ]
-        # trace now (Unsupported must surface at compile time, where the
-        # fallback ladder lives): proj fills vmeta, agg fills meta_holder
-        # (out / nullcnt_map) — the same metadata the fused trace produces
-        jax.eval_shape(pred_raw, cols_spec, luts_spec, mask_spec, builds_spec)
-        pv_spec = jax.eval_shape(proj_raw, cols_spec, luts_spec, mask_spec,
-                                 builds_spec)
-        jax.eval_shape(agg_raw, mask_spec, pv_spec)
-
-        def staged_fn(cols, luts, mask, build_args, span_s=None):
-            t0 = time.perf_counter()
-            m = jp(cols, luts, mask, build_args)
-            jax.block_until_ready(m)
-            t1 = time.perf_counter()
-            pv = jproj(cols, luts, mask, build_args)
-            jax.block_until_ready(pv)
-            t2 = time.perf_counter()
-            outs = jagg(m, pv)
-            jax.block_until_ready(list(outs))
-            t3 = time.perf_counter()
-            if span_s is not None:
-                span_s["predicate"] = t1 - t0
-                span_s["project"] = t2 - t1
-                span_s["aggregate"] = t3 - t2
-            return outs
-
-        meta = {
-            "mode": "unrolled",
-            "exec": "staged",
-            "fusion_mode": "staged",
-            "fused_spans": 0,
-            "spans": span_meta,
-            "out": meta_holder["out"],
-            "nullcnt_map": meta_holder.get("nullcnt_map", {}),
-            "group_src_slots": group_src_slots,
-            "pad_sizes": pad_sizes,
-            "G": G,
-        }
-        return staged_fn, ctx, meta, None
 
     def _compile_sorted(self, dt: DeviceTable, ctx: Lowering, P: int, N: int,
                         builds: list[BuildTable], group_fns, agg_fns, key_slots,
@@ -2792,8 +2521,7 @@ def _mk_col_reader(i: int, kind: str, scale: int, dictionary, valid_idx=None):
     return run
 
 
-def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict,
-                    pallas: bool = False):
+def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
     """Closure computing (clamped build index, matched mask) for one join.
 
     'direct' unique mode: the build shipped a dense key→row int32 table —
@@ -2806,19 +2534,10 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict,
     device range guards mirroring the host-side guards, so out-of-range
     keys can never alias a real build key. XLA CSEs the duplicate lookups
     issued by the per-column gathers.
-
-    `pallas=True` (direct unique mode only) routes the lookup through the
-    tiled `hash_probe` kernel: table VMEM-resident, gather + match mask
-    fused. Every build-column gather closure re-invokes the finder, and
-    XLA does not CSE custom calls the way it CSEs gathers — so the kernel
-    result is memoized per trace, keyed by the identity of the traced
-    `cols` list (a strong ref pins the list so its id cannot be recycled;
-    the identity check makes a stale hit impossible).
     """
     mode, shifts, dup = bt.mode, bt.shifts, bt.dup
     has_cnt = bt.cnt is not None
     b_static = bt.padded_rows()  # in shape_key, so cache hits can't go stale
-    _probe_memo: dict = {}
 
     def run(cols, luts):
         import jax.numpy as jnp
@@ -2844,18 +2563,6 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict,
         if mode == "direct" and not has_cnt:
             T = keys_arr.shape[0]
             in_range = valid & (k >= 0) & (k < T)
-            if pallas:
-                from ballista_tpu.ops.tpu.pallas_kernels import hash_probe
-
-                hit = _probe_memo.get(id(cols))
-                if hit is None or hit[0] is not cols:
-                    kq = jnp.where(in_range, k, 0).astype(jnp.int32)
-                    rows, matched = hash_probe(kq, keys_arr, in_range)
-                    if len(_probe_memo) > 4:
-                        _probe_memo.clear()
-                    hit = (cols, rows, matched)
-                    _probe_memo[id(cols)] = hit
-                return hit[1], DevVal("bool", hit[2])
             row = keys_arr[jnp.where(in_range, k, 0)]
             matched = in_range & (row >= 0)
             idxc = jnp.clip(row, 0, None).astype(jnp.int32)

@@ -40,10 +40,9 @@ def test_bound_device_and_scope():
 
 
 def test_platform_questions_are_asked_of_the_bound_device():
-    """current_device()/platform() follow the thread's device_scope pin, not
-    jax.devices()[0] — on a process that sees several devices the cost
-    model, the Pallas interpret switch and the HBM budget must ask the
-    device the task is bound to."""
+    """current_device() follows the thread's device_scope pin, not
+    jax.devices()[0] — on a process that sees several devices the HBM
+    budget must ask the device the task is bound to."""
     import jax
 
     from ballista_tpu.ops.tpu import hbm, runtime
@@ -52,7 +51,7 @@ def test_platform_questions_are_asked_of_the_bound_device():
     assert runtime.current_device() is devs[0]
     with runtime.device_scope(5):
         assert runtime.current_device() is devs[5]
-        assert runtime.platform() == "cpu"
+        assert runtime.current_device().platform == "cpu"
         # the CPU backend reports no memory stats: 0 by observation, and
         # the budget falls to the configured ceiling — not by exception
         assert hbm.detect_device_memory_bytes() == 0

@@ -279,10 +279,7 @@ def test_session_quota_forces_spill_plan():
     from ballista_tpu.ops.tpu import hbm
     from ballista_tpu.ops.tpu.fusion import StageEstimate
 
-    est = StageEstimate(
-        rows=1 << 20, partitions=2, group_domain=8, n_group_keys=1, lanes=1,
-        has_mult=False, n_filters=0, n_projections=0, n_joins=0,
-        max_probe_table=0, table_bytes=4 << 20, dict_bytes=1 << 20)
+    est = StageEstimate(table_bytes=4 << 20, dict_bytes=1 << 20)
     cfg = BallistaConfig({TPU_HBM_BUDGET_BYTES: 1 << 30})
     roomy = hbm.plan_stage(est, hbm.resolve_hbm_budget(cfg),
                            grace_eligible=True, grace_fanout=8,

@@ -68,6 +68,32 @@ def test_config_validation():
     assert c2.get(DEFAULT_SHUFFLE_PARTITIONS) == 8
 
 
+@pytest.mark.parametrize("key", [
+    "ballista.tpu.pallas.enabled",
+    "ballista.tpu.fusion.enabled",
+    "ballista.tpu.fusion.mode",
+    "ballista.tpu.fusion.min.rows",
+    "ballista.tpu.fusion.pallas.max.groups",
+    "ballista.tpu.fusion.pallas.max.probe.rows",
+    "ballista.tpu.sort.pallas.max.rows",
+    "ballista.tpu.topk.enabled",
+    "ballista.tpu.topk.max.k",
+    "ballista.tpu.hash.table.load.factor",
+    "ballista.tpu.allow.f32.money",
+])
+def test_removed_engine_keys_are_unknown(key):
+    """The keys that steered the lowerings that went (and two nothing ever
+    read) are not registered: setting one is an error like any unknown key,
+    at construction and at `set`, with no alias that swallows it."""
+    from ballista_tpu.config import VALID_ENTRIES
+
+    assert key not in VALID_ENTRIES
+    with pytest.raises(ConfigurationError, match="unknown config key"):
+        BallistaConfig({key: "1"})
+    with pytest.raises(ConfigurationError, match="unknown config key"):
+        BallistaConfig().set(key, "1")
+
+
 def test_config_docs_generation():
     from ballista_tpu.config import generate_config_docs
 

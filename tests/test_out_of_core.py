@@ -38,9 +38,7 @@ from .test_tpu_fill import _assert_tables_identical, _mixed_table, _scan
 
 def _est(table=1000, dicts=0, build=4000, jidx=0, has_mult=False):
     return StageEstimate(
-        rows=100, partitions=2, group_domain=64, n_group_keys=1, lanes=1,
-        has_mult=has_mult, n_filters=0, n_projections=0, n_joins=1,
-        max_probe_table=0, table_bytes=table, dict_bytes=dicts,
+        has_mult=has_mult, table_bytes=table, dict_bytes=dicts,
         build_bytes=build, max_build_bytes=build, max_build_jidx=jidx)
 
 

@@ -1,14 +1,15 @@
 """What the chip's compiler accepts, checked without the chip.
 
 The TPU compiler is installed here and compiles for a device that is
-described, not attached (`jax.experimental.topologies`): every Pallas kernel
-the cost model can select on a TPU, the ordering primitive every sorted-path
-/ final / window stage is built on, and the `fused_xla` q1 and q3 stages are
-compiled for one chip of a v5e 2x2 at the [P, N] the SF10 stages of
-chip_smoke.py produce ([8, 8388608]: 60M lineitem rows over the default 8
-scan partitions, bucketed). A compile that passes is not a chip run and says
-nothing about speed; what it catches is the compiler REFUSING the program —
-the way all six Pallas kernels were refused before anyone tried.
+described, not attached (`jax.experimental.topologies`): the ordering
+primitive and the prefix sum every sorted-path / final / window stage is
+built on, the sort / window family's own programs, and the partial stages of
+q1, q6, q3, q5, q12, q19 and q18 (its subquery's and its own) are compiled
+for one chip of a v5e 2x2 at the [P, N] the SF10 stages of chip_smoke.py
+produce ([8, 8388608]: 60M lineitem rows over the default 8 scan partitions,
+bucketed). These are the programs a chip really runs. A compile that passes
+is not a chip run and says nothing about speed; what it catches is the
+compiler REFUSING the program.
 
 Only one process may hold the TPU library, so the topology is described
 inside a module-scoped fixture (never at import), every test here compiles in
@@ -68,56 +69,6 @@ def _compile(fn, *specs):
     t0 = time.time()
     compiled = jax.jit(fn).lower(*specs).compile()
     return compiled, time.time() - t0
-
-
-# ------------------------------------------------------------ pallas kernels
-
-
-@pytest.mark.parametrize("P,N,G", [
-    (P10, N10, 8),       # q1's group domain at SF10
-    (P10, N10, 4096),    # the multi-tile ceiling
-    (3, 1 << 20, 300),   # a partition count that is no multiple of 8
-    (12, 1 << 20, 40),   # more than 8 partitions: padded to 16, blocked by 8
-])
-def test_masked_group_reduce_compiles_for_v5e(one_chip, P, N, G):
-    import jax.numpy as jnp
-
-    from ballista_tpu.ops.tpu import fusion, pallas_kernels as pk
-
-    assert "masked_group_reduce" in fusion.TPU_KERNELS
-    Pp, Np, bn = pk._tile(P, N, 2048)
-    fn = pk._build_group_reduce.__wrapped__(Pp, Np, bn, G, False)  # compiled, not interpreted
-    compiled, _ = _compile(fn, _spec(one_chip, (Pp, Np), jnp.float32),
-                           _spec(one_chip, (Pp, Np), jnp.int32),
-                           _spec(one_chip, (Pp, Np), jnp.int32))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("P,N,T", [
-    (P10, N10, 128),                  # a small dictionary: one LUT row
-    (P10, N10, 1024),                 # MAX_DICT_LUT: eight lane-gather rounds
-    (3, 1 << 20, 128),
-])
-def test_dict_filter_compiles_for_v5e(one_chip, P, N, T):
-    import jax.numpy as jnp
-
-    from ballista_tpu.ops.tpu import fusion, pallas_kernels as pk
-
-    assert "dict_filter" in fusion.TPU_KERNELS and T <= pk.MAX_DICT_LUT
-    Pp, Np, bn = pk._tile(P, N, 2048)
-    fn = pk._build_dict_filter.__wrapped__(Pp, Np, bn, T, False)
-    compiled, _ = _compile(fn, _spec(one_chip, (Pp, Np), jnp.int32),
-                           _spec(one_chip, (Pp, Np), jnp.int32),
-                           _spec(one_chip, (T // pk.LANES, pk.LANES), jnp.int32))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_every_kernel_the_cost_model_can_select_is_covered():
-    """The two tests above are the whole list: anything added to
-    fusion.TPU_KERNELS needs its compile test here first."""
-    from ballista_tpu.ops.tpu import fusion
-
-    assert fusion.TPU_KERNELS == {"masked_group_reduce", "dict_filter"}
 
 
 # ----------------------------------------------------- ordering + prefix sum
@@ -186,18 +137,40 @@ def test_int_cumsum_of_one_block_is_fused_triangles_for_v5e(one_chip, dtype):
 # ------------------------------------------------------ fused_xla stages
 
 
-def _walk(node):
-    yield node
+def _stages(node, cfg):
+    """Every TpuStageExec a query runs, outermost first. A join's build side
+    hangs off the outer stage's ops and is a stage of its own in the
+    execution graph: the executor that gets it compiles it the same way."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
+    from ballista_tpu.plan.physical import HashJoinExec
+
+    if isinstance(node, sc.TpuStageExec):
+        yield node
+        for op in node.ops:
+            if isinstance(op, HashJoinExec):
+                yield from _stages(maybe_compile_tpu(op.left, cfg), cfg)
     for c in node.children():
-        yield from _walk(c)
+        yield from _stages(c, cfg)
 
 
-def _sf10_stage(q, tpch_dir, one_chip):
-    """q's first TpuStageExec with its table and join builds as SF10-shaped
+@pytest.fixture(scope="module")
+def tpch_mid_dir(tmp_path_factory):
+    """SF0.05, seed 1: q18's big-quantity orders exist (at the session
+    fixture's SF0.01 that build side is empty and cannot be prepared)."""
+    from ballista_tpu.testing.tpchgen import generate_tpch
+
+    d = tmp_path_factory.mktemp("tpch-mid") / "sf005"
+    generate_tpch(str(d), scale=0.05, seed=1, files_per_table=2)
+    return str(d)
+
+
+def _sf10_stage(q, nth, data_dir, data_scale, one_chip):
+    """q's nth TpuStageExec with its table and join builds as SF10-shaped
     specs. Encode metadata (kinds, dictionaries, stored dtypes) comes from a
-    real fill of the SF0.01 fixture data; shapes are scaled to SF10 (x1000
-    rows: direct join tables to the next power of two, capped like
-    _prepare_build caps them). `_compile` consults nothing else."""
+    real fill of the fixture data; shapes are scaled to SF10 (direct join
+    tables to the next power of two, capped like _prepare_build caps them).
+    `_compile` consults nothing else."""
     import ballista_tpu.ops.tpu.stage_compiler as sc
     from ballista_tpu.client.context import SessionContext
     from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
@@ -207,16 +180,17 @@ def _sf10_stage(q, tpch_dir, one_chip):
 
     cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu"})
     ctx = SessionContext(cfg)
-    register_tpch(ctx, tpch_dir)
+    register_tpch(ctx, data_dir)
     phys = maybe_compile_tpu(
         ctx.create_physical_plan(ctx.sql(tpch_query(q)).plan), cfg)
-    stage = next(n for n in _walk(phys) if isinstance(n, sc.TpuStageExec))
+    stage = list(_stages(phys, cfg))[nth]
     tc = TaskContext(cfg)
     dt = sc.DEVICE_CACHE.get(stage.scan, stage.buckets, tc, 1 << 34)
     table_key = sc.DEVICE_CACHE.key_of(stage.scan)
     joins = [o for o in stage.ops if isinstance(o, HashJoinExec)]
     builds = [stage._prepare_build(op, j, tc, table_key)
               for j, op in enumerate(joins)]
+    up = round(10 / data_scale)
 
     def spec(a, shape):
         return _spec(one_chip, shape, a.dtype)
@@ -231,13 +205,13 @@ def _sf10_stage(q, tpch_dir, one_chip):
         [None if v is None else spec(v, (P10, N10)) for v in dt.valids])
     big_builds = []
     for bt in builds:
-        T = pow2(bt.keys.shape[0] * 1000)
+        T = pow2(bt.keys.shape[0] * up)
         if bt.mode == "direct":
             T = min(T, sc.DIRECT_TABLE_MAX)
-        B = pow2(bt.padded_rows() * 1000)
+        B = pow2(bt.padded_rows() * up)
         nb = sc.BuildTable(
             bt.mode, spec(bt.keys, (T,)), [spec(p, (B,)) for p in bt.payloads],
-            bt.kinds, bt.scales, bt.dicts, bt.n_rows * 1000, device=True,
+            bt.kinds, bt.scales, bt.dicts, bt.n_rows * up, device=True,
             dup=bt.dup, cnt=None if bt.cnt is None else spec(bt.cnt, (T,)),
             pay_valids=[None if v is None else spec(v, (B,))
                         for v in bt.pay_valids])
@@ -246,26 +220,86 @@ def _sf10_stage(q, tpch_dir, one_chip):
     return stage, big, big_builds
 
 
-@pytest.mark.parametrize("q,mode,max_s", [
-    (1, "unrolled", 120),  # scan-aggregate over a small code domain
-    (3, "sorted", 600),    # join probe + sort-based aggregation over 2^26 rows
-])
-def test_fused_xla_stage_compiles_for_v5e_at_sf10(q, mode, max_s, tpch_dir, one_chip):
-    stage, big, builds = _sf10_stage(q, tpch_dir, one_chip)
-    dec, _ = stage._fusion_decision(big, builds)
-    assert dec.mode == "fused_xla", dec.reason
+# (query, which of its stages, lowering, seconds allowed)
+STAGE_CASES = [
+    (1, 0, "direct", 120),   # scan-aggregate over a small code domain
+    (6, 0, "direct", 120),   # no group key: one global reduction
+    (3, 0, "sorted", 600),   # join probe + sort-based aggregation over 2^26 rows
+    (5, 0, "direct", 300),   # the four-join chain, grouped by a dictionary
+    (12, 0, "direct", 300),  # an expansion join (three match lanes) over orders
+    (19, 0, "direct", 300),  # join + a disjunctive residual filter, no group key
+    (18, 0, "sorted", 900),  # two joins, five group keys
+    (18, 1, "sorted", 600),  # its subquery: lineitem by l_orderkey
+]
+
+
+@pytest.mark.parametrize("q,nth,family,max_s", STAGE_CASES)
+def test_fused_xla_stage_compiles_for_v5e_at_sf10(q, nth, family, max_s, tpch_dir,
+                                                  tpch_mid_dir, one_chip):
+    data = (tpch_mid_dir, 0.05) if q == 18 else (tpch_dir, 0.01)
+    stage, big, builds = _sf10_stage(q, nth, *data, one_chip)
     jitted, lowering, meta, _ = stage._compile(
-        big, list(zip(big.kinds, big.scales)), big.dicts, P10, N10, builds,
-        mode_req="fused_xla")
-    assert meta["mode"] == mode and meta["fusion_mode"] == "fused_xla"
+        big, list(zip(big.kinds, big.scales)), big.dicts, P10, N10, builds)
+    assert meta["mode"] == {"direct": "unrolled", "sorted": "sorted"}[family]
     luts = [_spec(one_chip, l.shape, l.dtype)
             for l in lowering.build_luts(big.dicts, [b.dicts for b in builds])]
     t0 = time.time()
-    compiled = jitted.lower(big.flat_cols(), luts, big.mask,
-                            [b.flat_arrays() for b in builds]).compile()
+    lowered = jitted.lower(big.flat_cols(), luts, big.mask,
+                           [b.flat_arrays() for b in builds])
+    assert f"module @jit_stage_partial_{family}_fused_xla" in lowered.as_text()
+    compiled = lowered.compile()
     secs = time.time() - t0
     mem = compiled.memory_analysis()
     resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes)
     assert resident < 14 << 30, f"q{q} stage needs {resident >> 20} MiB of a 16 GiB chip"
     assert secs < max_s, f"q{q} stage took {secs:.0f}s to compile for v5e"
+
+
+# ------------------------------------------------- sort / window programs
+
+# (jitted family, key lanes, log2 of the lanes). The ordering at the lanes a
+# 2^23-row partition pads to. The scans at 2^17, what chip_smoke.py's window
+# query pads to at SF1: an associative scan unrolls a level for every doubling
+# and the chip compiler's time for it grows faster than the lanes (5 s at 2^17,
+# 121 s at 2^20, over nine minutes at 2^23 on this host) — PERF.md §7.
+SORT_WINDOW_CASES = [
+    ("sort_lex_order", 1, 23), ("sort_lex_order", 2, 23), ("sort_lex_order", 4, 23),
+    ("window_segscan_sum", 0, 17), ("window_segscan_min", 0, 17),
+    ("window_segscan_max", 0, 17),
+]
+
+
+@pytest.mark.parametrize("name,key_lanes,log2_lanes", SORT_WINDOW_CASES)
+def test_sort_window_programs_compile_for_v5e(name, key_lanes, log2_lanes, one_chip):
+    """What `TpuSortStageExec` / `TpuWindowStageExec` dispatch: the ordering
+    permutation over 1, 2 and 4 key lanes (an int32 lane, a nullable one's
+    null rank before it, int64 lanes) and the three segmented scans."""
+    import jax.numpy as jnp
+
+    from ballista_tpu.ops.tpu import sort_window as sw
+
+    L = 1 << log2_lanes
+    if key_lanes:
+        dtypes = [jnp.int32, jnp.int32, jnp.int64, jnp.int64][:key_lanes]
+        fn, specs = sw._lex_order_jit(), [_spec(one_chip, (L,), d) for d in dtypes]
+    else:
+        fn = sw._segscan_jit(name.rsplit("_", 1)[1])
+        specs = [_spec(one_chip, (L,), jnp.int64), _spec(one_chip, (L,), jnp.bool_)]
+    t0 = time.time()
+    lowered = fn.lower(*specs)
+    assert f"module @jit_{name}" in lowered.as_text()
+    lowered.compile()
+    secs = time.time() - t0
+    assert secs < 300, f"{name} took {secs:.0f}s to compile for v5e"
+
+
+def test_every_jitted_stage_family_is_covered():
+    """The cases above are the whole list: a jitted stage function the
+    engine gains needs its name in tests/test_tracing.py and its compile
+    case here first."""
+    from .test_tracing import JITTED_STAGE_FAMILIES
+
+    covered = {f"stage_partial_{family}_fused_xla" for _, _, family, _ in STAGE_CASES}
+    covered |= {name for name, _, _ in SORT_WINDOW_CASES}
+    assert covered == set(JITTED_STAGE_FAMILIES)

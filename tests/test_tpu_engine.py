@@ -455,48 +455,6 @@ def _walk(node):
         yield from _walk(c)
 
 
-def test_pallas_fused_aggregation_path():
-    """ballista.tpu.pallas.enabled: float sums + counts route through the
-    fused Pallas masked-group-reduction kernel (interpret mode on CPU) and
-    match pandas; exact int64 money stays on XLA and stays correct."""
-    from ballista_tpu.client.context import SessionContext
-    from ballista_tpu.config import TPU_PALLAS
-
-    rng = np.random.default_rng(21)
-    n = 30_000
-    tbl = pa.table({
-        "cat": rng.choice(["a", "b", "c", "d", "e"], n),
-        "w": rng.uniform(0.0, 10.0, n),        # true f64 → pallas path
-        "qty": rng.integers(1, 50, n),
-    })
-    cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0, TPU_PALLAS: True})
-    ctx = SessionContext(cfg)
-    ctx.register_arrow_table("t", tbl, partitions=4)
-    sql = "select cat, sum(w) s, count(*) c from t where qty > 10 group by cat order by cat"
-    out = ctx.sql(sql).collect().to_pandas()
-    df = tbl.to_pandas()
-    df = df[df.qty > 10]
-    g = df.groupby("cat", as_index=False).agg(s=("w", "sum"), c=("w", "size")).sort_values("cat")
-    assert out.cat.tolist() == g.cat.tolist()
-    assert (out.c.values == g.c.values).all()
-    # f32 kernel accumulation: tolerance scaled to the sums
-    assert np.allclose(out.s.values, g.s.values, rtol=2e-5)
-
-    # device path ran, no fallback
-    import ballista_tpu.ops.tpu.stage_compiler as sc
-    from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
-    from ballista_tpu.plan.physical import TaskContext
-
-    phys = maybe_compile_tpu(ctx.create_physical_plan(ctx.sql(sql).plan), cfg)
-    stages = [nd for nd in _walk(phys) if isinstance(nd, sc.TpuStageExec)]
-    assert stages
-    tc = TaskContext(cfg)
-    for p in range(phys.output_partition_count()):
-        list(phys.execute(p, tc))
-    assert sum(s.tpu_count for s in stages) >= 1
-    assert sum(s.fallback_count for s in stages) == 0
-
-
 @pytest.mark.parametrize("share,divisor", LIVE_SHARES)
 def test_device_side_shuffle_routing(tmp_path, share, divisor):
     """ROADMAP device-side shuffle write: the sorted path emits a __pid

@@ -355,7 +355,7 @@ def test_estimate_stage_matches_actual_device_bytes():
     tbl = _mixed_table()
     scan = _scan(tbl)
     dt = _load(scan, fill_threads=1)
-    est = fusion.estimate_stage(scan, [], None, dt, [])
+    est = fusion.estimate_stage([], None, dt, [])
     assert est.table_bytes == dt.nbytes
     # "flag" is dictionary-encoded: the LUT rows must be priced
     assert any(d for d in dt.dicts)

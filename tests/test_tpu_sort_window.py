@@ -1,5 +1,5 @@
-"""On-device sort / window / top-k stages: byte parity with the CPU engine
-on adversarial inputs, across all three fusion rungs.
+"""On-device sort / window stages: byte parity with the CPU engine on
+adversarial inputs, at row counts on both sides of the lane padding.
 
 Parity is asserted per column over Arrow IPC stream bytes — bitwise
 (NaN payloads, ±0.0 signs) without the chunk-slicing layout artifacts a
@@ -14,11 +14,8 @@ import pytest
 
 from ballista_tpu.config import (
     BallistaConfig,
-    TPU_FUSION_MODE,
     TPU_MIN_ROWS,
     TPU_SORT_ENABLED,
-    TPU_SORT_PALLAS_MAX_ROWS,
-    TPU_TOPK_ENABLED,
 )
 from ballista_tpu.plan.expressions import Column, SortKey, WindowFunction
 from ballista_tpu.plan.physical import (
@@ -28,9 +25,6 @@ from ballista_tpu.plan.physical import (
     WindowExec,
 )
 from ballista_tpu.plan.schema import DFSchema
-
-MODES = ("staged", "fused_xla", "fused_pallas")
-
 
 class _Src(ExecutionPlan):
     def __init__(self, tbl, df_schema, chunk=97):
@@ -48,10 +42,8 @@ class _Src(ExecutionPlan):
         yield from self.tbl.to_batches(max_chunksize=self.chunk)
 
 
-def _cfg(mode, **extra):
-    settings = {TPU_MIN_ROWS: 0, TPU_FUSION_MODE: mode}
-    settings.update(extra)
-    return BallistaConfig(settings)
+def _cfg():
+    return BallistaConfig({TPU_MIN_ROWS: 0})
 
 
 def _collect(plan, cfg):
@@ -93,19 +85,21 @@ def _adversarial_table(n=384):
             [None if j % 5 == 0 else int(v)
              for j, v in enumerate(rng.integers(0, 7, n))], pa.int32()),
         "s": pa.array([["aa", "b", "aa", "zz", "m"][j % 5] if j % 13 else None
-                       for j in range(n)]),
+                       for j in range(n)], pa.string()),
     })
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_sort_parity_adversarial(mode):
+@pytest.mark.parametrize("n", [1, 127, 128, 129, 4097])
+def test_sort_parity_adversarial(n):
     """NULLS FIRST/LAST per key, NaN and ±0.0 ordering, string keys, multi
-    key DESC — byte-identical to the CPU sort, with and without LIMIT."""
+    key DESC — byte-identical to the CPU sort, with and without LIMIT, at
+    one row, around a power-of-two lane count (the sentinel padding) and
+    past several batches."""
     from ballista_tpu.ops.tpu.sort_window import TpuSortStageExec
 
-    tbl = _adversarial_table()
+    tbl = _adversarial_table(n)
     schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg(mode)
+    cfg = _cfg()
     keysets = [
         [SortKey(Column("i")), SortKey(Column("f"), ascending=False,
                                        nulls_first=True)],
@@ -122,29 +116,31 @@ def test_sort_parity_adversarial(mode):
                 cfg)
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_topk_ties_at_cut_boundary(mode):
-    """Duplicate key values straddling the LIMIT cut: the fused top-k must
-    keep exactly the rows the stable full sort keeps."""
+TIES_N = 300
+
+
+@pytest.mark.parametrize("k", [1, 5, TIES_N - 1, TIES_N, TIES_N + 1])
+def test_topk_ties_at_cut_boundary(k):
+    """Duplicate key values straddling the LIMIT cut: ORDER BY ... LIMIT k
+    keeps exactly the rows the CPU engine's stable sort keeps — a cut inside
+    a tie run, at the last row, at the row count and past it."""
     from ballista_tpu.ops.tpu.sort_window import TpuSortStageExec
 
-    n = 300
     # every key value appears 20×, so any small LIMIT cuts inside a tie run
     tbl = pa.table({
-        "k": pa.array([j % 15 for j in range(n)], pa.int64()),
-        "payload": pa.array(range(n), pa.int64()),
+        "k": pa.array([j % 15 for j in range(TIES_N)], pa.int64()),
+        "payload": pa.array(range(TIES_N), pa.int64()),
     })
     schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg(mode)
-    for fetch in (7, 20, 33):
-        keys = [SortKey(Column("k"))]
-        _assert_parity(SortExec(_Src(tbl, schema), keys, fetch),
-                       TpuSortStageExec(_Src(tbl, schema), keys, fetch, cfg),
-                       cfg)
+    cfg = _cfg()
+    keys = [SortKey(Column("k"))]
+    cpu, _ = _assert_parity(SortExec(_Src(tbl, schema), keys, k),
+                            TpuSortStageExec(_Src(tbl, schema), keys, k, cfg),
+                            cfg)
+    assert cpu.num_rows == min(k, TIES_N)
 
 
-@pytest.mark.parametrize("mode", ("staged", "fused_pallas"))
-def test_sort_dictionary_duplicate_values(mode):
+def test_sort_dictionary_duplicate_values():
     """A dictionary whose entries contain duplicate strings: equal strings
     must share a rank (ties fall to stability), matching the CPU sort of
     the decoded column. The CPU oracle itself cannot sort dictionary
@@ -160,7 +156,7 @@ def test_sort_dictionary_duplicate_values(mode):
     dec = pa.table({"s": dup.cast(pa.string()), "p": payload})
     dec_schema = DFSchema.from_arrow(dec.schema)
     keys = [SortKey(Column("s"), ascending=False), SortKey(Column("p"))]
-    cfg = _cfg(mode)
+    cfg = _cfg()
     devp = TpuSortStageExec(_Src(tbl, schema), keys, None, cfg)
     dev = _collect(devp, cfg)
     assert devp.tpu_count == 1 and devp.fallback_count == 0
@@ -178,12 +174,13 @@ def _window_schema(tbl, wexprs, schema):
            for j, w in enumerate(wexprs)]))
 
 
-@pytest.mark.parametrize("mode", MODES)
-def test_window_parity_adversarial(mode):
-    """row_number/rank/count/sum/min/max over partition+order with NaN
-    order keys, nullable agg args, and peer frames whose order values
-    repeat ACROSS partition boundaries (scan resets must isolate
-    partitions)."""
+@pytest.mark.parametrize(
+    "func", ["row_number", "rank", "count", "sum", "min", "max", "all"])
+def test_window_parity_adversarial(func):
+    """row_number/rank/count/sum/min/max — each alone, and all six over
+    their shared frames — over partition+order with NaN order keys, nullable
+    agg args, and peer frames whose order values repeat ACROSS partition
+    boundaries (scan resets must isolate partitions)."""
     from ballista_tpu.ops.tpu.sort_window import TpuWindowStageExec
 
     rng = np.random.default_rng(23)
@@ -213,15 +210,16 @@ def test_window_parity_adversarial(mode):
         WindowFunction("max", [Column("vnull")], [],
                        [SortKey(Column("o"), ascending=False)], None),
     ]
+    if func != "all":
+        wexprs = [w for w in wexprs if w.func == func]
     wschema = _window_schema(tbl, wexprs, schema)
-    cfg = _cfg(mode)
+    cfg = _cfg()
     _assert_parity(WindowExec(_Src(tbl, schema), wexprs, wschema),
                    TpuWindowStageExec(_Src(tbl, schema), wexprs, wschema, cfg),
                    cfg)
 
 
-@pytest.mark.parametrize("mode", ("fused_xla", "fused_pallas"))
-def test_window_empty_and_all_null_partitions(mode):
+def test_window_empty_and_all_null_partitions():
     """Partitions of size one and partitions whose aggregate argument is
     entirely NULL (SQL: aggregate over zero valid rows is NULL)."""
     from ballista_tpu.ops.tpu.sort_window import TpuWindowStageExec
@@ -242,7 +240,7 @@ def test_window_empty_and_all_null_partitions(mode):
         WindowFunction("rank", [], *over, None),
     ]
     wschema = _window_schema(tbl, wexprs, schema)
-    cfg = _cfg(mode)
+    cfg = _cfg()
     _assert_parity(WindowExec(_Src(tbl, schema), wexprs, wschema),
                    TpuWindowStageExec(_Src(tbl, schema), wexprs, wschema, cfg),
                    cfg)
@@ -256,7 +254,7 @@ def test_zero_row_input():
 
     tbl = pa.table({"a": pa.array([], pa.int64())})
     schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg("fused_pallas")
+    cfg = _cfg()
     keys = [SortKey(Column("a"))]
     out = _collect(TpuSortStageExec(_Src(tbl, schema), keys, 5, cfg), cfg)
     assert out.num_rows == 0
@@ -267,11 +265,10 @@ def test_zero_row_input():
     assert out.num_rows == 0 and out.num_columns == 2
 
 
-@pytest.mark.parametrize("mode", ("fused_xla", "fused_pallas"))
-def test_estimate_covers_device_bytes(mode):
+def test_estimate_covers_device_bytes():
     """Fill test: estimate_sort_stage must price at least the bytes the
     stage actually shipped (RUN_STATS device_bytes) — for a plain sort, a
-    top-k, and a window stage."""
+    sort with LIMIT, and a window stage."""
     from ballista_tpu.ops.tpu import fusion
     from ballista_tpu.ops.tpu.sort_window import (
         TpuSortStageExec,
@@ -283,7 +280,7 @@ def test_estimate_covers_device_bytes(mode):
     tbl = _adversarial_table()
     n = tbl.num_rows
     schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg(mode)
+    cfg = _cfg()
     keys = [SortKey(Column("inull"), nulls_first=True),
             SortKey(Column("f"), ascending=False)]
     batch = tbl.combine_chunks().to_batches()[0]
@@ -296,8 +293,7 @@ def test_estimate_covers_device_bytes(mode):
         _collect(devp, cfg)
         assert devp.tpu_count == 1
         actual = int(RUN_STATS.snapshot()["device_bytes"])
-        est = fusion.estimate_sort_stage(
-            n, key_meta, fetch=fetch if len(keys) == 1 else None)
+        est = fusion.estimate_sort_stage(n, key_meta)
         assert est.table_bytes >= actual > 0, (est.table_bytes, actual)
 
     wexprs = [
@@ -317,24 +313,6 @@ def test_estimate_covers_device_bytes(mode):
     assert west.table_bytes >= actual > 0, (west.table_bytes, actual)
 
 
-def test_demotion_reason_recorded():
-    """A forced fused_pallas sort over the lane ceiling demotes to
-    fused_xla with the cost model's rationale in RUN_STATS."""
-    from ballista_tpu.ops.tpu.sort_window import TpuSortStageExec
-    from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS
-
-    tbl = pa.table({"a": pa.array(range(600), pa.int64())})
-    schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg("fused_pallas", **{TPU_SORT_PALLAS_MAX_ROWS: 128})
-    devp = TpuSortStageExec(_Src(tbl, schema), [SortKey(Column("a"))], None,
-                            cfg)
-    _collect(devp, cfg)
-    assert devp.tpu_count == 1 and devp.fallback_count == 0
-    stats = RUN_STATS.snapshot()
-    assert stats["fusion_mode"] == "fused_xla"
-    assert "forced fused_pallas but" in stats["fusion_reason"]
-
-
 def test_counters_flow_to_heartbeat_gauges():
     """RunStats → ExecutorProcess._tpu_metrics: the sort-family gauges are
     exported once the family has run (stats-sync invariant, live)."""
@@ -343,16 +321,16 @@ def test_counters_flow_to_heartbeat_gauges():
 
     tbl = _adversarial_table()
     schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg("fused_pallas")
+    cfg = _cfg()
     devp = TpuSortStageExec(_Src(tbl, schema),
                             [SortKey(Column("i"))], 5, cfg)
     _collect(devp, cfg)
     gauges = dict(ExecutorProcess._tpu_metrics())
-    for key in ("tpu_sort_kernel_s", "tpu_topk_invocations",
-                "tpu_topk_rows_kept"):
+    for key in ("tpu_sort_kernel_s", "tpu_sort_invocations",
+                "tpu_sort_full_materializations"):
         assert key in gauges, key
-    assert gauges["tpu_topk_invocations"] >= 1
-    assert gauges["tpu_topk_rows_kept"] >= 5
+    assert gauges["tpu_sort_invocations"] >= 1
+    assert gauges["tpu_sort_full_materializations"] >= 1
 
 
 def test_engine_wiring_and_knob_gate():
@@ -390,9 +368,9 @@ def test_engine_wiring_and_knob_gate():
             assert not nodes, phys.display()
 
 
-def test_topk_knob_disables_fused_cut():
-    """ballista.tpu.topk.enabled=false: LIMIT sorts still run on device but
-    through the full sort (sort_full_materializations counts it)."""
+def test_limit_is_full_sort_then_slice():
+    """ORDER BY ... LIMIT runs on the device as the full order, sliced:
+    sort_full_materializations counts it, a sort without LIMIT does not."""
     from ballista_tpu.ops.tpu.sort_window import (
         TpuSortStageExec,
         counters_snapshot,
@@ -400,13 +378,14 @@ def test_topk_knob_disables_fused_cut():
 
     tbl = _adversarial_table()
     schema = DFSchema.from_arrow(tbl.schema)
-    cfg = _cfg("fused_pallas", **{TPU_TOPK_ENABLED: False})
-    before = counters_snapshot()["sort_full_materializations"]
-    devp = TpuSortStageExec(_Src(tbl, schema), [SortKey(Column("i"))], 5, cfg)
-    cpu = SortExec(_Src(tbl, schema), [SortKey(Column("i"))], 5)
-    _assert_parity(cpu, devp, cfg)
-    after = counters_snapshot()["sort_full_materializations"]
-    assert after == before + 1
+    cfg = _cfg()
+    keys = [SortKey(Column("i"))]
+    counts = [counters_snapshot()["sort_full_materializations"]]
+    for fetch in (5, None):
+        _assert_parity(SortExec(_Src(tbl, schema), keys, fetch),
+                       TpuSortStageExec(_Src(tbl, schema), keys, fetch, cfg), cfg)
+        counts.append(counters_snapshot()["sort_full_materializations"])
+    assert counts[1] == counts[0] + 1 and counts[2] == counts[1]
 
 
 def test_float_extremes_cross_the_device_as_ordered_int64():
