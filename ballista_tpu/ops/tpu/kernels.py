@@ -207,16 +207,21 @@ def int_cumsum(x, block: int = 2048):
 
 
 def live_slots(valid, cap: int):
-    """Slots (int32 [cap]) of the first `cap` True rows of the 1-D mask
-    `valid`, in slot order; past the live count, the last slot. The j-th
-    live row is where the running count of live rows first reaches j + 1: a
-    binary search of the prefix sum — log2(M) steps of cap-row gathers, and
-    no M-row gather, sort or scatter, which the TPU does slowly."""
+    """Slots (int32 [..., cap]) of the first `cap` True entries along the
+    last axis of the mask `valid` ([N], or [R, N]: each row for itself), in
+    slot order; past a row's live count, its dead slots. One stable
+    two-operand sort of (dead, slot) a row: the live slots come first and
+    keep their order. On a v5e that is 8-12 ms for 2^23 slots whatever
+    `cap` is, where a binary search of the prefix sum (log2(N) steps of
+    cap-row gathers) takes 25 ms at N / 64 and 0.2 s at N / 8, and a
+    scatter of the slot ids 40-60 ms."""
+    import jax
+
     jnp = _jnp()
-    seen = int_cumsum(valid.astype(jnp.int32))
-    nth = jnp.arange(1, cap + 1, dtype=jnp.int32)
-    return jnp.minimum(jnp.searchsorted(seen, nth, side="left"),
-                       valid.shape[0] - 1).astype(jnp.int32)
+    slot = jnp.broadcast_to(jnp.arange(valid.shape[-1], dtype=jnp.int32), valid.shape)
+    _, order = jax.lax.sort((~valid, slot), dimension=valid.ndim - 1, num_keys=1,
+                            is_stable=True)
+    return order[..., :cap]
 
 
 # -- bit-exact twin of ops/hashing.py ---------------------------------------

@@ -81,12 +81,18 @@ from ballista_tpu.plan.schema import DFSchema
 log = logging.getLogger(__name__)
 
 MAX_SEGMENTS = 1 << 16
-# the sorted path holds at most this many groups a dispatch, and orders its
-# LIVE rows only: of a stage's M row slots it takes M / 64 where that holds
-# them, else M (_compile_sorted). Every tier is one more ordering to trace,
-# compile and load: add one only for a measured workload that lands in it
+# the sorted path holds at most this many groups a dispatch
 SORTED_MAX_GROUPS = 1 << 22
-SORTED_LIVE_TIERS = (64,)
+# A stage works over its LIVE rows: of its row slots it takes the smallest of
+# slots / 64, slots / 8 and all of them that holds the rows alive, counted by
+# the program itself. The sorted path (_compile_sorted) projects, orders and
+# reduces at the first divisor only or at every slot: each of its tiers is an
+# ordering of its own to trace, compile and load, and no measured workload
+# lands between (q3 keeps 0.4 % of its slots, q18 all or 441 rows). The direct
+# path (_compile) probes its join chain at all three, a partition's rows
+# compacted within the partition: q5's first join keeps 10.8 % of the slots,
+# and a tier there is a handful of gathers, not an ordering.
+LIVE_TIERS = (64, 8)
 
 # LruDict moved to utils/lru.py (PR 9) so CPU-side modules can bound their
 # caches without importing this module — the executor heartbeat keys TPU
@@ -1406,6 +1412,16 @@ class TpuStageExec(ExecutionPlan):
         lane_cells = [{"d": 0} for _ in builds]
         lane_dups: list[int] = []  # per build: lanes to unroll (1 for semi/anti)
         outer_jidx: set[int] = set()  # joins whose build gathers are nullable-by-miss
+        # (filters, joins) up to and including the FIRST join whose match mask
+        # filters probe rows (inner, semi, anti): the prefix the direct path
+        # evaluates over every slot before it probes the rest of the chain at
+        # the live rows' tier. None: no such join, no tier
+        probe_prefix = None
+
+        def selective_join():
+            nonlocal probe_prefix
+            if probe_prefix is None:
+                probe_prefix = (len(filter_fns), jidx + 1)
 
         # Aggregate-through-join pre-scan: when the LAST op is an inner/right
         # join whose build columns appear ONLY as count(col) arguments (and
@@ -1457,6 +1473,7 @@ class TpuStageExec(ExecutionPlan):
                             lambda cols, luts, _f=finder, _n=neg:
                             DevVal("bool", ~_f(cols, luts)[1].arr if _n else _f(cols, luts)[1].arr)
                         )
+                        selective_join()
                     else:
                         # EXISTS with a correlated residual predicate (q21's
                         # l2.l_suppkey <> l1.l_suppkey): OR the filtered
@@ -1500,18 +1517,21 @@ class TpuStageExec(ExecutionPlan):
                             return DevVal("bool", ~any_m if _n else any_m)
 
                         filter_fns.append(run)
+                        selective_join()
                     lane_dups.append(1)
                     jidx += 1
                     continue
                 if jidx == mult_jidx:
                     # aggregate-through-join: ONE count gather replaces all
                     # dup match lanes; build columns are never materialized
-                    counter = _scoped(probe_scope, _mk_join_counter(off, probe_fns, bt))
+                    counter = _scoped(probe_scope, _mk_join_counter(
+                        off, probe_fns, bt, lane_cells[jidx]))
                     if op.join_type == "inner":
                         filter_fns.append(
                             lambda cols, luts, _c=counter:
                             DevVal("bool", _c(cols, luts) > 0)
                         )
+                        selective_join()
                     mult_weight_fn = counter
                     n_bf = len(op.left.df_schema)
                     ctx.env_fns = [
@@ -1528,17 +1548,17 @@ class TpuStageExec(ExecutionPlan):
                     outer_jidx.add(jidx)
                     # right outer: every probe row emits — on lane 0
                     # unconditionally (unmatched rows ride lane 0 with NULL
-                    # build gathers), on later lanes only when matched
+                    # build gathers), on later lanes only when matched. The
+                    # lane is an int, or an int32 a row where the sorted path
+                    # has compacted several lanes' rows into one set
                     def emit(cols, luts, _f=finder, _cell=lane_cells[jidx]):
-                        jnp = ensure_jax().numpy
                         _, matched = _f(cols, luts)
-                        if _cell["d"] == 0:
-                            return DevVal("bool", jnp.ones_like(matched.arr))
-                        return matched
+                        return DevVal("bool", matched.arr | (_cell["d"] == 0))
 
                     filter_fns.append(emit)
                 else:
                     filter_fns.append(lambda cols, luts, _f=finder: _f(cols, luts)[1])
+                    selective_join()
                 lane_dups.append(bt.dup)
                 build_fns = [
                     _mk_build_gather(pay_off, ci, bt.kinds[ci], bt.scales[ci], bt.dicts[ci],
@@ -1676,18 +1696,19 @@ class TpuStageExec(ExecutionPlan):
 
         # --- span closures, composed into ONE traced function below -------
 
-        def eval_pred(cols, luts, mask):
+        def eval_pred(cols, luts, mask, fns):
             """predicate span: scan filters, FilterExec predicates, semi/
-            anti membership masks, join match masks — one fused [P, N]
-            boolean."""
+            anti membership masks, join match masks — one fused boolean of
+            `mask`'s shape."""
             m = mask
-            for ff in filter_fns:
+            for ff in fns:
                 m = m & true_mask(ff(cols, luts))
             return m
 
-        def eval_proj(cols, luts):
-            """project/probe span: group-id composition and agg value lanes
-            (join-probe gathers ride inside the lowered column closures)."""
+        def eval_proj(cols, luts, shape):
+            """project/probe span: group-id composition, agg value lanes and
+            the aggregate-through-join weight (join-probe gathers ride inside
+            the lowered column closures)."""
             if group_fns:
                 gid = None
                 for gf, psz in zip(group_fns, pad_sizes):
@@ -1696,11 +1717,17 @@ class TpuStageExec(ExecutionPlan):
             else:
                 gid = None
             vs = [af(cols, luts) if af is not None else None for af in agg_fns]
-            return gid, vs
+            w = None
+            if mult_weight_fn is not None:
+                w = jnp.broadcast_to(mult_weight_fn(cols, luts), shape)
+            return gid, vs, w
 
-        def aggregate_lane(m, gid, vs, w, m_eff):
+        def aggregate_lane(m, gid, vs, w):
             """aggregate span, one expansion lane: per-group masked
             reductions (the XLA form — pure VPU, no scatter)."""
+            m_eff = None
+            if w is not None:
+                m_eff = jnp.maximum(w, 1) if mult_outer else w
             gmasks = [m & (gid == g) for g in range(G)] if gid is not None else [m]
             outs_lane = []
             out_meta = []
@@ -1740,6 +1767,76 @@ class TpuStageExec(ExecutionPlan):
         eval_proj = _scoped("project", eval_proj)
         aggregate_lane = _scoped("partial_agg", aggregate_lane)
 
+        prefix_fns, prefix_joins = probe_prefix or (0, 0)
+        # the capacities a partition's live rows may be probed at, from N alone
+        capacities = _tier_capacities(N, LIVE_TIERS[1:])
+
+        def probe_at(cap, founds):
+            """One tier of the probe: everything behind the prefix — the later
+            joins' finders and match masks, predicates, payload gathers, group
+            ids, aggregate inputs — over each partition's live rows, gathered
+            in slot order into `cap` slots of the scan columns. The prefix's
+            joins are probed again there for their payloads, `cap` rows and
+            not N; at N, over the slots as they are, their payload gathers
+            reuse what the prefix found (`founds`, a prefix lane each). Every
+            tier hands what the aggregate reads back as [P, N], the slots
+            past `cap` dead, so all have the one signature."""
+
+            def compacted(cols, live, count):
+                # as ONE flat row set, [P * cap]: a flat gather takes the chip
+                # half the time of a batched one, and the chain's lookups are
+                # flat gathers already
+                with jax.named_scope("compact_live"):
+                    src = (live_slots(live, cap) + jnp.arange(
+                        0, P * N, N, dtype=jnp.int32)[:, None]).reshape(-1)
+                    return ([c.reshape(-1)[src] for c in cols[:n_flat_cols]]
+                            + cols[n_flat_cols:],
+                            (jnp.arange(cap, dtype=jnp.int32)[None, :]
+                             < count[:, None]).reshape(-1))
+
+            def branch(cols, luts, lives, counts):
+                if cap < N:
+                    row_sets = [compacted(cols, *lc) for lc in zip(lives, counts)]
+                else:
+                    row_sets = [(cols, live) for live in lives]
+                outs = []
+                for lane, at in zip(lane_sets, lane_prefix):
+                    _set_lanes(lane_cells, lane, founds[at] if cap == N else ())
+                    at_cols, live = row_sets[at]
+                    m = eval_pred(at_cols, luts, live, filter_fns[prefix_fns:])
+                    gid, vs, w = eval_proj(at_cols, luts, live.shape)
+                    # a switch carries arrays: what a value is (kind, scale)
+                    # is the closures' alone, the same in every tier
+                    meta_holder["vs"] = [v and (v.kind, v.scale) for v in vs]
+                    planes = [v and [None if x is None else jnp.broadcast_to(x, live.shape)
+                                     for x in (v.arr, v.valid)] for v in vs]
+                    outs.append(jax.tree.map(
+                        lambda x: jnp.pad(x.reshape(P, cap), ((0, 0), (0, N - cap))),
+                        (m, gid, w, planes)))
+                return outs
+
+            return branch
+
+        # lanes that differ only behind the prefix share its mask (and, in a
+        # tier, its compaction)
+        prefix_lanes = sorted({lane[:prefix_joins] for lane in lane_sets})
+        lane_prefix = [prefix_lanes.index(lane[:prefix_joins]) for lane in lane_sets]
+
+        def merge_lane(outs, lane_outs):
+            """reductions accumulate across expansion lanes"""
+            if outs is None:
+                return lane_outs
+            merged = []
+            for d, prev, cur in zip(aggs, outs[0], lane_outs[0]):
+                if d.func == "min":
+                    merged.append(jnp.minimum(prev, cur))
+                elif d.func == "max":
+                    merged.append(jnp.maximum(prev, cur))
+                else:  # sum / count: additive across lanes
+                    merged.append(prev + cur)
+            return (merged, [p_ + c_ for p_, c_ in zip(outs[1], lane_outs[1])],
+                    outs[2] + lane_outs[2])
+
         def raw(cols, luts, mask, build_args):
             # keep [P, N]: partitions are the leading axis, reductions run
             # over axis=1 — XLA fuses the per-group masked sums into single
@@ -1750,34 +1847,39 @@ class TpuStageExec(ExecutionPlan):
             # accumulate across lanes.
             cols = list(cols) + [a for b in build_args for a in b]
             outs = None
-            presence = None
-            nullcnts: list = []
-            for lane in lane_sets:
-                for cell, d_ in zip(lane_cells, lane):
-                    cell["d"] = d_
-                m = eval_pred(cols, luts, mask)
-                gid, vs = eval_proj(cols, luts)
-                w = m_eff = None
-                if mult_weight_fn is not None:
-                    w = jnp.broadcast_to(mult_weight_fn(cols, luts), mask.shape)
-                    m_eff = jnp.maximum(w, 1) if mult_outer else w
-                outs_lane, nullcnt_lane, presence_lane = aggregate_lane(
-                    m, gid, vs, w, m_eff)
-                if outs is None:
-                    outs, presence, nullcnts = outs_lane, presence_lane, nullcnt_lane
-                else:
-                    merged = []
-                    for d, prev, cur in zip(aggs, outs, outs_lane):
-                        if d.func == "min":
-                            merged.append(jnp.minimum(prev, cur))
-                        elif d.func == "max":
-                            merged.append(jnp.maximum(prev, cur))
-                        else:  # sum / count: additive across lanes
-                            merged.append(prev + cur)
-                    outs = merged
-                    presence = presence + presence_lane
-                    nullcnts = [p_ + c_ for p_, c_ in zip(nullcnts, nullcnt_lane)]
-            return tuple(outs) + tuple(nullcnts) + (presence,)
+            if probe_prefix is None:
+                for lane in lane_sets:
+                    _set_lanes(lane_cells, lane)
+                    m = eval_pred(cols, luts, mask, filter_fns)
+                    outs = merge_lane(outs, aggregate_lane(
+                        m, *eval_proj(cols, luts, mask.shape)))
+                _set_lanes(lane_cells, lane_sets[0])  # the cells keep no traced value
+                return tuple(outs[0]) + tuple(outs[1]) + (outs[2],)
+            # a join's match mask filters the rows: the prefix over every
+            # slot, then a `lax.switch` on the fullest partition's live count
+            # probes the rest of the chain at the smallest capacity that
+            # holds every partition's live rows; the aggregate's G masked
+            # passes run once, behind the switch
+            lives, founds = [], []
+            for lane in prefix_lanes:
+                _set_lanes(lane_cells, lane + (0,) * (len(lane_cells) - prefix_joins))
+                lives.append(eval_pred(cols, luts, mask, filter_fns[:prefix_fns]))
+                founds.append([cell["last"] for cell in lane_cells])
+            with jax.named_scope("compact_live"):
+                counts = [m.sum(axis=1, dtype=jnp.int32) for m in lives]
+                fullest = jnp.max(jnp.stack(counts))
+                tier = sum((fullest > cap).astype(jnp.int32) for cap in capacities[:-1])
+            probed = jax.lax.switch(tier, [probe_at(cap, founds) for cap in capacities],
+                                    cols, luts, lives, counts)
+            for m, gid, w, vs in probed:
+                vs = [v and DevVal(what[0], v[0], what[1], valid=v[1])
+                      for v, what in zip(vs, meta_holder["vs"])]
+                outs = merge_lane(outs, aggregate_lane(m, gid, vs, w))
+            _set_lanes(lane_cells, lane_sets[0])  # the cells keep no traced value
+            n_live = sum(counts[at].sum() for at in lane_prefix)
+            probe_counts = jnp.stack([
+                n_live, jnp.asarray(capacities, jnp.int32)[tier] * (P * len(lane_sets))])
+            return tuple(outs[0]) + tuple(outs[1]) + (outs[2], probe_counts)
 
         # a stable name from what the stage is, never a plan hash: the trace
         # reads jit_stage_partial_direct_fused_xla(..fingerprint)/fusion.N
@@ -1802,6 +1904,11 @@ class TpuStageExec(ExecutionPlan):
             "group_src_slots": group_src_slots,
             "pad_sizes": pad_sizes,
             "G": G,
+            # the stage's row slots; where a join's match filters them the
+            # last output is int32 (live rows behind the prefix, row slots
+            # probed): RunStats `probe_rows_live` / `probe_rows`
+            "slots": P * N * len(lane_sets),
+            "probe_counts": probe_prefix is not None,
         }
         return jitted, ctx, meta, lowered
 
@@ -1823,15 +1930,20 @@ class TpuStageExec(ExecutionPlan):
         groups) raises and the stage re-runs on the CPU engine.
 
         Gathers and scatters are what the chip does slowly, and all of the
-        above is gathers and scatters over every row slot. So the program
-        counts its live rows (the slots the filters and join matches left
-        valid) and a `lax.switch` on the count runs all of it over the
-        smaller capacity of `M / 64` and `M` (SORTED_LIVE_TIERS) that holds
-        them: below `M`, over the live rows gathered, in slot order, through
-        `kernels.live_slots`; at `M`, over the slots as they are. One body
-        (`reduce_rows`) serves every tier, each padding its outputs to [C].
-        The last output is int32 (groups, live rows, slots ordered): RunStats
-        `sorted_rows_live` / `sorted_rows_ordered`.
+        above — and every join payload lookup of the projection before it —
+        is gathers and scatters over every row slot. So the program
+        evaluates its filters and join matches over the slots, counts the
+        live rows, and a `lax.switch` on the count runs the projection (group
+        keys, aggregate inputs: `project`) and all of the above over the
+        smaller capacity of `M / 64` and `M` (LIVE_TIERS' first) that holds
+        them: below `M`, over the SCAN COLUMNS gathered at the live slots, in
+        slot order, through `kernels.live_slots` (the joins are probed again
+        there for their payloads, `cap` rows and not `M`); at `M`, over the
+        slots as they are. One body (`project`, `reduce_rows`) serves every
+        tier, each padding its outputs to [C]. The last output is int32
+        (groups, live rows, slots ordered): RunStats `sorted_rows_live` /
+        `sorted_rows_ordered`, and `probe_rows_live` / `probe_rows`, which
+        they equal here.
         """
         jax = ensure_jax()
         jnp = jax.numpy
@@ -1844,7 +1956,8 @@ class TpuStageExec(ExecutionPlan):
         C = min(_pow2(M), SORTED_MAX_GROUPS)
         # the capacities a dispatch may order its live rows at, from M alone
         # (so the compile key and `meta` stay what they are)
-        capacities = sorted({-(-M // d) for d in SORTED_LIVE_TIERS} - {M}) + [M]
+        capacities = _tier_capacities(M, LIVE_TIERS[:1])
+        n_scan = len(dt.flat_cols())
         meta_holder: dict = {}
         # device-side shuffle routing: emit a __pid column over the
         # compacted output rows (bit-exact twin of ops/hashing.py — string
@@ -1892,20 +2005,25 @@ class TpuStageExec(ExecutionPlan):
 
         def raw(cols, luts, mask, build_args):
             cols = list(cols) + [a for b in build_args for a in b]
-            # per expansion-join match lane: (valid, key operands, payloads);
-            # lanes concatenate into one row set feeding a single ordering.
-            # A NULLABLE group key contributes TWO sort operands — a null
-            # marker then the (filled) value — so NULL forms its own group
-            # (SQL GROUP BY treats NULLs as equal) without sentinel values.
-            lane_valid, lane_keyops, lane_pays = [], [], []
+            # per expansion-join match lane: valid over every slot (and what
+            # its joins' finders found there); lanes concatenate into one row
+            # set feeding a single ordering
+            lane_valid, founds = [], []
             for lane in lane_sets:
-                for cell, d_ in zip(lane_cells, lane):
-                    cell["d"] = d_
+                _set_lanes(lane_cells, lane)
                 m = mask
                 with jax.named_scope("filter"):
                     for ff in filter_fns:
                         m = m & true_mask(ff(cols, luts))
                 lane_valid.append(m.reshape(-1))
+                founds.append([cell["last"] for cell in lane_cells])
+
+            def project(cols, shape):
+                """(key operands, payloads) of one row set: `cols` of `shape`,
+                a lane's [P, N] slots or the live rows of every lane.
+                A NULLABLE group key contributes TWO sort operands — a null
+                marker then the (filled) value — so NULL forms its own group
+                (SQL GROUP BY treats NULLs as equal) without sentinel values."""
                 with jax.named_scope("project"):
                     keyops = []  # flat key operand list
                     key_meta = []  # per key: (kind, scale, slot, has_null)
@@ -1921,18 +2039,18 @@ class TpuStageExec(ExecutionPlan):
                             arr = arr.astype(jnp.int32)
                         has_null = v.valid is not None
                         if has_null:
-                            marker = jnp.broadcast_to(~v.valid, mask.shape).reshape(-1)
+                            marker = jnp.broadcast_to(~v.valid, shape).reshape(-1)
                             keyops.append(marker.astype(jnp.int32))
                             key_narrow.append(True)
-                        keyops.append(jnp.broadcast_to(arr, mask.shape).reshape(-1))
+                        keyops.append(jnp.broadcast_to(arr, shape).reshape(-1))
                         key_narrow.append(slot_fits_int32(slot))
                         key_meta.append((v.kind, v.scale, slot, has_null))
                     meta_holder["key_meta"] = key_meta
-                    lane_keyops.append(keyops)
+                    meta_holder["key_narrow"] = key_narrow
                     w_b = m_eff = None
                     if mult is not None:
                         wfn, mouter = mult
-                        w_b = jnp.broadcast_to(wfn(cols, luts), mask.shape)
+                        w_b = jnp.broadcast_to(wfn(cols, luts), shape)
                         m_eff = jnp.maximum(w_b, 1) if mouter else w_b
                     # payload plan: per agg → (pay_idx|None, ncnt_idx|None)
                     pays = []
@@ -1960,7 +2078,7 @@ class TpuStageExec(ExecutionPlan):
                             else:
                                 # count(x): number of non-null x per group (each
                                 # probe row weighted by its join multiplicity)
-                                vb = jnp.broadcast_to(v.valid, mask.shape)
+                                vb = jnp.broadcast_to(v.valid, shape)
                                 cnt1 = m_eff if m_eff is not None else 1
                                 pays.append(jnp.where(vb, cnt1, 0)
                                             .reshape(-1).astype(jnp.int64))
@@ -1990,15 +2108,15 @@ class TpuStageExec(ExecutionPlan):
                                            if jnp.issubdtype(arr.dtype, jnp.integer) else -jnp.inf)
                             arr = jnp.where(v.valid, arr, neutral)
                             pays.append(jnp.broadcast_to(
-                                v.valid, mask.shape).reshape(-1).astype(jnp.int64))
+                                v.valid, shape).reshape(-1).astype(jnp.int64))
                             ncnt_idx = len(pays) - 1
-                        pays.append(jnp.broadcast_to(arr, mask.shape).reshape(-1))
+                        pays.append(jnp.broadcast_to(arr, shape).reshape(-1))
                         pay_plan.append((len(pays) - 1, ncnt_idx))
                         if d.func in ("welford_mean", "welford_m2"):
                             welford_pay[id(d.expr)] = pay_plan[-1]
                     meta_holder["out"] = out_meta
                     meta_holder["pay_plan"] = pay_plan
-                    lane_pays.append(pays)
+                return keyops, pays
 
             def reduce_rows(valid, keys, pays):
                 """The ordering, the segmented reduction and the routing hash
@@ -2011,7 +2129,7 @@ class TpuStageExec(ExecutionPlan):
                 with jax.named_scope("sorted_agg"):
                     perm = lex_order([~valid] + [
                         k.astype(jnp.int32) if fits and k.dtype == jnp.int64 else k
-                        for k, fits in zip(keys, key_narrow)])
+                        for k, fits in zip(keys, meta_holder["key_narrow"])])
                     svalid = valid[perm]
                     skeys = [k[perm] for k in keys]
                     spays = [p[perm] for p in pays]
@@ -2146,31 +2264,34 @@ class TpuStageExec(ExecutionPlan):
                 return tuple(jnp.pad(o, (0, C - Ct)) for o in outs) + (n_seg,)
 
             def at_capacity(cap):
-                def branch(valid, keys, pays, n_live):
+                def branch(cols, luts, valid, n_live):
                     if cap == M:  # mostly alive: over the slots as they are
+                        lanes = []
+                        for lane, found in zip(lane_sets, founds):
+                            _set_lanes(lane_cells, lane, found)
+                            lanes.append(project(cols, mask.shape))
+                        keys, pays = ([jnp.concatenate(xs) for xs in zip(*part)]
+                                      for part in zip(*lanes))
                         return reduce_rows(valid, keys, pays)
                     with jax.named_scope("compact_live"):
                         src = live_slots(valid, cap)
-                        live = (jnp.arange(cap, dtype=jnp.int32) < n_live,
-                                [k[src] for k in keys], [p[src] for p in pays])
-                    return reduce_rows(*live)
+                        # a row of the concatenated lanes: its lane, its slot
+                        lane_of, slot = jnp.divmod(src, mask.size)
+                        live_cols = [c.reshape(-1)[slot] for c in cols[:n_scan]] + cols[n_scan:]
+                        _set_lanes(lane_cells, lane_sets[0] if len(lane_sets) == 1 else
+                                   jnp.asarray(lane_sets, jnp.int32)[lane_of].T)
+                    keys, pays = project(live_cols, (cap,))
+                    return reduce_rows(jnp.arange(cap, dtype=jnp.int32) < n_live, keys, pays)
                 return branch
 
             with jax.named_scope("sorted_agg"):
                 valid = jnp.concatenate(lane_valid)
-                n_keyops = len(lane_keyops[0])
-                cat_keys = [
-                    jnp.concatenate([lk[i] for lk in lane_keyops]) for i in range(n_keyops)
-                ]
-                cat_pays = [
-                    jnp.concatenate([lp[i] for lp in lane_pays])
-                    for i in range(len(lane_pays[0]))
-                ]
                 # a scalar predicate outside any vmap: only the taken tier runs
                 n_live = valid.sum(dtype=jnp.int32)
                 tier = sum((n_live > cap).astype(jnp.int32) for cap in capacities[:-1])
             *outs, n_seg = jax.lax.switch(tier, [at_capacity(cap) for cap in capacities],
-                                          valid, cat_keys, cat_pays, n_live)
+                                          cols, luts, valid, n_live)
+            _set_lanes(lane_cells, lane_sets[0])  # the cells keep no traced value
             counts = jnp.stack([n_seg, n_live, jnp.asarray(capacities, jnp.int32)[tier]])
             return tuple(outs) + (counts,)
 
@@ -2206,7 +2327,14 @@ class TpuStageExec(ExecutionPlan):
                 return self._decode_sorted(outs, meta, P, dicts, build_dicts, span)
         with RUN_STATS.span("bt.device.fetch"):
             outs = ensure_jax().device_get(list(outs))  # ONE batched fetch
-        with RUN_STATS.span("bt.decode"):
+        with RUN_STATS.span("bt.decode") as span:
+            n_probed = meta["slots"]  # no join's match filters the rows: all, uncounted
+            if meta["probe_counts"]:
+                n_live, n_probed = (int(x) for x in outs.pop())
+                RUN_STATS.set("probe_rows_live", n_live)
+                span.set(probe_rows_live=n_live)
+            RUN_STATS.set("probe_rows", n_probed)
+            span.set(probe_rows=n_probed)
             return self._decode_all(outs, meta, P, dicts, build_dicts)
 
     def _decode_sorted(self, outs, meta: dict, P: int, dicts,
@@ -2227,7 +2355,11 @@ class TpuStageExec(ExecutionPlan):
             n, n_live, n_ordered = (int(x) for x in jax.device_get(outs[-1]))
         RUN_STATS.set("sorted_rows_live", n_live)
         RUN_STATS.set("sorted_rows_ordered", n_ordered)
-        span.set(sorted_rows_live=n_live, sorted_rows_ordered=n_ordered)
+        # the projection and its probes run where the ordering runs
+        RUN_STATS.set("probe_rows_live", n_live)
+        RUN_STATS.set("probe_rows", n_ordered)
+        span.set(sorted_rows_live=n_live, sorted_rows_ordered=n_ordered,
+                 probe_rows_live=n_live, probe_rows=n_ordered)
         if n > C:
             raise Unsupported(f"group capacity overflow ({n} > {C})")
         results = {p: [_empty_batch(schema)] for p in range(P)}
@@ -2480,6 +2612,12 @@ def _masked_reduce(jnp, v, gm, func: str):
     raise Unsupported(f"agg {func}")
 
 
+def _tier_capacities(slots: int, divisors) -> list[int]:
+    """The capacities, ascending, a stage's live rows may take of `slots`:
+    `slots` over each divisor, then all of them."""
+    return sorted({-(-slots // d) for d in divisors} - {slots}) + [slots]
+
+
 def _pow2(n: int) -> int:
     p = 1
     while p < max(n, 1):
@@ -2521,6 +2659,18 @@ def _mk_col_reader(i: int, kind: str, scale: int, dictionary, valid_idx=None):
     return run
 
 
+def _set_lanes(lane_cells: list, lane, found=()) -> None:
+    """Point the joins' closures at a row set, at trace time: each join's
+    match lane `d` (an int; an int32 a row where the sorted path has compacted
+    several lanes' rows into one set) and, where the row set is one its
+    finder has already run over — the slots as they are, at the top tier —
+    what it `found` there, so the payload gathers behind reuse the lookup
+    instead of issuing an `M`-row gather of their own. Each finder leaves its
+    `last` result in its cell."""
+    for cell, d_, f in itertools.zip_longest(lane_cells, lane, found):
+        cell.update(d=d_, found=f, last=None)
+
+
 def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
     """Closure computing (clamped build index, matched mask) for one join.
 
@@ -2532,14 +2682,19 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
     binary search over sorted keys with an int64.max tail (two searches
     when expansion). Multi-key probes combine as k1 << shift | k2 with
     device range guards mirroring the host-side guards, so out-of-range
-    keys can never alias a real build key. XLA CSEs the duplicate lookups
-    issued by the per-column gathers.
+    keys can never alias a real build key. Shape-generic in `cols`: the
+    stage calls it over every row slot where its match is the filter, and
+    again over the live rows' compacted scan columns for the payload
+    gathers behind it (a lookup of its own there, `cap` rows and not `M`);
+    the lookups the per-column gathers of ONE row set issue are duplicates
+    XLA CSEs. The match lane is an int, or an int32 a row where the sorted
+    path has compacted several lanes' rows into one set.
     """
     mode, shifts, dup = bt.mode, bt.shifts, bt.dup
     has_cnt = bt.cnt is not None
     b_static = bt.padded_rows()  # in shape_key, so cache hits can't go stale
 
-    def run(cols, luts):
+    def find(cols, luts):
         import jax.numpy as jnp
 
         keys_arr = cols[off]
@@ -2586,6 +2741,19 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
         matched = valid & (lo + d < hi)
         idxc = jnp.clip(lo + d, 0, keys_arr.shape[0] - 1).astype(jnp.int32)
         return idxc, DevVal("bool", matched)
+
+    return _found_once(find, cell)
+
+
+def _found_once(find, cell: dict):
+    """`find(cols, luts)` unless the cell already holds what it found over
+    this row set (`_set_lanes`)."""
+
+    def run(cols, luts):
+        if cell.get("found") is None:
+            cell["last"] = find(cols, luts)
+            return cell["last"]
+        return cell["found"]
 
     return run
 
@@ -2661,7 +2829,7 @@ def _mult_shape_check(partial_agg, ops, join) -> dict | None:
     return out
 
 
-def _mk_join_counter(off: int, probe_fns, bt: BuildTable):
+def _mk_join_counter(off: int, probe_fns, bt: BuildTable, cell: dict):
     """Closure computing each probe row's MATCH COUNT against the build —
     the aggregate-through-join weight. Where every build-column use in the
     stage is multiplicity-shaped (count(col), count(*), probe-side sums),
@@ -2670,7 +2838,7 @@ def _mk_join_counter(off: int, probe_fns, bt: BuildTable):
     mode, shifts = bt.mode, bt.shifts
     has_cnt = bt.cnt is not None
 
-    def run(cols, luts):
+    def count(cols, luts):
         import jax.numpy as jnp
 
         keys_arr = cols[off]
@@ -2705,7 +2873,7 @@ def _mk_join_counter(off: int, probe_fns, bt: BuildTable):
         hi = jnp.searchsorted(keys_arr, k, side="right")
         return jnp.where(valid, (hi - lo).astype(jnp.int32), zero)
 
-    return run
+    return _found_once(count, cell)
 
 
 def _mk_raising(msg: str):

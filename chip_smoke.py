@@ -95,7 +95,8 @@ ORACLE_COLUMNS = {
 }
 STAGE_KEYS = ("dispatches", "table_shape", "fused_spans", "fill_s", "encode_s",
               "upload_s", "trace_s", "xla_compile_s", "compile_overlap_s", "exec_s", "device_bytes",
-              "persist_cache_hits", "persist_cache_misses", "hbm_plan")
+              "persist_cache_hits", "persist_cache_misses", "hbm_plan",
+              "probe_rows_live", "probe_rows")
 
 
 def emit(obj: dict) -> None:

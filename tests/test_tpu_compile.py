@@ -3,7 +3,7 @@
 The TPU compiler is installed here and compiles for a device that is
 described, not attached (`jax.experimental.topologies`): the ordering
 primitive and the prefix sum every sorted-path / final / window stage is
-built on, the sort / window family's own programs, and the partial stages of
+built on, the compaction a join stage's tiers start with, the sort / window family's own programs, and the partial stages of
 q1, q6, q3, q5, q12, q19 and q18 (its subquery's and its own) are compiled
 for one chip of a v5e 2x2 at the [P, N] the SF10 stages of chip_smoke.py
 produce ([8, 8388608]: 60M lineitem rows over the default 8 scan partitions,
@@ -132,6 +132,35 @@ def test_int_cumsum_of_one_block_is_fused_triangles_for_v5e(one_chip, dtype):
     assert secs < 60, f"int_cumsum took {secs:.0f}s to compile"
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 64 * 64
     assert "reduce-window" not in compiled.as_text(), "a scan, not the triangle"
+
+
+@pytest.mark.parametrize("rows,max_s", [(1 << 20, 120), (1 << 23, 300)])
+def test_live_slots_compiles_inside_a_conditional_for_v5e(one_chip, rows, max_s):
+    """The compaction every tier below the top starts with (`live_slots`: one
+    stable two-operand sort of (dead, slot) along a row), as the stages hold
+    it — inside a branch of a `lax.switch`, a partition a row, the scan
+    columns gathered flat behind it: 8 partitions of 2^20 (SF1's lineitem,
+    2^23 slots) and of 2^23 (SF10's, 2^26)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ballista_tpu.ops.tpu.kernels import live_slots
+
+    P, cap = 8, rows // 8
+
+    def tiers(col, valid):
+        def compact(col, valid):
+            src = live_slots(valid, cap) + jnp.arange(0, P * rows, rows, dtype=jnp.int32)[:, None]
+            return jnp.pad(col.reshape(-1)[src], ((0, 0), (0, rows - cap)))
+
+        fullest = valid.sum(axis=1, dtype=jnp.int32).max()
+        return jax.lax.switch((fullest > cap).astype(jnp.int32),
+                              [compact, lambda col, valid: col], col, valid)
+
+    compiled, secs = _compile(tiers, _spec(one_chip, (P, rows), jnp.int32),
+                              _spec(one_chip, (P, rows), jnp.bool_))
+    assert compiled.as_text().count(" sort(") == 1
+    assert secs < max_s, f"live_slots at [8, {rows}] took {secs:.0f}s to compile"
 
 
 # ------------------------------------------------------ fused_xla stages

@@ -1,0 +1,383 @@
+"""A join stage probes at its live rows' tier.
+
+A device stage whose chain holds a join evaluates its filters up to the first
+join whose match mask filters probe rows over every slot, counts the rows
+left alive and runs everything behind — later probes, payload lookups,
+predicates, group ids, aggregate inputs — over the scan columns compacted to
+the smallest capacity that holds them: a partition's rows into N / 8 slots on
+the direct path, all rows into M / 64 on the sorted path, else over the slots
+as they are. The capacity follows the data alone; every case here is one
+device stage on the CPU backend beside the CPU engine's answer, with the
+counts the stage recorded (RunStats `probe_rows_live`, `probe_rows`).
+"""
+
+import hashlib
+
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from .conftest import tpch_query
+from .test_tpu_engine import _device_oracle, _in_batches
+
+ROWS, PARTS = 8000, 2   # two partitions of 4000 rows: [2, 4096] row slots
+SLOTS = 4096
+DIM = 500               # build keys 0 .. 499; probe keys 500 .. 1199 match nothing
+
+
+def _fact(lives, alive_in_dim=True):
+    """The probe table: partition p holds exactly `lives[p]` rows alive behind
+    the join with `dim` on `fk` — rows whose key is in `dim` (or, for an anti
+    join, is not) — spread among the others in a fixed random order."""
+    rng = np.random.default_rng(17)
+    per = ROWS // PARTS
+    fk = []
+    for n_live in lives:
+        hit = np.zeros(per, bool)
+        hit[rng.choice(per, n_live, replace=False)] = True
+        if not alive_in_dim:
+            hit = ~hit
+        fk.append(np.where(hit, rng.integers(0, DIM, per), rng.integers(DIM, 1200, per)))
+    fk = np.concatenate(fk).astype("int64")
+    return _in_batches(pa.table({
+        "fk": fk,
+        "sk": rng.integers(0, 8, ROWS).astype("int64"),
+        "g": (rng.permutation(ROWS) % 3000).astype("int64"),
+        "tag": pa.array([f"t{i}" for i in rng.integers(0, 4, ROWS)]),
+        "amt": np.round(rng.uniform(1, 100, ROWS), 2),
+        "qty": rng.integers(1, 50, ROWS).astype("int64"),
+    }), PARTS)
+
+
+def _dims():
+    rng = np.random.default_rng(19)
+    ids = np.arange(DIM)
+    w = rng.uniform(0, 5, DIM)
+    xid = np.repeat(ids, rng.integers(1, 4, DIM))     # 1 to 3 rows a key
+    return {
+        "dim": pa.table({
+            "id": pa.array(ids, pa.int64()),
+            "cat": pa.array([f"c{i % 5}" for i in ids]),
+            "nk": pa.array(ids % 8, pa.int64()),
+            "prio": pa.array(ids % 3, pa.int64()),
+            "w": pa.array([None if i % 4 == 0 else float(x) for i, x in zip(ids, w)],
+                          pa.float64()),
+        }),
+        # every (sk, nk) pair: the second join of the chain, on two keys, one
+        # of them the first join's payload
+        "dim2": pa.table({
+            "sid": pa.array(np.repeat(np.arange(8), 8), pa.int64()),
+            "snk": pa.array(np.tile(np.arange(8), 8), pa.int64()),
+            "name": pa.array([f"n{(a * 3 + b) % 6}" for a in range(8) for b in range(8)]),
+        }),
+        "dimx": pa.table({"xid": pa.array(xid, pa.int64()),
+                          "mode": pa.array([f"m{i % 3}" for i in range(len(xid))]),
+                          "bid": pa.array(np.arange(len(xid)), pa.int64())}),
+        # a residual that never rules a pair out: sq is no fact row's qty
+        "dims": pa.table({"sid2": pa.array(xid, pa.int64()),
+                          "sq": pa.array(np.full(len(xid), -1), pa.int64())}),
+    }
+
+
+# name -> (sql, tables beside fact, path, lanes of the first join (None: by
+# the build's duplicates), alive rows are those whose key is in the build)
+SHAPES = {
+    # q5: a chain, the second join probing on two keys, grouped by a dictionary
+    "chain_two_key_probe": (
+        "SELECT name, sum(amt) AS s, count(*) AS c FROM fact, dim, dim2 "
+        "WHERE fk = id AND sk = sid AND nk = snk GROUP BY name ORDER BY name",
+        ("dim", "dim2"), "direct", 1, True),
+    # q3: one join, its payloads group keys beside a large int domain
+    "sorted_one_join": (
+        "SELECT g, prio, sum(amt) AS s, count(*) AS c FROM fact JOIN dim ON fk = id "
+        "WHERE qty > 2 GROUP BY g, prio ORDER BY g, prio",
+        ("dim",), "sorted", 1, True),
+    # q12: duplicate build keys, three match lanes
+    "expansion_lanes": (
+        "SELECT mode, sum(qty) AS s, count(*) AS c FROM fact JOIN dimx ON fk = xid "
+        "GROUP BY mode ORDER BY mode",
+        ("dimx",), "direct", None, True),
+    "expansion_lanes_sorted": (
+        "SELECT g, mode, sum(qty) AS s, count(*) AS c FROM fact JOIN dimx ON fk = xid "
+        "GROUP BY g, mode ORDER BY g, mode",
+        ("dimx",), "sorted", None, True),
+    # q19: a disjunctive residual over both sides behind the join, no group key
+    "residual_filter": (
+        "SELECT sum(amt) AS s, count(*) AS c FROM fact JOIN dim ON fk = id "
+        "WHERE (cat = 'c1' AND qty < 20) OR (cat = 'c3' AND qty >= 20)",
+        ("dim",), "direct", 1, True),
+    # q13: count(build column) through the join's match count, one lane
+    "aggregate_through_join": (
+        "SELECT tag, count(bid) AS cb, count(*) AS c FROM dimx JOIN fact ON xid = fk "
+        "GROUP BY tag ORDER BY tag",
+        ("dimx",), "direct", 1, True),
+    # q21: EXISTS / NOT EXISTS with a correlated residual
+    "semi_with_residual": (
+        "SELECT tag, count(*) AS c, sum(amt) AS s FROM fact WHERE EXISTS "
+        "(SELECT 1 FROM dims WHERE sid2 = fk AND sq <> qty) GROUP BY tag ORDER BY tag",
+        ("dims",), "direct", 1, True),
+    "anti_with_residual": (
+        "SELECT tag, count(*) AS c, sum(amt) AS s FROM fact WHERE NOT EXISTS "
+        "(SELECT 1 FROM dims WHERE sid2 = fk AND sq <> qty) GROUP BY tag ORDER BY tag",
+        ("dims",), "direct", 1, False),
+    "nullable_build_payload": (
+        "SELECT tag, sum(w) AS s, count(w) AS cw, count(*) AS c FROM fact JOIN dim "
+        "ON fk = id GROUP BY tag ORDER BY tag",
+        ("dim",), "direct", 1, True),
+}
+
+# live rows of partition 0 and of partition 1, of 4096 slots each. The direct
+# path probes at 512 slots a partition where the fullest holds that few, the
+# sorted path at 128 of the stage's 8192 where all its live rows do
+LIVES = [
+    pytest.param((0, 0), id="none_alive"),
+    pytest.param((40, 60), id="few_alive"),
+    pytest.param((64, 64), id="sorted_tier_full"),
+    pytest.param((64, 65), id="sorted_tier_one_over"),
+    pytest.param((512, 300), id="direct_tier_full"),
+    pytest.param((513, 2), id="fullest_partition_one_over"),
+    pytest.param((4000, 4000), id="all_alive"),
+]
+
+
+def _stage_record():
+    """The record of the ONE partial device stage the last query ran."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+
+    recs = [r for r in sc.RUN_STATS.stages().values() if "probe_rows" in r]
+    assert len(recs) == 1, [sorted(r) for r in sc.RUN_STATS.stages().values()]
+    return recs[0]
+
+
+def _assert_same_answer(tpu: pa.Table, cpu: pa.Table):
+    assert tpu.schema.names == cpu.schema.names and tpu.num_rows == cpu.num_rows
+    for name in tpu.schema.names:
+        a, b = tpu.column(name).to_pylist(), cpu.column(name).to_pylist()
+        if pa.types.is_floating(tpu.schema.field(name).type):
+            assert [x is None for x in a] == [x is None for x in b], name
+            assert np.allclose([x or 0.0 for x in a], [x or 0.0 for x in b],
+                               rtol=1e-12, atol=1e-9), name
+        else:
+            assert a == b, name
+
+
+@pytest.mark.parametrize("lives", LIVES)
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_join_stage_probes_at_its_live_rows_tier(shape, lives):
+    sql, names, path, lanes, alive_in_dim = SHAPES[shape]
+    dims = _dims()
+    fact = _fact(lives, alive_in_dim)
+    tpu, cpu = _device_oracle(sql, {"fact": fact, **{n: dims[n] for n in names}})
+    _assert_same_answer(tpu, cpu)
+
+    rec = _stage_record()
+    assert rec["table_shape"] == [PARTS, SLOTS]
+    # matches a probe row finds in the first join's build, lane by lane
+    fk = fact.column("fk").to_numpy()
+    if lanes is None:
+        per_key = np.bincount(dims[names[0]].column(0).to_numpy(), minlength=1200)
+        lanes = int(per_key.max())
+        matches = per_key[fk]
+    else:
+        matches = ((fk < DIM) == alive_in_dim).astype(int)
+    if path == "sorted" and shape == "sorted_one_join":
+        matches = matches * (fact.column("qty").to_numpy() > 2)  # the scan filter
+    live = int(np.minimum(matches, lanes).sum())
+    assert rec["probe_rows_live"] == live
+    if path == "sorted":
+        slots = PARTS * SLOTS * lanes
+        want = slots // 64 if live <= slots // 64 else slots
+        assert rec["sorted_rows_ordered"] == want and rec["sorted_rows_live"] == live
+    else:
+        fullest = max(int((matches[p * 4000:(p + 1) * 4000] > 0).sum()) for p in range(PARTS))
+        want = (SLOTS // 8 if fullest <= SLOTS // 8 else SLOTS) * PARTS * lanes
+        assert "sorted_rows_ordered" not in rec
+    assert rec["probe_rows"] == want
+
+
+def test_right_outer_join_compacts_nothing():
+    """A right outer join's first lane emits every probe row: its match mask
+    filters nothing, the stage has no tier and counts no live rows."""
+    sql = ("SELECT tag, count(w) AS cw, count(*) AS c, sum(amt) AS s FROM dim "
+           "RIGHT JOIN fact ON id = fk GROUP BY tag ORDER BY tag")
+    tpu, cpu = _device_oracle(sql, {"fact": _fact((40, 60)), "dim": _dims()["dim"]})
+    _assert_same_answer(tpu, cpu)
+    rec = _stage_record()
+    assert rec["probe_rows"] == PARTS * SLOTS and "probe_rows_live" not in rec
+
+
+def test_inner_join_behind_a_right_outer_join_is_the_first_that_filters():
+    """The prefix runs up to the first join whose match FILTERS: the outer
+    join before it is inside the prefix, and its payloads are probed again
+    at the tier, NULL where unmatched."""
+    dims = _dims()
+    outer = pa.table({"oid": pa.array(np.arange(0, 8, 2), pa.int64()),
+                      "ow": pa.array([1.5, 2.5, 3.5, 4.5])})
+    sql = ("SELECT tag, count(ow) AS co, sum(ow) AS so, count(*) AS c FROM outerd "
+           "RIGHT JOIN fact ON oid = sk JOIN dim ON fk = id GROUP BY tag ORDER BY tag")
+    tpu, cpu = _device_oracle(sql, {"fact": _fact((300, 20)), "dim": dims["dim"],
+                                    "outerd": outer})
+    _assert_same_answer(tpu, cpu)
+    rec = _stage_record()
+    # the planner may probe dim first or second: either way 320 rows are alive
+    assert rec["probe_rows_live"] == 320 and rec["probe_rows"] == PARTS * SLOTS // 8
+
+
+def test_tiers_agree_bit_for_bit():
+    """The same rows alive behind the whole chain, once with few and once with
+    many rows alive behind its FIRST join (the others die at the residual
+    behind it): probed at the tier and over the slots as they are, every
+    exact kind (money in int64 cents, counts) comes out the same to the bit,
+    float sums to their rounding (the reduction sees its addends at other
+    slots)."""
+    sql = ("SELECT sum(amt) AS s, sum(w) AS sw, count(*) AS c FROM fact JOIN dim ON fk = id "
+           "WHERE (cat = 'c1' AND qty < 20) OR (cat = 'c3' AND qty >= 20)")
+    few = _fact((200, 300)).to_pandas()
+    many = few.copy()
+    # every dead row now finds a build row of category c0, which the residual drops
+    many.loc[many.fk >= DIM, "fk"] = (many.fk[many.fk >= DIM] % 100) * 5
+    outs = []
+    for fact, want in ((few, PARTS * SLOTS // 8), (many, PARTS * SLOTS)):
+        tables = {"fact": _in_batches(pa.Table.from_pandas(fact), PARTS), "dim": _dims()["dim"]}
+        tpu, cpu = _device_oracle(sql, tables)
+        _assert_same_answer(tpu, cpu)
+        assert _stage_record()["probe_rows"] == want
+        outs.append(tpu.to_pydict())
+    assert outs[0]["s"] == outs[1]["s"] and outs[0]["c"] == outs[1]["c"]
+    assert np.allclose(outs[0]["sw"], outs[1]["sw"], rtol=1e-13)
+
+
+def _partial_stage_text(ctx, q):
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
+    from ballista_tpu.plan.physical import TaskContext
+
+    from .test_tpu_engine import _walk
+
+    phys = maybe_compile_tpu(ctx.create_physical_plan(ctx.sql(tpch_query(q)).plan), ctx.config)
+    stage = next(n for n in _walk(phys) if isinstance(n, sc.TpuStageExec))
+    dt = sc.DEVICE_CACHE.get(stage.scan, stage.buckets, TaskContext(ctx.config), 1 << 34)
+    _, _, meta, lowered = stage._compile(
+        dt, list(zip(dt.kinds, dt.scales)), dt.dicts, *dt.shape, [])
+    return meta, lowered.as_text()
+
+
+# sha256 of the lowered text of q1's and q6's partial stage over the session's
+# SF0.01 tables, taken from the parent commit of the PR that brought the tiers
+# (jax 0.9.0). A jax upgrade changes them: take them again from a tree known
+# good; any other change to them is a change to the join-less programs
+JOINLESS_PROGRAMS = {
+    1: "11a8ebffda24c5d69f5fb987d0b0735e7977572de3a45d9bea37c56d973808de",
+    6: "69b551c5e9a638db26f72b6bd2074abd8b6b7f1daa7d9a4a29815d58263bbb6e",
+}
+
+
+@pytest.mark.parametrize("q", sorted(JOINLESS_PROGRAMS))
+def test_a_stage_without_a_join_traces_no_switch(q, tpch_dir):
+    """q1 and q6 have no join: no prefix, no count, no conditional — the
+    program the parent traced, to the byte (so the persistent compile cache
+    still holds it)."""
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
+    from ballista_tpu.testing.tpchgen import register_tpch
+
+    ctx = SessionContext(BallistaConfig({EXECUTOR_ENGINE: "tpu"}))
+    register_tpch(ctx, tpch_dir)
+    meta, text = _partial_stage_text(ctx, q)
+    assert meta["mode"] == "unrolled" and not meta["probe_counts"]
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert "stablehlo.sort" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == JOINLESS_PROGRAMS[q]
+
+
+def test_probe_counts_land_on_the_stage_record_and_its_decode_span(tpch_dir):
+    """Served through the standalone cluster: q3 (sorted path) and q5 (direct
+    path) leave `probe_rows_live` / `probe_rows` on the device stage's record
+    and as numbers of its `bt.decode` span in the job's record."""
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
+    from ballista_tpu.testing.tpchgen import register_tpch
+    from ballista_tpu.tracing import RUN_STATS
+
+    ctx = SessionContext.standalone(BallistaConfig({EXECUTOR_ENGINE: "tpu"}), num_executors=1)
+    try:
+        register_tpch(ctx, tpch_dir)
+        for q in (3, 5):
+            # the first collect's record also takes the spans earlier tests
+            # closed outside any job: read the second
+            ctx.sql(tpch_query(q)).collect()
+            RUN_STATS.clear()
+            ctx.sql(tpch_query(q)).collect()
+            rec = _stage_record()
+            P, N = rec["table_shape"]
+            assert 0 < rec["probe_rows_live"] <= rec["probe_rows"] <= P * N
+            numbers = [s[7] for tag, job in RUN_STATS.stages().items() if tag.startswith("job_")
+                       for s in job["spans"] if s[0] == "bt.decode" and "probe_rows" in s[7]]
+            assert len(numbers) == 1, "one bt.decode span carries the counts"
+            assert numbers[0]["probe_rows"] == rec["probe_rows"]
+            assert numbers[0]["probe_rows_live"] == rec["probe_rows_live"]
+    finally:
+        ctx.shutdown()
+
+
+@pytest.mark.parametrize("lives,divisor", [((40, 60), 64), ((4000, 4000), 1)])
+def test_emit_pid_routes_a_join_stage_probed_at_its_tier(tmp_path, lives, divisor):
+    """Device-side shuffle routing behind a join: the group keys — one of them
+    a payload of the join, looked up at the tier — are hashed over the
+    compacted groups, and every written bucket holds the rows the host's
+    hash would send there."""
+    import glob
+    import json
+
+    import pyarrow.ipc as ipc
+    import pyarrow.parquet as pq
+
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, TPU_MIN_ROWS, BallistaConfig
+    from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
+    from ballista_tpu.ops.hashing import partition_indices
+    from ballista_tpu.plan.physical import TaskContext
+    from ballista_tpu.scheduler.planner import DistributedPlanner
+    from ballista_tpu.shuffle import paths as sp
+
+    from .test_tpu_engine import _walk
+
+    fact = _fact(lives)
+    pq.write_table(fact, str(tmp_path / "fact.parquet"))
+    cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0})
+    ctx = SessionContext(cfg)
+    ctx.register_parquet("fact", str(tmp_path / "fact.parquet"))
+    ctx.register_arrow_table("dim", _dims()["dim"], partitions=1)
+    sql = "SELECT g, prio, sum(amt) AS s FROM fact JOIN dim ON fk = id GROUP BY g, prio"
+    stage1 = DistributedPlanner("jprobe").plan_query_stages(
+        ctx.create_physical_plan(ctx.sql(sql).plan))[0]
+    compiled = maybe_compile_tpu(stage1.plan, cfg)
+    tpu = [nd for nd in _walk(compiled) if isinstance(nd, sc.TpuStageExec)]
+    assert tpu and tpu[0].emit_pid is not None
+    assert any(type(op).__name__ == "HashJoinExec" for op in tpu[0].ops)
+
+    tc = TaskContext(cfg, task_id="t0", work_dir=str(tmp_path / "work"))
+    sc.RUN_STATS.clear()
+    for p in range(stage1.partitions):
+        list(compiled.execute(p, tc))
+    assert tpu[0].pid_emitted >= 1 and tpu[0].fallback_count == 0
+    rec = _stage_record()
+    P, N = rec["table_shape"]  # the parquet file's own partitioning
+    assert rec["probe_rows_live"] == sum(lives) and rec["probe_rows"] == P * N // divisor
+
+    want = fact.to_pandas().merge(_dims()["dim"].to_pandas(), left_on="fk", right_on="id")
+    seen = 0
+    for f in glob.glob(f"{tmp_path}/work/jprobe/1/*.arrow"):
+        for pid_s, entry in json.load(open(sp.index_path(f))).items():
+            with open(f, "rb") as fh:
+                fh.seek(entry[0])
+                got = ipc.open_stream(pa.BufferReader(fh.read(entry[1]))).read_all()
+            assert "__pid" not in got.column_names
+            if got.num_rows:
+                host = partition_indices(
+                    [got.column(k).combine_chunks() for k in ("g", "prio")],
+                    stage1.output_partitions)
+                assert (host == int(pid_s)).all()
+                seen += got.num_rows
+    assert seen == len(want.groupby(["g", "prio"]))  # every group routed, none twice
