@@ -1941,9 +1941,9 @@ class TpuStageExec(ExecutionPlan):
         there for their payloads, `cap` rows and not `M`); at `M`, over the
         slots as they are. One body (`project`, `reduce_rows`) serves every
         tier, each padding its outputs to [C]. The last output is int32
-        (groups, live rows, slots ordered): RunStats `sorted_rows_live` /
-        `sorted_rows_ordered`, and `probe_rows_live` / `probe_rows`, which
-        they equal here.
+        (groups, live rows, slots ordered): RunStats `sorted_groups`,
+        `sorted_rows_live` / `sorted_rows_ordered`, and `probe_rows_live` /
+        `probe_rows`, which the last two equal here.
         """
         jax = ensure_jax()
         jnp = jax.numpy
@@ -2355,10 +2355,12 @@ class TpuStageExec(ExecutionPlan):
             n, n_live, n_ordered = (int(x) for x in jax.device_get(outs[-1]))
         RUN_STATS.set("sorted_rows_live", n_live)
         RUN_STATS.set("sorted_rows_ordered", n_ordered)
+        RUN_STATS.set("sorted_groups", n)
         # the projection and its probes run where the ordering runs
         RUN_STATS.set("probe_rows_live", n_live)
         RUN_STATS.set("probe_rows", n_ordered)
         span.set(sorted_rows_live=n_live, sorted_rows_ordered=n_ordered,
+                 sorted_groups=n, sorted_capacity=C,
                  probe_rows_live=n_live, probe_rows=n_ordered)
         if n > C:
             raise Unsupported(f"group capacity overflow ({n} > {C})")

@@ -62,9 +62,9 @@ DEPLOYMENT_SCALE = 10.0
 # sorted path holds at most 2^22 groups, so q18's GROUP BY l_orderkey leaves
 # the device from SF3 up.
 DEFAULT_SCALE = 1.0
-REDUCED_WHY = ("whole script must fit 1200 s with compilation (last timed at "
-               "PR 21, with eight dispatches a stage; one since PR 26) and the "
-               "sorted path's 2^22 group capacity overflows on q18 from SF3")
+REDUCED_WHY = ("whole script must fit 1200 s with compilation (PERF.md has the "
+               "timings of every query here) and the sorted path's 2^22 group "
+               "capacity overflows on q18 from SF3")
 LABEL = "smoke timing, not a benchmark result"
 
 TPCH_QUERIES = (1, 6, 3, 5, 18)
