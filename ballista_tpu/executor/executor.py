@@ -6,11 +6,11 @@ Rebuild of Executor::execute_query_stage + the ExecutionEngine seam
 - `ExecutionEngine.create_query_stage_exec` prepares a stage plan for this
   executor: stamps the work dir, and (tpu engine) compiles supported
   subtrees to XLA (engine/tpu_engine.py);
-- `execute_query_stage` drives the stage's ShuffleWriterExec for every
-  partition in the task's slice, converts metadata batches to
-  PartitionLocations (zero-byte outputs dropped — the reference's
-  sentinel rule, execution_engine.rs:336), catches panics, and returns a
-  TaskStatus-shaped result;
+- `execute_query_stage` hands the stage's ShuffleWriterExec the task's
+  slice of partitions (pulled one by one, committed once), converts the
+  metadata batch to PartitionLocations (zero-byte outputs dropped — the
+  reference's sentinel rule, execution_engine.rs:336), catches panics, and
+  returns a TaskStatus-shaped result;
 - cancellation via a cooperative flag checked between partitions.
 """
 
@@ -34,6 +34,10 @@ from ballista_tpu.tracing import RUN_STATS
 from ballista_tpu.version import WIRE_PROTOCOL_VERSION
 
 log = logging.getLogger(__name__)
+
+
+class _DeadlineExpired(Exception):
+    """The task's deadline passed between two partitions of its slice."""
 
 
 @dataclass
@@ -293,54 +297,61 @@ class Executor:
             assert isinstance(plan, ShuffleWriterExec), f"stage root must be a shuffle writer: {plan}"
             with RUN_STATS.span("bt.task.prepare"):
                 prepared = self.engine.create_query_stage_exec(plan, cfg, task.stage_attempt)
-            locations: list[PartitionLocation] = []
-            for p in task.partitions:
+            ctx = TaskContext(cfg, task_id=f"{task.task_id}", work_dir=self.work_dir)
+            ctx.device_ordinal = self.metadata.device_ordinal
+            ctx.task_attempt = int(getattr(task, "task_attempt", 0))
+            ctx.deadline_at = deadline_at
+            # long-running operators (and chaos stragglers) poll this so
+            # a CancelTasks push preempts mid-partition, not between
+            ctx.cancel_check = (
+                lambda j=task.job_id, s=task.stage_id, t=task.task_id: self._is_cancelled(j, s, t)
+            )
+            if self.session_pools is not None:
+                # concurrent tasks of one session share the pool: idle
+                # tasks lend spill budget to a heavy sort (try_grow)
+                ctx.memory_pool = self.session_pools.get(task.session_id)
+                if str(cfg.get(EXECUTOR_ENGINE)) == "tpu":
+                    # attach the device-side ledger: HBM headroom is
+                    # split-accounted from the host spill budget (the
+                    # stage compiler resyncs device_reserved from the
+                    # device-cache residency each run)
+                    from ballista_tpu.ops.tpu import hbm
+
+                    ctx.memory_pool.set_device_capacity(
+                        hbm.resolve_hbm_budget(cfg))
+
+            def before_partition() -> None:
                 if self._is_cancelled(task.job_id, task.stage_id, task.task_id):
                     raise Cancelled(f"task {task.task_id} cancelled")
                 if deadline_at and time.time() > deadline_at:
-                    self.tasks_failed += 1
-                    base.error = (f"task {task.task_id} exceeded its {deadline:.1f}s deadline "
-                                  f"after {time.monotonic() - started:.1f}s")
-                    base.error_kind = "ExecutionError"
-                    base.retryable = True
-                    base.timed_out = True
-                    log.warning("task %s/%s timed out: %s", task.job_id, task.task_id, base.error)
-                    return base
-                ctx = TaskContext(cfg, task_id=f"{task.task_id}", work_dir=self.work_dir)
-                ctx.device_ordinal = self.metadata.device_ordinal
-                ctx.task_attempt = int(getattr(task, "task_attempt", 0))
-                ctx.deadline_at = deadline_at
-                # long-running operators (and chaos stragglers) poll this so
-                # a CancelTasks push preempts mid-partition, not between
-                ctx.cancel_check = (
-                    lambda j=task.job_id, s=task.stage_id, t=task.task_id: self._is_cancelled(j, s, t)
-                )
-                if self.session_pools is not None:
-                    # concurrent tasks of one session share the pool: idle
-                    # tasks lend spill budget to a heavy sort (try_grow)
-                    ctx.memory_pool = self.session_pools.get(task.session_id)
-                    if str(cfg.get(EXECUTOR_ENGINE)) == "tpu":
-                        # attach the device-side ledger: HBM headroom is
-                        # split-accounted from the host spill budget (the
-                        # stage compiler resyncs device_reserved from the
-                        # device-cache residency each run)
-                        from ballista_tpu.ops.tpu import hbm
+                    raise _DeadlineExpired()
 
-                        ctx.memory_pool.set_device_capacity(
-                            hbm.resolve_hbm_budget(cfg))
-                for meta_batch in prepared.execute(p, ctx):
-                    locations.extend(
-                        metadata_to_locations(
-                            meta_batch, task.job_id, task.stage_id, p,
-                            self.metadata.id, self.metadata.host, self.metadata.flight_port,
-                        )
+            # the writer pulls the slice a partition at a time (the checks
+            # above run between partitions) and commits it ONCE: the
+            # locations are the slice's, reported under its first partition
+            locations: list[PartitionLocation] = []
+            for meta_batch in prepared.execute_slice(task.partitions, ctx, before_partition):
+                locations.extend(
+                    metadata_to_locations(
+                        meta_batch, task.job_id, task.stage_id, task.partitions[0],
+                        self.metadata.id, self.metadata.host, self.metadata.flight_port,
                     )
+                )
             base.state = "success"
             base.locations = locations
             base.metrics = [
                 {"depth": d, "name": n, **m} for d, n, m in collect_metrics(prepared)
             ]
             self.tasks_run += 1
+            return base
+        except _DeadlineExpired:
+            self.tasks_failed += 1
+            base.error = (f"task {task.task_id} exceeded its {deadline:.1f}s deadline "
+                          f"after {time.monotonic() - started:.1f}s")
+            base.error_kind = "ExecutionError"
+            base.retryable = True
+            base.timed_out = True
+            log.warning("task %s/%s timed out: %s", task.job_id, task.task_id, base.error)
             return base
         except Cancelled as e:
             base.state = "cancelled"

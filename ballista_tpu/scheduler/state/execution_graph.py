@@ -111,7 +111,8 @@ class ExecutionStage:
         # it (cover / no-overlap / order)
         self.skew_report = None
         self.running: dict[int, RunningTask] = {}
-        # map_partition → locations published by the finished task
+        # map_partition → locations published by the finished task: all of
+        # a slice's under its first partition, [] under the others
         self.completed: dict[int, list[PartitionLocation]] = {}
         self.failure_reasons: set[str] = set()
         self.task_failures = 0
@@ -342,10 +343,19 @@ class ExecutionGraph:
             if state == "success":
                 # FIRST ATTEMPT WINS: a duplicate (speculative) attempt
                 # finishing second must not replace the winner's committed
-                # locations — downstream readers may already hold them
-                fresh = [p for p in partitions if p not in stage.completed]
+                # locations — downstream readers may already hold them.
+                # And it wins for its WHOLE slice or not at all: a task
+                # commits one file set, reported under its first partition
+                # (a hash exchange's ranges hold every partition's rows), so
+                # the carrier is never taken without its companions nor they
+                # without it. A late attempt whose slice only partly overlaps
+                # what is committed is dropped, and what nobody covers of it
+                # goes back to pending.
+                fresh = [] if any(p in stage.completed for p in partitions) else list(partitions)
                 for p in fresh:
                     stage.completed[p] = [l for l in locations if l.map_partition == p]
+                if not fresh:
+                    self._repend_uncovered(stage, partitions)
                 if running is not None:
                     stage.task_durations.append(max(0.0, time.time() - running.launched_at))
                     self._cancel_rival(stage, running)

@@ -12,10 +12,21 @@ ShuffleWriterExec rebuilds the reference's two writers behind one node:
   disk when `ballista.shuffle.sort.memory.limit` is exceeded and are
   merged at finish (2×M files instead of N×M).
 
-execute(map_partition) drives the child and yields ONE metadata batch
-(output_partition, path, rows, bytes, layout) — the same
-results-as-metadata-batches contract the reference uses to report
-ShuffleWritePartition summaries (execution_engine.rs:304).
+execute_slice(map_partitions) drives the child over the task's whole slice
+and yields ONE metadata batch (output_partition, path, rows, bytes, layout)
+— the same results-as-metadata-batches contract the reference uses to
+report ShuffleWritePartition summaries (execution_engine.rs:304).
+
+A task commits ONCE, whatever the length of its slice: the slice is pulled
+a partition at a time and lands in one file set. A hash exchange buckets
+rows by key across the slice (range k = output partition k's rows from
+every map partition of the slice, small batches merged up to the session's
+batch size); a passthrough keeps partition identity
+(range p = map partition p's rows, streamed as they are pulled). A slice of
+one map partition writes what a map task always wrote — same layout, paths
+and bytes — so the length of the slice is all that decides. The locations
+of a longer slice are reported under its FIRST map partition
+(docs/tpu_engine.md#how-a-device-stage-is-tasked).
 
 On-device partitioning: when the child pipeline ran on the TPU engine the
 hash is computed with the jax twin of ops/hashing.py; host and device
@@ -24,6 +35,7 @@ partitions are bit-identical so readers never care who wrote a file.
 
 from __future__ import annotations
 
+import contextlib
 import io
 import os
 import json
@@ -73,6 +85,25 @@ def _unlink_quiet(*ps: str) -> None:
             pass
 
 
+@contextlib.contextmanager
+def _sweeping(what: str, *leftovers: str):
+    """A write that must leave nothing behind when it fails: an attempt
+    killed on the way (cancel, deadline, crash, a full disk) removes its
+    `.tmp` and spill files — they will never be renamed — and a full disk
+    comes out typed (`DiskExhausted`) with `what` it was writing."""
+    try:
+        yield
+    except OSError as e:
+        _unlink_quiet(*leftovers)
+        typed = disk.wrap_enospc(e, what)
+        if typed is not None:
+            raise typed from e
+        raise
+    except BaseException:
+        _unlink_quiet(*leftovers)
+        raise
+
+
 def _checksum_on(ctx: TaskContext) -> bool:
     return bool(ctx.config.get(SHUFFLE_CHECKSUM_ENABLED))
 
@@ -91,6 +122,44 @@ def _write_crc_sidecar(data_path: str, digest: str | None) -> None:
         _unlink_quiet(cp + ".tmp")
         raise
     os.replace(cp + ".tmp", cp)
+
+
+def _merge_small(batches: list[pa.RecordBatch], target_rows: int) -> list[pa.RecordBatch]:
+    """Neighbouring batches merged up to `target_rows` rows each (the
+    session's batch size): same rows, same order, fewer IPC messages. A
+    batch that is large already is passed on untouched, never copied."""
+    out: list[pa.RecordBatch] = []
+    run: list[pa.RecordBatch] = []
+    rows = 0
+    for b in batches + [None]:
+        if run and (b is None or rows + b.num_rows > target_rows):
+            out.append(run[0] if len(run) == 1 else pa.concat_batches(run))
+            run, rows = [], 0
+        if b is not None:
+            run.append(b)
+            rows += b.num_rows
+    return out
+
+
+def _index_entry(start: int, length: int, rows: int, crc: str | None) -> list:
+    """One range of a sort-layout index: [offset, length, rows, bytes] + the
+    range's checksum string where checksums are on (shuffle/paths.py)."""
+    entry: list = [start, length, rows, length]
+    if crc:
+        entry.append(crc)
+    return entry
+
+
+def _commit_data_and_index(data_path: str, index: dict[str, list], what: str) -> None:
+    """Commit a whole `.tmp` data file and its index under their final
+    names, data BEFORE index: a reader that finds the index finds every
+    range it names."""
+    idx_path = paths.index_path(data_path)
+    with _sweeping(what, data_path + ".tmp", idx_path + ".tmp"):
+        os.replace(data_path + ".tmp", data_path)
+        with open(idx_path + ".tmp", "w") as f:
+            json.dump(index, f)
+        os.replace(idx_path + ".tmp", idx_path)
 
 
 def _codec(ctx: TaskContext) -> Optional[str]:
@@ -149,59 +218,107 @@ class ShuffleWriterExec(ExecutionPlan):
         )
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
-        # the whole partition write: the stage's operators are pulled through
+        return self.execute_slice([partition], ctx)
+
+    def execute_slice(self, partitions: list[int], ctx: TaskContext,
+                      before_partition=None) -> Iterator[pa.RecordBatch]:
+        """Write the map partitions one task holds and commit them once.
+        `before_partition` is called ahead of every partition's pull (the
+        task runner's cancel and deadline checks); what it raises aborts
+        the write like any other error — nothing under a final name, no
+        `.tmp` left."""
+        partitions = list(partitions)
+        if not partitions:
+            return iter(())
+        # the whole slice's write: the stage's operators are pulled through
         # it, so a device stage's spans (and the commit) nest inside and its
         # self time is the pull, the partitioning and operators with no span
-        with RUN_STATS.span("bt.shuffle.write") as span:
-            meta = self._write(partition, ctx)
+        with RUN_STATS.span("bt.shuffle.write", map_partitions=len(partitions)) as span:
+            if not ctx.work_dir:
+                raise ExecutionError("shuffle writer needs a work_dir in TaskContext")
+            task_id = ctx.task_id or f"{partitions[0]}-{uuid.uuid4().hex[:6]}"
+            write = self._write_passthrough if self.output_partitions <= 0 else self._write_exchange
+            meta = write(partitions, task_id, ctx, before_partition or (lambda: None))
             span.set(rows=sum(meta.column("num_rows").to_pylist()),
                      bytes=sum(meta.column("num_bytes").to_pylist()))
         return self._timed(iter([meta]))
 
     # ------------------------------------------------------------------
 
-    def _write(self, map_partition: int, ctx: TaskContext) -> pa.RecordBatch:
-        if not ctx.work_dir:
-            raise ExecutionError("shuffle writer needs a work_dir in TaskContext")
-        task_id = ctx.task_id or f"{map_partition}-{uuid.uuid4().hex[:6]}"
+    def _commit_span(self, partitions: list[int]):
+        """One a task: its numbers say what the commit put on disk."""
+        return RUN_STATS.span("bt.shuffle.commit", map_partitions=len(partitions))
+
+    @staticmethod
+    def _set_commit(span, meta: pa.RecordBatch, files: int) -> None:
+        span.set(ranges=meta.num_rows, files=files,
+                 bytes=sum(meta.column("num_bytes").to_pylist()))
+
+    def _write_passthrough(self, partitions: list[int], task_id, ctx: TaskContext,
+                           before_partition) -> pa.RecordBatch:
+        """Stage collapse / preserved partitioning: partition identity is
+        the contract (a consumer may merge sorted partitions or join
+        co-partitioned ones), so every map partition keeps a range of its
+        own and its batches go to the sink as they are pulled.
+
+        One map partition: the hash layout's file under the partition's
+        directory + its `.crc` sidecar. A longer slice: ONE data file of
+        the sort layout, range p = map partition p, + ONE index.
+
+        tmp + atomic rename: a task killed mid-write (deadline, cancel,
+        crash) must never leave a truncated file under the final name."""
+        first = partitions[0]
+        one = len(partitions) == 1
         schema = self.input.schema()
-
-        if self.output_partitions <= 0:
-            # passthrough: stage collapse / preserved partitioning.
-            # tmp + atomic rename: a task killed mid-write (deadline, cancel,
-            # crash) must never leave a truncated file under the final name
-            path = paths.hash_data_path(ctx.work_dir, self.job_id, self.stage_id, map_partition, task_id)
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            maybe_disk_full(ctx.config, self.job_id, self.stage_id, map_partition,
-                            ctx.task_attempt, "shuffle passthrough write")
-            try:
-                with open(path + ".tmp", "wb") as f:
-                    sink = ChecksumSink(f, enabled=_checksum_on(ctx))
-                    rows = 0
-                    batches = 0
-                    with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
-                        for b in self.input.execute(map_partition, ctx):
-                            if b.num_rows:
-                                w.write_batch(b)
-                                rows += b.num_rows
-                                batches += 1
-                    nbytes = f.tell()
-            except OSError as e:
-                _unlink_quiet(path + ".tmp")
-                typed = disk.wrap_enospc(e, f"shuffle write {self.job_id}/{self.stage_id}/{map_partition}")
-                if typed is not None:
-                    raise typed from e
-                raise
-            except BaseException:
-                # an attempt killed mid-write (cancel, deadline, crash) must
-                # not leave its .tmp around — it will never be renamed
-                _unlink_quiet(path + ".tmp")
-                raise
-            with RUN_STATS.span("bt.shuffle.commit"):
-                _write_crc_sidecar(path, sink.digest())
+        if one:
+            path = paths.hash_data_path(ctx.work_dir, self.job_id, self.stage_id, first, task_id)
+        else:
+            path = paths.sort_data_path(ctx.work_dir, self.job_id, self.stage_id, first, task_id)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        maybe_disk_full(ctx.config, self.job_id, self.stage_id, first,
+                        ctx.task_attempt, "shuffle passthrough write")
+        index: dict[str, list] = {}
+        out = []
+        what = f"shuffle write {self.job_id}/{self.stage_id}/{first}"
+        with _sweeping(what, path + ".tmp"), open(path + ".tmp", "wb") as f:
+            sink = ChecksumSink(f, enabled=_checksum_on(ctx))
+            for p in partitions:
+                before_partition()
+                start = f.tell()
+                rows = 0
+                batches = 0
+                # per-RANGE checksum: a partition's byte range is the
+                # unit readers fetch and verify (one partition: the file)
+                sink.start_range()
+                with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
+                    for b in self.input.execute(p, ctx):
+                        if b.num_rows:
+                            w.write_batch(b)
+                            rows += b.num_rows
+                            batches += 1
+                length = f.tell() - start
+                index[str(p)] = _index_entry(start, length, rows, sink.digest())
+                out.append((p, path, rows, batches, length, "hash" if one else "sort"))
+        meta = self._meta(out)
+        with self._commit_span(partitions) as span:
+            if one:
+                digest = sink.digest()
+                _write_crc_sidecar(path, digest)
                 os.replace(path + ".tmp", path)
-            return self._meta([(map_partition, path, rows, batches, nbytes, "hash")])
+                self._set_commit(span, meta, 2 if digest else 1)
+            else:
+                _commit_data_and_index(path, index, what)
+                self._set_commit(span, meta, 2)
+        return meta
 
+    def _write_exchange(self, partitions: list[int], task_id, ctx: TaskContext,
+                        before_partition) -> pa.RecordBatch:
+        """Hash exchange: rows are bucketed by key across the WHOLE slice —
+        bucket k holds output partition k's rows from every map partition
+        the task holds — and drained once. The memory limit, the session
+        pool and the spills work over the slice's buckets."""
+        first = partitions[0]
+        schema = self.input.schema()
         bound = [bind_expr(k, self.input.df_schema) for k in self.keys]
         K = self.output_partitions
         buckets: list[list[pa.RecordBatch]] = [[] for _ in range(K)]
@@ -229,19 +346,13 @@ class ShuffleWriterExec(ExecutionPlan):
             k = max(range(K), key=lambda i: sum(b.nbytes for b in buckets[i]))
             if not buckets[k]:
                 return False
-            maybe_disk_full(ctx.config, self.job_id, self.stage_id, map_partition,
+            maybe_disk_full(ctx.config, self.job_id, self.stage_id, first,
                             ctx.task_attempt, "sort-shuffle spill")
-            sp = paths.sort_data_path(ctx.work_dir, self.job_id, self.stage_id, map_partition, task_id) + f".spill{len(spills[k])}.{k}"
+            sp = paths.sort_data_path(ctx.work_dir, self.job_id, self.stage_id, first, task_id) + f".spill{len(spills[k])}.{k}"
             os.makedirs(os.path.dirname(sp), exist_ok=True)
-            try:
-                with open(sp, "wb") as f:
-                    _, sp_bytes = write_ipc_stream(buckets[k], schema, f, ctx)
-            except OSError as e:
-                _unlink_quiet(sp)
-                typed = disk.wrap_enospc(e, f"sort-shuffle spill {self.job_id}/{self.stage_id}/{map_partition}")
-                if typed is not None:
-                    raise typed from e
-                raise
+            what = f"sort-shuffle spill {self.job_id}/{self.stage_id}/{first}"
+            with _sweeping(what, sp), open(sp, "wb") as f:
+                _, sp_bytes = write_ipc_stream(buckets[k], schema, f, ctx)
             spills[k].append(sp)
             freed = sum(b.nbytes for b in buckets[k])
             buffered -= freed
@@ -284,47 +395,62 @@ class ShuffleWriterExec(ExecutionPlan):
 
         skew = skew_params(ctx.config)
         try:
-            for b in self.input.execute(map_partition, ctx):
-                if b.num_rows == 0:
-                    continue
-                pids = None
-                if getattr(self, "device_routed", False) and "__pid" in b.schema.names:
-                    if skew is not None and bound:
-                        # chaos skew reroutes by the row's KEY HASH, but the
-                        # device only shipped final partition ids. Recompute
-                        # the keys on the host (the jax hash is a bit-exact
-                        # twin) so every writer of this exchange — host- or
-                        # device-hashed — remaps the same rows.
-                        key_arrays = [evaluate_to_array(kb, b) for kb in bound]
-                        b = b.select([n for n in b.schema.names if n != "__pid"])
+            for p in partitions:
+                before_partition()
+                for b in self.input.execute(p, ctx):
+                    if b.num_rows == 0:
+                        continue
+                    pids = None
+                    if getattr(self, "device_routed", False) and "__pid" in b.schema.names:
+                        if skew is not None and bound:
+                            # chaos skew reroutes by the row's KEY HASH, but the
+                            # device only shipped final partition ids. Recompute
+                            # the keys on the host (the jax hash is a bit-exact
+                            # twin) so every writer of this exchange — host- or
+                            # device-hashed — remaps the same rows.
+                            key_arrays = [evaluate_to_array(kb, b) for kb in bound]
+                            b = b.select([n for n in b.schema.names if n != "__pid"])
+                        else:
+                            # device-side routing: the TPU stage already hashed
+                            # rows to partitions (bit-exact twin); consume and
+                            # drop the column. Gated on the engine-set flag so a
+                            # user column named __pid is never misinterpreted.
+                            i = b.schema.get_field_index("__pid")
+                            pids = b.column(i).to_numpy(zero_copy_only=False).astype(np.uint64)
+                            b = b.select([n for n in b.schema.names if n != "__pid"])
+                            key_arrays = []
                     else:
-                        # device-side routing: the TPU stage already hashed
-                        # rows to partitions (bit-exact twin); consume and
-                        # drop the column. Gated on the engine-set flag so a
-                        # user column named __pid is never misinterpreted.
-                        i = b.schema.get_field_index("__pid")
-                        pids = b.column(i).to_numpy(zero_copy_only=False).astype(np.uint64)
-                        b = b.select([n for n in b.schema.names if n != "__pid"])
-                        key_arrays = []
-                else:
-                    key_arrays = [evaluate_to_array(kb, b) for kb in bound]
-                if skew is not None and key_arrays:
-                    pids = skew_remap_pids(hash_arrays(key_arrays), K, *skew)
-                for k, part in split_batch_by_partition(b, key_arrays, K, precomputed_pids=pids):
-                    reserve(part.nbytes)
-                    buckets[k].append(part)
-                    bucket_rows[k] += part.num_rows
-                    bucket_batches[k] += 1
-                    buffered += part.nbytes
-                while limit and buffered > limit:
-                    if not spill_largest():
-                        break
+                        key_arrays = [evaluate_to_array(kb, b) for kb in bound]
+                    if skew is not None and key_arrays:
+                        pids = skew_remap_pids(hash_arrays(key_arrays), K, *skew)
+                    for k, part in split_batch_by_partition(b, key_arrays, K, precomputed_pids=pids):
+                        reserve(part.nbytes)
+                        buckets[k].append(part)
+                        bucket_rows[k] += part.num_rows
+                        bucket_batches[k] += 1
+                        buffered += part.nbytes
+                    while limit and buffered > limit:
+                        if not spill_largest():
+                            break
 
             # the buckets drained to their files, checksummed and renamed
-            with RUN_STATS.span("bt.shuffle.commit"):
+            with self._commit_span(partitions) as span:
+                if len(partitions) > 1:
+                    # a bucket of a slice holds a batch a map partition it
+                    # pulled: drained as they are, a range would cost its
+                    # partitions' count to write and to read, not its rows
+                    for k in range(K):
+                        merged = _merge_small(buckets[k], ctx.batch_size)
+                        bucket_batches[k] -= len(buckets[k]) - len(merged)
+                        buckets[k] = merged
                 if self.sort_shuffle:
-                    return self._finish_sort(map_partition, task_id, schema, buckets, spills, bucket_rows, bucket_batches, ctx)
-                return self._finish_hash(map_partition, task_id, schema, buckets, bucket_rows, bucket_batches, ctx)
+                    meta, files = self._finish_sort(first, task_id, schema, buckets, spills,
+                                                    bucket_rows, bucket_batches, ctx)
+                else:
+                    meta, files = self._finish_hash(first, task_id, schema, buckets,
+                                                    bucket_rows, bucket_batches, ctx)
+                self._set_commit(span, meta, files)
+            return meta
         except BaseException:
             # consolidation removes spills as it streams them; an aborted
             # attempt has to sweep up whatever it spilled itself
@@ -343,36 +469,28 @@ class ShuffleWriterExec(ExecutionPlan):
 
         live = [k for k in range(len(buckets)) if rows[k]]
         if not live:
-            return self._meta([])
+            return self._meta([]), 0
         maybe_disk_full(ctx.config, self.job_id, self.stage_id, map_partition,
                         ctx.task_attempt, "hash-shuffle commit")
 
         def drain(k: int):
             path = paths.hash_data_path(ctx.work_dir, self.job_id, self.stage_id, k, task_id)
             os.makedirs(os.path.dirname(path), exist_ok=True)
-            try:
-                with open(path + ".tmp", "wb") as f:
-                    sink = ChecksumSink(f, enabled=_checksum_on(ctx))
-                    _, nbytes = write_ipc_stream(buckets[k], schema, sink, ctx)
-            except OSError as e:
-                _unlink_quiet(path + ".tmp")
-                typed = disk.wrap_enospc(e, f"shuffle write {self.job_id}/{self.stage_id}/{k}")
-                if typed is not None:
-                    raise typed from e
-                raise
-            except BaseException:
-                _unlink_quiet(path + ".tmp")
-                raise
+            what = f"shuffle write {self.job_id}/{self.stage_id}/{k}"
+            with _sweeping(what, path + ".tmp"), open(path + ".tmp", "wb") as f:
+                sink = ChecksumSink(f, enabled=_checksum_on(ctx))
+                _, nbytes = write_ipc_stream(buckets[k], schema, sink, ctx)
             _write_crc_sidecar(path, sink.digest())
             os.replace(path + ".tmp", path)
             return (k, path, rows[k], batches[k], nbytes, "hash")
 
+        files = len(live) * (2 if _checksum_on(ctx) else 1)
         if len(live) == 1:
-            return self._meta([drain(live[0])])
+            return self._meta([drain(live[0])]), files
         with fut.ThreadPoolExecutor(max_workers=min(len(live), 8),
                                     thread_name_prefix="shuffle-drain") as pool:
             out = list(pool.map(drain, live))
-        return self._meta(out)
+        return self._meta(out), files
 
     @staticmethod
     def _iter_bucket_batches(in_memory: list, spill_files: list[str]):
@@ -392,53 +510,37 @@ class ShuffleWriterExec(ExecutionPlan):
 
         The data file name is attempt-unique (task_id baked in) and both
         files commit via tmp + atomic rename, data BEFORE index: duplicate
-        attempts of the same map partition (speculation) each produce a
-        complete private file set, and whichever status reaches the
-        scheduler first decides which set readers ever see."""
+        attempts of the same slice (speculation) each produce a complete
+        private file set, and whichever status reaches the scheduler first
+        decides which set readers ever see. `map_partition` names the file:
+        the slice's first."""
         data_path = paths.sort_data_path(ctx.work_dir, self.job_id, self.stage_id, map_partition, task_id)
         os.makedirs(os.path.dirname(data_path), exist_ok=True)
         maybe_disk_full(ctx.config, self.job_id, self.stage_id, map_partition,
                         ctx.task_attempt, "sort-shuffle commit")
         index: dict[str, list] = {}
         out = []
-        idx_path = paths.index_path(data_path)
-        try:
-            with open(data_path + ".tmp", "wb") as f:
-                sink = ChecksumSink(f, enabled=_checksum_on(ctx))
-                for k in range(len(buckets)):
-                    if not rows[k]:
-                        continue
-                    start = f.tell()
-                    nrows = 0
-                    # per-RANGE checksum: each bucket's byte range is the unit
-                    # readers fetch and verify, so the digest resets here
-                    sink.start_range()
-                    with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
-                        for b in self._iter_bucket_batches(buckets[k], spills[k]):
-                            if b.num_rows:
-                                w.write_batch(b)
-                                nrows += b.num_rows
-                    length = f.tell() - start
-                    crc = sink.digest()
-                    entry: list = [start, length, nrows, length]
-                    if crc:
-                        entry.append(crc)
-                    index[str(k)] = entry
-                    out.append((k, data_path, nrows, batches[k], length, "sort"))
-            os.replace(data_path + ".tmp", data_path)
-            with open(idx_path + ".tmp", "w") as f:
-                json.dump(index, f)
-        except OSError as e:
-            _unlink_quiet(data_path + ".tmp", idx_path + ".tmp")
-            typed = disk.wrap_enospc(e, f"sort-shuffle commit {self.job_id}/{self.stage_id}/{map_partition}")
-            if typed is not None:
-                raise typed from e
-            raise
-        except BaseException:
-            _unlink_quiet(data_path + ".tmp", idx_path + ".tmp")
-            raise
-        os.replace(idx_path + ".tmp", idx_path)
-        return self._meta(out)
+        what = f"sort-shuffle commit {self.job_id}/{self.stage_id}/{map_partition}"
+        with _sweeping(what, data_path + ".tmp"), open(data_path + ".tmp", "wb") as f:
+            sink = ChecksumSink(f, enabled=_checksum_on(ctx))
+            for k in range(len(buckets)):
+                if not rows[k]:
+                    continue
+                start = f.tell()
+                nrows = 0
+                # per-RANGE checksum: each bucket's byte range is the unit
+                # readers fetch and verify, so the digest resets here
+                sink.start_range()
+                with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
+                    for b in self._iter_bucket_batches(buckets[k], spills[k]):
+                        if b.num_rows:
+                            w.write_batch(b)
+                            nrows += b.num_rows
+                length = f.tell() - start
+                index[str(k)] = _index_entry(start, length, nrows, sink.digest())
+                out.append((k, data_path, nrows, batches[k], length, "sort"))
+        _commit_data_and_index(data_path, index, what)
+        return self._meta(out), 2
 
     def _meta(self, rows: list[tuple]) -> pa.RecordBatch:
         schema = self.schema()

@@ -375,13 +375,27 @@ def test_the_served_path_dispatches_a_device_stage_once_an_executor(q, served, c
     others = [t for t in by_name(spans, "bt.task.run")
               if t[ID] not in {task_of(d)[ID] for d in dispatches}]
     assert others and {t[NUMBERS]["partitions"] for t in others} == {1}
+    # a task commits its shuffle output once: one commit a task, and the
+    # device stage's holds its whole slice in one data file + one index
+    commits = by_name(spans, "bt.shuffle.commit")
+    assert len(commits) == len(by_name(spans, "bt.task.run"))
+    device_tasks = {task_of(d)[ID] for d in dispatches}
+    sliced = [c[NUMBERS] for c in commits if task_of(c)[ID] in device_tasks]
+    assert [c["map_partitions"] for c in sliced] == [8 // executors] * executors
+    assert all(c["files"] == 2 for c in sliced)
+    # ... and its consumers open at most a location an output partition of a
+    # slice (a passthrough: a location a map partition, as before)
+    out_parts = max(c["ranges"] for c in sliced)
+    opened = [r[NUMBERS]["partitions"] for r in by_name(spans, "bt.shuffle.read")]
+    assert max(opened) <= max(out_parts, 8 // executors) * executors
 
 
 # -- (c) the fallback contract of a slice under emit_pid ----------------------
 
 
 def _map_outputs(work, job, stage):
-    """map partition → every row it wrote, over all its reduce buckets."""
+    """first map partition of a slice → every row the slice's one commit
+    wrote, over all its reduce buckets."""
     out = {}
     for f in glob.glob(f"{work}/{job}/{stage}/*.arrow"):
         from ballista_tpu.shuffle import paths as sp
@@ -443,16 +457,19 @@ def test_a_demoted_slice_keeps_the_emit_pid_layout(demoted, tmp_path, monkeypatc
             if name in down:
                 m.setattr(sc.TpuStageExec, "_dispatch_all", boom)
             tc = TaskContext(cfg, task_id=name, work_dir=work)
-            for p in parts:
-                list(compiled.execute(p, tc))
+            list(compiled.execute_slice(parts, tc))
     for name, st in stages.items():
         # the instance decided once, for every partition of its slice
         assert (st.tpu_count, st.fallback_count) == (
             (0, len(slices[name])) if name in down else (1, 0))
 
+    # one data file + one index a slice, named after its first partition
     outs = _map_outputs(work, "jfb", 1)
-    assert sorted(outs) == list(range(P))
-    assert all(outs[p] is None or outs[p].num_rows == 0 for p in range(1, P))
+    assert sorted(outs) == [0, P // 2]
+    assert sorted(os.listdir(f"{work}/jfb/1")) == sorted(
+        f"data-{parts[0]}-{name}.{ext}" for name, parts in slices.items()
+        for ext in ("arrow", "idx"))
+    assert outs[P // 2] is None or outs[P // 2].num_rows == 0
     got = outs[0].group_by("k").aggregate(
         [("__acc0", "sum"), ("__acc1", "sum"), ("__acc1", "count")])
     assert pc.max(got["__acc1_count"]).as_py() == 1  # no group twice
