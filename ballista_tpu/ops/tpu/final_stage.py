@@ -49,6 +49,7 @@ from ballista_tpu.ops.tpu.kernels import (
     int_cumsum,
     lex_order,
     lower_expr,
+    segmented_scan,
     true_mask,
 )
 from ballista_tpu.ops.tpu.runtime import ensure_jax
@@ -635,7 +636,7 @@ class TpuFinalStageExec(ExecutionPlan):
 
     def _compile(self, kinds, scales, dicts, valids_np, cols_np, P: int, N: int,
                  merge_all: bool = False):
-        from ballista_tpu.ops.tpu.stage_compiler import _bind_env, _pow2, _segscan
+        from ballista_tpu.ops.tpu.stage_compiler import _bind_env, _pow2
 
         jax = ensure_jax()
         jnp = jax.numpy
@@ -839,9 +840,9 @@ class TpuFinalStageExec(ExecutionPlan):
                     if fname == "sum" and jnp.issubdtype(sv.dtype, jnp.integer):
                         accs.append(int_segsum(sv))
                     elif fname == "sum":
-                        accs.append(compact(_segscan(jnp, sv, boundary, "sum")))
+                        accs.append(compact(segmented_scan(sv, boundary, "sum")))
                     else:
-                        out = compact(_segscan(jnp, sv, boundary, fname))
+                        out = compact(segmented_scan(sv, boundary, fname))
                         if kinds[src] in ("i64", "money") and out.dtype != jnp.int64:
                             out = out.astype(jnp.int64)
                         accs.append(out)

@@ -13,9 +13,10 @@ split of labor is the parity contract:
   bytes are the CPU engine's bytes by construction.
 - The DEVICE computes only the permutation (and, for windows, the
   segmented scans): `kernels.lex_order` (stable LSD radix passes over the
-  keys' 32-bit lanes) and an associative segmented scan, both jitted at
-  power-of-two lane counts so one compilation serves every partition of
-  a bucket. ORDER BY ... LIMIT is the full order, sliced. An ineligible
+  keys' 32-bit lanes) and `kernels.segmented_scan` (blocked: seconds to
+  compile at 2^24 lanes), both jitted at power-of-two lane counts so one
+  compilation serves every partition of a bucket. Key lanes are uploaded
+  every query; nothing of the family stays resident. ORDER BY ... LIMIT is the full order, sliced. An ineligible
   shape raises Unsupported and the operator falls back to the CPU oracle
   over the SAME materialized input (never re-executing the child).
 
@@ -46,9 +47,11 @@ reimplementations.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import threading
+import zlib
 from typing import Iterator, Optional
 
 import numpy as np
@@ -131,6 +134,20 @@ def _note_kernel_s(dt: float) -> None:
 def counters_snapshot() -> dict:
     with _CTR_LOCK:
         return dict(_COUNTERS, sort_kernel_s=round(_KERNEL_S[0], 4))
+
+
+@contextlib.contextmanager
+def _dispatch(family: str, exprs: list, partition: int, ctx: TaskContext):
+    """One task's device attempt: the `bt.stage.dispatch` span, and a RunStats
+    record of its own (`<family>_<crc of the stage's expressions>_p<partition>`:
+    a sort / window stage is a task a partition, so a record a task, each
+    with `dispatches` 1) holding what the attempt sets and, if it ends, its
+    seconds as `exec_s`."""
+    tag = f"{family}_{zlib.crc32(', '.join(map(str, exprs)).encode()):08x}_p{partition}"
+    with device_scope(ctx.device_ordinal), RUN_STATS.run(tag), \
+            RUN_STATS.span("bt.stage.dispatch", family=family) as span:
+        yield
+        RUN_STATS.set("exec_s", round(span.seconds, 6))
 
 
 # ---------------------------------------------------------------------------
@@ -239,12 +256,40 @@ def _admit(est, config: BallistaConfig) -> None:
         raise Unsupported(f"hbm admission: {plan.reason}")
 
 
+# analysis: ignore[bounded-cache] one entry a program and shape jax has compiled in this process: lanes are powers of two (under 40), six kernels, a few dtype lists
+_CALLED: set = set()  # (kernel, lanes, operand dtypes) dispatched once in this process
+
+
+@contextlib.contextmanager
+def _device_call(kernel: str, n: int, lanes: int, operands: list):
+    """The span of one synced device call of the family — pad, upload, the
+    jitted program, the result's fetch: the host blocked on the device.
+    `bt.device.exec`; the first call of a program at a shape compiles it (or
+    loads the persistent cache's binary) inside, and is named
+    `bt.compile.xla` as a stage's is, its seconds added to the task's
+    `xla_compile_s`."""
+    key = (kernel, lanes, tuple(str(a.dtype) for a in operands))
+    with _CTR_LOCK:
+        first = key not in _CALLED
+        _CALLED.add(key)
+    with RUN_STATS.span("bt.compile.xla" if first else "bt.device.exec", rows=n,
+                        kernel=kernel, lanes=lanes) as span:
+        yield span
+    _note_kernel_s(span.seconds)
+    if first:
+        rec = RUN_STATS.current() or {}
+        RUN_STATS.set("xla_compile_s", round(rec.get("xla_compile_s", 0.0) + span.seconds, 6))
+
+
 class _Uploads:
     """Tracks actual device bytes of every operand shipped for a stage, so
     the fill test can assert estimate >= actual (RUN_STATS device_bytes)."""
 
     def __init__(self):
         self.bytes = 0
+        self.lanes = 0  # padded lanes ordered (`window_lanes`)
+        self.scans = 0  # segmented scans dispatched (`window_scans`)
+        self.segments = 0  # window partitions found (`window_segments`)
 
     def put(self, arr: np.ndarray):
         jax = ensure_jax()
@@ -253,7 +298,9 @@ class _Uploads:
 
 
 def _perm_full(key_ops: list, n: int, up: _Uploads) -> np.ndarray:
-    """Full ordering permutation of n rows by the encoded key operands."""
+    """Full ordering permutation of n rows by the encoded key operands:
+    pad, upload, `kernels.lex_order` and the permutation's fetch, the host
+    blocked on the device throughout (one `bt.device.exec`)."""
     jax = ensure_jax()
     # the stable lexicographic order over every operand, at a power-of-two
     # lane count: max-value sentinels pad the tail and, the order being
@@ -261,16 +308,21 @@ def _perm_full(key_ops: list, n: int, up: _Uploads) -> np.ndarray:
     # are the real rows in order. Lanes whose values fit ship as int32 (one
     # radix pass instead of two).
     L = _pow2(n)
-    flat: list = []
+    operands: list = []
     for nrank, lane in key_ops:
         if nrank is not None:
-            flat.append(up.put(_pad_max(nrank.astype(np.int32), L)))
+            operands.append(nrank.astype(np.int32))
         if len(lane) and _I32_MIN <= lane.min() and lane.max() <= _I32_MAX:
             lane = lane.astype(np.int32)
-        flat.append(up.put(_pad_max(lane, L)))
-    up.bytes += L * 4
-    perm = _lex_order_jit()(*flat)
-    return np.asarray(jax.device_get(perm))[:n]
+        operands.append(lane)
+    before = up.bytes
+    with _device_call("lex_order", n, L, operands) as span:
+        flat = [up.put(_pad_max(lane, L)) for lane in operands]
+        up.bytes += L * 4
+        perm = np.asarray(jax.device_get(_lex_order_jit()(*flat)))[:n]
+        span.set(bytes=up.bytes - before)
+    up.lanes += L
+    return perm
 
 
 def _pad_max(a: np.ndarray, L: int) -> np.ndarray:
@@ -294,15 +346,13 @@ def _lex_order_jit():
 
 @functools.lru_cache(maxsize=8)
 def _segscan_jit(func: str):
-    from ballista_tpu.ops.tpu.stage_compiler import _segscan
-
-    jax = ensure_jax()
+    from ballista_tpu.ops.tpu.kernels import segmented_scan
 
     def window_segscan(v, b):
-        return _segscan(jax.numpy, v, b, func)
+        return segmented_scan(v, b, func)
 
     window_segscan.__name__ = window_segscan.__qualname__ = f"window_segscan_{func}"
-    return jax.jit(window_segscan)
+    return ensure_jax().jit(window_segscan)
 
 
 def _pow2(n: int) -> int:
@@ -386,14 +436,11 @@ def _device_sort(tbl: pa.Table, df_schema: DFSchema, keys: list,
     _admit(fusion.estimate_sort_stage(n, key_meta), config)
 
     up = _Uploads()
-    # upload, kernel and the permutation's fetch: the host blocked on the device
-    with RUN_STATS.span("bt.device.exec", rows=n) as span:
-        perm = _perm_full(key_ops, n, up)
-        _count("sort_invocations")
-        if fetch is not None:
-            # ORDER BY ... LIMIT orders every row, then slices
-            _count("sort_full_materializations")
-    _note_kernel_s(span.seconds)
+    perm = _perm_full(key_ops, n, up)
+    _count("sort_invocations")
+    if fetch is not None:
+        # ORDER BY ... LIMIT orders every row, then slices
+        _count("sort_full_materializations")
     RUN_STATS.set("device_bytes", up.bytes)
 
     out = tbl.take(pa.array(perm))
@@ -442,8 +489,7 @@ class TpuSortStageExec(ExecutionPlan):
         batches = [b for b in self.input.execute(partition, ctx) if b.num_rows]
         tbl = _concat(batches, self.schema())
         try:
-            with device_scope(ctx.device_ordinal), \
-                    RUN_STATS.span("bt.stage.dispatch", family="sort"):
+            with _dispatch("sort", self.keys, partition, ctx):
                 out = _device_sort(tbl, self.df_schema, self.keys, self.fetch,
                                    self.config)
             if tbl.num_rows:  # an empty partition dispatches nothing
@@ -484,55 +530,64 @@ def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
     from ballista_tpu.ops.cpu.window import _Frame, _changes, _first_only
     from ballista_tpu.ops.tpu import fusion
     n = batch.num_rows
-    part_arrays = [evaluate_to_array(bind_expr(e, schema), batch)
-                   for e in w.partition_by]
-    order_arrays = [evaluate_to_array(bind_expr(k.expr, schema), batch)
-                    for k in w.order_by]
-    arrays = part_arrays + order_arrays
-    orders = [(True, False)] * len(part_arrays) + [
-        (k.ascending, k.nulls_first) for k in w.order_by
-    ]
-    key_ops, key_meta = _encode_key_arrays(arrays, orders)
+    with RUN_STATS.span("bt.window.keys", rows=n) as span:
+        part_arrays = [evaluate_to_array(bind_expr(e, schema), batch)
+                       for e in w.partition_by]
+        order_arrays = [evaluate_to_array(bind_expr(k.expr, schema), batch)
+                        for k in w.order_by]
+        arrays = part_arrays + order_arrays
+        orders = [(True, False)] * len(part_arrays) + [
+            (k.ascending, k.nulls_first) for k in w.order_by
+        ]
+        key_ops, key_meta = _encode_key_arrays(arrays, orders)
+        span.set(key_lanes=sum(1 + (nrank is not None) for nrank, _ in key_ops))
     _admit(fusion.estimate_sort_stage(n, key_meta or [("i64", False)],
                                       window_funcs=max(window_funcs, 1)),
            config)
 
-    with RUN_STATS.span("bt.device.exec", rows=n) as span:
-        if key_ops:
-            idx = _perm_full(key_ops, n, up).astype(np.int64)
-        else:
-            idx = np.arange(n, dtype=np.int64)
-    _note_kernel_s(span.seconds)
+    if key_ops:
+        idx = _perm_full(key_ops, n, up).astype(np.int64)
+    else:
+        idx = np.arange(n, dtype=np.int64)
 
-    inv = np.empty(n, dtype=np.int64)
-    inv[idx] = np.arange(n, dtype=np.int64)
-    new_part = _changes(part_arrays, idx) if part_arrays else _first_only(n)
-    new_peer = new_part | (_changes(order_arrays, idx) if order_arrays
-                           else np.zeros(n, bool))
-    arange = np.arange(n, dtype=np.int64)
-    seg_start = np.maximum.accumulate(np.where(new_part, arange, 0))
-    starts = np.flatnonzero(new_part)
-    ends = np.r_[starts[1:] - 1, n - 1] if len(starts) else np.array([], np.int64)
-    counts = ends - starts + 1 if len(starts) else np.array([], np.int64)
-    seg_end = np.repeat(ends, counts) if len(starts) else np.zeros(n, np.int64)
+    with RUN_STATS.span("bt.window.emit", rows=n) as span:
+        inv = np.empty(n, dtype=np.int64)
+        inv[idx] = np.arange(n, dtype=np.int64)
+        new_part = _changes(part_arrays, idx) if part_arrays else _first_only(n)
+        new_peer = new_part | (_changes(order_arrays, idx) if order_arrays
+                               else np.zeros(n, bool))
+        arange = np.arange(n, dtype=np.int64)
+        seg_start = np.maximum.accumulate(np.where(new_part, arange, 0))
+        starts = np.flatnonzero(new_part)
+        ends = np.r_[starts[1:] - 1, n - 1] if len(starts) else np.array([], np.int64)
+        counts = ends - starts + 1 if len(starts) else np.array([], np.int64)
+        seg_end = np.repeat(ends, counts) if len(starts) else np.zeros(n, np.int64)
+        span.set(partitions=int(len(starts)))
     _count("window_partitions", int(len(starts)))
+    up.segments += int(len(starts))
     return _Frame(idx, inv, new_part, new_peer, seg_start, seg_end)
 
 
 def _seg_scan(vals: np.ndarray, boundary: np.ndarray, func: str,
               up: _Uploads) -> np.ndarray:
-    """Device inclusive segmented scan (reset at boundary lanes)."""
+    """Device inclusive segmented scan (reset at boundary lanes): pad,
+    upload, `window_segscan_<func>` and the result's fetch, the host blocked
+    on the device throughout (one `bt.device.exec`)."""
     jax = ensure_jax()
     n = len(vals)
     # power-of-two lanes: one compilation per bucket, not per partition
     # row count
     L = _pow2(n)
-    v = np.zeros(L, dtype=vals.dtype)
-    v[:n] = vals
-    f = np.ones(L, dtype=bool)  # padding lanes self-reset
-    f[:n] = boundary
-    out = _segscan_jit(func)(up.put(v), up.put(f))
-    return np.asarray(jax.device_get(out))[:n]
+    before = up.bytes
+    with _device_call(f"segscan_{func}", n, L, [vals]) as span:
+        v = np.zeros(L, dtype=vals.dtype)
+        v[:n] = vals
+        f = np.ones(L, dtype=bool)  # padding lanes self-reset
+        f[:n] = boundary
+        out = np.asarray(jax.device_get(_segscan_jit(func)(up.put(v), up.put(f))))[:n]
+        span.set(bytes=up.bytes - before)
+    up.scans += 1
+    return out
 
 
 def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
@@ -544,12 +599,11 @@ def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
     out_type = w.data_type(schema)
     if n == 0:
         return pa.array([], out_type)
-    with RUN_STATS.span("bt.device.exec", rows=n) as span:
+    with RUN_STATS.span("bt.window.emit", rows=n, func=w.func):
         boundary = fr.new_part.copy()
         boundary[0] = True
         arange = np.arange(n, dtype=np.int64)
 
-        arr = None
         if w.func == "row_number":
             out_sorted = _seg_scan(np.ones(n, np.int64), boundary, "sum", up)
         elif w.func == "rank":
@@ -557,15 +611,12 @@ def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
             peer_start = _seg_scan(marked, boundary, "max", up)
             out_sorted = peer_start - fr.seg_start + 1
         else:
-            arr = _emit_scan_agg(batch, w, schema, fr, boundary, up,
-                                 out_type, _decimal_prepare, _emit_agg,
-                                 _peer_last, n)
-    _note_kernel_s(span.seconds)
-    if arr is not None:
-        return arr
-    out = np.empty(n, dtype=np.int64)
-    out[fr.idx] = out_sorted
-    return pa.array(out, out_type)
+            return _emit_scan_agg(batch, w, schema, fr, boundary, up,
+                                  out_type, _decimal_prepare, _emit_agg,
+                                  _peer_last, n)
+        out = np.empty(n, dtype=np.int64)
+        out[fr.idx] = out_sorted
+        return pa.array(out, out_type)
 
 
 def _emit_scan_agg(batch, w, schema, fr, boundary, up, out_type,
@@ -663,6 +714,10 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
                                         groups[key], up)
         out.append(_device_compute_one(batch, w, schema, frames[key], up))
     RUN_STATS.set("device_bytes", up.bytes)
+    RUN_STATS.set("window_rows", n)
+    RUN_STATS.set("window_lanes", up.lanes)
+    RUN_STATS.set("window_segments", up.segments)
+    RUN_STATS.set("window_scans", up.scans)
     _count("window_invocations")
     return out
 
@@ -713,8 +768,7 @@ class TpuWindowStageExec(ExecutionPlan):
             yield _empty_batch(self.schema())
             return
         try:
-            with device_scope(ctx.device_ordinal), \
-                    RUN_STATS.span("bt.stage.dispatch", family="window"):
+            with _dispatch("window", self.window_exprs, partition, ctx):
                 wins = _device_windows(batch, self.window_exprs,
                                        self.input.df_schema, self.config)
             STAGE_OUTCOMES.note("window", "device")

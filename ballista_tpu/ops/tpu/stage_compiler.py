@@ -61,6 +61,7 @@ from ballista_tpu.ops.tpu.kernels import (
     lex_order,
     live_slots,
     lower_expr,
+    segmented_scan,
     true_mask,
 )
 from ballista_tpu.ops.tpu.runtime import ensure_jax
@@ -1922,8 +1923,8 @@ class TpuStageExec(ExecutionPlan):
         ordering permutation over (validity, key...) (`kernels.lex_order`),
         keys and agg inputs gathered through it, segment boundaries from
         adjacent-key diffs, per-segment totals via
-        cumsum-subtract (sum/count: exact int64) or a segmented associative
-        scan (min/max), then ONE unique-index scatter per output column to
+        cumsum-subtract (sum/count: exact int64) or `kernels.segmented_scan`
+        (min/max, float sums), then ONE unique-index scatter per output column to
         compact segment results into a static [C] capacity. The fetch is
         sliced to pow2(actual segment count), so a 4M-slot capacity costs
         nothing when a query yields 10k groups. Overflow (> C distinct
@@ -2200,7 +2201,7 @@ class TpuStageExec(ExecutionPlan):
                                     c_c = int_segsum(spays[ncnt_idx])
                                 else:
                                     c_c = compact((arange - start + 1).astype(jnp.int64))
-                                s1_c = compact(_segscan(jnp, sv, boundary, "sum"))
+                                s1_c = compact(segmented_scan(sv, boundary, "sum"))
                                 mean_c = s1_c / jnp.maximum(c_c, 1).astype(sv.dtype)
                                 ncnt_pos = None
                                 if ncnt_idx is not None:
@@ -2216,7 +2217,7 @@ class TpuStageExec(ExecutionPlan):
                                     # null x slots were sum-neutralized to 0; keep
                                     # them out of the square sum too
                                     d2 = jnp.where(spays[ncnt_idx] > 0, d2, 0.0)
-                                agg_outs.append(compact(_segscan(jnp, d2, boundary, "sum")))
+                                agg_outs.append(compact(segmented_scan(d2, boundary, "sum")))
                             if ncnt_pos is not None:
                                 ncnt_map[ai] = ncnt_pos
                             continue
@@ -2227,7 +2228,7 @@ class TpuStageExec(ExecutionPlan):
                             # float sums use the segmented scan too: cumsum-subtract
                             # would difference two near-equal whole-table totals
                             # (catastrophic cancellation for small late segments)
-                            agg_outs.append(compact(_segscan(jnp, sv, boundary, fname)))
+                            agg_outs.append(compact(segmented_scan(sv, boundary, fname)))
                         if ncnt_idx is not None:
                             ncnt_map[ai] = len(ncnt_outs)
                             ncnt_outs.append(int_segsum(spays[ncnt_idx]))
@@ -2540,23 +2541,6 @@ def _stage_mesh(config: BallistaConfig):
     from jax.sharding import Mesh
 
     return Mesh(np.array(devs), ("part",))
-
-
-def _segscan(jnp, values, boundary, func: str):
-    """Inclusive segmented sum/min/max scan: resets at boundary rows. The
-    combine is the classic segmented-scan monoid — associative, so XLA
-    lowers it to a log-depth scan."""
-    import jax
-
-    op = {"min": jnp.minimum, "max": jnp.maximum, "sum": jnp.add}[func]
-
-    def combine(a, b):
-        av, af = a
-        bv, bf = b
-        return jnp.where(bf, bv, op(av, bv)), af | bf
-
-    out, _ = jax.lax.associative_scan(combine, (values, boundary))
-    return out
 
 
 def _masked_reduce_w(jnp, v, gm, func: str, m_eff):

@@ -10,6 +10,14 @@ the engine, and verifies against pandas.
 q6 (median/sd) and q9 (corr) need aggregates outside the engine's set and
 are reported as skipped — the same subset public h2o runs mark for engines
 without those aggregates.
+
+A departure from the source: `gen_groupby` draws `id3` and `id6` from
+`rows // 10 + 1` values (about ten rows a group), where the source's
+`_data/groupby-datagen.R` draws them from N / K = `rows // 100` (about K =
+100 rows a group), and leaves the `id%03d` / `id%010d` padding out. This
+file's tests keep their data; the benchmark's generator
+(`bench/lib/generator_h2o.py`, configuration `h2o_g1_1chip`) is the source's
+recipe, and `bench/queries/h2o_q8.sql` the source's text of q8.
 """
 
 from __future__ import annotations

@@ -406,3 +406,77 @@ def test_float_extremes_cross_the_device_as_ordered_int64():
     assert np.array_equal(v[real][np.argsort(enc[real], kind="stable")],
                           np.sort(v[real], kind="stable"))
     assert _f64_to_ordered(np.array([-0.0]))[0] < _f64_to_ordered(np.array([0.0]))[0]
+
+
+# ---------------------------------------------------------------------------
+# kernels.segmented_scan: the blocked scan against the sequential loop
+
+
+def _sequential_scan(v, b, func):
+    """The definition: lane by lane, restarting where a boundary is set."""
+    op = {"sum": lambda a, c: a + c, "min": min, "max": max}[func]
+    out = [int(v[0])]
+    for i in range(1, len(v)):
+        out.append(int(v[i]) if b[i] else op(out[-1], int(v[i])))
+    # int64 addition wraps: so does the device's
+    return np.array([((x + 2**63) % 2**64) - 2**63 for x in out], dtype=np.int64)
+
+
+def _blocked_scan(v, b, func, block=2048):
+    from ballista_tpu.ops.tpu.kernels import segmented_scan
+    from ballista_tpu.ops.tpu.runtime import ensure_jax
+
+    jnp = ensure_jax().numpy
+    return np.asarray(segmented_scan(jnp.asarray(v), jnp.asarray(b), func, block))
+
+
+SCAN_LENGTHS = [1, 63, 64, 2047, 2048, 2049, 3 * 2048 + 5, 1 << 17]
+
+
+@pytest.mark.parametrize("func", ["sum", "min", "max"])
+@pytest.mark.parametrize("n", SCAN_LENGTHS)
+def test_blocked_scan_is_the_sequential_scan_bit_for_bit(n, func):
+    """Seeded int64 lanes, boundaries a lane in about fifty (segments that
+    cross blocks, and the blocks of carries at 2^17)."""
+    rng = np.random.default_rng([n, len(func)])
+    v = rng.integers(-2**62, 2**62, n, dtype=np.int64)
+    b = rng.random(n) < 0.02
+    assert (_blocked_scan(v, b, func) == _sequential_scan(v, b, func)).all()
+    # the jitted program the window family dispatches is the same function
+    from ballista_tpu.ops.tpu.sort_window import _segscan_jit
+
+    assert (np.asarray(_segscan_jit(func)(v, b)) == _sequential_scan(v, b, func)).all()
+
+
+@pytest.mark.parametrize("func", ["sum", "min", "max"])
+@pytest.mark.parametrize("where", ["block_first", "block_last", "every_lane", "lane_0_only",
+                                   "no_flag_at_all"])
+def test_blocked_scan_boundaries_at_the_blocks_edges(where, func):
+    n = 3 * 2048 + 5
+    rng = np.random.default_rng(7)
+    v = rng.integers(-1000, 1000, n, dtype=np.int64)
+    b = np.zeros(n, dtype=bool)
+    if where == "block_first":
+        b[::2048] = True
+    elif where == "block_last":
+        b[2047::2048] = True
+    elif where == "every_lane":
+        b[:] = True
+    elif where == "lane_0_only":
+        b[0] = True
+    # "no_flag_at_all": lane 0 starts a segment whatever its flag says
+    assert (_blocked_scan(v, b, func) == _sequential_scan(v, b, func)).all()
+    # a block that does not divide the length, and one shorter than the carries' count
+    assert (_blocked_scan(v, b, func, block=64) == _sequential_scan(v, b, func)).all()
+
+
+@pytest.mark.parametrize("func", ["min", "max"])
+def test_blocked_scan_keeps_int64s_extremes(func):
+    """The window family's identities and NaN marks ARE int64's extremes
+    (`_emit_scan_agg`): no lane may be combined with a made-up identity."""
+    n = 2 * 2048 + 1
+    rng = np.random.default_rng(11)
+    v = rng.choice(np.array([-2**63, -2**63 + 1, -1, 0, 1, 2**63 - 2, 2**63 - 1],
+                            dtype=np.int64), n)
+    b = rng.random(n) < 0.01
+    assert (_blocked_scan(v, b, func) == _sequential_scan(v, b, func)).all()

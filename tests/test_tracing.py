@@ -308,6 +308,96 @@ def test_one_collect_leaves_one_job_record_covering_the_served_path(standalone):
         assert not (DEVICE & names) and counted == 0
 
 
+@pytest.fixture(scope="module")
+def window_query():
+    """A window query served once through `SessionContext.standalone` with
+    the TPU engine: two window functions over one PARTITION BY / ORDER BY
+    (one ordering, two scans) and a third over an ordering of its own."""
+    import numpy as np
+    import pyarrow as pa
+
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, TPU_MIN_ROWS, BallistaConfig
+    from ballista_tpu.ops.tpu import sort_window as sw
+
+    rng = np.random.default_rng(34)
+    n = 5000
+    t = pa.table({"g": pa.array(rng.integers(0, 40, n), pa.int32()),
+                  "v": pa.array(np.round(rng.uniform(0, 100, n), 6)),
+                  "w": pa.array(rng.integers(0, 1000, n), pa.int64())})
+    ctx = SessionContext.standalone(BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0}),
+                                    num_executors=1)
+    try:
+        ctx.register_arrow_table("t", t, partitions=2)
+        sql = ("SELECT g, row_number() OVER (PARTITION BY g ORDER BY v DESC) rn, "
+               "max(w) OVER (PARTITION BY g ORDER BY v DESC) mw, "
+               "rank() OVER (PARTITION BY g ORDER BY w) rk FROM t")
+        sw._CALLED.clear()  # whatever this process ran before: every program is new to it
+        ctx.sql(sql).collect()  # the first call of a program is a `bt.compile.xla`
+        cold = RUN_STATS.stages()
+        RUN_STATS.clear()
+        out = ctx.sql(sql).collect()
+        stages = RUN_STATS.stages()
+    finally:
+        ctx.shutdown()
+    (_, rec), = ((t_, r) for t_, r in stages.items() if t_.startswith("job_"))
+    return n, out, rec["spans"], {t_: r for t_, r in stages.items() if t_.startswith("window_")}, cold
+
+
+def test_the_window_familys_spans_nest_under_its_dispatch(window_query):
+    n, out, spans, _, _ = window_query
+    assert out.num_rows == n
+    dispatches = [s for s in by_name(spans, "bt.stage.dispatch")
+                  if s[NUMBERS].get("family") == "window"]
+    assert dispatches
+    assert_a_tree_under(spans, by_name(spans, "bt.client.collect")[0])
+    rows = 0
+    for d in dispatches:
+        under = [s for s in spans if s[PARENT] == d[ID]]
+        keys = [s for s in under if s[NAME] == "bt.window.keys"]
+        emits = [s for s in under if s[NAME] == "bt.window.emit"]
+        execs = [s for s in under if s[NAME] == "bt.device.exec"]
+        # two distinct orderings: keys, the ordering and the boundaries once each; three
+        # functions: an emit each, the scan's device call inside it
+        assert len(keys) == 2 and len(execs) == 2 and len(emits) == 2 + 3
+        assert {s[NAME] for s in under} == {"bt.window.keys", "bt.window.emit", "bt.device.exec"}
+        assert all(k[NUMBERS]["key_lanes"] == 2 and k[NUMBERS]["rows"] > 0 for k in keys)
+        for e in execs:
+            nums = e[NUMBERS]
+            assert nums["kernel"] == "lex_order" and nums["lanes"] >= nums["rows"] > 0
+            assert nums["lanes"] & (nums["lanes"] - 1) == 0 and nums["bytes"] >= 12 * nums["lanes"]
+        frames = [e for e in emits if "partitions" in e[NUMBERS]]
+        funcs = [e for e in emits if "func" in e[NUMBERS]]
+        assert len(frames) == 2 and all(0 < f[NUMBERS]["partitions"] <= 40 for f in frames)
+        assert sorted(f[NUMBERS]["func"] for f in funcs) == ["max", "rank", "row_number"]
+        scans = [s for s in spans if s[NAME] == "bt.device.exec"
+                 and s[PARENT] in {f[ID] for f in funcs}]
+        # row_number: a sum scan; max: the count's sum scan and the max scan; rank: a max scan
+        assert sorted(s[NUMBERS]["kernel"] for s in scans) == [
+            "segscan_max", "segscan_max", "segscan_sum", "segscan_sum"]
+        assert all(s[NUMBERS]["bytes"] == 9 * s[NUMBERS]["lanes"] for s in scans)
+        rows += keys[0][NUMBERS]["rows"]
+    assert rows == n
+
+
+def test_a_window_task_leaves_a_record_of_its_own(window_query):
+    n, _, spans, records, cold = window_query
+    dispatches = [s for s in by_name(spans, "bt.stage.dispatch")
+                  if s[NUMBERS].get("family") == "window"]
+    assert len(records) == len(dispatches) >= 1
+    assert sum(r["window_rows"] for r in records.values()) == n
+    for rec in records.values():
+        assert rec["dispatches"] == 1 and rec["exec_s"] > 0 and rec["device_bytes"] > 0
+        # two orderings of the task's rows, padded; 40 groups found by each; four scans
+        assert rec["window_lanes"] >= 2 * rec["window_rows"]
+        assert 0 < rec["window_segments"] <= 2 * 40 and rec["window_scans"] == 4
+        assert "xla_compile_s" not in rec  # every program had been called before
+    # the first query's first calls were the compiles: named so, and counted on the record
+    compiles = [s for r in cold.values() for s in r.get("spans", ()) if s[NAME] == "bt.compile.xla"]
+    assert {s[NUMBERS]["kernel"] for s in compiles} == {"lex_order", "segscan_sum", "segscan_max"}
+    assert sum(r.get("xla_compile_s", 0) for t, r in cold.items() if t.startswith("window_")) > 0
+
+
 def test_the_profiler_trace_holds_the_programs_spans(standalone, tmp_path):
     import jax
     from jax.profiler import ProfileData
