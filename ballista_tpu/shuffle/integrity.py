@@ -3,7 +3,8 @@
 The shuffle contract carries a per-partition checksum from the writer all
 the way to the reader (SURVEY.md §5: the materialized shuffle output is
 the durable unit, so IT is what must be verifiable): the writer records a
-checksum over each output partition's stored byte range as it writes, the
+checksum over each output partition's byte range AS STORED (read back from
+the closed `.tmp` once, before the rename that publishes it), the
 Flight servers ship the recorded value in their per-location headers, and
 clients/local readers recompute it over the received bytes BEFORE handing
 them to the Arrow decoder. A flipped bit therefore surfaces as a typed
@@ -144,46 +145,6 @@ def verify_blocks(blocks, expected: str) -> bool:
     for b in blocks:
         c.update(memoryview(b))
     return c.digest() == expected
-
-
-class ChecksumSink:
-    """File-object wrapper that checksums bytes AS THEY ARE WRITTEN
-    (per-range: `start_range()` resets the running value so one physical
-    file yields one digest per output-partition byte range). Implements
-    just enough of the binary-file protocol for pyarrow's IPC writer."""
-
-    closed = False
-
-    def __init__(self, f, enabled: bool = True):
-        self._f = f
-        self._cs = Checksum() if enabled else None
-
-    def write(self, data) -> int:
-        if self._cs is not None:
-            self._cs.update(data)
-        return self._f.write(data)
-
-    def tell(self) -> int:
-        return self._f.tell()
-
-    def flush(self) -> None:
-        self._f.flush()
-
-    def writable(self) -> bool:
-        return True
-
-    def seekable(self) -> bool:
-        return False
-
-    def readable(self) -> bool:
-        return False
-
-    def start_range(self) -> None:
-        if self._cs is not None:
-            self._cs.reset()
-
-    def digest(self) -> str | None:
-        return None if self._cs is None else self._cs.digest()
 
 
 # -- executor-wide corruption accounting -------------------------------------

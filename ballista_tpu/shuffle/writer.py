@@ -28,6 +28,14 @@ and bytes — so the length of the slice is all that decides. The locations
 of a longer slice are reported under its FIRST map partition
 (docs/tpu_engine.md#how-a-device-stage-is-tasked).
 
+A range is drained inside Arrow: the data file is a native sink (Arrow's
+own buffered file stream), never a Python object, so between a range's
+first and last byte the IPC writer calls nothing back — in-memory batches
+go down in one `write_table`, streamed ones (a spill file, a passthrough's
+pull) one `write_batch` each. The checksum is taken afterwards over the
+bytes AS STORED: each range of the closed `.tmp` read back once, before
+any rename.
+
 On-device partitioning: when the child pipeline ran on the TPU engine the
 hash is computed with the jax twin of ops/hashing.py; host and device
 partitions are bit-identical so readers never care who wrote a file.
@@ -36,11 +44,11 @@ partitions are bit-identical so readers never care who wrote a file.
 from __future__ import annotations
 
 import contextlib
-import io
 import os
 import json
+import time
 import uuid
-from typing import Iterator, Optional
+from typing import Iterable, Iterator, Optional
 
 import numpy as np
 import pyarrow as pa
@@ -54,7 +62,7 @@ from ballista_tpu.config import (
 from ballista_tpu.errors import ExecutionError
 from ballista_tpu.executor import disk
 from ballista_tpu.executor.chaos import maybe_disk_full
-from ballista_tpu.shuffle.integrity import ChecksumSink
+from ballista_tpu.shuffle.integrity import Checksum
 from ballista_tpu.ops.hashing import partition_indices
 from ballista_tpu.ops.phys_expr import bind_expr, evaluate_to_array
 from ballista_tpu.plan.expressions import Expr
@@ -168,19 +176,74 @@ def _codec(ctx: TaskContext) -> Optional[str]:
 
 
 def _ipc_options(ctx: TaskContext) -> ipc.IpcWriteOptions:
-    return ipc.IpcWriteOptions(compression=_codec(ctx))
+    # the task slots are the parallelism: a drain that borrows the process's
+    # CPU pool for 48 KB buffers pays more in hand-offs than the codec costs
+    return ipc.IpcWriteOptions(compression=_codec(ctx), use_threads=False)
 
 
-def write_ipc_stream(batches: list[pa.RecordBatch], schema: pa.Schema, sink, ctx: TaskContext) -> tuple[int, int]:
-    """Write batches as one IPC stream; returns (rows, bytes_written)."""
+# Arrow hands a sink every message header, buffer and padding by itself
+# (seven a batch: 21,000 for 150 MB of 48 KB batches) and a file system call
+# is ~90 us on a sandboxed host, so the sink coalesces them: 1 MiB a write.
+_SINK_BUFFER = 1 << 20
+
+
+def _open_sink(path: str) -> pa.NativeFile:
+    """The native sink every data file, `.tmp` and spill is written through."""
+    return pa.output_stream(path, compression=None, buffer_size=_SINK_BUFFER)
+
+
+def _drain_range(sink: pa.NativeFile, schema: pa.Schema, ctx: TaskContext,
+                 held: Iterable[pa.RecordBatch] = (),
+                 streamed: Iterable[pa.RecordBatch] = ()) -> tuple[int, int, int, int]:
+    """Write ONE IPC stream at the sink's position: the batches `held` in
+    memory in one call (a table over the batches' own buffers: nothing is
+    copied), then the `streamed` ones as they come, never more than one
+    alive. Returns (start, length, rows, batches) of the range. `sink` is an
+    Arrow native file, so no byte of the range passes through Python."""
     start = sink.tell()
-    rows = 0
+    held = [b for b in held if b.num_rows]
+    rows = sum(b.num_rows for b in held)
+    n = len(held)
     with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
-        for b in batches:
+        if held:
+            w.write_table(pa.Table.from_batches(held, schema=schema))
+        for b in streamed:
             if b.num_rows:
                 w.write_batch(b)
                 rows += b.num_rows
-    return rows, sink.tell() - start
+                n += 1
+    return start, sink.tell() - start, rows, n
+
+
+# the most a read-back holds at once: a longer range is checksummed in pieces
+_READ_BACK = 16 << 20
+
+
+def _checksum_ranges(tmp_path: str, ranges: list[tuple[int, int]],
+                     ctx: TaskContext) -> list[str | None]:
+    """The checksum of each (start, length) range of a closed `.tmp`, over
+    the bytes as stored: one open a file, one `pread` a range of up to
+    16 MiB (three system calls for a one-range file: a mapping costs three
+    times that where system calls are dear). Nothing is read back where
+    checksums are off (or there is no range)."""
+    if not ranges or not _checksum_on(ctx):
+        return [None] * len(ranges)
+    digests = []
+    fd = os.open(tmp_path, os.O_RDONLY)
+    try:
+        for start, length in ranges:
+            c = Checksum()
+            while length:
+                part = os.pread(fd, min(length, _READ_BACK), start)
+                if not part:
+                    raise OSError(f"{tmp_path}: {length} bytes of a written range are missing")
+                c.update(part)
+                start += len(part)
+                length -= len(part)
+            digests.append(c.digest())
+    finally:
+        os.close(fd)
+    return digests
 
 
 class ShuffleWriterExec(ExecutionPlan):
@@ -250,9 +313,14 @@ class ShuffleWriterExec(ExecutionPlan):
         return RUN_STATS.span("bt.shuffle.commit", map_partitions=len(partitions))
 
     @staticmethod
-    def _set_commit(span, meta: pa.RecordBatch, files: int) -> None:
+    def _set_commit(span, meta: pa.RecordBatch, files: int, write_s: float,
+                    checksum_s: float) -> None:
+        """`write_ms`: the drains that ran inside the span (thread-ms; none
+        for a passthrough, whose ranges are written during the pull);
+        `checksum_ms`: the read-back of the stored ranges."""
         span.set(ranges=meta.num_rows, files=files,
-                 bytes=sum(meta.column("num_bytes").to_pylist()))
+                 bytes=sum(meta.column("num_bytes").to_pylist()),
+                 write_ms=round(write_s * 1e3, 3), checksum_ms=round(checksum_s * 1e3, 3))
 
     def _write_passthrough(self, partitions: list[int], task_id, ctx: TaskContext,
                            before_partition) -> pa.RecordBatch:
@@ -277,38 +345,32 @@ class ShuffleWriterExec(ExecutionPlan):
         os.makedirs(os.path.dirname(path), exist_ok=True)
         maybe_disk_full(ctx.config, self.job_id, self.stage_id, first,
                         ctx.task_attempt, "shuffle passthrough write")
-        index: dict[str, list] = {}
-        out = []
         what = f"shuffle write {self.job_id}/{self.stage_id}/{first}"
-        with _sweeping(what, path + ".tmp"), open(path + ".tmp", "wb") as f:
-            sink = ChecksumSink(f, enabled=_checksum_on(ctx))
+        ranges = []
+        with _sweeping(what, path + ".tmp"), _open_sink(path + ".tmp") as f:
             for p in partitions:
                 before_partition()
-                start = f.tell()
-                rows = 0
-                batches = 0
-                # per-RANGE checksum: a partition's byte range is the
-                # unit readers fetch and verify (one partition: the file)
-                sink.start_range()
-                with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
-                    for b in self.input.execute(p, ctx):
-                        if b.num_rows:
-                            w.write_batch(b)
-                            rows += b.num_rows
-                            batches += 1
-                length = f.tell() - start
-                index[str(p)] = _index_entry(start, length, rows, sink.digest())
-                out.append((p, path, rows, batches, length, "hash" if one else "sort"))
-        meta = self._meta(out)
+                ranges.append(_drain_range(f, schema, ctx, streamed=self.input.execute(p, ctx)))
+        layout = "hash" if one else "sort"
+        meta = self._meta([(p, path, rows, n, length, layout)
+                           for p, (_, length, rows, n) in zip(partitions, ranges)])
         with self._commit_span(partitions) as span:
+            # per-RANGE checksum: a partition's byte range is the unit
+            # readers fetch and verify (one partition: the file)
+            t0 = time.perf_counter()
+            with _sweeping(what, path + ".tmp"):
+                digests = _checksum_ranges(path + ".tmp", [r[:2] for r in ranges], ctx)
+            checksum_s = time.perf_counter() - t0
             if one:
-                digest = sink.digest()
-                _write_crc_sidecar(path, digest)
+                _write_crc_sidecar(path, digests[0])
                 os.replace(path + ".tmp", path)
-                self._set_commit(span, meta, 2 if digest else 1)
+                files = 2 if digests[0] else 1
             else:
+                index = {str(p): _index_entry(start, length, rows, crc)
+                         for p, (start, length, rows, _), crc in zip(partitions, ranges, digests)}
                 _commit_data_and_index(path, index, what)
-                self._set_commit(span, meta, 2)
+                files = 2
+            self._set_commit(span, meta, files, 0.0, checksum_s)
         return meta
 
     def _write_exchange(self, partitions: list[int], task_id, ctx: TaskContext,
@@ -351,8 +413,8 @@ class ShuffleWriterExec(ExecutionPlan):
             sp = paths.sort_data_path(ctx.work_dir, self.job_id, self.stage_id, first, task_id) + f".spill{len(spills[k])}.{k}"
             os.makedirs(os.path.dirname(sp), exist_ok=True)
             what = f"sort-shuffle spill {self.job_id}/{self.stage_id}/{first}"
-            with _sweeping(what, sp), open(sp, "wb") as f:
-                _, sp_bytes = write_ipc_stream(buckets[k], schema, f, ctx)
+            with _sweeping(what, sp), _open_sink(sp) as f:
+                _, sp_bytes, _, _ = _drain_range(f, schema, ctx, buckets[k])
             spills[k].append(sp)
             freed = sum(b.nbytes for b in buckets[k])
             buffered -= freed
@@ -444,12 +506,13 @@ class ShuffleWriterExec(ExecutionPlan):
                         bucket_batches[k] -= len(buckets[k]) - len(merged)
                         buckets[k] = merged
                 if self.sort_shuffle:
-                    meta, files = self._finish_sort(first, task_id, schema, buckets, spills,
-                                                    bucket_rows, bucket_batches, ctx)
+                    done = self._finish_sort(first, task_id, schema, buckets, spills,
+                                             bucket_rows, bucket_batches, ctx)
                 else:
-                    meta, files = self._finish_hash(first, task_id, schema, buckets,
-                                                    bucket_rows, bucket_batches, ctx)
-                self._set_commit(span, meta, files)
+                    done = self._finish_hash(first, task_id, schema, buckets,
+                                             bucket_rows, bucket_batches, ctx)
+                meta = done[0]
+                self._set_commit(span, *done)
             return meta
         except BaseException:
             # consolidation removes spills as it streams them; an aborted
@@ -462,14 +525,15 @@ class ShuffleWriterExec(ExecutionPlan):
 
     def _finish_hash(self, map_partition, task_id, schema, buckets, rows, batches, ctx):
         """Drain the K bucket files CONCURRENTLY (the reference's K
-        concurrent per-output drain tasks, shuffle_writer.rs:214-303):
-        Arrow's IPC write releases the GIL for compression + IO, so the
-        drains genuinely overlap."""
+        concurrent per-output drain tasks, shuffle_writer.rs:214-303): a
+        drain runs in Arrow from its first byte to its last, with the GIL
+        released, so the drains genuinely overlap. Returns the metadata,
+        the files written, and the drains' and the read-backs' seconds."""
         import concurrent.futures as fut
 
         live = [k for k in range(len(buckets)) if rows[k]]
         if not live:
-            return self._meta([]), 0
+            return self._meta([]), 0, 0.0, 0.0
         maybe_disk_full(ctx.config, self.job_id, self.stage_id, map_partition,
                         ctx.task_attempt, "hash-shuffle commit")
 
@@ -477,27 +541,34 @@ class ShuffleWriterExec(ExecutionPlan):
             path = paths.hash_data_path(ctx.work_dir, self.job_id, self.stage_id, k, task_id)
             os.makedirs(os.path.dirname(path), exist_ok=True)
             what = f"shuffle write {self.job_id}/{self.stage_id}/{k}"
-            with _sweeping(what, path + ".tmp"), open(path + ".tmp", "wb") as f:
-                sink = ChecksumSink(f, enabled=_checksum_on(ctx))
-                _, nbytes = write_ipc_stream(buckets[k], schema, sink, ctx)
-            _write_crc_sidecar(path, sink.digest())
+            t0 = time.perf_counter()
+            with _sweeping(what, path + ".tmp"):
+                with _open_sink(path + ".tmp") as f:
+                    _, nbytes, _, _ = _drain_range(f, schema, ctx, buckets[k])
+                t1 = time.perf_counter()
+                (digest,) = _checksum_ranges(path + ".tmp", [(0, nbytes)], ctx)
+            t2 = time.perf_counter()
+            _write_crc_sidecar(path, digest)
             os.replace(path + ".tmp", path)
-            return (k, path, rows[k], batches[k], nbytes, "hash")
+            return (k, path, rows[k], batches[k], nbytes, "hash"), t1 - t0, t2 - t1
 
         files = len(live) * (2 if _checksum_on(ctx) else 1)
         if len(live) == 1:
-            return self._meta([drain(live[0])]), files
-        with fut.ThreadPoolExecutor(max_workers=min(len(live), 8),
-                                    thread_name_prefix="shuffle-drain") as pool:
-            out = list(pool.map(drain, live))
-        return self._meta(out), files
+            out = [drain(live[0])]
+        else:
+            with fut.ThreadPoolExecutor(max_workers=min(len(live), 8),
+                                        thread_name_prefix="shuffle-drain") as pool:
+                out = list(pool.map(drain, live))
+        return (self._meta([o[0] for o in out]), files,
+                sum(o[1] for o in out), sum(o[2] for o in out))
 
     @staticmethod
     def _iter_bucket_batches(in_memory: list, spill_files: list[str]):
-        """Stream a bucket's batches: in-memory first, then each spill file
-        decoded ONE BATCH AT A TIME. Consolidation must never rebuffer what
-        it spilled — that would peak at exactly the memory the spill
-        existed to avoid (sort_shuffle/spill.rs:46 streams the same way)."""
+        """Stream a bucket's batches: in-memory first (none from the drain,
+        which writes those in one call), then each spill file decoded ONE
+        BATCH AT A TIME. Consolidation must never rebuffer what it spilled
+        — that would peak at exactly the memory the spill existed to avoid
+        (sort_shuffle/spill.rs:46 streams the same way)."""
         for b in in_memory:
             yield b
         for sp in spill_files:
@@ -518,29 +589,25 @@ class ShuffleWriterExec(ExecutionPlan):
         os.makedirs(os.path.dirname(data_path), exist_ok=True)
         maybe_disk_full(ctx.config, self.job_id, self.stage_id, map_partition,
                         ctx.task_attempt, "sort-shuffle commit")
-        index: dict[str, list] = {}
-        out = []
         what = f"sort-shuffle commit {self.job_id}/{self.stage_id}/{map_partition}"
-        with _sweeping(what, data_path + ".tmp"), open(data_path + ".tmp", "wb") as f:
-            sink = ChecksumSink(f, enabled=_checksum_on(ctx))
-            for k in range(len(buckets)):
-                if not rows[k]:
-                    continue
-                start = f.tell()
-                nrows = 0
-                # per-RANGE checksum: each bucket's byte range is the unit
-                # readers fetch and verify, so the digest resets here
-                sink.start_range()
-                with ipc.new_stream(sink, schema, options=_ipc_options(ctx)) as w:
-                    for b in self._iter_bucket_batches(buckets[k], spills[k]):
-                        if b.num_rows:
-                            w.write_batch(b)
-                            nrows += b.num_rows
-                length = f.tell() - start
-                index[str(k)] = _index_entry(start, length, nrows, sink.digest())
-                out.append((k, data_path, nrows, batches[k], length, "sort"))
+        live = [k for k in range(len(buckets)) if rows[k]]
+        t0 = time.perf_counter()
+        with _sweeping(what, data_path + ".tmp"):
+            with _open_sink(data_path + ".tmp") as f:
+                ranges = [_drain_range(f, schema, ctx, buckets[k],
+                                       self._iter_bucket_batches([], spills[k]))
+                          for k in live]
+            t1 = time.perf_counter()
+            # per-RANGE checksum: each bucket's byte range is the unit
+            # readers fetch and verify
+            digests = _checksum_ranges(data_path + ".tmp", [r[:2] for r in ranges], ctx)
+        t2 = time.perf_counter()
+        index = {str(k): _index_entry(start, length, nrows, crc)
+                 for k, (start, length, nrows, _), crc in zip(live, ranges, digests)}
         _commit_data_and_index(data_path, index, what)
-        return self._meta(out), 2
+        meta = self._meta([(k, data_path, nrows, batches[k], length, "sort")
+                           for k, (_, length, nrows, _) in zip(live, ranges)])
+        return meta, 2, t1 - t0, t2 - t1
 
     def _meta(self, rows: list[tuple]) -> pa.RecordBatch:
         schema = self.schema()
