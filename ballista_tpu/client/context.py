@@ -47,6 +47,7 @@ class SessionContext:
         self.session_id: SessionId = new_session_id()
         self._cluster = None  # StandaloneCluster (standalone mode)
         self._remote = None  # RemoteSchedulerClient (remote mode)
+        self._last_job_id = ""  # the job of the last standalone collect()
         self._num_executors = num_executors
         self._vcores = vcores
         self._scheduler_url = scheduler_url
@@ -66,16 +67,26 @@ class SessionContext:
         return cls(config, mode="remote", scheduler_url=scheduler_url)
 
     def job_diagnostics(self, job_id: str = "") -> dict:
-        """Remote mode: ONE `job_<id>` record of a query this context
-        collected (default: the last), this process's spans joined with the
-        scheduler's and every executor's, and their counters beside it
-        (client/remote.py `job_diagnostics`; docs/tpu_engine.md
-        #observability). In the other modes every span is already in this
-        process: `RUN_STATS.stages()` holds the record."""
-        if self.mode != "remote":
-            raise ValueError("job_diagnostics asks a remote scheduler; in this mode "
-                             "tracing.RUN_STATS.stages() already holds the job's record")
-        return self._ensure_remote().job_diagnostics(job_id)
+        """ONE `job_<id>` record of a query this context collected (default:
+        the last) and, under "path", where its wall time went: the critical
+        path's seconds by span name and its ten longest segments with their
+        stage and task (`tracing.job_path`; docs/tpu_engine.md
+        #observability). Remote mode: this process's spans joined with the
+        scheduler's and every executor's, their counters beside them
+        (client/remote.py `job_diagnostics`). The other modes: the record
+        `RUN_STATS.stages()` holds — every span is already in this process —
+        or one without spans where the recorder no longer has the job."""
+        from ballista_tpu.tracing import RUN_STATS, job_path
+
+        if self.mode == "remote":
+            record = self._ensure_remote().job_diagnostics(job_id)
+        else:
+            records = {tag[len("job_"):]: rec for tag, rec in RUN_STATS.stages().items()
+                       if tag.startswith("job_")}
+            job_id = job_id or self._last_job_id or next(reversed(records), "")
+            record = {"spans": [], "spans_dropped": 0, **records.get(job_id, {}),
+                      "job_id": job_id}
+        return {**record, "path": job_path(record["spans"])}
 
     def _ensure_cluster(self):
         if self._cluster is None:
@@ -513,6 +524,7 @@ class DataFrame:
                     physical = self.ctx.create_physical_plan(self.plan)
                     job_id = scheduler.submit_physical_plan(physical, session_id)
                 root.set(job=job_id)
+                self.ctx._last_job_id = job_id
             with RUN_STATS.span("bt.client.wait"):
                 status = scheduler.wait_for_job(
                     job_id, timeout=float(self.ctx.config.get(CLIENT_JOB_TIMEOUT_S)))
@@ -543,7 +555,8 @@ class DataFrame:
                     lines.append(
                         f"  {'  ' * int(m.get('depth', 0))}{m.get('name', '')}: "
                         f"rows={m.get('output_rows', 0)} "
-                        f"elapsed_ms={m.get('elapsed_ns', 0) / 1e6:.2f}"
+                        f"elapsed_ms={m.get('elapsed_ns', 0) / 1e6:.2f} "
+                        f"self_ms={m.get('self_ns', 0) / 1e6:.2f}"
                     )
             types.append("analyzed_plan (distributed)")
             plans.append("\n".join(lines))
@@ -566,7 +579,8 @@ class DataFrame:
                 for m in list(sp.metrics)[:100]:
                     lines.append(
                         f"  {'  ' * m.depth}{m.name}: rows={m.output_rows} "
-                        f"elapsed_ms={m.elapsed_ns / 1e6:.2f}"
+                        f"elapsed_ms={m.elapsed_ns / 1e6:.2f} "
+                        f"self_ms={m.extra.get('self_ns', 0) / 1e6:.2f}"
                     )
             types.append("analyzed_plan (distributed)")
             plans.append("\n".join(lines))
@@ -583,7 +597,9 @@ class DataFrame:
 
             lines = []
             for depth, name, m in collect_metrics(physical):
-                lines.append(f"{'  ' * depth}{name}: rows={m['output_rows']} elapsed_ms={m['elapsed_ns'] / 1e6:.2f}")
+                lines.append(f"{'  ' * depth}{name}: rows={m['output_rows']} "
+                             f"elapsed_ms={m['elapsed_ns'] / 1e6:.2f} "
+                             f"self_ms={m['self_ns'] / 1e6:.2f}")
             types.append("analyzed_plan")
             plans.append("\n".join(lines))
         return pa.table({"plan_type": pa.array(types), "plan": pa.array(plans)})

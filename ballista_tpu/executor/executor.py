@@ -26,7 +26,13 @@ from dataclasses import dataclass, field
 from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
 from ballista_tpu.errors import BallistaError, Cancelled, error_to_proto_kind
 from ballista_tpu.ids import ExecutorId, new_executor_id
-from ballista_tpu.plan.physical import ExecutionPlan, TaskContext, collect_metrics
+from ballista_tpu.plan.physical import (
+    ExecutionPlan,
+    TaskContext,
+    collect_metrics,
+    operator_rows,
+    task_metrics,
+)
 from ballista_tpu.scheduler.state.execution_graph import TaskDescription
 from ballista_tpu.shuffle.types import PartitionLocation
 from ballista_tpu.shuffle.writer import ShuffleWriterExec, metadata_to_locations
@@ -268,10 +274,12 @@ class Executor:
         ids = {"job": task.job_id, "stage": task.stage_id, "task": task.task_id}
         # the time the work waited for a slot and a thread
         RUN_STATS.add_span("bt.task.queued", task.created_ns, **ids)
-        with RUN_STATS.span("bt.task.run", partitions=len(task.partitions), **ids):
-            return self._execute_task(task, config)
+        with RUN_STATS.span("bt.task.run", partitions=len(task.partitions), **ids), \
+                task_metrics() as held:
+            return self._execute_task(task, config, held)
 
-    def _execute_task(self, task: TaskDescription, config: BallistaConfig | None) -> TaskResult:
+    def _execute_task(self, task: TaskDescription, config: BallistaConfig | None,
+                      held: dict | None = None) -> TaskResult:
         cfg = config or self.default_config
         from ballista_tpu import udf
 
@@ -339,9 +347,14 @@ class Executor:
                 )
             base.state = "success"
             base.locations = locations
-            base.metrics = [
-                {"depth": d, "name": n, **m} for d, n, m in collect_metrics(prepared)
-            ]
+            # one walk of the plan: the task's metrics for the scheduler, and
+            # what its `bt.shuffle.write` span holds by operator (on the record
+            # only: the span has closed, its trace annotation with it)
+            metrics = collect_metrics(prepared, held)
+            base.metrics = [{"depth": d, "name": n, **m} for d, n, m in metrics]
+            if ctx.write_span is not None:
+                ops, ops_ms = operator_rows(metrics[1:])
+                ctx.write_span.set(ops=ops, ops_ms=ops_ms)
             self.tasks_run += 1
             return base
         except _DeadlineExpired:

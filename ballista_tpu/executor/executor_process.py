@@ -369,7 +369,7 @@ class ExecutorProcess:
         stats = sc.RUN_STATS.snapshot()
         for key in ("fill_s", "encode_s", "upload_s", "compile_s",
                     "compile_overlap_s", "exec_s", "device_bytes",
-                    "fused_spans", "fused_kernel_s",
+                    "fused_spans",
                     "mesh_devices", "exchange_bytes_on_device", "exchange_s",
                     "hbm_budget_bytes", "hbm_spill_bytes", "hbm_spill_events",
                     "hbm_reupload_events", "grace_splits", "hbm_oom_retries",

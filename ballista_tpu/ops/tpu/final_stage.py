@@ -212,6 +212,8 @@ class TpuFinalStageExec(ExecutionPlan):
     docstring). Counters (device_runs / cpu_fallbacks) surface in EXPLAIN
     ANALYZE exactly like TpuStageExec's."""
 
+    own_span = True  # `bt.stage.dispatch` and the spans inside it
+
     def __init__(self, sort, post_ops: list, agg: HashAggregateExec,
                  child: ExecutionPlan, config: BallistaConfig, coalesce: bool = False):
         top = sort if sort is not None else (post_ops[0] if post_ops else agg)

@@ -454,6 +454,8 @@ class TpuSortStageExec(ExecutionPlan):
     ordering permutation on device, take on the host. Unsupported shapes
     host-sort the SAME materialized table (no child re-execution)."""
 
+    own_span = True  # `bt.stage.dispatch` and the spans inside it
+
     def __init__(self, input: ExecutionPlan, keys: list[SortKey],
                  fetch: Optional[int], config: BallistaConfig):
         super().__init__(input.df_schema)
@@ -726,6 +728,8 @@ class TpuWindowStageExec(ExecutionPlan):
     """WindowExec on the device: sort permutation + segmented scans on
     device, boundary/emit logic shared with the CPU oracle. Ineligible
     shapes run `compute_windows` over the SAME materialized batch."""
+
+    own_span = True  # `bt.stage.dispatch` and the spans inside it
 
     def __init__(self, input: ExecutionPlan, window_exprs: list,
                  df_schema: DFSchema, config: BallistaConfig):

@@ -14,6 +14,7 @@ replaces with shuffle writer/reader pairs at stage boundaries
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -54,6 +55,26 @@ class Metrics:
         }
 
 
+# In-process, the tasks of a stage share the stage's plan objects (and with
+# them a join's build side), so an operator's `metrics` add up over tasks that
+# run side by side. Inside `task_metrics()` whatever `_timed` measures on the
+# calling thread is also kept apart, an operator: that task's own share.
+_TASK = threading.local()
+
+
+@contextlib.contextmanager
+def task_metrics():
+    """The calling thread's own operator metrics while it runs one task:
+    yields {id(operator): Metrics}, filled by `_timed` on this thread only
+    (`collect_metrics(plan, held)` reads it)."""
+    held: dict[int, Metrics] = {}
+    _TASK.held = held
+    try:
+        yield held
+    finally:
+        _TASK.held = None
+
+
 class TaskContext:
     def __init__(self, config: BallistaConfig | None = None, task_id: str = "", work_dir: str = ""):
         self.config = config or BallistaConfig()
@@ -73,10 +94,18 @@ class TaskContext:
         self.task_attempt = 0
         self.cancel_check = None
         self.deadline_at = 0.0
+        # the task's `bt.shuffle.write` span, left here by the writer when it
+        # closes: the task runner adds what the span held by operator
+        self.write_span = None
 
 
 class ExecutionPlan:
     """Base physical operator."""
+
+    # an operator whose work lies under a `bt.*` span of its own (the device
+    # stage families, the shuffle reader): `collect_metrics` flags it, and the
+    # writer's span does not count it among the operators it holds
+    own_span = False
 
     def __init__(self, df_schema: DFSchema):
         self.df_schema = df_schema
@@ -107,29 +136,95 @@ class ExecutionPlan:
             lines.append(c.display(indent + 1))
         return "\n".join(lines)
 
+    def _timed_metrics(self) -> tuple:
+        """What a pull on the calling thread adds to: the operator's metrics
+        and, inside `task_metrics()`, the task's own share of them."""
+        held = getattr(_TASK, "held", None)
+        if held is None:
+            return (self.metrics,)
+        mine = held.get(id(self))
+        if mine is None:
+            mine = held[id(self)] = Metrics()
+        return (self.metrics, mine)
+
     def _timed(self, it: Iterator[pa.RecordBatch]) -> Iterator[pa.RecordBatch]:
-        m = self.metrics
+        timed = self._timed_metrics()
         while True:
             t0 = time.perf_counter_ns()
             try:
                 b = next(it)
             except StopIteration:
-                m.elapsed_ns += time.perf_counter_ns() - t0
+                dt = time.perf_counter_ns() - t0
+                for m in timed:
+                    m.elapsed_ns += dt
                 return
-            m.elapsed_ns += time.perf_counter_ns() - t0
-            m.output_rows += b.num_rows
-            m.output_batches += 1
+            dt = time.perf_counter_ns() - t0
+            for m in timed:
+                m.elapsed_ns += dt
+                m.output_rows += b.num_rows
+                m.output_batches += 1
             yield b
 
 
-def collect_metrics(plan: ExecutionPlan, out: list | None = None, depth: int = 0) -> list:
-    """Recursive metrics harvest (reference: utils.rs collect_plan_metrics)."""
-    if out is None:
-        out = []
-    out.append((depth, plan.node_str(), plan.metrics.as_dict()))
-    for c in plan.children():
-        collect_metrics(c, out, depth + 1)
+def collect_metrics(plan: ExecutionPlan, held: dict | None = None) -> list:
+    """Recursive metrics harvest (reference: utils.rs collect_plan_metrics):
+    `(depth, node_str, numbers)` an operator, the plan's root first.
+    With `held` (a task's `task_metrics()`), an operator's numbers are the
+    task's own; one the task's thread never pulled (a helper thread did, or
+    another task of the stage built what this one shared) keeps the
+    operator's shared numbers and says so (`other_thread` 1).
+
+    Beside the inclusive `elapsed_ns` every operator gets `self_ns`: its
+    elapsed less its children's (those pulled elsewhere left out), clamped
+    at 0 (`self_clamped` 1) where a child still counted more than its parent
+    waited. `own_span` 1 marks an operator whose work lies under a `bt.*`
+    span of its own (`ExecutionPlan.own_span`)."""
+    out: list = []
+
+    def numbers(node: ExecutionPlan) -> tuple[Metrics, bool]:
+        mine = None if held is None else held.get(id(node))
+        return (node.metrics, held is not None) if mine is None else (mine, False)
+
+    def walk(node: ExecutionPlan, depth: int) -> None:
+        children = node.children()
+        metrics, elsewhere = numbers(node)
+        m = metrics.as_dict()
+        if metrics is not node.metrics:
+            m.update(node.metrics.extra)  # operators write their extras on the shared one
+        self_ns = m["elapsed_ns"] - sum(cm.elapsed_ns for cm, away in map(numbers, children)
+                                        if not away)
+        m["self_ns"] = max(self_ns, 0)
+        if self_ns < 0:
+            m["self_clamped"] = 1
+        if elsewhere and (metrics.elapsed_ns or metrics.output_batches):
+            m["other_thread"] = 1
+        if node.own_span:
+            m["own_span"] = 1
+        out.append((depth, node.node_str(), m))
+        for c in children:
+            walk(c, depth + 1)
+
+    walk(plan, 0)
     return out
+
+
+def operator_rows(metrics: list) -> tuple[list[list], float]:
+    """What a `bt.shuffle.write` span says of the operators pulled through
+    it, from `collect_metrics`' list (same order): one `[depth, operator,
+    self_ms, rows, batches, flag]` an operator — flag "span" where its work
+    lies under a span of its own, "elsewhere" where the task's thread never
+    pulled it, "clamped" where its self time was clamped, else "" — and
+    `ops_ms`: the self milliseconds of those this thread pulled that have no
+    span of their own, which is what the write's self time holds of them."""
+    rows, ops_ns = [], 0
+    for depth, name, m in metrics:
+        flag = ("span" if m.get("own_span") else "elsewhere" if m.get("other_thread")
+                else "clamped" if m.get("self_clamped") else "")
+        if flag in ("", "clamped"):
+            ops_ns += m["self_ns"]
+        rows.append([depth, name.split(":", 1)[0], round(m["self_ns"] / 1e6, 3),
+                     m["output_rows"], m["output_batches"], flag])
+    return rows, round(ops_ns / 1e6, 3)
 
 
 def _empty_batch(schema: pa.Schema) -> pa.RecordBatch:

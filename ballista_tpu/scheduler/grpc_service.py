@@ -268,10 +268,12 @@ class SchedulerGrpcService:
                 sp = out.stages.add()
                 sp.stage_id = sid
                 for m in metrics:
-                    sp.metrics.add(
+                    mp = sp.metrics.add(
                         name=str(m.get("name", "")), output_rows=int(m.get("output_rows", 0)),
                         elapsed_ns=int(m.get("elapsed_ns", 0)), depth=int(m.get("depth", 0)),
                     )
+                    # what `elapsed_ns` is inclusive of: the operator's own share
+                    mp.extra["self_ns"] = int(m.get("self_ns", 0))
         return out
 
     # -- diagnostics (pull only: nothing rides the heartbeat) ------------------

@@ -45,6 +45,8 @@ from ballista_tpu.utils.lru import LruDict
 
 
 class ShuffleReaderExec(ExecutionPlan):
+    own_span = True  # `bt.shuffle.read`
+
     def __init__(self, df_schema: DFSchema, partition_locations: list[list[PartitionLocation]],
                  broadcast: bool = False):
         super().__init__(df_schema)
@@ -71,9 +73,12 @@ class ShuffleReaderExec(ExecutionPlan):
         return f"ShuffleReaderExec: partitions={len(self.partition_locations)} locations={n}{b}"
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
-        return self._timed(self._run(partition, ctx))
+        # not through `_timed`: the generator times its own pulls (the same two
+        # clock reads a batch), once for the metrics and for the span's `read_ms`
+        return self._run(partition, ctx)
 
     def _run(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+        timed = self._timed_metrics()
         if self.broadcast:
             locs = [l for part in self.partition_locations for l in part]
         else:
@@ -91,31 +96,51 @@ class ShuffleReaderExec(ExecutionPlan):
         else:
             stream = (b for loc in locs for b in fetch_partition(
                 loc, ctx, force_remote=force_remote, governor=gov, counters=ctr))
+        read_ns = 0  # inside the reader's own generator: open, index, checksum, decode
+        t1 = t0
         try:
-            for b in stream:
+            stream = iter(stream)
+            while True:
+                try:
+                    b = next(stream)
+                except StopIteration:
+                    break
+                finally:
+                    t2 = time.perf_counter_ns()
+                    read_ns += t2 - t1
                 if b.num_rows:
                     if not produced:
-                        self.metrics.extra["time_to_first_batch_ns"] = (
-                            time.perf_counter_ns() - t0)
+                        self.metrics.extra["time_to_first_batch_ns"] = t2 - t0
                     produced = True
+                    for m in timed:
+                        m.output_rows += b.num_rows
+                        m.output_batches += 1
                     yield b
+                    t1 = time.perf_counter_ns()
+                else:
+                    t1 = t2
         finally:
+            for m in timed:
+                m.elapsed_ns += read_ns
             # data-plane accounting for EXPLAIN ANALYZE / the scheduler's
             # task metrics: RPCs issued and bytes moved by provenance
             counts = ctr.snapshot()
             self.metrics.extra.update(counts)
             # first pull to exhaustion, one span a partition read (never one a
             # batch): it holds what the consumer did between batches too
+            # (`read_ms` is the reading; the rest of the span is the consumer's)
             read = RUN_STATS.add_span(
                 "bt.shuffle.read", t0, parent=task_span, partitions=len(locs),
                 bytes=counts["bytes_read_local"] + counts["bytes_fetched_remote"],
-                local=int(counts["fetch_rpcs"] == 0))
+                local=int(counts["fetch_rpcs"] == 0), read_ms=round(read_ns / 1e6, 3))
             # what came from another executor over Flight, one span a location,
             # inside the read (its fetch threads have no span of their own)
             for start, end, nbytes in ctr.fetches():
                 RUN_STATS.add_span("bt.flight.fetch", start, end_ns=end, parent=read,
                                    bytes=nbytes)
         if not produced:
+            for m in timed:
+                m.output_batches += 1
             yield _empty_batch(self.schema())
 
 

@@ -70,7 +70,7 @@ from ballista_tpu.plan.physical import ExecutionPlan, TaskContext, _empty_batch
 from ballista_tpu.plan.schema import DFField, DFSchema
 from ballista_tpu.shuffle import paths
 from ballista_tpu.shuffle.types import PartitionStats
-from ballista_tpu.tracing import RUN_STATS
+from ballista_tpu.tracing import RUN_STATS, now_ns
 
 
 METADATA_SCHEMA = DFSchema(
@@ -246,6 +246,36 @@ def _checksum_ranges(tmp_path: str, ranges: list[tuple[int, int]],
     return digests
 
 
+class _Pull:
+    """The writer's pulls of its input, timed: `ns` is the time spent inside
+    the stage's operators — `execute()` (a device stage dispatches in it) and
+    every `next()`: two clock reads a batch — and `between_ns` the rest of
+    the span up to `done()`: the writer's own work between pulls."""
+
+    __slots__ = ("ns", "between_ns", "_t0")
+
+    def __init__(self):
+        self.ns = self.between_ns = 0
+        self._t0 = now_ns()
+
+    def of(self, plan: ExecutionPlan, partition: int,
+           ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+        t0 = now_ns()
+        it = iter(plan.execute(partition, ctx))
+        while True:
+            try:
+                b = next(it)
+            except StopIteration:
+                self.ns += now_ns() - t0
+                return
+            self.ns += now_ns() - t0
+            yield b
+            t0 = now_ns()
+
+    def done(self) -> None:
+        self.between_ns = now_ns() - self._t0 - self.ns
+
+
 class ShuffleWriterExec(ExecutionPlan):
     def __init__(self, input: ExecutionPlan, job_id: str, stage_id: int,
                  output_partitions: int, keys: list[Expr] | None,
@@ -301,9 +331,20 @@ class ShuffleWriterExec(ExecutionPlan):
                 raise ExecutionError("shuffle writer needs a work_dir in TaskContext")
             task_id = ctx.task_id or f"{partitions[0]}-{uuid.uuid4().hex[:6]}"
             write = self._write_passthrough if self.output_partitions <= 0 else self._write_exchange
-            meta = write(partitions, task_id, ctx, before_partition or (lambda: None))
+            pull = _Pull()
+            meta = write(partitions, task_id, ctx, before_partition or (lambda: None), pull)
+            # `pull_ms`: inside `next()` on the stage's operators (their spans
+            # nest in it); `partition_ms`: the writer's own work between
+            # pulls, up to the commit (keys, split, reserve, spills; a
+            # passthrough's IPC writes)
             span.set(rows=sum(meta.column("num_rows").to_pylist()),
-                     bytes=sum(meta.column("num_bytes").to_pylist()))
+                     bytes=sum(meta.column("num_bytes").to_pylist()),
+                     pull_ms=round(pull.ns / 1e6, 3),
+                     partition_ms=round(pull.between_ns / 1e6, 3))
+        # closed, but its numbers still reach the job's record: the task runner
+        # adds the operators' share once it has harvested the plan's metrics
+        # (handed over on the task's own context: in-process, tasks share the plan)
+        ctx.write_span = span
         return self._timed(iter([meta]))
 
     # ------------------------------------------------------------------
@@ -323,7 +364,7 @@ class ShuffleWriterExec(ExecutionPlan):
                  write_ms=round(write_s * 1e3, 3), checksum_ms=round(checksum_s * 1e3, 3))
 
     def _write_passthrough(self, partitions: list[int], task_id, ctx: TaskContext,
-                           before_partition) -> pa.RecordBatch:
+                           before_partition, pull: _Pull) -> pa.RecordBatch:
         """Stage collapse / preserved partitioning: partition identity is
         the contract (a consumer may merge sorted partitions or join
         co-partitioned ones), so every map partition keeps a range of its
@@ -350,7 +391,9 @@ class ShuffleWriterExec(ExecutionPlan):
         with _sweeping(what, path + ".tmp"), _open_sink(path + ".tmp") as f:
             for p in partitions:
                 before_partition()
-                ranges.append(_drain_range(f, schema, ctx, streamed=self.input.execute(p, ctx)))
+                ranges.append(_drain_range(f, schema, ctx,
+                                           streamed=pull.of(self.input, p, ctx)))
+        pull.done()
         layout = "hash" if one else "sort"
         meta = self._meta([(p, path, rows, n, length, layout)
                            for p, (_, length, rows, n) in zip(partitions, ranges)])
@@ -374,7 +417,7 @@ class ShuffleWriterExec(ExecutionPlan):
         return meta
 
     def _write_exchange(self, partitions: list[int], task_id, ctx: TaskContext,
-                        before_partition) -> pa.RecordBatch:
+                        before_partition, pull: _Pull) -> pa.RecordBatch:
         """Hash exchange: rows are bucketed by key across the WHOLE slice —
         bucket k holds output partition k's rows from every map partition
         the task holds — and drained once. The memory limit, the session
@@ -459,7 +502,7 @@ class ShuffleWriterExec(ExecutionPlan):
         try:
             for p in partitions:
                 before_partition()
-                for b in self.input.execute(p, ctx):
+                for b in pull.of(self.input, p, ctx):
                     if b.num_rows == 0:
                         continue
                     pids = None
@@ -495,6 +538,7 @@ class ShuffleWriterExec(ExecutionPlan):
                         if not spill_largest():
                             break
 
+            pull.done()
             # the buckets drained to their files, checksummed and renamed
             with self._commit_span(partitions) as span:
                 if len(partitions) > 1:

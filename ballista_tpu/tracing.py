@@ -460,6 +460,110 @@ def join_job_parts(job_id: str, parts: list[dict]) -> dict:
             "processes": [part.get("process", str(i)) for i, part in enumerate(parts)]}
 
 
+# a span that closes on another thread or in another process may end a little
+# after the moment its successor says it started (a hand-over between threads,
+# a joined part's clock pair, the rounding to the microsecond): that far past
+# `t` a span still counts as having ended by `t`. Half a millisecond is fifty
+# times the widest such overlap in the recorded jobs (under 10 us) and a
+# hundredth of the shortest task: no neighbour's end falls that close by chance
+PATH_TOLERANCE_S = 0.0005
+
+
+def critical_path(rows: list, root: str = "bt.client.collect",
+                  waiting: tuple = ("bt.client.wait", "bt.sched.stage"),
+                  tolerance_s: float = PATH_TOLERANCE_S) -> dict | None:
+    """Which spans the client waited for: the critical path through the rows
+    of a `job_<id>` record (one process's or a joined one; plain lists).
+    Returns {"root": id, "segments": [[start_s, end_s, span id, name], ...],
+    "seconds": {name: seconds}} — the segments, in time order, tile the root
+    span's interval exactly — or None where no span is named `root`.
+
+    The walk goes back from the root's end. In span S at time t the next span
+    on the path is the candidate with the latest end <= t (+ `tolerance_s`)
+    that starts before t: S owns (that end, t], the candidate is walked over
+    its own interval (clipped to S's), and S goes on from the candidate's
+    start. A candidate still running at t is a neighbour, not a predecessor:
+    with four slots the path leaves a task at its start and enters the task
+    whose end freed the slot. What no candidate covers is S's own.
+
+    `waiting` spans only wait for others and never hide work: S's candidates
+    are its children and, through a waiting child, that child's children
+    (recursively), wherever the waiting spans were hung; time no candidate
+    covers goes to the innermost (shortest) waiting span open then, else S."""
+    tops = [r for r in rows if r[0] == root]
+    if not tops:
+        return None
+    top = max(tops, key=lambda r: r[4] - r[3])
+    children: dict = {}
+    for r in rows:
+        if r[2] is not None and r is not top:
+            children.setdefault(r[2], []).append(r)
+    waits = set(waiting)
+    found: list[list] = []  # latest first
+
+    def own(span, looked: list, lo: float, hi: float) -> None:
+        if hi <= lo:
+            return
+        cuts = sorted({lo, hi, *(x for w in looked for x in (w[3], w[4]) if lo < x < hi)})
+        for a, b in zip(cuts[-2::-1], cuts[:0:-1]):
+            open_then = [w for w in looked if w[3] <= a and b <= w[4]]
+            w = min(open_then, key=lambda w: (w[4] - w[3], w[1])) if open_then else span
+            found.append([a, b, w[1], w[0]])
+
+    def walk(span, lo: float, hi: float) -> None:
+        candidates, looked = [], []
+        stack = list(children.get(span[1], ()))
+        while stack:
+            c = stack.pop()
+            if c[0] in waits:
+                looked.append(c)
+                stack.extend(children.get(c[1], ()))
+            else:
+                candidates.append(c)
+        candidates.sort(key=lambda c: (c[4], c[1]), reverse=True)
+        t = hi
+        for c in candidates:
+            if t <= lo:
+                break
+            if c[4] > t + tolerance_s or c[3] >= t or c[4] <= lo:
+                continue  # running at t (a neighbour), or outside (lo, t]
+            end, start = min(c[4], t), max(c[3], lo)
+            own(span, looked, end, t)
+            walk(c, start, end)
+            t = start
+        own(span, looked, lo, t)
+
+    walk(top, top[3], top[4])
+    segments: list[list] = []
+    for seg in reversed(found):
+        if segments and segments[-1][2] == seg[2] and segments[-1][1] == seg[0]:
+            segments[-1][1] = seg[1]
+        else:
+            segments.append(seg)
+    seconds: dict = {}
+    for a, b, _, name in segments:
+        seconds[name] = seconds.get(name, 0.0) + (b - a)
+    return {"root": top[1], "segments": segments, "seconds": seconds}
+
+
+def job_path(rows: list, longest: int = 10) -> dict | None:
+    """A record's critical path as `SessionContext.job_diagnostics()` hands it
+    out: the root's seconds, the path's seconds by span name (largest first)
+    and its `longest` segments as [start_s, end_s, span id, name, stage,
+    task]. None where the record has no `bt.client.collect`."""
+    path = critical_path(rows)
+    if path is None:
+        return None
+    by_id = {r[1]: r for r in rows}
+    top = by_id[path["root"]]
+    longest_first = sorted(path["segments"], key=lambda s: s[0] - s[1])[:longest]
+    return {"root_s": round(top[4] - top[3], 6),
+            "seconds": {name: round(s, 6) for name, s in
+                        sorted(path["seconds"].items(), key=lambda kv: -kv[1])},
+            "longest": [[a, b, sid, name, by_id[sid][5], by_id[sid][6]]
+                        for a, b, sid, name in longest_first]}
+
+
 RUN_STATS = RunStats()
 
 

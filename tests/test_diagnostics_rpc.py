@@ -347,6 +347,67 @@ def test_joined_record_of_a_remote_query(cluster, tpch_dir):
                ctx._ensure_remote().diagnostics(record["job_id"])["executors"])
 
 
+def test_the_joined_record_carries_its_critical_path(cluster, tpch_dir):
+    """`job_diagnostics()` says where the query's wall time went: the path's
+    seconds by name sum to the client's `collect()`, equal the sum of its
+    segments, and hold what only a deployment of several processes waits for —
+    the launches, the scheduler's hand-overs, the client's poll."""
+    from ballista_tpu.tracing import critical_path
+
+    c, _ = cluster
+    ctx = c.context(tpch_dir)
+    ctx.sql(tpch_query(5)).collect()
+    record = ctx.job_diagnostics()
+    path = record["path"]
+    root = next(r for r in record["spans"] if r[0] == "bt.client.collect")
+    assert path["root_s"] == pytest.approx(root[4] - root[3], abs=1e-6)
+    assert sum(path["seconds"].values()) == pytest.approx(path["root_s"], abs=1e-4)
+    full = critical_path(record["spans"])
+    by_name_s: dict = {}
+    for a, b, _, name in full["segments"]:
+        by_name_s[name] = by_name_s.get(name, 0.0) + (b - a)
+    assert {n: round(v, 6) for n, v in by_name_s.items()} == path["seconds"]
+    assert {"bt.task.launch", "bt.sched.stage", "bt.client.wait", "bt.task.run",
+            "bt.shuffle.write"} <= set(path["seconds"]), path["seconds"]
+    assert 1 <= len(path["longest"]) <= 10
+    stages = {r[5] for r in record["spans"] if r[0] == "bt.task.run"}
+    assert any(seg[4] in stages and seg[5] is not None for seg in path["longest"])
+    # an executor's write span says what it held, through the rpc and the join
+    writes = [r for r in record["spans"] if r[0] == "bt.shuffle.write"]
+    assert writes and all(r[7]["proc"] >= 2 and "ops_ms" in r[7] and r[7]["ops"]
+                          for r in writes)
+
+
+def test_job_diagnostics_answers_in_standalone_mode_too(tpch_dir):
+    """No rpc to ask: the record `RUN_STATS` published, with its path; for a
+    job the recorder no longer has, a record without spans and without one."""
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
+    from ballista_tpu.testing.tpchgen import register_tpch
+    from ballista_tpu.tracing import RUN_STATS
+
+    ctx = SessionContext.standalone(BallistaConfig({EXECUTOR_ENGINE: "cpu"}), num_executors=1)
+    try:
+        register_tpch(ctx, tpch_dir)
+        ctx.sql(tpch_query(6)).collect()
+        first = ctx.job_diagnostics()
+        ctx.sql(tpch_query(1)).collect()
+        record = ctx.job_diagnostics()
+        assert record["job_id"] and record["job_id"] != first["job_id"]
+        assert record["spans"] == RUN_STATS.stages()[f"job_{record['job_id']}"]["spans"]
+        path = record["path"]
+        root = next(r for r in record["spans"] if r[0] == "bt.client.collect")
+        assert path["root_s"] == pytest.approx(root[4] - root[3], abs=1e-6)
+        assert sum(path["seconds"].values()) == pytest.approx(path["root_s"], abs=1e-4)
+        assert {"bt.shuffle.write", "bt.task.run"} <= set(path["seconds"])
+        assert all(len(seg) == 6 for seg in path["longest"])
+        assert ctx.job_diagnostics(first["job_id"])["path"]["root_s"] == first["path"]["root_s"]
+        gone = ctx.job_diagnostics("no-such-job")
+        assert gone["spans"] == [] and gone["path"] is None and gone["job_id"] == "no-such-job"
+    finally:
+        ctx.shutdown()
+
+
 def test_counters_outcomes_memory_and_cache_of_every_executor(cluster):
     c, ctx = cluster
     client = ctx._ensure_remote()

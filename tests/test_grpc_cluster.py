@@ -227,6 +227,12 @@ def test_remote_explain_analyze(grpc_cluster, remote_ctx):
     plans = dict(zip(out.column("plan_type").to_pylist(), out.column("plan").to_pylist()))
     body = plans.get("analyzed_plan (distributed)", "")
     assert "stage" in body and "elapsed_ms" in body, plans
+    # one operator number, one meaning: the inclusive time and the operator's own
+    import re
+
+    pairs = re.findall(r"elapsed_ms=([0-9.]+) self_ms=([0-9.]+)", body)
+    assert pairs and all(float(own) <= float(whole) + 0.01 for whole, own in pairs), body
+    assert any(float(own) > 0 for _, own in pairs), body
 
 
 def test_concurrent_sessions_and_jobs(grpc_cluster, tpch_dir, tpch_ref_tables):

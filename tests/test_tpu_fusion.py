@@ -217,19 +217,17 @@ def test_string_predicate_over_dictionary_codes(kind, T):
 
 
 def test_runstats_and_heartbeat_gauges(tpch_dir):
-    """The stage lands in RunStats with its span count, its kernel seconds
-    and the [P, N] stack it ran over, and in the heartbeat's gauges."""
+    """The stage lands in RunStats with its span count and the [P, N] stack
+    it ran over, and in the heartbeat's gauges."""
     from ballista_tpu.executor.executor_process import ExecutorProcess
 
     _, stats = _on_device(tpch_query(1), tpch_dir=tpch_dir)
     assert stats.get("fused_spans", 0) >= 2  # filter→project→agg stage
-    assert stats.get("fused_kernel_s", 0.0) > 0.0
     P, N = stats["table_shape"]
     assert P >= 1 and N & (N - 1) == 0  # a [P, bucket] stack
 
     gauges = dict(ExecutorProcess._tpu_metrics())
     assert gauges.get("tpu_fused_spans", 0.0) >= 2.0
-    assert gauges.get("tpu_fused_kernel_s", 0.0) > 0.0
 
 
 def _walk(node):

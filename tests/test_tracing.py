@@ -11,7 +11,14 @@ import time
 
 import pytest
 
-from ballista_tpu.tracing import MAX_SPANS_PER_JOB, RUN_STATS, RunStats
+from ballista_tpu.tracing import (
+    MAX_SPANS_PER_JOB,
+    RUN_STATS,
+    RunStats,
+    critical_path,
+    job_path,
+    join_job_parts,
+)
 
 from .conftest import tpch_query
 
@@ -507,3 +514,362 @@ def test_every_jitted_stage_function_has_a_name_of_its_own():
     assert set(lowered) == set(JITTED_STAGE_FAMILIES[2:])
     for name, text in lowered.items():
         assert f"module @jit_{name}" in text
+
+
+# -------------------------------------------------------- the critical path
+#
+# hand-made records, plain rows: [name, id, parent, start_s, end_s, stage, task, numbers]
+
+
+def _row(name, sid, parent, start, end, stage=None, task=None, **numbers):
+    return [name, sid, parent, start, end, stage, task, numbers]
+
+
+def _one_thread_nested():
+    """A query on one thread: every span the child of the one around it."""
+    return [
+        _row("bt.client.collect", 1, None, 0.0, 10.0),
+        _row("bt.client.submit", 2, 1, 0.0, 1.0),
+        _row("bt.sched.plan", 3, 2, 0.2, 0.8),
+        _row("bt.client.wait", 4, 1, 1.0, 9.0),
+        _row("bt.sched.stage", 5, 4, 1.0, 8.5, 1),
+        _row("bt.task.run", 6, 5, 1.5, 8.0, 1, 1),
+        _row("bt.shuffle.write", 7, 6, 2.0, 7.5, 1, 1),
+        _row("bt.stage.dispatch", 8, 7, 2.5, 6.0, 1, 1),
+        _row("bt.device.exec", 9, 8, 3.0, 5.0, 1, 1),
+        _row("bt.shuffle.commit", 10, 7, 6.5, 7.0, 1, 1),
+        _row("bt.client.fetch_results", 11, 1, 9.0, 9.9),
+    ], {"bt.client.collect": 0.1, "bt.client.submit": 0.4, "bt.sched.plan": 0.6,
+        "bt.client.wait": 0.5, "bt.sched.stage": 1.0, "bt.task.run": 1.0,
+        "bt.shuffle.write": 1.5, "bt.stage.dispatch": 1.5, "bt.device.exec": 2.0,
+        "bt.shuffle.commit": 0.5, "bt.client.fetch_results": 0.9}
+
+
+def _four_slots_three_waves():
+    """Twelve tasks of one stage on four slots: a slot's next task starts when
+    its last one ends. The path takes one task a wave — the one whose end
+    freed the slot the next one on the path ran in — never a neighbour that
+    was still running."""
+    rows = [_row("bt.client.collect", 1, None, 0.0, 40.0),
+            _row("bt.client.wait", 2, 1, 0.0, 40.0),
+            _row("bt.sched.stage", 3, 2, 0.0, 40.0, 1)]
+    ends = {0: [10.0, 21.0, 33.0], 1: [11.0, 22.0, 34.0],
+            2: [12.0, 20.0, 40.0], 3: [13.0, 26.0, 31.0]}
+    sid = 10
+    for slot, stops in ends.items():
+        start = 0.0
+        for wave, stop in enumerate(stops):
+            rows.append(_row("bt.task.run", sid, 3, start, stop, 1, 4 * wave + slot))
+            start, sid = stop, sid + 1
+    # the last to end is slot 2's third task (20 -> 40); before it slot 2's
+    # second (12 -> 20) and first (0 -> 12): 40 s of bt.task.run, no wait
+    return rows, {"bt.task.run": 40.0}, [(0.0, 12.0), (12.0, 20.0), (20.0, 40.0)]
+
+
+def _first_stage_beside_the_wait():
+    """A query's first stage becomes runnable inside bt.client.submit, so its
+    bt.sched.stage hangs under bt.client.collect BESIDE bt.client.wait, which
+    covers it: its tasks are on the path all the same, and the hand-over
+    between its tasks goes to the stage, not to the client's wait."""
+    return [
+        _row("bt.client.collect", 1, None, 0.0, 20.0),
+        _row("bt.client.submit", 2, 1, 0.0, 1.0),
+        _row("bt.sched.stage", 3, 1, 0.5, 9.0, 1),       # hung beside the wait
+        _row("bt.client.wait", 4, 1, 1.0, 19.0),
+        _row("bt.task.run", 5, 3, 1.0, 5.0, 1, 1),
+        _row("bt.task.run", 6, 3, 5.5, 8.5, 1, 2),
+        _row("bt.sched.stage", 7, 4, 9.5, 18.0, 2),
+        _row("bt.task.run", 8, 7, 10.0, 18.0, 2, 3),
+        _row("bt.client.fetch_results", 9, 1, 19.0, 20.0),
+    ], {"bt.client.submit": 1.0, "bt.task.run": 15.0,
+        "bt.sched.stage": 0.5 + 0.5 + 0.5,   # 5–5.5 and 8.5–9 in stage 1, 9.5–10 in stage 2
+        "bt.client.wait": 0.5 + 1.0,         # 9–9.5 between the stages, 18–19 the poll
+        "bt.client.fetch_results": 1.0}
+
+
+def _task_clipped_by_its_stage():
+    """A task's report ends after its stage has closed, and a task begins a
+    little before its stage says it was runnable (another process's clock):
+    both are clipped to what encloses them, and nothing is counted twice."""
+    return [
+        _row("bt.client.collect", 1, None, 0.0, 10.0),
+        _row("bt.client.wait", 2, 1, 0.5, 9.5),
+        _row("bt.sched.stage", 3, 2, 1.0, 6.0, 1),
+        _row("bt.task.run", 4, 3, 0.8, 5.0, 1, 1),       # starts before its stage
+        _row("bt.task.report", 5, 3, 5.0, 6.4, 1, 1),    # ends after it
+        _row("bt.sched.stage", 6, 2, 6.0, 9.0, 2),
+        _row("bt.task.run", 7, 6, 6.4, 9.0, 2, 2),
+    ], {"bt.client.collect": 1.0, "bt.client.wait": 0.8, "bt.task.run": 4.2 + 2.6,
+        "bt.task.report": 1.4}
+
+
+def _six_processes():
+    """The same query as six processes recorded it (ids collide, clocks differ,
+    no parent crosses a process), joined into one record."""
+    S = 1_000_000_000
+
+    def part(process, offset_s, rows):
+        shifted = [[r[0], r[1], r[2], r[3] + offset_s, r[4] + offset_s, *r[5:]] for r in rows]
+        return {"process": process, "clock": [int((100 + offset_s) * S), 1000 * S],
+                "spans": shifted, "spans_dropped": 0}
+
+    parts = [
+        part("client", 0.0, [
+            _row("bt.client.collect", 1, None, 0.0, 20.0),
+            _row("bt.client.submit", 2, 1, 0.0, 1.0),
+            _row("bt.client.wait", 3, 1, 1.0, 19.0),
+            _row("bt.client.fetch_results", 4, 1, 19.0, 20.0)]),
+        part("scheduler:s0", 500.25, [
+            _row("bt.sched.plan", 1, None, 0.2, 0.8),
+            _row("bt.sched.stage", 2, None, 1.05, 9.0, 1),
+            _row("bt.task.launch", 3, None, 1.05, 1.2, 1, 1, tasks=2),
+            _row("bt.task.launch", 4, None, 1.05, 1.3, 1, 2, tasks=2),
+            _row("bt.sched.stage", 5, None, 9.2, 18.5, 2),
+            _row("bt.task.launch", 6, None, 9.2, 9.5, 2, 5, tasks=1)]),
+        part("executor:e0", -77.5, [
+            _row("bt.task.run", 1, None, 1.2, 6.0, 1, 1),
+            _row("bt.shuffle.write", 2, 1, 1.3, 5.9, 1, 1)]),
+        part("executor:e1", 3.0, [
+            _row("bt.task.run", 1, None, 1.3, 8.8, 1, 2),
+            _row("bt.shuffle.write", 2, 1, 1.4, 8.7, 1, 2)]),
+        part("executor:e2", 1e6, [
+            _row("bt.task.run", 1, None, 9.5, 18.2, 2, 5),
+            _row("bt.device.exec", 2, 1, 10.0, 17.0, 2, 5)]),
+        part("executor:e3", 0.125, []),
+    ]
+    return join_job_parts("j", parts)["spans"], {
+        "bt.client.submit": 0.2 + 0.2, "bt.sched.plan": 0.6, "bt.task.launch": 0.25 + 0.3,
+        "bt.task.run": 0.1 + 0.1 + 0.5 + 1.2, "bt.shuffle.write": 7.3,
+        "bt.device.exec": 7.0, "bt.sched.stage": 0.2 + 0.3,
+        "bt.client.wait": 0.05 + 0.2 + 0.5, "bt.client.fetch_results": 1.0}
+
+
+PATH_CASES = {
+    "one_thread_nested": _one_thread_nested,
+    "four_slots_three_waves": lambda: _four_slots_three_waves()[:2],
+    "first_stage_beside_the_wait": _first_stage_beside_the_wait,
+    "task_clipped_by_its_stage": _task_clipped_by_its_stage,
+    "six_processes_joined": _six_processes,
+}
+
+
+@pytest.mark.parametrize("case", sorted(PATH_CASES))
+def test_the_critical_path_charges_each_span_what_the_client_waited_for(case):
+    rows, want = PATH_CASES[case]()
+    path = critical_path(rows)
+    got = {name: round(s, 6) for name, s in path["seconds"].items() if round(s, 6)}
+    assert got == {name: pytest.approx(s, abs=2e-6) for name, s in want.items()}, got
+
+
+@pytest.mark.parametrize("case", sorted(PATH_CASES))
+def test_the_paths_segments_tile_the_root_span(case):
+    """The identity: no gap, no overlap, no negative segment, and the seconds
+    by name are the segments' and sum to the root's duration."""
+    rows, _ = PATH_CASES[case]()
+    path = critical_path(rows)
+    root = next(r for r in rows if r[ID] == path["root"])
+    segments = path["segments"]
+    assert segments[0][0] == root[START] and segments[-1][1] == root[END]
+    ids = {r[ID]: r[NAME] for r in rows}
+    for (a, b, sid, name), nxt in zip(segments, segments[1:] + [None]):
+        assert b > a and ids[sid] == name
+        assert nxt is None or nxt[0] == b  # the next starts where this one ends, exactly
+    assert sum(path["seconds"].values()) == pytest.approx(root[END] - root[START], abs=1e-9)
+    by_name_s: dict = {}
+    for a, b, _, name in segments:
+        by_name_s[name] = by_name_s.get(name, 0.0) + (b - a)
+    assert by_name_s == path["seconds"]
+
+
+def test_with_four_slots_the_path_takes_the_task_whose_end_freed_the_slot():
+    rows, _, want = _four_slots_three_waves()
+    segments = critical_path(rows)["segments"]
+    assert [(a, b) for a, b, _, name in segments if name == "bt.task.run"] == want
+    # a neighbour still running at that moment is no predecessor: slot 3's
+    # second task (13 -> 26) overlaps the path's last task and is not on it
+    neighbour = next(r[ID] for r in rows if r[START] == 13.0 and r[END] == 26.0)
+    assert neighbour not in {sid for _, _, sid, _ in segments}
+
+
+def test_a_waiting_span_never_hides_the_work_beside_it():
+    """The walk that trusts the tree would charge all of stage 1 to
+    bt.client.wait, which covers it; looked through, wherever the stage was
+    hung the path is the same."""
+    rows, want = _first_stage_beside_the_wait()
+    mended = [list(r) for r in rows]
+    next(r for r in mended if r[ID] == 3)[PARENT] = 4  # the stage hung UNDER the wait
+    assert critical_path(mended)["seconds"] == critical_path(rows)["seconds"]
+    assert critical_path(rows)["seconds"]["bt.client.wait"] == pytest.approx(1.5)
+    # not looked through, the wait swallows what ran beside and inside it
+    blind = critical_path(rows, waiting=())["seconds"]
+    assert blind["bt.client.wait"] == pytest.approx(9.5) and blind["bt.task.run"] == 8.0
+
+
+def test_a_span_that_ends_a_little_late_is_still_a_predecessor():
+    """A hand-over between threads or processes: the report says it began
+    0.2 ms before the task's end came (another clock). Within the tolerance
+    the task is the predecessor; a span running well past `t` is not."""
+    rows = [_row("bt.client.collect", 1, None, 0.0, 2.0),
+            _row("bt.task.run", 2, 1, 0.0, 1.0002, 1, 1),
+            _row("bt.task.report", 3, 1, 1.0, 2.0, 1, 1)]
+    assert critical_path(rows)["seconds"] == {
+        "bt.task.run": pytest.approx(1.0), "bt.task.report": pytest.approx(1.0)}
+    rows[1][END] = 1.5  # still running half a second into the report: a neighbour
+    assert critical_path(rows)["seconds"] == {
+        "bt.client.collect": pytest.approx(1.0), "bt.task.report": pytest.approx(1.0)}
+
+
+def test_a_record_without_a_root_has_no_path():
+    rows, _ = _task_clipped_by_its_stage()
+    assert critical_path([r for r in rows if r[NAME] != "bt.client.collect"]) is None
+    assert job_path([]) is None
+
+
+def test_the_joined_record_gives_the_path_of_the_one_process_record():
+    """Six processes' parts joined, and the same spans as one process would
+    have recorded them (parents in place): the same seconds by name."""
+    joined, want = _six_processes()
+    ids = {(r[NAME], r[STAGE], r[TASK], round(r[START], 3)): r[ID] for r in joined}
+    one = [list(r) for r in joined]
+    stage_of = {r[STAGE]: r[ID] for r in one if r[NAME] == "bt.sched.stage"}
+    wait = next(r[ID] for r in one if r[NAME] == "bt.client.wait")
+    for r in one:  # hang everything as the in-process recorder does
+        if r[NAME] == "bt.sched.stage":
+            r[PARENT] = wait
+        elif r[NAME] in ("bt.task.run", "bt.task.launch"):
+            r[PARENT] = stage_of[r[STAGE]]
+    a, b = critical_path(joined)["seconds"], critical_path(one)["seconds"]
+    assert a == pytest.approx(b) and len(ids) == len(joined)
+    assert {"bt.task.launch", "bt.sched.stage", "bt.client.wait"} <= set(a)
+
+
+def test_job_path_names_the_ten_longest_segments_with_stage_and_task():
+    rows, want = _first_stage_beside_the_wait()
+    path = job_path(rows)
+    assert path["root_s"] == 20.0
+    assert list(path["seconds"])[0] == "bt.task.run" and path["seconds"]["bt.task.run"] == 15.0
+    assert sum(path["seconds"].values()) == pytest.approx(20.0)
+    a, b, sid, name, stage, task = path["longest"][0]
+    assert (a, b, name, stage, task) == (10.0, 18.0, "bt.task.run", 2, 3)
+    assert len(path["longest"]) == min(10, len(critical_path(rows)["segments"]))
+    assert [s[1] - s[0] for s in path["longest"]] == sorted(
+        (s[1] - s[0] for s in path["longest"]), reverse=True)
+
+
+# ------------------------------------- what the catch-all spans say they hold
+
+
+def test_the_writers_span_names_the_operators_it_pulled(standalone):
+    """`bt.shuffle.write` states the pull, the partitioning and the plan's
+    operators; `bt.shuffle.read` the reading inside it."""
+    engine, ctx = standalone
+    ctx.sql(tpch_query(3)).collect()
+    RUN_STATS.clear()
+    ctx.sql(tpch_query(3)).collect()
+    (_, rec), = job_records(RUN_STATS).items()
+    spans = rec["spans"]
+    writes = by_name(spans, "bt.shuffle.write")
+    assert writes
+    for w in writes:
+        n = w[NUMBERS]
+        assert n["pull_ms"] >= 0 and n["partition_ms"] >= 0
+        assert n["pull_ms"] + n["partition_ms"] <= 1e3 * (w[END] - w[START]) + 0.01, w
+        assert n["ops"] and all(len(o) == 6 for o in n["ops"])
+        assert [o[0] for o in n["ops"]][0] == 1  # the writer's input, depth 1
+        counted = sum(o[2] for o in n["ops"] if o[5] in ("", "clamped"))
+        assert n["ops_ms"] == pytest.approx(counted, abs=0.001 * len(n["ops"]))
+        assert all(o[5] == "span" for o in n["ops"]
+                   if o[1] in ("ShuffleReaderExec", "TpuStageExec", "TpuFinalStageExec"))
+    flagged = {o[1] for w in writes for o in w[NUMBERS]["ops"] if o[5] == "span"}
+    assert "ShuffleReaderExec" in flagged
+    if engine == "tpu":
+        assert "TpuStageExec" in flagged
+        device = next(w for w in writes
+                      if any(o[1] == "TpuStageExec" for o in w[NUMBERS]["ops"]))
+        # a device stage's operator is listed, flagged and not summed: its
+        # dispatch is a span of its own inside the pull
+        assert device[NUMBERS]["ops_ms"] < 1.0 < device[NUMBERS]["pull_ms"]
+    scans = [o for w in writes for o in w[NUMBERS]["ops"] if o[1] == "ParquetScanExec" and o[3]]
+    assert scans and all(o[5] == "" and o[2] > 0 for o in scans)
+    # the operators in `collect_metrics` order: the scheduler's copy of the
+    # same harvest, a task of the stage, names the same operators
+    sched = ctx._cluster.scheduler
+    with sched._jobs_lock:
+        g = list(sched.jobs.values())[-1]
+    for w in writes:
+        per_task = g.stage_metrics[w[STAGE]]
+        first = per_task[:len(w[NUMBERS]["ops"]) + 1]  # one task's harvest, the writer first
+        assert [m["depth"] for m in first[1:]] == [o[0] for o in w[NUMBERS]["ops"]]
+        assert [m["name"].split(":")[0] for m in first[1:]] == [o[1] for o in w[NUMBERS]["ops"]]
+        assert all("self_ns" in m and m["self_ns"] <= max(m["elapsed_ns"], 0) for m in per_task)
+    reads = by_name(spans, "bt.shuffle.read")
+    assert reads
+    for r in reads:
+        assert 0 <= r[NUMBERS]["read_ms"] <= 1e3 * (r[END] - r[START]) + 0.01, r
+
+
+def test_a_tasks_operator_metrics_are_its_own_where_tasks_share_a_plan(tmp_path):
+    """In-process the tasks of a stage share the stage's plan objects: what a
+    task reports, and what its write span says, is what ITS thread pulled."""
+    import pyarrow as pa
+
+    from ballista_tpu.config import BallistaConfig
+    from ballista_tpu.executor.executor import Executor, ExecutorMetadata
+    from ballista_tpu.plan.expressions import Column
+    from ballista_tpu.plan.physical import MemoryScanExec
+    from ballista_tpu.plan.schema import DFSchema
+    from ballista_tpu.scheduler.state.execution_graph import TaskDescription
+    from ballista_tpu.shuffle.writer import ShuffleWriterExec
+
+    table = pa.table({"k": list(range(2000)), "v": [float(i) for i in range(2000)]})
+    # batch i goes to partition i % 2: ten batches of 100 rows a partition
+    scan = MemoryScanExec(DFSchema.from_arrow(table.schema),
+                          table.to_batches(max_chunksize=100), partitions=2)
+    plan = ShuffleWriterExec(scan, "job-m", 1, 4, [Column("k")])
+    ex = Executor(str(tmp_path), ExecutorMetadata(id="ex-m"))
+    stats_before = {t for t in RUN_STATS.stages()}
+    results = []
+    for task_id, partition in ((1, 0), (2, 1)):
+        task = TaskDescription(job_id="job-m", stage_id=1, stage_attempt=0, task_id=task_id,
+                               partitions=[partition], plan=plan, session_id="s")
+        results.append(ex.execute_task(task, BallistaConfig()))
+    assert [r.state for r in results] == ["success", "success"]
+    for r in results:  # each task saw its own 1,000 rows, not the plan's 2,000
+        scan_m = r.metrics[1]
+        assert scan_m["name"].startswith("MemoryScanExec") and scan_m["output_rows"] == 1000
+        assert scan_m["output_batches"] == 10 and scan_m["self_ns"] == scan_m["elapsed_ns"]
+    assert scan.metrics.output_rows == 2000  # the shared operator still adds up
+    writes = [s for s in RUN_STATS.job_spans("job-m") if s.name == "bt.shuffle.write"]
+    assert len(writes) == 2 and stats_before == set(RUN_STATS.stages())
+    for s in writes:
+        assert s.attrs["ops"] == [[1, "MemoryScanExec", pytest.approx(s.attrs["ops_ms"]),
+                                   1000, 10, ""]]
+    RUN_STATS.take_job_spans("job-m")
+
+
+@pytest.mark.parametrize("mode", ["local", "standalone"])
+def test_explain_analyze_prints_an_operators_own_time_beside_the_inclusive(mode, tpch_dir):
+    """`self_ms` is `elapsed_ms` less the operator's inputs: what the write
+    span's `ops` carry, printed where a user reads operator times."""
+    import re
+
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
+    from ballista_tpu.testing.tpchgen import register_tpch
+
+    config = BallistaConfig({EXECUTOR_ENGINE: "cpu"})
+    ctx = SessionContext.standalone(config, num_executors=1) if mode == "standalone" \
+        else SessionContext(config)
+    try:
+        register_tpch(ctx, tpch_dir)
+        out = ctx.sql("explain analyze " + tpch_query(6)).collect()
+        plans = dict(zip(out.column("plan_type").to_pylist(), out.column("plan").to_pylist()))
+        body = next(v for k, v in plans.items() if k.startswith("analyzed_plan"))
+        pairs = re.findall(r"elapsed_ms=([0-9.]+) self_ms=([0-9.]+)", body)
+        assert len(pairs) == len(body.strip().splitlines()) - body.count("stage ")
+        assert all(float(own) <= float(whole) + 0.01 for whole, own in pairs), body
+        scan = next(l for l in body.splitlines() if "ParquetScanExec" in l)
+        whole, own = re.search(r"elapsed_ms=([0-9.]+) self_ms=([0-9.]+)", scan).groups()
+        assert whole == own and float(own) > 0  # a leaf's time is its own
+    finally:
+        ctx.shutdown()
