@@ -375,7 +375,8 @@ class ExecutorProcess:
                     "hbm_reupload_events", "grace_splits", "hbm_oom_retries",
                     "sort_kernel_s", "sort_invocations",
                     "topk_rows_kept", "window_invocations",
-                    "window_partitions", "sort_full_materializations",
+                    "window_partitions", "window_fused_frames",
+                    "sort_full_materializations",
                     "delta_fill_rows",
                     "daemon_attached", "init_platform_probe_s",
                     "init_jax_devices_s", "init_first_compile_s"):

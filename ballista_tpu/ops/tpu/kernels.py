@@ -169,6 +169,29 @@ def lex_order(keys):
     return perm
 
 
+def lex_order_sorted(keys):
+    """`lex_order`'s permutation, and the keys' most significant 32-bit lane
+    in that order: the same LSD radix passes, the last one (over that lane)
+    taken out of the loop so its sorted first output, which `lex_order`
+    discards, comes back. A boundary over a one-lane leading key then costs
+    no gather. Returns (perm int32 [M], sorted lane int32 [M])."""
+    import jax
+
+    jnp = _jnp()
+    lanes = [lane for k in keys for lane in _order_lanes(k)]
+    M = lanes[0].shape[0]
+
+    def radix_pass(perm, lane):
+        _, perm = jax.lax.sort((lane[perm], perm), num_keys=1, is_stable=True)
+        return perm, None
+
+    perm = jnp.arange(M, dtype=jnp.int32)
+    if len(lanes) > 1:
+        perm, _ = jax.lax.scan(radix_pass, perm, jnp.stack(lanes[:0:-1]))
+    top, perm = jax.lax.sort((lanes[0][perm], perm), num_keys=1, is_stable=True)
+    return perm, top
+
+
 # rows this short are summed as a masked triangle, not scanned (int_cumsum)
 CUMSUM_TRIANGLE = 64
 

@@ -4,21 +4,34 @@
 TpuSortStageExec / TpuWindowStageExec (`ballista.tpu.sort.enabled`). The
 split of labor is the parity contract:
 
-- The HOST evaluates the sort-key expressions with the exact same
-  `bind_expr`/`evaluate_to_array` calls the CPU oracle sorts, encodes them
-  to order-preserving int64 lanes (ints/dates widened, floats bit-twiddled,
-  strings as lexicographic-rank dictionary codes, NULLS FIRST/LAST as a
-  leading null-rank operand), and applies the resulting PERMUTATION with
+- ORDER BY: the HOST evaluates the sort-key expressions with the exact
+  same `bind_expr`/`evaluate_to_array` calls the CPU oracle sorts, encodes
+  them to order-preserving int64 lanes (`_encode_key_arrays`: ints/dates
+  widened, floats bit-twiddled, strings as lexicographic-rank dictionary
+  codes, NULLS FIRST/LAST as a leading null-rank operand), the DEVICE
+  computes the permutation (`kernels.lex_order`: stable LSD radix passes
+  over the keys' 32-bit lanes) and the host applies it with
   `pa.Table.take` — payload columns never leave the host, so the output
-  bytes are the CPU engine's bytes by construction.
-- The DEVICE computes only the permutation (and, for windows, the
-  segmented scans): `kernels.lex_order` (stable LSD radix passes over the
-  keys' 32-bit lanes) and `kernels.segmented_scan` (blocked: seconds to
-  compile at 2^24 lanes), both jitted at power-of-two lane counts so one
-  compilation serves every partition of a bucket. Key lanes are uploaded
-  every query; nothing of the family stays resident. ORDER BY ... LIMIT is the full order, sliced. An ineligible
-  shape raises Unsupported and the operator falls back to the CPU oracle
-  over the SAME materialized input (never re-executing the child).
+  bytes are the CPU engine's bytes by construction. ORDER BY ... LIMIT is
+  the full order, sliced.
+- Windows: a window frame (one PARTITION BY / ORDER BY) is ONE device
+  program a task (`_frame_jit`, `window_segscan_<functions>`). The host
+  evaluates the key expressions and hands over zero-copy views of their
+  values (`_key_operand`: a float's raw bits, an int32 / int64 as stored;
+  only dictionary ranks, decimals, dates, bools and narrow ints come as
+  `_order_lane`'s host-built lane). The device encodes the lanes by
+  `_encode_key_arrays`' rules (`_device_lane`), orders them
+  (`kernels.lex_order_sorted`), finds the partition and peer boundaries
+  over the sorted lanes, scans `row_number` / `rank`
+  (`kernels.segmented_scan`, blocked: seconds to compile at 2^24 lanes)
+  and scatters them back to input order: one int32 lane a function comes
+  back, and the host builds the Arrow array.
+
+Both are jitted at power-of-two lane counts so one compilation serves
+every partition of a bucket; nothing of the family stays resident. An
+ineligible shape raises Unsupported and the operator falls back to the
+CPU oracle over the SAME materialized input (never re-executing the
+child).
 
 Order-preserving int64 encoding per key kind:
 
@@ -36,12 +49,11 @@ Order-preserving int64 encoding per key kind:
                              complement trick below keeps nulls ahead;
                              always sorted ascending
 
-Window aggregates keep the CPU oracle's skeleton (ops/cpu/window.py):
-boundary flags and peer-last sharing are computed with the oracle's own
-`_changes`/`_peer_last` over the device permutation, the per-segment
-cumulative state runs as device segmented scans, and the oracle's
-`_emit_agg`/`_decimal_prepare` build the output arrays — so NULL masks,
-decimal reconstruction, and NaN peer-splitting are shared code, not
+Window aggregates take their frame from the same program (the
+permutation and both boundary planes come back with it), run their
+per-segment cumulative state as device segmented scans of their own, and
+keep the oracle's `_peer_last`/`_emit_agg`/`_decimal_prepare` — so NULL
+masks, decimal reconstruction, and peer sharing are shared code, not
 reimplementations.
 """
 
@@ -52,6 +64,7 @@ import functools
 import logging
 import threading
 import zlib
+from dataclasses import dataclass
 from typing import Iterator, Optional
 
 import numpy as np
@@ -99,6 +112,7 @@ _COUNTERS = {
     "topk_rows_kept": 0,
     "window_partitions": 0,
     "sort_full_materializations": 0,
+    "window_fused_frames": 0,
 }
 _KERNEL_S = [0.0]
 
@@ -122,6 +136,7 @@ def _publish_counters() -> None:
     RUN_STATS.set("window_partitions", snap["window_partitions"])
     RUN_STATS.set("sort_full_materializations",
                   snap["sort_full_materializations"])
+    RUN_STATS.set("window_fused_frames", snap["window_fused_frames"])
 
 
 def _note_kernel_s(dt: float) -> None:
@@ -261,14 +276,14 @@ _CALLED: set = set()  # (kernel, lanes, operand dtypes) dispatched once in this 
 
 
 @contextlib.contextmanager
-def _device_call(kernel: str, n: int, lanes: int, operands: list):
+def _device_call(kernel: str, n: int, lanes: int, operands: list, spec: tuple = ()):
     """The span of one synced device call of the family — pad, upload, the
     jitted program, the result's fetch: the host blocked on the device.
-    `bt.device.exec`; the first call of a program at a shape compiles it (or
-    loads the persistent cache's binary) inside, and is named
-    `bt.compile.xla` as a stage's is, its seconds added to the task's
-    `xla_compile_s`."""
-    key = (kernel, lanes, tuple(str(a.dtype) for a in operands))
+    `bt.device.exec`; the first call of a program at a shape (and `spec`,
+    what else the program was built for) compiles it (or loads the
+    persistent cache's binary) inside, and is named `bt.compile.xla` as a
+    stage's is, its seconds added to the task's `xla_compile_s`."""
+    key = (kernel, lanes, tuple(str(a.dtype) for a in operands), spec)
     with _CTR_LOCK:
         first = key not in _CALLED
         _CALLED.add(key)
@@ -288,7 +303,8 @@ class _Uploads:
     def __init__(self):
         self.bytes = 0
         self.lanes = 0  # padded lanes ordered (`window_lanes`)
-        self.scans = 0  # segmented scans dispatched (`window_scans`)
+        self.frames = 0  # frames built by one program (`window_frames_fused`)
+        self.scans = 0  # separate segmented scans dispatched (`window_scans`)
         self.segments = 0  # window partitions found (`window_segments`)
 
     def put(self, arr: np.ndarray):
@@ -524,50 +540,204 @@ class TpuSortStageExec(ExecutionPlan):
 # window aggregates
 
 
+@dataclass
+class _DeviceFrame:
+    """What one frame program hands back: each ranking function's values in
+    input order (int32), and for the aggregates the sorted row order and
+    its two boundary planes (None where no aggregate shares the frame)."""
+
+    ranked: dict
+    idx: Optional[np.ndarray] = None
+    new_part: Optional[np.ndarray] = None
+    new_peer: Optional[np.ndarray] = None
+
+
+def _values_view(arr: pa.Array, dtype) -> np.ndarray:
+    """The values buffer of a fixed-width Arrow array, zero-copy (null slots
+    hold whatever the buffer holds there)."""
+    buf = arr.buffers()[1]
+    return np.frombuffer(buf, dtype=dtype, count=arr.offset + len(arr))[arr.offset:]
+
+
+def _key_operand(arr) -> tuple:
+    """One evaluated window key as the frame program takes it: (src, values,
+    valid bool[n] | None, kind for the estimate). `f64`: a float key's raw
+    bits, an int64 view of its values; `int`: an int32 / int64 key's own
+    values; `host`: the order-preserving lane `_order_lane` builds for the
+    kinds whose order needs the host (dictionary ranks, decimals, dates,
+    bools, narrow ints), taken as ordered. The plane rides only where the
+    column has nulls."""
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.combine_chunks()
+    t = arr.type
+    if pa.types.is_floating(t) or t in (pa.int32(), pa.int64()):
+        valid = arr.is_valid().to_numpy(zero_copy_only=False) if arr.null_count else None
+        if pa.types.is_floating(t):
+            if not pa.types.is_float64(t):
+                arr = arr.cast(pa.float64())
+            return "f64", _values_view(arr, np.int64), valid, "f64"
+        return "int", _values_view(arr, t.to_pandas_dtype()), valid, "i64"
+    lane, valid, _, kind = _order_lane(arr)
+    if len(lane) and _I32_MIN <= lane.min() and lane.max() <= _I32_MAX:
+        lane = lane.astype(np.int32)
+    return "host", lane, valid, kind
+
+
+def _device_lane(x, src: str, asc: bool):
+    """A key's order-preserving lane, on the device, by `_encode_key_arrays`'
+    rules in integer operations alone (the chip emulates float64 inexactly):
+    a float's bits have -0.0 folded into +0.0 and the sign folded
+    (b >= 0 → b, else ~b | sign bit), DESC is the NOT of the lane, and NaN
+    takes INT64_MAX - 1 after the flip (pyarrow places NaN last in both
+    directions). Integers and host lanes are ordered already."""
+    jnp = ensure_jax().numpy
+    lane = x
+    if src == "f64":
+        nan = (x & jnp.int64(_I64_MAX)) > jnp.int64(0x7FF0000000000000)
+        b = jnp.where(x == jnp.int64(_I64_MIN), jnp.int64(0), x)
+        lane = jnp.where(b >= 0, b, ~b | jnp.int64(_I64_MIN))
+    if not asc:
+        lane = ~lane
+    if src == "f64":
+        lane = jnp.where(nan, jnp.int64(_I64_MAX - 1), lane)
+    return lane
+
+
+@functools.lru_cache(maxsize=32)
+def _frame_jit(keys: tuple, ranking: tuple, frame: bool, L: int):
+    """The one device program of a window frame at L lanes. `keys` is
+    ((src, ascending, nulls_first, nullable, partition key)) in sort order;
+    the program takes (n, then each key's values and, where nullable, its
+    validity plane) and, in order: encodes the lanes (`_device_lane`; a
+    null's lane is 0, its null rank a leading operand; lanes past n take
+    their dtype's largest value), orders them (`kernels.lex_order_sorted`),
+    finds the partition starts over the sorted partition keys and, where
+    `rank` or an aggregate needs them, the peer starts (`_changes`' rule:
+    nulls equal, NaN never), scans each function in `ranking` in sorted
+    order (`kernels.segmented_scan`) and scatters it back to input order.
+    Returns (the ranking results, int32 [L] each; the partitions found),
+    and with `frame` the permutation and both boundary planes, for the
+    aggregates' value scans. Named `window_segscan_<functions>`."""
+    jax = ensure_jax()
+    jnp = jax.numpy
+    from ballista_tpu.ops.tpu.kernels import _order_lanes, lex_order_sorted, segmented_scan
+
+    def window_segscan(n, *cols):
+        iota = jnp.arange(L, dtype=jnp.int32)
+        live = iota < n
+        ops: list = []  # (operand, key index, float lane)
+        it = iter(cols)
+        for k, (src, asc, nulls_first, nullable, _) in enumerate(keys):
+            lane = _device_lane(next(it), src, asc)
+            if nullable:
+                valid = next(it)
+                nrank = (valid if nulls_first else ~valid).astype(jnp.int32)
+                ops.append((jnp.where(live, nrank, _I32_MAX), k, False))
+                lane = jnp.where(valid, lane, jnp.zeros((), lane.dtype))
+            ops.append((jnp.where(live, lane, jnp.iinfo(lane.dtype).max), k, src == "f64"))
+        if ops:
+            perm, top = lex_order_sorted([op for op, _, _ in ops])
+        else:
+            perm, top = iota, None
+        first = iota == 0
+
+        def starts(which):
+            flag = first
+            at = 0
+            for op, k, is_f in ops:
+                lanes = _order_lanes(op)
+                if k in which:
+                    s = [top if at + j == 0 else lane[perm] for j, lane in enumerate(lanes)]
+                    for lane in s:
+                        flag = flag | jnp.concatenate([first[:1], lane[1:] != lane[:-1]])
+                    if is_f:  # INT64_MAX - 1 as its two lanes: a NaN is a group of its own
+                        flag = flag | ((s[0] == _I32_MAX) & (s[1] == _I32_MAX - 1))
+                at += len(lanes)
+            return flag
+
+        part = {k for k, key in enumerate(keys) if key[4]}
+        new_part = starts(part)
+        new_peer = None
+        if frame or "rank" in ranking:
+            new_peer = new_part | starts(set(range(len(keys))) - part)
+        out = []
+        if ranking:
+            rn = segmented_scan(jnp.ones((L,), jnp.int32), new_part, "sum")
+        for func in ranking:
+            r = rn
+            if func == "rank":  # peer start - segment start + 1
+                r = segmented_scan(jnp.where(new_peer, iota, _I32_MIN), new_part, "max") - iota + rn
+            out.append(jnp.zeros((L,), jnp.int32).at[perm].set(r, unique_indices=True))
+        found = jnp.sum(new_part & live, dtype=jnp.int32)
+        if frame:
+            return tuple(out), found, perm, new_part, new_peer
+        return tuple(out), found
+
+    name = "window_segscan_" + "_".join(ranking or ("frame",))
+    window_segscan.__name__ = window_segscan.__qualname__ = name
+    return jax.jit(window_segscan)
+
+
+def _pad_zero(a: np.ndarray, L: int) -> np.ndarray:
+    """`a` padded to L lanes with zeros (the program masks lanes past n)."""
+    if len(a) == L:
+        return np.ascontiguousarray(a)
+    out = np.empty(L, dtype=a.dtype)
+    out[: len(a)] = a
+    out[len(a):] = 0
+    return out
+
+
 def _device_frame(batch: pa.RecordBatch, w: WindowFunction, schema: DFSchema,
-                  config: BallistaConfig, window_funcs: int, up: "_Uploads"):
-    """The oracle's _Frame, with the sort permutation computed on device.
-    Boundary flags reuse the oracle's `_changes` (nulls equal, NaN splits
-    peers) so peer semantics cannot drift."""
-    from ballista_tpu.ops.cpu.window import _Frame, _changes, _first_only
+                  config: BallistaConfig, funcs: list, up: "_Uploads") -> _DeviceFrame:
+    """A window frame (one PARTITION BY / ORDER BY) and the ranking functions
+    over it, in ONE device call: the host evaluates the keys and hands over
+    zero-copy views of their values (`_key_operand`), the frame program
+    (`_frame_jit`) does the rest, and one int32 lane of results comes back
+    a ranking function; the permutation and the boundary planes only where
+    an aggregate shares the frame."""
     from ballista_tpu.ops.tpu import fusion
+    jax = ensure_jax()
     n = batch.num_rows
     with RUN_STATS.span("bt.window.keys", rows=n) as span:
-        part_arrays = [evaluate_to_array(bind_expr(e, schema), batch)
-                       for e in w.partition_by]
-        order_arrays = [evaluate_to_array(bind_expr(k.expr, schema), batch)
-                        for k in w.order_by]
-        arrays = part_arrays + order_arrays
-        orders = [(True, False)] * len(part_arrays) + [
-            (k.ascending, k.nulls_first) for k in w.order_by
-        ]
-        key_ops, key_meta = _encode_key_arrays(arrays, orders)
-        span.set(key_lanes=sum(1 + (nrank is not None) for nrank, _ in key_ops))
+        part = [_key_operand(evaluate_to_array(bind_expr(e, schema), batch))
+                for e in w.partition_by]
+        order = [_key_operand(evaluate_to_array(bind_expr(k.expr, schema), batch))
+                 for k in w.order_by]
+        span.set(key_lanes=sum(1 + (valid is not None) for _, _, valid, _ in part + order))
+    key_meta = [(kind, valid is not None) for _, _, valid, kind in part + order]
     _admit(fusion.estimate_sort_stage(n, key_meta or [("i64", False)],
-                                      window_funcs=max(window_funcs, 1)),
+                                      window_funcs=max(len(funcs), 1)),
            config)
 
-    if key_ops:
-        idx = _perm_full(key_ops, n, up).astype(np.int64)
-    else:
-        idx = np.arange(n, dtype=np.int64)
-
-    with RUN_STATS.span("bt.window.emit", rows=n) as span:
-        inv = np.empty(n, dtype=np.int64)
-        inv[idx] = np.arange(n, dtype=np.int64)
-        new_part = _changes(part_arrays, idx) if part_arrays else _first_only(n)
-        new_peer = new_part | (_changes(order_arrays, idx) if order_arrays
-                               else np.zeros(n, bool))
-        arange = np.arange(n, dtype=np.int64)
-        seg_start = np.maximum.accumulate(np.where(new_part, arange, 0))
-        starts = np.flatnonzero(new_part)
-        ends = np.r_[starts[1:] - 1, n - 1] if len(starts) else np.array([], np.int64)
-        counts = ends - starts + 1 if len(starts) else np.array([], np.int64)
-        seg_end = np.repeat(ends, counts) if len(starts) else np.zeros(n, np.int64)
-        span.set(partitions=int(len(starts)))
-    _count("window_partitions", int(len(starts)))
-    up.segments += int(len(starts))
-    return _Frame(idx, inv, new_part, new_peer, seg_start, seg_end)
+    orders = [(True, False, True)] * len(part) + [
+        (k.ascending, k.nulls_first, False) for k in w.order_by]
+    keys = tuple((src, asc, nf, valid is not None, is_part)
+                 for (src, _, valid, _), (asc, nf, is_part) in zip(part + order, orders))
+    ranking = tuple(f for f in ("row_number", "rank") if f in funcs)
+    frame = any(f not in ranking for f in funcs)
+    operands = [a for _, values, valid, _ in part + order
+                for a in ((values,) if valid is None else (values, valid))]
+    L = _pow2(n)
+    program = _frame_jit(keys, ranking, frame, L)
+    before = up.bytes
+    with _device_call(program.__name__[len("window_"):], n, L, operands,
+                      spec=(keys, ranking, frame)) as span:
+        flat = [up.put(_pad_zero(a, L)) for a in operands]
+        got = jax.device_get(program(np.int32(n), *flat))
+        up.bytes += L * (4 * len(ranking) + (4 + 1 + 1 if frame else 0))
+        found = int(got[1])
+        span.set(bytes=up.bytes - before, partitions=found)
+    if keys:
+        up.lanes += L
+    up.frames += 1
+    up.segments += found
+    _count("window_partitions", found)
+    _count("window_fused_frames")
+    ranked = {f: np.asarray(r)[:n] for f, r in zip(ranking, got[0])}
+    if not frame:
+        return _DeviceFrame(ranked)
+    return _DeviceFrame(ranked, *(np.asarray(a)[:n] for a in got[2:]))
 
 
 def _seg_scan(vals: np.ndarray, boundary: np.ndarray, func: str,
@@ -593,32 +763,21 @@ def _seg_scan(vals: np.ndarray, boundary: np.ndarray, func: str,
 
 
 def _device_compute_one(batch: pa.RecordBatch, w: WindowFunction,
-                        schema: DFSchema, fr, up: _Uploads) -> pa.Array:
-    """One window expression over a shared frame: device segmented scans
-    inside the oracle's gather/scatter/emit skeleton."""
+                        schema: DFSchema, fr: _DeviceFrame, up: _Uploads) -> pa.Array:
+    """One window expression over its frame: a ranking function's values
+    are the frame program's, built into Arrow; an aggregate's value scans
+    run on the device inside the oracle's gather/scatter/emit skeleton."""
     from ballista_tpu.ops.cpu.window import _decimal_prepare, _emit_agg, _peer_last
     n = batch.num_rows
     out_type = w.data_type(schema)
     if n == 0:
         return pa.array([], out_type)
     with RUN_STATS.span("bt.window.emit", rows=n, func=w.func):
-        boundary = fr.new_part.copy()
-        boundary[0] = True
-        arange = np.arange(n, dtype=np.int64)
-
-        if w.func == "row_number":
-            out_sorted = _seg_scan(np.ones(n, np.int64), boundary, "sum", up)
-        elif w.func == "rank":
-            marked = np.where(fr.new_peer, arange, np.int64(_I64_MIN))
-            peer_start = _seg_scan(marked, boundary, "max", up)
-            out_sorted = peer_start - fr.seg_start + 1
-        else:
-            return _emit_scan_agg(batch, w, schema, fr, boundary, up,
-                                  out_type, _decimal_prepare, _emit_agg,
-                                  _peer_last, n)
-        out = np.empty(n, dtype=np.int64)
-        out[fr.idx] = out_sorted
-        return pa.array(out, out_type)
+        if w.func in fr.ranked:
+            return pa.array(fr.ranked[w.func]).cast(out_type)
+        return _emit_scan_agg(batch, w, schema, fr, fr.new_part, up,
+                              out_type, _decimal_prepare, _emit_agg,
+                              _peer_last, n)
 
 
 def _emit_scan_agg(batch, w, schema, fr, boundary, up, out_type,
@@ -700,14 +859,14 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
         raise BelowRowFloor(n)
     if not window_static_ok(window_exprs, schema):
         raise Unsupported("window shape not device-eligible")
-    groups: dict[tuple, int] = {}
+    groups: dict[tuple, list] = {}
     for w in window_exprs:
         key = (tuple(str(e) for e in w.partition_by),
                tuple(str(k) for k in w.order_by))
-        groups[key] = groups.get(key, 0) + 1
-    frames: dict[tuple, object] = {}
+        groups.setdefault(key, []).append(w.func)
+    frames: dict[tuple, _DeviceFrame] = {}
     out = []
-    up = _Uploads()  # stage-total device bytes: sorts + scans (fill test)
+    up = _Uploads()  # stage-total device bytes: frames + scans (fill test)
     for w in window_exprs:
         key = (tuple(str(e) for e in w.partition_by),
                tuple(str(k) for k in w.order_by))
@@ -719,15 +878,18 @@ def _device_windows(batch: pa.RecordBatch, window_exprs: list,
     RUN_STATS.set("window_rows", n)
     RUN_STATS.set("window_lanes", up.lanes)
     RUN_STATS.set("window_segments", up.segments)
+    RUN_STATS.set("window_frames_fused", up.frames)
     RUN_STATS.set("window_scans", up.scans)
     _count("window_invocations")
     return out
 
 
 class TpuWindowStageExec(ExecutionPlan):
-    """WindowExec on the device: sort permutation + segmented scans on
-    device, boundary/emit logic shared with the CPU oracle. Ineligible
-    shapes run `compute_windows` over the SAME materialized batch."""
+    """WindowExec on the device: a frame program a PARTITION BY / ORDER BY
+    (order, boundaries, ranking scans, scatter back), the aggregates'
+    value scans beside it, their emit logic shared with the CPU oracle.
+    Ineligible shapes run `compute_windows` over the SAME materialized
+    batch."""
 
     own_span = True  # `bt.stage.dispatch` and the spans inside it
 

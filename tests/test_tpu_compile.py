@@ -301,6 +301,9 @@ SORT_WINDOW_CASES = [
     ("window_segscan_max", 0, 23, 60),
     ("window_segscan_sum", 0, 24, 60), ("window_segscan_min", 0, 24, 60),
     ("window_segscan_max", 0, 24, 60),
+    # h2o q8's whole window frame in one program (PR 37): encode, order, boundaries,
+    # the row_number scan and the scatter back; 30 s on this host at 2^23
+    ("window_segscan_row_number", 2, 23, 300),
 ]
 
 
@@ -308,13 +311,20 @@ SORT_WINDOW_CASES = [
 def test_sort_window_programs_compile_for_v5e(name, key_lanes, log2_lanes, max_s, one_chip):
     """What `TpuSortStageExec` / `TpuWindowStageExec` dispatch: the ordering
     permutation over 1, 2 and 4 key lanes (an int32 lane, a nullable one's
-    null rank before it, int64 lanes) and the three segmented scans."""
+    null rank before it, int64 lanes), the three segmented scans and a
+    window frame's one program."""
     import jax.numpy as jnp
 
     from ballista_tpu.ops.tpu import sort_window as sw
 
     L = 1 << log2_lanes
-    if key_lanes:
+    if name == "window_segscan_row_number":
+        # PARTITION BY an int32 key, ORDER BY a float64 one DESC (its raw bits)
+        keys = (("int", True, False, False, True), ("f64", False, False, False, False))
+        fn = sw._frame_jit(keys, ("row_number",), False, L)
+        specs = [_spec(one_chip, (), jnp.int32), _spec(one_chip, (L,), jnp.int32),
+                 _spec(one_chip, (L,), jnp.int64)]
+    elif key_lanes:
         dtypes = [jnp.int32, jnp.int32, jnp.int64, jnp.int64][:key_lanes]
         fn, specs = sw._lex_order_jit(), [_spec(one_chip, (L,), d) for d in dtypes]
     else:

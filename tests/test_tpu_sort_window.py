@@ -174,49 +174,130 @@ def _window_schema(tbl, wexprs, schema):
            for j, w in enumerate(wexprs)]))
 
 
+def _float_extremes(rng, n, domain=4):
+    """Floats from a small domain (ties) salted with what an order-preserving
+    image must get right: ±0.0, NaNs of several payloads, ±inf, subnormals."""
+    f = rng.integers(-domain, domain, n).astype(np.float64)
+    salt = np.array([0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, 1e-310, -1e-310])
+    f[::5] = salt[rng.integers(0, len(salt), len(f[::5]))]
+    bits = f.view(np.int64)
+    nan_at = np.arange(3, n, 7)
+    # quiet and signalling payloads, both signs: NaN never equals NaN
+    payloads = np.array([0x7FF8000000000000, 0x7FF0000000000001, 0xFFF8000000000123,
+                         0x7FFFFFFFFFFFFFFF], dtype=np.uint64).view(np.int64)
+    bits[nan_at] = payloads[np.arange(len(nan_at)) % len(payloads)]
+    return f
+
+
+def _nullable(rng, values, every):
+    return pa.array([None if j % every == 0 else v for j, v in enumerate(values.tolist())])
+
+
+def _window_case(shape, rng):
+    """(table, the device's table, PARTITION BY, ORDER BY, aggregate args) of
+    one adversarial shape; the device's table differs only where the CPU
+    oracle cannot sort the column (a dictionary key: it gets the decoded)."""
+    n = {"n_1": 1, "n_2047": 2047, "n_2048": 2048, "n_2049": 2049, "n_3001": 3001}.get(shape, 384)
+    vnull = pa.array([None if j % 4 == 0 else int(v)
+                      for j, v in enumerate(rng.integers(-50, 50, n))], pa.int64())
+    fx = pa.array(_float_extremes(rng, n))
+    # the aggregates' float argument: the extremes with one NaN payload and
+    # no -0.0. A min / max scan carries the order-preserving image, in which
+    # -0.0 < +0.0 and a NaN has no payload, where the oracle's
+    # np.minimum.accumulate keeps the first NaN's payload and lets ±0.0 tie
+    # (so does the parent: the aggregates' scans are not the frame's)
+    fm = pa.array(np.where(np.isnan(fx.to_numpy()), np.nan, fx.to_numpy() + 0.0))
+    cols = {"g": pa.array(rng.integers(0, 8, n), pa.int64()),
+            "o": pa.array(rng.integers(0, 3, n), pa.int64()),
+            "vnull": vnull, "fx": fx, "fm": fm}
+    over = ([Column("g")], [SortKey(Column("o"))])
+    if shape == "float_keys":
+        # a float PARTITION BY and a float ORDER BY, both from the extremes
+        cols["pf"] = pa.array(_float_extremes(rng, n, domain=3))
+        over = ([Column("pf")], [SortKey(Column("fx"), ascending=False)])
+    elif shape.startswith("nulls_"):
+        _, direction, placement = shape.split("_")
+        cols["pnull"] = _nullable(rng, rng.integers(0, 5, n).astype(np.int32), 6).cast(pa.int32())
+        cols["onull"] = _nullable(rng, _float_extremes(rng, n, domain=2), 5).cast(pa.float64())
+        over = ([Column("pnull")], [SortKey(Column("onull"), ascending=direction == "asc",
+                                            nulls_first=placement == "first")])
+    elif shape == "int64_high_tie":
+        # every key shares one of three high words; the low words decide
+        # (across the low word's sign bit too), so the boundary needs them
+        hi = np.array([7, -3, 0], dtype=np.int64)[rng.integers(0, 3, n)] << 32
+        lo = np.array([0, 1, 5, 0x7FFFFFFF, 0x80000000, 0xFFFFFFFF])[rng.integers(0, 6, n)]
+        cols["p64"] = pa.array(hi + lo, pa.int64())
+        over = ([Column("p64")], [SortKey(Column("o"), ascending=False)])
+    elif shape == "dict_dup":
+        codes = pa.array(rng.integers(0, 5, n), pa.int32())
+        cols["sd"] = pa.DictionaryArray.from_arrays(codes, pa.array(["b", "aa", "b", "c", "aa"]))
+        over = ([Column("sd")], [SortKey(Column("fx"))])
+    elif shape == "two_part":
+        cols["a"] = pa.array(rng.integers(0, 3, n), pa.int32())
+        cols["pf"] = pa.array(_float_extremes(rng, n, domain=2))
+        over = ([Column("a"), Column("pf")], [SortKey(Column("o")), SortKey(Column("g"))])
+    dev = pa.table(cols)
+    cpu = pa.table({c: (a.cast(pa.string()) if pa.types.is_dictionary(a.type) else a)
+                    for c, a in cols.items()})
+    return cpu, dev, over
+
+
+WINDOW_SHAPES = ["base", "float_keys", "nulls_asc_first", "nulls_asc_last", "nulls_desc_first",
+                 "nulls_desc_last", "int64_high_tie", "dict_dup", "two_part",
+                 "n_1", "n_2047", "n_2048", "n_2049", "n_3001"]
+
+
+@pytest.mark.parametrize("shape", WINDOW_SHAPES)
 @pytest.mark.parametrize(
     "func", ["row_number", "rank", "count", "sum", "min", "max", "all"])
-def test_window_parity_adversarial(func):
-    """row_number/rank/count/sum/min/max — each alone, and all six over
-    their shared frames — over partition+order with NaN order keys, nullable
-    agg args, and peer frames whose order values repeat ACROSS partition
-    boundaries (scan resets must isolate partitions)."""
+def test_window_parity_adversarial(func, shape):
+    """row_number/rank/count/sum/min/max — each alone, and all six over their
+    shared frames — through the frame program against the CPU oracle, value
+    for value and null for null: ties in both keys; ±0.0, NaN payloads, ±inf
+    and subnormals in a float ORDER BY and PARTITION BY; nulls in both keys
+    under ASC/DESC × NULLS FIRST/LAST; an int64 PARTITION BY whose high words
+    tie; a dictionary key with duplicate entries; two partition keys; peer
+    frames whose order values repeat ACROSS partition boundaries (scan resets
+    must isolate partitions); one row and the sizes around a block of the
+    scans and a power-of-two lane count."""
     from ballista_tpu.ops.tpu.sort_window import TpuWindowStageExec
 
-    rng = np.random.default_rng(23)
-    n = 384
-    f = rng.integers(-10, 10, n).astype(np.float64)
-    f[::9] = np.nan
-    # order values drawn from a tiny domain: every partition contains the
-    # same order values, so peer groups abut identically-valued rows in
-    # the neighbor partition — any boundary leak shows up in rank/sum
-    tbl = pa.table({
-        "g": pa.array(rng.integers(0, 8, n), pa.int64()),
-        "o": pa.array(rng.integers(0, 3, n), pa.int64()),
-        "f": pa.array(f),
-        "vnull": pa.array(
-            [None if j % 4 == 0 else int(v)
-             for j, v in enumerate(rng.integers(-50, 50, n))], pa.int64()),
-    })
-    schema = DFSchema.from_arrow(tbl.schema)
-    over = ([Column("g")], [SortKey(Column("o"))])
+    rng = np.random.default_rng([23, WINDOW_SHAPES.index(shape)])
+    cpu_tbl, dev_tbl, over = _window_case(shape, rng)
+    if shape == "base":
+        # the first shape: the min and the max over frames of their own
+        f = rng.integers(-10, 10, len(cpu_tbl)).astype(np.float64)
+        f[::9] = np.nan
+        cpu_tbl = dev_tbl = cpu_tbl.append_column("f", pa.array(f))
+        mn = WindowFunction("min", [Column("f")], [Column("g")],
+                            [SortKey(Column("f"), nulls_first=True)], None)
+        mx = WindowFunction("max", [Column("vnull")], [],
+                            [SortKey(Column("o"), ascending=False)], None)
+    else:
+        mn = WindowFunction("min", [Column("fm")], *over, None)
+        mx = WindowFunction("max", [Column("vnull")], *over, None)
     wexprs = [
         WindowFunction("row_number", [], *over, None),
         WindowFunction("rank", [], *over, None),
         WindowFunction("count", [Column("vnull")], *over, None),
         WindowFunction("sum", [Column("vnull")], *over, None),
-        WindowFunction("min", [Column("f")], [Column("g")],
-                       [SortKey(Column("f"), nulls_first=True)], None),
-        WindowFunction("max", [Column("vnull")], [],
-                       [SortKey(Column("o"), ascending=False)], None),
+        mn, mx,
     ]
     if func != "all":
         wexprs = [w for w in wexprs if w.func == func]
-    wschema = _window_schema(tbl, wexprs, schema)
     cfg = _cfg()
-    _assert_parity(WindowExec(_Src(tbl, schema), wexprs, wschema),
-                   TpuWindowStageExec(_Src(tbl, schema), wexprs, wschema, cfg),
-                   cfg)
+    cpu_schema, dev_schema = (DFSchema.from_arrow(t.schema) for t in (cpu_tbl, dev_tbl))
+    cpu_plan = WindowExec(_Src(cpu_tbl, cpu_schema), wexprs,
+                          _window_schema(cpu_tbl, wexprs, cpu_schema))
+    dev_plan = TpuWindowStageExec(_Src(dev_tbl, dev_schema), wexprs,
+                                  _window_schema(dev_tbl, wexprs, dev_schema), cfg)
+    if cpu_tbl is dev_tbl:
+        _assert_parity(cpu_plan, dev_plan, cfg)
+        return
+    cpu, dev = _collect(cpu_plan, cfg), _collect(dev_plan, cfg)
+    assert dev_plan.tpu_count >= 1 and dev_plan.fallback_count == 0
+    wins = [f"w{j}" for j in range(len(wexprs))]
+    assert _column_bytes(cpu.select(wins)) == _column_bytes(dev.select(wins))
 
 
 def test_window_empty_and_all_null_partitions():
@@ -406,6 +487,99 @@ def test_float_extremes_cross_the_device_as_ordered_int64():
     assert np.array_equal(v[real][np.argsort(enc[real], kind="stable")],
                           np.sort(v[real], kind="stable"))
     assert _f64_to_ordered(np.array([-0.0]))[0] < _f64_to_ordered(np.array([0.0]))[0]
+
+
+@pytest.mark.parametrize("ascending", [True, False])
+def test_the_devices_key_lanes_are_the_hosts_bit_for_bit(ascending):
+    """The window frame program encodes its keys on the device; the sort
+    family keeps the host encoder (`_encode_key_arrays`). On the same
+    extremes — ±0.0, subnormals, ±inf, NaNs of several payloads and signs,
+    int64's own extremes — both give the same lanes bit for bit, in both
+    directions, so the two cannot drift apart."""
+    from ballista_tpu.ops.tpu.runtime import ensure_jax
+    from ballista_tpu.ops.tpu.sort_window import _device_lane, _encode_key_arrays
+
+    jax = ensure_jax()
+    rng = np.random.default_rng(5)
+    v = np.concatenate([rng.normal(size=500) * 1e5,
+                        [0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, 1e-310, -1e-310,
+                         np.finfo(np.float64).max, -np.finfo(np.float64).max]])
+    nan_bits = np.array([0x7FF8000000000000, 0x7FF0000000000001, 0xFFF8000000000123,
+                         0x7FFFFFFFFFFFFFFF, 0xFFFFFFFFFFFFFFFF], dtype=np.uint64).view(np.int64)
+    v = np.concatenate([v, nan_bits.view(np.float64)])
+    i = np.array([-2**63, -2**63 + 1, -2**32, -1, 0, 1, 2**31, 2**63 - 2, 2**63 - 1],
+                 dtype=np.int64)
+    for values, src in ((v.view(np.int64), "f64"), (i, "int")):
+        arr = pa.array(values.view(np.float64) if src == "f64" else values)
+        (key_ops,), _ = _encode_key_arrays([arr], [(ascending, False)])
+        host = key_ops[1]
+        dev = np.asarray(jax.jit(lambda x, s=src: _device_lane(x, s, ascending))(values))
+        assert dev.dtype == np.int64 and np.array_equal(dev, host), src
+
+
+def test_a_row_number_frame_is_one_device_program():
+    """h2o q8's shape (PARTITION BY an int32 key, ORDER BY a float64 one DESC,
+    row_number): the task builds its frame in ONE program — one device call
+    (`bt.device.exec`, or `bt.compile.xla` on its first), `window_frames_fused`
+    1, `window_scans` 0 — whose jitted name is one the benchmark's
+    `window_roofline` reads device seconds under."""
+    import json
+    import pathlib
+
+    from ballista_tpu.ops.tpu import sort_window as sw
+    from ballista_tpu.tracing import RUN_STATS
+
+    rng = np.random.default_rng(37)
+    n = 3000
+    tbl = pa.table({"id6": pa.array(rng.integers(1, 60, n), pa.int32()),
+                    "v3": pa.array(np.round(rng.uniform(0, 100, n), 6))})
+    schema = DFSchema.from_arrow(tbl.schema)
+    wexprs = [WindowFunction("row_number", [], [Column("id6")],
+                             [SortKey(Column("v3"), ascending=False)], None)]
+    cfg = _cfg()
+    plan = sw.TpuWindowStageExec(_Src(tbl, schema), wexprs,
+                                 _window_schema(tbl, wexprs, schema), cfg)
+    RUN_STATS.clear()
+    RUN_STATS.take_job_spans("-")  # what earlier tests left outside any job
+    _collect(plan, cfg)
+    assert plan.tpu_count == 1 and plan.fallback_count == 0
+    spans = RUN_STATS.take_job_spans("-")["spans"]
+    (rec,) = [r for t, r in RUN_STATS.stages().items() if t.startswith("window_")]
+    assert rec["window_frames_fused"] == 1 and rec["window_scans"] == 0
+    assert rec["window_lanes"] == 4096 and rec["dispatches"] == 1
+    calls = [s for s in spans if s[0] in ("bt.device.exec", "bt.compile.xla")]
+    assert len(calls) == 1
+    kernel = calls[0][7]["kernel"]
+    assert calls[0][7]["lanes"] == 4096 and calls[0][7]["rows"] == n
+    # the program that ran, and the name its operations carry in a trace
+    keys = (("int", True, False, False, True), ("f64", False, False, False, False))
+    program = sw._frame_jit(keys, ("row_number",), False, 4096)
+    text = program.lower(np.int32(n), np.zeros(4096, np.int32),
+                         np.zeros(4096, np.int64)).as_text()
+    assert f"module @jit_window_{kernel} " in text
+    metric = pathlib.Path(__file__).parents[1] / "bench" / "metrics" / "window_roofline.json"
+    modules = json.loads(metric.read_text())["args"]["modules"]
+    assert any(f"jit_window_{kernel}".startswith(m) for m in modules), (kernel, modules)
+    assert sw.counters_snapshot()["window_fused_frames"] >= 1
+
+
+@pytest.mark.parametrize("dtypes", [("int32",), ("int64",), ("int32", "int32", "int64")])
+def test_lex_order_sorted_is_lex_orders_permutation_and_its_leading_lane(dtypes):
+    """`kernels.lex_order_sorted` (the window frame program's ordering) gives
+    `lex_order`'s permutation, ties and all, and the most significant 32-bit
+    lane in that order — one key, one 64-bit key (two lanes), three keys."""
+    from ballista_tpu.ops.tpu.kernels import _order_lanes, lex_order, lex_order_sorted
+    from ballista_tpu.ops.tpu.runtime import ensure_jax
+
+    jax = ensure_jax()
+    rng = np.random.default_rng(len(dtypes))
+    n = 3000
+    keys = [rng.integers(-3, 3, n).astype(d) << (33 if d == "int64" else 0) for d in dtypes]
+    perm, top = jax.jit(lambda *k: lex_order_sorted(list(k)))(*keys)
+    want = jax.jit(lambda *k: lex_order(list(k)))(*keys)
+    assert np.array_equal(np.asarray(perm), np.asarray(want))
+    lead = np.asarray(_order_lanes(jax.numpy.asarray(keys[0]))[0])
+    assert np.array_equal(np.asarray(top), lead[np.asarray(want)])
 
 
 # ---------------------------------------------------------------------------

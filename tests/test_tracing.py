@@ -364,24 +364,22 @@ def test_the_window_familys_spans_nest_under_its_dispatch(window_query):
         keys = [s for s in under if s[NAME] == "bt.window.keys"]
         emits = [s for s in under if s[NAME] == "bt.window.emit"]
         execs = [s for s in under if s[NAME] == "bt.device.exec"]
-        # two distinct orderings: keys, the ordering and the boundaries once each; three
-        # functions: an emit each, the scan's device call inside it
-        assert len(keys) == 2 and len(execs) == 2 and len(emits) == 2 + 3
+        # two distinct orderings: keys and ONE device call (the frame program: order,
+        # boundaries, the ranking function's scan and scatter) once each; three
+        # functions: an emit each, the aggregate's value scans inside its own
+        assert len(keys) == 2 and len(execs) == 2 and len(emits) == 3
         assert {s[NAME] for s in under} == {"bt.window.keys", "bt.window.emit", "bt.device.exec"}
         assert all(k[NUMBERS]["key_lanes"] == 2 and k[NUMBERS]["rows"] > 0 for k in keys)
+        assert sorted(e[NUMBERS]["kernel"] for e in execs) == ["segscan_rank", "segscan_row_number"]
         for e in execs:
             nums = e[NUMBERS]
-            assert nums["kernel"] == "lex_order" and nums["lanes"] >= nums["rows"] > 0
+            assert nums["lanes"] >= nums["rows"] > 0 and 0 < nums["partitions"] <= 40
             assert nums["lanes"] & (nums["lanes"] - 1) == 0 and nums["bytes"] >= 12 * nums["lanes"]
-        frames = [e for e in emits if "partitions" in e[NUMBERS]]
-        funcs = [e for e in emits if "func" in e[NUMBERS]]
-        assert len(frames) == 2 and all(0 < f[NUMBERS]["partitions"] <= 40 for f in frames)
-        assert sorted(f[NUMBERS]["func"] for f in funcs) == ["max", "rank", "row_number"]
+        assert sorted(e[NUMBERS]["func"] for e in emits) == ["max", "rank", "row_number"]
         scans = [s for s in spans if s[NAME] == "bt.device.exec"
-                 and s[PARENT] in {f[ID] for f in funcs}]
-        # row_number: a sum scan; max: the count's sum scan and the max scan; rank: a max scan
-        assert sorted(s[NUMBERS]["kernel"] for s in scans) == [
-            "segscan_max", "segscan_max", "segscan_sum", "segscan_sum"]
+                 and s[PARENT] in {e[ID] for e in emits}]
+        # max: the count's sum scan and the max scan; row_number and rank: none
+        assert sorted(s[NUMBERS]["kernel"] for s in scans) == ["segscan_max", "segscan_sum"]
         assert all(s[NUMBERS]["bytes"] == 9 * s[NUMBERS]["lanes"] for s in scans)
         rows += keys[0][NUMBERS]["rows"]
     assert rows == n
@@ -395,13 +393,16 @@ def test_a_window_task_leaves_a_record_of_its_own(window_query):
     assert sum(r["window_rows"] for r in records.values()) == n
     for rec in records.values():
         assert rec["dispatches"] == 1 and rec["exec_s"] > 0 and rec["device_bytes"] > 0
-        # two orderings of the task's rows, padded; 40 groups found by each; four scans
+        # two orderings of the task's rows, padded, each by a frame program; 40 groups
+        # found by each; the aggregate's two scans beside them
         assert rec["window_lanes"] >= 2 * rec["window_rows"]
-        assert 0 < rec["window_segments"] <= 2 * 40 and rec["window_scans"] == 4
+        assert 0 < rec["window_segments"] <= 2 * 40
+        assert rec["window_frames_fused"] == 2 and rec["window_scans"] == 2
         assert "xla_compile_s" not in rec  # every program had been called before
     # the first query's first calls were the compiles: named so, and counted on the record
     compiles = [s for r in cold.values() for s in r.get("spans", ()) if s[NAME] == "bt.compile.xla"]
-    assert {s[NUMBERS]["kernel"] for s in compiles} == {"lex_order", "segscan_sum", "segscan_max"}
+    assert {s[NUMBERS]["kernel"] for s in compiles} == {
+        "segscan_row_number", "segscan_rank", "segscan_sum", "segscan_max"}
     assert sum(r.get("xla_compile_s", 0) for t, r in cold.items() if t.startswith("window_")) > 0
 
 
@@ -496,7 +497,7 @@ def test_named_scopes_change_metadata_only(q, module, scopes, tpch_dir, monkeypa
 JITTED_STAGE_FAMILIES = (
     "stage_partial_direct_fused_xla", "stage_partial_sorted_fused_xla",
     "sort_lex_order", "window_segscan_sum", "window_segscan_min",
-    "window_segscan_max")
+    "window_segscan_max", "window_segscan_row_number")
 
 
 def test_every_jitted_stage_function_has_a_name_of_its_own():
@@ -511,6 +512,8 @@ def test_every_jitted_stage_function_has_a_name_of_its_own():
     for func in ("sum", "min", "max"):
         lowered[f"window_segscan_{func}"] = \
             sw._segscan_jit(func).lower(keys, keys > 3).as_text()
+    frame = sw._frame_jit((("int", True, False, False, True),), ("row_number",), False, 8)
+    lowered["window_segscan_row_number"] = frame.lower(jnp.int32(8), keys).as_text()
     assert set(lowered) == set(JITTED_STAGE_FAMILIES[2:])
     for name, text in lowered.items():
         assert f"module @jit_{name}" in text
