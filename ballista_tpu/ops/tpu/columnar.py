@@ -196,6 +196,17 @@ def encode_table(tbl: pa.Table, buckets: list[int]) -> Optional[DeviceBatch]:
     return DeviceBatch(n, cols, mask)
 
 
+def decode_codes(codes: np.ndarray, dictionary: list, null_mask: Optional[np.ndarray],
+                 type_: pa.DataType) -> pa.Array:
+    """Dictionary codes fetched from the device as an Arrow array of
+    `type_`: one take over the dictionary, nulls where `null_mask` is set
+    (their codes are never read), never a Python lookup a row."""
+    value_type = type_.value_type if pa.types.is_dictionary(type_) else type_
+    idx = pa.array(np.asarray(codes).astype(np.int32, copy=False), pa.int32(), mask=null_mask)
+    out = pa.array(dictionary or [], value_type).take(idx)
+    return out.cast(type_) if out.type != type_ else out
+
+
 def _pad(a: np.ndarray, n: int) -> np.ndarray:
     if len(a) == n:
         return a

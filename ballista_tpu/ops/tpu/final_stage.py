@@ -24,22 +24,24 @@ partials add, min/max partials re-reduce, NULL accumulators are skipped
 and an all-NULL group decodes to NULL.
 
 Fallback is runtime-adaptive like the partial path: unencodable inputs,
-welford triples, capacity overflow, or tiny inputs re-run the original
-CPU subtree; `match_final_stage` pre-lowers every expression at plan time
-with static kinds so stages that CANNOT lower are never wrapped (the
-device/fallback counters in EXPLAIN ANALYZE stay honest).
+welford triples, a working set past the HBM budget, or tiny inputs re-run
+the original CPU subtree; `match_final_stage` pre-lowers every expression
+at plan time with static kinds so stages that CANNOT lower are never
+wrapped (the device/fallback counters in EXPLAIN ANALYZE stay honest).
 """
 
 from __future__ import annotations
 
+import functools
 import threading
+import zlib
 from typing import Iterator
 
 import numpy as np
 import pyarrow as pa
 
 from ballista_tpu.config import TPU_MAX_DEVICE_BYTES, TPU_MIN_ROWS, BallistaConfig, _env_int
-from ballista_tpu.ops.tpu.columnar import encode_column, next_bucket
+from ballista_tpu.ops.tpu.columnar import decode_codes, encode_column, next_bucket
 from ballista_tpu.ops.tpu.stage_compiler import RUN_STATS, STAGE_OUTCOMES, LruDict
 from ballista_tpu.ops.tpu.kernels import (
     BelowRowFloor,
@@ -66,8 +68,6 @@ from ballista_tpu.plan.physical import (
     _concat,
     _empty_batch,
 )
-
-MAX_CAPACITY = 1 << 22
 
 # bounded: long-lived executors see one entry per (stage fingerprint, shape)
 # and would otherwise grow without limit (stage_compiler's LruDict is
@@ -237,9 +237,10 @@ class TpuFinalStageExec(ExecutionPlan):
         self._mat_input: tuple | None = None
         self._mat_node = None
         # fallback partitions already served off the materialized copy; once
-        # every expected partition has been read the copy is dropped (it can
-        # pin the stage's whole input on the host otherwise)
+        # every expected partition (`_mat_expected`) has been read the copy is
+        # dropped (it can pin the stage's whole input on the host otherwise)
         self._mat_served: set[int] = set()
+        self._mat_expected: set[int] = set()
         self._mat_released_merged = False
         # partitions served since the last (re-)dispatch — see
         # _note_served_locked for the re-run retention bound
@@ -290,7 +291,7 @@ class TpuFinalStageExec(ExecutionPlan):
                     self._mat_input = None
             if self._results is None:
                 try:
-                    self._results = self._device_run(ctx)
+                    self._results = self._device_run(ctx, partition)
                     self._mat_input = None  # success: release the host copy
                 except Unsupported as e:
                     logging.getLogger(__name__).info(
@@ -320,7 +321,7 @@ class TpuFinalStageExec(ExecutionPlan):
                         hbm.note_oom(self.fingerprint)
                         hbm.consume_oom_hint(self.fingerprint)  # no grace rung here
                         try:
-                            self._results = self._device_run(ctx)
+                            self._results = self._device_run(ctx, partition)
                             self._mat_input = None
                             _sc.RUN_STATS.set("hbm_oom_retries",
                                               hbm.oom_retry_count())
@@ -342,7 +343,7 @@ class TpuFinalStageExec(ExecutionPlan):
                 # partition); caches are hot, so re-running the device path
                 # costs ~one dispatch — never a host re-aggregation
                 try:
-                    self._results.update(self._device_run(ctx))
+                    self._results.update(self._device_run(ctx, partition))
                     self._mat_input = None
                     self._served_since_dispatch = set()
                     # serve WITHOUT popping: one re-dispatch covers all K
@@ -363,14 +364,19 @@ class TpuFinalStageExec(ExecutionPlan):
                 return out
         return self._fallback(partition, ctx)
 
-    def _device_run(self, ctx: TaskContext) -> dict[int, list[pa.RecordBatch]]:
-        """One local device dispatch of the whole stage on the task's bound
-        device, counted (call under _results_lock)."""
+    def _device_run(self, ctx: TaskContext, partition: int) -> dict[int, list[pa.RecordBatch]]:
+        """One local device dispatch on the task's bound device, counted
+        (call under _results_lock): of the whole stage, or of `partition`
+        alone where the input is a shuffle's (`_tpu_run_all`)."""
         from ballista_tpu.ops.tpu.runtime import device_scope
 
-        with device_scope(ctx.device_ordinal), \
+        # a stage record of its own (`final_<crc>`) that holds what the
+        # dispatch was sized by, and none of the keys the partial stages'
+        # counters sum (`dispatches`, `exec_s`): the spans time it
+        tag = f"final_{zlib.crc32(self.fingerprint.encode()):08x}"
+        with device_scope(ctx.device_ordinal), RUN_STATS.run(tag, counted=False), \
                 RUN_STATS.span("bt.stage.dispatch", family="final"):
-            out = self._tpu_run_all(ctx)
+            out = self._tpu_run_all(ctx, partition)
         STAGE_OUTCOMES.note("final", "device")
         self.tpu_count += 1
         self._device_ok = True
@@ -421,14 +427,13 @@ class TpuFinalStageExec(ExecutionPlan):
     def _note_mat_served(self, partition: int, merged: bool) -> None:
         """Drop the materialized child copy once the LAST expected fallback
         partition has been served: merged/coalesced stages only ever serve
-        partition 0; hash-placed stages serve every output partition."""
+        partition 0; hash-placed stages every output partition the copy
+        holds (one, where the task read its own alone)."""
         with self._results_lock:
             if self._mat_node is None:
                 return
             self._mat_served.add(partition)
-            expected = ({0} if (merged or self.coalesce)
-                        else set(range(self.output_partition_count())))
-            if self._mat_served >= expected:
+            if self._mat_served >= self._mat_expected:
                 self._mat_node = None
                 self._mat_served.clear()
                 self._mat_released_merged = merged
@@ -474,11 +479,13 @@ class TpuFinalStageExec(ExecutionPlan):
 
     # ------------------------------------------------------------------
 
-    def _tpu_run_all(self, ctx: TaskContext) -> dict[int, list[pa.RecordBatch]]:
+    def _tpu_run_all(self, ctx: TaskContext,
+                     partition: int | None = None) -> dict[int, list[pa.RecordBatch]]:
         import concurrent.futures as fut
 
         from ballista_tpu.ops.tpu.stage_compiler import _pow2, _put
         from ballista_tpu.plan.physical import RepartitionExec
+        from ballista_tpu.shuffle.reader import ShuffleReaderExec
 
         child = self.child
         P_result = self.output_partition_count()
@@ -504,6 +511,14 @@ class TpuFinalStageExec(ExecutionPlan):
             child = child.input
             bypass = True
         P_in = child.output_partition_count()
+        # a served stage: its input is a shuffle's hash-placed partitions and
+        # it goes out as a task a partition, each of which builds its own
+        # node; output partition p merges input partition p and nothing else,
+        # so a task reads and merges its own alone (the CPU engine's final
+        # aggregate does the same) — never the whole stage once a task
+        own = (partition is not None and not self.coalesce
+               and isinstance(child, ShuffleReaderExec))
+        parts = [partition] if own else list(range(P_in))
 
         # the session quota is thread-local (one-handler-thread-per-request
         # in the daemon); re-scope it on the pool threads or a daemon-routed
@@ -517,13 +532,22 @@ class TpuFinalStageExec(ExecutionPlan):
                 return _concat([b for b in child.execute(p, ctx) if b.num_rows],
                                child.schema())
 
-        with fut.ThreadPoolExecutor(max_workers=min(max(P_in, 1), 8)) as pool:
-            tables = list(pool.map(read, range(P_in)))
+        with fut.ThreadPoolExecutor(max_workers=min(max(len(parts), 1), 8)) as pool:
+            tables = list(pool.map(read, parts))
         # from here on the child's output is in hand: any decline below must
         # aggregate THESE tables on the CPU, not re-execute the child (whose
         # device results this read just consumed — re-deriving them on the
         # host is the 100x overhead the profile pinned)
-        self._mat_input = (tables, child.df_schema, bypass)
+        if own:
+            held = [_concat([], child.schema()) for _ in range(P_in)]
+            held[partition] = tables[0]
+            self._mat_input = (held, child.df_schema, bypass)
+            self._mat_expected = {partition}
+            P_result = 1
+        else:
+            self._mat_input = (tables, child.df_schema, bypass)
+            self._mat_expected = ({0} if (bypass or self.coalesce)
+                                  else set(range(P_result)))
         self._mat_node = None
         part_rows = [t.num_rows for t in tables]
         total = sum(part_rows)
@@ -535,7 +559,14 @@ class TpuFinalStageExec(ExecutionPlan):
         jax = ensure_jax()
 
         full = pa.concat_tables(tables)
-        N = next_bucket(max(max(part_rows), 1), self.buckets)
+        widest = max(part_rows)
+        if own:
+            # every task of the stage stacks at ONE shape, sized by the
+            # stage's widest input partition (the reader's location stats),
+            # so the stage compiles one program, as a whole-stage merge did
+            widest = max([widest] + [sum(loc.stats.num_rows for loc in locs)
+                                     for locs in child.partition_locations])
+        N = next_bucket(max(widest, 1), self.buckets)
         P = len(part_rows)
 
         # encode first (cheap dtype/validity info), then enforce the HBM
@@ -555,6 +586,13 @@ class TpuFinalStageExec(ExecutionPlan):
             proj_bytes += cell_bytes * dc.data.dtype.itemsize
             if dc.valid is not None:
                 proj_bytes += cell_bytes  # bool validity plane
+        # the program's own: its group capacity follows its rows (groups never
+        # outnumber them), C = pow2(P * N); the [C] output lanes (8 B a value,
+        # 1 B a validity plane, the partition ids) and the first ordering's
+        # scratch (the permutation, a sorted copy of every input lane)
+        C = _pow2(cell_bytes)
+        proj_bytes += C * (9 * len(self.schema()) + 4 + 4)
+        proj_bytes += cell_bytes * (4 + 8 * len(encoded))
         max_bytes = int(self.config.get(TPU_MAX_DEVICE_BYTES))
         # fold the HBM admission budget into the pre-upload cap: the final
         # stage has no build side to grace-split, so the ladder here is just
@@ -628,11 +666,16 @@ class TpuFinalStageExec(ExecutionPlan):
         mask = _put(None, mask_np)
         # a fresh entry's first call compiles inside it; `_decode` fetches a
         # count first and then a sliced fetch, so its fetches sit inside it
+        RUN_STATS.set("table_shape", [P, N])
+        RUN_STATS.set("sorted_capacity", meta["C"])
+        if dispatch is not None:  # `bt.stage.dispatch`
+            dispatch.set(sorted_capacity=meta["C"])
         with run_lock, RUN_STATS.span("bt.device.exec"):
             outs = fn(flat, luts, mask)
             jax.block_until_ready(list(outs))
         with RUN_STATS.span("bt.decode", family="final"):
-            return self._decode(outs, meta, P_result, dicts)
+            res = self._decode(outs, meta, P_result, dicts)
+        return {partition: res[0]} if own else res
 
     # ------------------------------------------------------------------
 
@@ -666,7 +709,7 @@ class TpuFinalStageExec(ExecutionPlan):
                 nxt += 1
 
         M = P * N
-        C = min(_pow2(M), MAX_CAPACITY)
+        C = _pow2(M)  # every group a dispatch can find: no more than its rows
 
         # ---- compacted-space env: post-op closures read segment results
         # from this cell, populated inside raw before they run
@@ -782,12 +825,11 @@ class TpuFinalStageExec(ExecutionPlan):
                 skeys = [k[perm1] for k in keyops]
                 spays = [p[perm1] for p in pays]
 
-                diff = jnp.zeros((M,), bool).at[0].set(True)
-                diff = diff | jnp.concatenate(
-                    [jnp.ones((1,), bool), spid[1:] != spid[:-1]])
-                for k in skeys:
-                    diff = diff | jnp.concatenate(
-                        [jnp.ones((1,), bool), k[1:] != k[:-1]])
+                # a row starts a group where its partition or a key changes,
+                # row 0 always (no M-row literal: see the sorted path's twin)
+                diff = functools.reduce(jnp.logical_or, [
+                    jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+                    for k in [spid] + skeys])
                 boundary = svalid & diff
                 seg = int_cumsum(boundary.astype(jnp.int32)) - 1
                 bor_inv = boundary | ~svalid
@@ -960,8 +1002,8 @@ class TpuFinalStageExec(ExecutionPlan):
         P_out = meta["P_out"]  # kernel pid space; ≤ P_result under bypass
         with RUN_STATS.span("bt.device.fetch", what="count"):
             n_seg, n_out = (int(x) for x in jax.device_get(outs[-2:]))
-        if n_seg > C:
-            raise Unsupported(f"group capacity overflow ({n_seg} > {C})")
+        # n_seg <= the rows <= C by construction
+        RUN_STATS.set("final_groups", n_seg)
         if self.sort is not None and self.sort.fetch is not None:
             from ballista_tpu.ops.tpu.sort_window import _count
 
@@ -986,10 +1028,7 @@ class TpuFinalStageExec(ExecutionPlan):
                 null_mask = ~valid_planes[vi][:n_out]
                 vi += 1
             if kind == "code":
-                dic = dicts[slot]
-                py = [None if (null_mask is not None and null_mask[j]) else dic[int(c)]
-                      for j, c in enumerate(v)]
-                arr = pa.array(py, f.type)
+                arr = decode_codes(v, dicts[slot], null_mask, f.type)
             elif kind == "date":
                 arr = pa.array(v.astype(np.int32), pa.int32(),
                                mask=null_mask).cast(pa.date32())

@@ -61,6 +61,9 @@ class StageEstimate:
     max_build_bytes: int = 0  # largest single build (the grace-split target)
     max_build_jidx: int = -1  # its join index, -1 when no builds
     has_mult: bool = False  # aggregate-through-join weights: no grace split
+    # what the traced program holds beside its inputs (the sorted path's
+    # [C] output lanes and its ordering's scratch): fixed, never split
+    program_bytes: int = 0
 
 
 def plan_spans(n_scan_filters: int, ops, agg) -> list[Span]:
@@ -98,8 +101,9 @@ def plan_spans(n_scan_filters: int, ops, agg) -> list[Span]:
     return spans
 
 
-def estimate_stage(ops, agg, dt, builds) -> StageEstimate:
-    """Build a StageEstimate from encode metadata + prepared builds."""
+def estimate_stage(ops, agg, dt, builds, program_bytes: int = 0) -> StageEstimate:
+    """Build a StageEstimate from encode metadata + prepared builds, and
+    the traced program's own bytes (`meta["program_bytes"]`)."""
     from ballista_tpu.plan.physical import HashJoinExec
 
     has_mult = False
@@ -141,6 +145,7 @@ def estimate_stage(ops, agg, dt, builds) -> StageEstimate:
         max_build_bytes=max_build_bytes,
         max_build_jidx=max_build_jidx,
         has_mult=has_mult,
+        program_bytes=int(program_bytes),
     )
 
 

@@ -238,16 +238,20 @@ def plan_stage(est, budget: int, *, grace_eligible: bool, grace_fanout: int,
 
     `est` is a fusion.StageEstimate carrying table_bytes / dict_bytes /
     build_bytes (all derivable from encode metadata, so the decision is
-    computable from a spec table during compile/fill overlap).
+    computable from a spec table during compile/fill overlap) and
+    program_bytes (what the traced program holds beside them: the sorted
+    path's [C] output lanes and ordering scratch, which grow with its row
+    slots — no constant caps its group capacity, the budget does).
     `resident_other` is the device-cache residency NOT owned by this stage
     (cold entries spillable to make room). `observed_bytes` is the AQE
     seam's observed input volume for a resolved/retried stage — a floor
     under the build estimate. `force_grace` is the post-OOM hint: the
     estimate said "fits" once already and the device disagreed."""
-    working = int(est.table_bytes) + int(est.dict_bytes) + int(est.build_bytes)
+    fixed_bytes = int(est.table_bytes) + int(est.dict_bytes) + int(est.program_bytes)
+    working = fixed_bytes + int(est.build_bytes)
     observed_extra = 0
     if observed_bytes > 0:
-        floored = int(est.table_bytes) + int(est.dict_bytes) + int(observed_bytes)
+        floored = fixed_bytes + int(observed_bytes)
         if floored > working:
             # the AQE seam observed more input volume than the estimate
             # priced: the excess is build-side data the grace split can

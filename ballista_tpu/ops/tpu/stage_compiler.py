@@ -25,6 +25,7 @@ domains, or tiny inputs re-run the original subtree on the CPU engine.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import os
@@ -51,7 +52,7 @@ from ballista_tpu.config import (
     _env_int,
 )
 from ballista_tpu.ops.tpu import fusion, hbm, runtime
-from ballista_tpu.ops.tpu.columnar import encode_column, encode_stacked, next_bucket
+from ballista_tpu.ops.tpu.columnar import decode_codes, encode_column, encode_stacked, next_bucket
 from ballista_tpu.ops.tpu.kernels import (
     BelowRowFloor,
     DevVal,
@@ -82,8 +83,6 @@ from ballista_tpu.plan.schema import DFSchema
 log = logging.getLogger(__name__)
 
 MAX_SEGMENTS = 1 << 16
-# the sorted path holds at most this many groups a dispatch
-SORTED_MAX_GROUPS = 1 << 22
 # A stage works over its LIVE rows: of its row slots it takes the smallest of
 # slots / 64, slots / 8 and all of them that holds the rows alive, counted by
 # the program itself. The sorted path (_compile_sorted) projects, orders and
@@ -1082,6 +1081,7 @@ class TpuStageExec(ExecutionPlan):
         from ballista_tpu.ops.tpu.runtime import device_scope
 
         jax = ensure_jax()
+        dispatch_span = RUN_STATS.current_span()  # `bt.stage.dispatch`
 
         max_bytes = int(self.config.get(TPU_MAX_DEVICE_BYTES))
         budget = hbm.resolve_hbm_budget(self.config)
@@ -1123,8 +1123,6 @@ class TpuStageExec(ExecutionPlan):
             import concurrent.futures as cf
 
             spec_ev = threading.Event()
-            # the helper threads' spans hang under this dispatch
-            dispatch = RUN_STATS.current_span()
 
             def on_spec(sdt: DeviceTable) -> None:
                 holder.setdefault("spec", sdt)
@@ -1136,7 +1134,8 @@ class TpuStageExec(ExecutionPlan):
                 def prep(op, jidx):
                     # jax.default_device is thread-local config state: every
                     # helper thread re-enters the executor's chip pin
-                    with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch):
+                    # the helper threads' spans hang under this dispatch
+                    with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch_span):
                         return self._prepare_build(op, jidx, ctx, table_key, mesh)
 
                 build_futs = [pool.submit(prep, op, jidx)
@@ -1150,7 +1149,7 @@ class TpuStageExec(ExecutionPlan):
                         return None  # fill failed; main thread raises
                     bts = [f.result() for f in build_futs]
                     t0 = time.perf_counter()
-                    with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch):
+                    with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch_span):
                         entry, fresh, lowered = self._compile_locked(
                             sdt, bts, rec)
                         if fresh and lowered is not None and mesh is None \
@@ -1203,7 +1202,17 @@ class TpuStageExec(ExecutionPlan):
             builds = [self._prepare_build(op, jidx, ctx, table_key, mesh)
                       for jidx, op in enumerate(join_ops)]
 
-        est = fusion.estimate_stage(self.ops, self.partial_agg, dt, builds)
+        # the program is traced before admission (the overlap worker has
+        # traced it already): what it holds beside its inputs is priced
+        if cached is None:
+            cached, _, _ = self._compile_locked(dt, builds, rec)
+        fn, lowering, meta, state = cached
+        est = fusion.estimate_stage(self.ops, self.partial_agg, dt, builds,
+                                    program_bytes=meta.get("program_bytes", 0))
+        if meta["mode"] == "sorted":
+            rec["sorted_capacity"] = meta["C"]
+            if dispatch_span is not None:
+                dispatch_span.set(sorted_capacity=meta["C"])
 
         # ---- HBM admission: every stage states its memory plan before the
         # dispatch, each demotion with its reason. Splitting
@@ -1251,9 +1260,6 @@ class TpuStageExec(ExecutionPlan):
             finally:
                 _record_spill_stats(rec, spill_pool)
 
-        if cached is None:
-            cached, _, _ = self._compile_locked(dt, builds, rec)
-        fn, lowering, meta, state = cached
         rec["fused_spans"] = meta.get("fused_spans", 0)
         dicts = dt.dicts
         P, N = dt.shape
@@ -1923,10 +1929,16 @@ class TpuStageExec(ExecutionPlan):
         adjacent-key diffs, per-segment totals via
         cumsum-subtract (sum/count: exact int64) or `kernels.segmented_scan`
         (min/max, float sums), then ONE unique-index scatter per output column to
-        compact segment results into a static [C] capacity. The fetch is
-        sliced to pow2(actual segment count), so a 4M-slot capacity costs
-        nothing when a query yields 10k groups. Overflow (> C distinct
-        groups) raises and the stage re-runs on the CPU engine.
+        compact segment results into a static [C] capacity. Groups never
+        outnumber the live rows and the live rows never outnumber the `M`
+        row slots, so C = pow2(M) holds every group a dispatch can find: no
+        constant caps it and no stage that dispatches can overflow it. What
+        the [C] lanes and the ordering's `M`-row scratch cost in HBM is
+        priced in `meta["program_bytes"]`, which admission
+        (`hbm.plan_stage`) weighs before the dispatch: a stage past the
+        budget declines then, never after. The fetch is sliced to
+        pow2(actual segment count), so a capacity of millions costs nothing
+        to fetch when a query yields 10k groups.
 
         Gathers and scatters are what the chip does slowly, and all of the
         above — and every join payload lookup of the projection before it —
@@ -1952,7 +1964,7 @@ class TpuStageExec(ExecutionPlan):
         lane_sets = ctx.lane_sets
         lane_cells = ctx.lane_cells
         M = P * N * len(lane_sets)
-        C = min(_pow2(M), SORTED_MAX_GROUPS)
+        C = _pow2(M)
         # the capacities a dispatch may order its live rows at, from M alone
         # (so the compile key and `meta` stay what they are)
         capacities = _tier_capacities(M, LIVE_TIERS[:1])
@@ -2115,6 +2127,7 @@ class TpuStageExec(ExecutionPlan):
                             welford_pay[id(d.expr)] = pay_plan[-1]
                     meta_holder["out"] = out_meta
                     meta_holder["pay_plan"] = pay_plan
+                    meta_holder["n_pays"] = len(pays)
                 return keyops, pays
 
             def reduce_rows(valid, keys, pays):
@@ -2124,7 +2137,7 @@ class TpuStageExec(ExecutionPlan):
                 Outputs are padded to the stage's [C], so every tier has the
                 one signature."""
                 Mt = valid.shape[0]
-                Ct = min(_pow2(Mt), SORTED_MAX_GROUPS)
+                Ct = _pow2(Mt)  # a tier holds no more groups than its rows
                 with jax.named_scope("sorted_agg"):
                     perm = lex_order([~valid] + [
                         k.astype(jnp.int32) if fits and k.dtype == jnp.int64 else k
@@ -2133,9 +2146,14 @@ class TpuStageExec(ExecutionPlan):
                     skeys = [k[perm] for k in keys]
                     spays = [p[perm] for p in pays]
 
-                    diff = jnp.zeros((Mt,), bool).at[0].set(True)
-                    for k in skeys:
-                        diff = diff | jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+                    # a row starts a group where a key changes, row 0 always:
+                    # every comparison leads with True. (Not from a
+                    # `zeros((Mt,)).at[0].set(True)`: XLA folds that into an
+                    # Mt-row literal the executable carries — 210 MB at 2^24,
+                    # past what the persistent compile cache keeps.)
+                    diff = functools.reduce(jnp.logical_or, [
+                        jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
+                        for k in skeys])
                     boundary = svalid & diff
                     seg = int_cumsum(boundary.astype(jnp.int32)) - 1
                     bor_inv = boundary | ~svalid
@@ -2305,6 +2323,12 @@ class TpuStageExec(ExecutionPlan):
             for b in builds
         ]
         lowered = jitted.lower(cols_spec, luts_spec, mask_spec, builds_spec)  # trace → meta
+        # HBM the program holds beside its inputs: its [C] outputs, and at
+        # the top tier the ordering's scratch — the permutation and a sorted
+        # copy of every key operand and payload, 8 B a row each at most
+        out_bytes = sum(int(np.prod(o.shape)) * np.dtype(o.dtype).itemsize
+                        for o in jax.tree_util.tree_leaves(lowered.out_info))
+        scratch = M * (4 + 8 * (len(meta_holder["key_narrow"]) + meta_holder["n_pays"]))
         meta = {
             "mode": "sorted",
             "out": meta_holder["out"],
@@ -2312,6 +2336,7 @@ class TpuStageExec(ExecutionPlan):
             "nullcnt_map": meta_holder.get("nullcnt_map", {}),
             "emit_pid": emit_keys is not None,
             "C": C,
+            "program_bytes": out_bytes + scratch,
         }
         return jitted, ctx, meta, lowered
 
@@ -2361,8 +2386,7 @@ class TpuStageExec(ExecutionPlan):
         span.set(sorted_rows_live=n_live, sorted_rows_ordered=n_ordered,
                  sorted_groups=n, sorted_capacity=C,
                  probe_rows_live=n_live, probe_rows=n_ordered)
-        if n > C:
-            raise Unsupported(f"group capacity overflow ({n} > {C})")
+        # n <= n_live <= C by construction (C = pow2 of the row slots)
         results = {p: [_empty_batch(schema)] for p in range(P)}
         if n == 0:
             return results
@@ -2395,9 +2419,7 @@ class TpuStageExec(ExecutionPlan):
                     dic = build_dicts[slot[1]][slot[2]]
                 else:
                     dic = dicts[slot]
-                py = [None if (null_mask is not None and null_mask[j]) else dic[int(c)]
-                      for j, c in enumerate(vals)]
-                arr = pa.array(py, f.type)
+                arr = decode_codes(vals, dic, null_mask, f.type)
             elif kind == "date":
                 arr = pa.array(vals.astype(np.int32), pa.int32(), mask=null_mask).cast(pa.date32())
             elif kind == "money":

@@ -147,7 +147,8 @@ class RunStats(Mapping):
     (`dict(RUN_STATS)`, `snapshot()`) is always a consistent
     most-recent-run-wins snapshot, and `stages()` keeps the last few
     per-stage records (merged over a stage's dispatches since the last
-    clear(), most recent value wins, with their number as `dispatches`).
+    clear(), most recent value wins, with their number as `dispatches`;
+    a `run(tag, counted=False)` record — the final family's — counts none).
     Every key is listed in docs/tpu_engine.md#observability (the
     stats-sync analysis pass holds the table to what the code emits).
 
@@ -183,7 +184,7 @@ class RunStats(Mapping):
     # -- counters ----------------------------------------------------------
 
     @contextlib.contextmanager
-    def run(self, tag: str):
+    def run(self, tag: str, counted: bool = True):
         rec: dict = {}
         prev = getattr(self._tls, "rec", None)
         self._tls.rec = rec
@@ -191,9 +192,9 @@ class RunStats(Mapping):
             yield rec
         finally:
             self._tls.rec = prev
-            self._publish(tag, rec)
+            self._publish(tag, rec, counted)
 
-    def _publish(self, tag: str, rec: dict) -> None:
+    def _publish(self, tag: str, rec: dict, counted: bool = True) -> None:
         if not rec:
             return
         with self._lock:
@@ -203,8 +204,10 @@ class RunStats(Mapping):
             # the cold-path keys (fill_s, xla_compile_s, persist_cache_*) —
             # a later dispatch's record must not erase them
             prev = self._stages.pop(tag, {})
-            self._keep_stage(tag, {**prev, **rec,
-                                   "dispatches": prev.get("dispatches", 0) + 1})
+            merged = {**prev, **rec}
+            if counted:
+                merged["dispatches"] = prev.get("dispatches", 0) + 1
+            self._keep_stage(tag, merged)
 
     def _keep_stage(self, tag: str, record: dict) -> None:
         self._stages[tag] = record

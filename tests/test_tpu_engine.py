@@ -253,32 +253,40 @@ def test_sorted_path_live_rows_at_a_tier_edge(live, ordered):
     assert tp.c.tolist() == [1] * live
 
 
-def test_sorted_path_group_overflow_reruns_on_cpu_engine(monkeypatch):
-    """More distinct groups than the sorted path holds: the stage raises
-    Unsupported after its count fetch and the CPU engine answers. The
-    capacity (1 << 22 groups, whatever the tier) is lowered for the test:
-    100 live rows of 8192 slots are ordered at the 128-slot tier, whose
-    group capacity is then the cap's 64."""
+def test_sorted_path_group_overflow_reruns_on_cpu_engine():
+    """A stage cannot overflow its groups: its capacity is pow2 of its row
+    slots. What bounds it is HBM admission, which prices the program's
+    [C] output lanes and ordering scratch beside the table: with the budget
+    one byte under that working set (and over the table and its LUTs,
+    which alone would have been admitted) the stage declines BEFORE it
+    dispatches — no count is fetched, no group decoded — and the CPU
+    engine answers."""
     import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.config import TPU_HBM_BUDGET_BYTES
 
-    assert sc.SORTED_MAX_GROUPS == 1 << 22
-    monkeypatch.setattr(sc, "SORTED_MAX_GROUPS", 64)
-    n, live = 8000, 100
-    alive = np.zeros(n, dtype="int64")
-    alive[np.random.default_rng(29).choice(n, live, replace=False)] = 1
+    n, live = 8000, 8000
     tbl = _in_batches(
-        pa.table({"k": np.arange(n) * 3, "w": np.arange(n) % 11, "alive": alive}), 2)
+        pa.table({"k": np.arange(n) * 3, "w": np.arange(n) % 11,
+                  "alive": np.ones(n, dtype="int64")}), 2)
     sql = "SELECT k, sum(w) AS s FROM t WHERE alive = 1 GROUP BY k ORDER BY k"
+    _device_oracle(sql, {"t": tbl})
+    rec, = (r for t, r in sc.RUN_STATS.stages().items()
+            if t.startswith("stage_") and "sorted_rows_ordered" in r)
+    assert rec["sorted_groups"] == live <= rec["sorted_capacity"] == 8192
+    working = int(rec["hbm_plan_reason"].split("working set ")[1].split(" B")[0])
+    assert working - 1 >= rec["device_bytes"] + 8192 * 9 * 2  # the [C] lanes are in it
+
     before = sc.STAGE_OUTCOMES.snapshot()
-    try:
-        tpu, cpu = _device_oracle(sql, {"t": tbl}, expect_device=False)
-    finally:
-        sc.clear_device_caches()  # no later test may meet a program compiled for 64 groups
+    tpu, cpu = _device_oracle(sql, {"t": tbl}, {TPU_HBM_BUDGET_BYTES: working - 1},
+                              expect_device=False)
     after = sc.STAGE_OUTCOMES.snapshot()
     assert after["declined"] > before["declined"]
-    assert any("group capacity overflow (100 > 64)" in str(r) for r in after["recent"])
+    assert any("hbm plan: working set" in str(r) for r in after["recent"])
+    assert not any("group capacity overflow" in str(r) for r in after["recent"])
+    rec, = (r for t, r in sc.RUN_STATS.stages().items() if t.startswith("stage_"))
+    assert rec["hbm_plan"] == "cpu_demote" and rec["sorted_capacity"] == 8192
+    assert "sorted_groups" not in rec and "exec_s" not in rec  # never dispatched
     assert tpu.num_rows == live and tpu.equals(cpu)
-    assert _sorted_stage_counts() == ([2, 4096], 100, 128)
 
 
 def test_tpu_stage_actually_ran(tpu_ctx):
