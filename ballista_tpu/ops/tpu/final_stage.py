@@ -47,6 +47,7 @@ from ballista_tpu.ops.tpu.kernels import (
     BelowRowFloor,
     DevVal,
     Lowering,
+    SegmentCompaction,
     Unsupported,
     int_cumsum,
     lex_order,
@@ -668,8 +669,10 @@ class TpuFinalStageExec(ExecutionPlan):
         # count first and then a sliced fetch, so its fetches sit inside it
         RUN_STATS.set("table_shape", [P, N])
         RUN_STATS.set("sorted_capacity", meta["C"])
+        for k, v in meta["compact"].items():
+            RUN_STATS.set(k, v)
         if dispatch is not None:  # `bt.stage.dispatch`
-            dispatch.set(sorted_capacity=meta["C"])
+            dispatch.set(sorted_capacity=meta["C"], **meta["compact"])
         with run_lock, RUN_STATS.span("bt.device.exec"):
             outs = fn(flat, luts, mask)
             jax.block_until_ready(list(outs))
@@ -843,13 +846,7 @@ class TpuFinalStageExec(ExecutionPlan):
                 )
                 start = spos[jnp.clip(seg, 0, C - 1)]
                 end_idx = jnp.where(is_end, seg, C)
-
-                def compact(src):
-                    return (
-                        jnp.zeros((C,), src.dtype)
-                        .at[end_idx]
-                        .set(src, mode="drop", unique_indices=True)
-                    )
+                compact = SegmentCompaction(end_idx, n_seg, C)
 
                 def int_segsum(sv):
                     w = sv.astype(jnp.int64)
@@ -902,6 +899,7 @@ class TpuFinalStageExec(ExecutionPlan):
                 cell["acc_valid"] = acc_valid
                 cell["acc_kind"] = acc_kind
                 cell["acc_scale"] = acc_scale
+                meta_holder["compact"] = compact.counts()
 
             arangeC = jnp.arange(C, dtype=jnp.int32)
             alive = arangeC < n_seg
@@ -988,6 +986,7 @@ class TpuFinalStageExec(ExecutionPlan):
             "out": meta_holder["out"],
             "C": C,
             "P_out": P_out,
+            "compact": meta_holder["compact"],
         }
         return jitted, ctx, meta
 

@@ -57,6 +57,7 @@ from ballista_tpu.ops.tpu.kernels import (
     BelowRowFloor,
     DevVal,
     Lowering,
+    SegmentCompaction,
     Unsupported,
     int_cumsum,
     lex_order,
@@ -1211,8 +1212,9 @@ class TpuStageExec(ExecutionPlan):
                                     program_bytes=meta.get("program_bytes", 0))
         if meta["mode"] == "sorted":
             rec["sorted_capacity"] = meta["C"]
+            rec.update(meta["compact"])
             if dispatch_span is not None:
-                dispatch_span.set(sorted_capacity=meta["C"])
+                dispatch_span.set(sorted_capacity=meta["C"], **meta["compact"])
 
         # ---- HBM admission: every stage states its memory plan before the
         # dispatch, each demotion with its reason. Splitting
@@ -1928,8 +1930,8 @@ class TpuStageExec(ExecutionPlan):
         keys and agg inputs gathered through it, segment boundaries from
         adjacent-key diffs, per-segment totals via
         cumsum-subtract (sum/count: exact int64) or `kernels.segmented_scan`
-        (min/max, float sums), then ONE unique-index scatter per output column to
-        compact segment results into a static [C] capacity. Groups never
+        (min/max, float sums), then `kernels.SegmentCompaction` moves each
+        output column's segment results into a static [C] capacity. Groups never
         outnumber the live rows and the live rows never outnumber the `M`
         row slots, so C = pow2(M) holds every group a dispatch can find: no
         constant caps it and no stage that dispatches can overflow it. What
@@ -2170,20 +2172,7 @@ class TpuStageExec(ExecutionPlan):
                     )
                     start = spos[jnp.clip(seg, 0, Ct - 1)]
                     end_idx = jnp.where(is_end, seg, Ct)
-
-                    def compact(src):
-                        if src.dtype == jnp.int64:
-                            # as two 32-bit scatters: the chip scatters a 64-bit
-                            # lane as one two-operand scatter that takes ~25x a
-                            # 32-bit one (0.94 s against 0.041 s for 2^23 updates)
-                            lo = compact((src & 0xFFFFFFFF).astype(jnp.uint32))
-                            hi = compact((src >> 32).astype(jnp.int32))
-                            return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
-                        return (
-                            jnp.zeros((Ct,), src.dtype)
-                            .at[end_idx]
-                            .set(src, mode="drop", unique_indices=True)
-                        )
+                    compact = SegmentCompaction(end_idx, n_seg, Ct)
 
                     def int_segsum(sv):
                         # exact int64: global cumsum minus prefix-at-segment-start
@@ -2249,6 +2238,7 @@ class TpuStageExec(ExecutionPlan):
                             ncnt_map[ai] = len(ncnt_outs)
                             ncnt_outs.append(int_segsum(spays[ncnt_idx]))
                     meta_holder["nullcnt_map"] = ncnt_map
+                    meta_holder["compact"] = compact.counts()
 
                 outs = key_outs + agg_outs + ncnt_outs
                 if emit_keys is not None:
@@ -2336,6 +2326,7 @@ class TpuStageExec(ExecutionPlan):
             "nullcnt_map": meta_holder.get("nullcnt_map", {}),
             "emit_pid": emit_keys is not None,
             "C": C,
+            "compact": meta_holder["compact"],
             "program_bytes": out_bytes + scratch,
         }
         return jitted, ctx, meta, lowered

@@ -133,3 +133,19 @@ def test_the_final_familys_dispatch_leaves_a_record(served):
     dispatch, = (s[7] for s in served["spans"]
                  if s[0] == "bt.stage.dispatch" and s[7].get("family") == "final")
     assert dispatch["sorted_capacity"] == final["sorted_capacity"]
+
+
+def test_no_compaction_scatters_a_64_bit_lane(served):
+    """The final merge's int64 lanes — `id4`, `id5`, `id6` (widened) and
+    `count` — are scattered as 32-bit halves, its float64 `sum(v3)` gathered
+    at the segments' ends; the partial stage compacts the same lanes the
+    same way. The counts are on the records and the dispatch spans."""
+    final, = (r for t, r in served["stages"].items() if t.startswith("final_"))
+    assert (final["compact_split_lanes"], final["compact_gathered_lanes"]) == (4, 1)
+    partial, = (r for t, r in served["stages"].items()
+                if t.startswith("stage_") and "sorted_groups" in r)
+    assert (partial["compact_split_lanes"], partial["compact_gathered_lanes"]) == (4, 1)
+    dispatch = {s[7]["family"]: s[7] for s in served["spans"] if s[0] == "bt.stage.dispatch"}
+    for family, rec in (("final", final), ("partial", partial)):
+        for key in ("compact_split_lanes", "compact_gathered_lanes"):
+            assert dispatch[family][key] == rec[key], (family, key)

@@ -62,6 +62,7 @@ def served(tmp_path_factory):
         "order_keys": generator.reference.load_tables(
             data_dir, {"lineitem": ["l_orderkey"]})["lineitem"].l_orderkey.nunique(),
         "records": records, "spans": sorted(spans, key=lambda n: -n["sorted_groups"]),
+        "merges": [r for t, r in stages.items() if t.startswith("final_") and "final_groups" in r],
         "outcomes": {k: after[k] - before[k] for k in sc.StageOutcomes.KINDS},
     }
 
@@ -115,3 +116,13 @@ def test_sorted_groups_counts_what_left_the_device(served, stage):
 def test_no_stage_fell_back(served):
     assert served["outcomes"]["device"] >= 2
     assert served["outcomes"]["declined"] == 0 and served["outcomes"]["error"] == 0
+
+
+def test_no_compaction_scatters_a_64_bit_lane(served):
+    """The subquery's merge compacts two int64 lanes — `l_orderkey` and
+    `sum(l_quantity)` — each as two 32-bit scatters, and no float64 lane: the
+    sorted stages' lanes are integer too, none of them gathered."""
+    merge, = served["merges"]
+    assert (merge["compact_split_lanes"], merge["compact_gathered_lanes"]) == (2, 0)
+    for rec in served["records"]:
+        assert rec["compact_split_lanes"] > 0 and rec["compact_gathered_lanes"] == 0

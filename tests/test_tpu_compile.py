@@ -285,6 +285,114 @@ def test_fused_xla_stage_compiles_for_v5e_at_sf10(q, nth, family, max_s, tpch_di
     assert secs < max_s, f"q{q} stage took {secs:.0f}s to compile for v5e"
 
 
+# ------------------------------------------ h2o q10: a group a row, f64 lanes
+
+Q10_SQL = ("SELECT id1, id2, id3, id4, id5, id6, sum(v3) AS v3, count(*) AS count "
+           "FROM x GROUP BY id1, id2, id3, id4, id5, id6")
+
+
+@pytest.fixture(scope="module")
+def q10_programs(tmp_path_factory):
+    """What h2o q10 compiles, taken from a served run over a small G1 table
+    (the source's columns, value ranges and eight files): the partial
+    stage's node and its filled table, the final family's node and the
+    arguments its merge was traced with. `_compile` consults nothing else."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, TPU_MIN_ROWS, BallistaConfig
+    from ballista_tpu.ops.tpu.final_stage import TpuFinalStageExec
+    from ballista_tpu.plan.provider import ParquetTable
+
+    rng = np.random.default_rng(10)
+    n, d = 40_000, tmp_path_factory.mktemp("h2o-q10")
+    ids = {f"id{i}": rng.integers(1, k + 1, n) for i, k in
+           ((1, 100), (2, 100), (3, 100_000), (4, 100), (5, 100), (6, 100_000))}
+    x = pa.table({**{f"id{i}": pa.array([f"id{v:0{w}d}" for v in ids[f"id{i}"]])
+                     for i, w in ((1, 3), (2, 3), (3, 10))},
+                  **{f"id{i}": ids[f"id{i}"].astype(np.int32) for i in (4, 5, 6)},
+                  "v3": np.round(rng.uniform(0, 100, n), 6)})
+    for i, batch in enumerate(x.to_batches(max_chunksize=n // 8)):
+        pq.write_table(pa.Table.from_batches([batch]), d / f"part-{i}.parquet")
+
+    seen = {}
+    originals = {cls: cls._compile for cls in (sc.TpuStageExec, TpuFinalStageExec)}
+
+    def recording(cls):
+        def _compile(self, *args, **kwargs):
+            seen[cls] = (self, args, kwargs)
+            return originals[cls](self, *args, **kwargs)
+        return _compile
+
+    ctx = SessionContext.standalone(BallistaConfig({EXECUTOR_ENGINE: "tpu", TPU_MIN_ROWS: 0}))
+    try:
+        for cls in originals:
+            cls._compile = recording(cls)
+        ctx.register_table("x", ParquetTable(str(d)))
+        assert ctx.sql(Q10_SQL).collect().num_rows > 0.99 * n
+    finally:
+        for cls, fn in originals.items():
+            cls._compile = fn
+        ctx.shutdown()
+    return seen[sc.TpuStageExec], seen[TpuFinalStageExec]
+
+
+@pytest.mark.parametrize("program,log2_rows,max_s", [("partial", 24, 300), ("final", 21, 300)])
+def test_h2o_q10_programs_compile_for_v5e(program, log2_rows, max_s, q10_programs, one_chip):
+    """h2o q10 at the benchmark's 1e7 rows: the partial stage over eight
+    partitions padded to 2^21 (2^24 slots), a final task's merge over one
+    partition of 2^21. Each compacts `sum(v3)` — a float64 lane — by a
+    gather, and no lane by a 64-bit scatter."""
+    import numpy as np
+
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+
+    (stage, (dt, *_), _), (final, fargs, fkw) = q10_programs
+    if program == "partial":
+        P, N = 8, 1 << (log2_rows - 3)
+        assert stage.emit_pid is not None  # the served stage routes its rows
+        big = sc.DeviceTable(
+            dt.kinds, dt.scales, dt.dicts,
+            [_spec(one_chip, (P, N), c.dtype) for c in dt.cols],
+            _spec(one_chip, (P, N), dt.mask.dtype), [N] * P, 0,
+            [None if v is None else _spec(one_chip, (P, N), v.dtype) for v in dt.valids])
+        jitted, lowering, meta, _ = stage._compile(
+            big, list(zip(big.kinds, big.scales)), big.dicts, P, N, [])
+        assert meta["mode"] == "sorted" and meta["C"] == 1 << log2_rows
+        luts = [_spec(one_chip, l.shape, l.dtype)
+                for l in lowering.build_luts(big.dicts, [])]
+        args = (big.flat_cols(), luts, big.mask, [])
+    else:
+        kinds, scales, dicts, valids, cols = fargs[:5]
+        P, N = 1, 1 << log2_rows
+        cols = [_spec(one_chip, (P, N), c.dtype) for c in cols]
+        valids = [None if v is None else _spec(one_chip, (P, N), np.bool_) for v in valids]
+        jitted, lowering, meta = final._compile(kinds, scales, dicts, valids, cols, P, N,
+                                                **fkw)
+        assert meta["C"] == 1 << log2_rows
+        luts = [_spec(one_chip, l.shape, l.dtype) for l in lowering.build_luts(dicts)]
+        args = (cols + [v for v in valids if v is not None], luts,
+                _spec(one_chip, (P, N), np.bool_))
+    assert meta["compact"] == {"compact_split_lanes": 4, "compact_gathered_lanes": 1}
+    t0 = time.time()
+    lowered = jitted.lower(*args)
+    hlo = lowered.compile()
+    secs = time.time() - t0
+    # the chip's compiler lowers a 64-bit lane's scatter as ONE scatter of
+    # two 32-bit operands, `(u32[n], u32[n]) scatter(...)` (an f64 lane's
+    # halves are f32): the optimised program holds none
+    wide = [line for line in hlo.as_text().splitlines()
+            if " scatter(" in line and line.split(" = ", 1)[1].startswith("(")]
+    assert not wide, wide[:3]
+    mem = hlo.memory_analysis()
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+    assert resident < 14 << 30, f"q10 {program} needs {resident >> 20} MiB of a 16 GiB chip"
+    assert secs < max_s, f"q10 {program} took {secs:.0f}s to compile for v5e"
+
+
 # ------------------------------------------------- sort / window programs
 
 # (jitted family, key lanes, log2 of the lanes, seconds it may take). The

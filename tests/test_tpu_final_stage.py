@@ -101,6 +101,49 @@ def test_final_stage_nullable_keys_and_accumulators():
     assert tp.c.tolist() == cp.c.tolist()
 
 
+def test_final_merge_of_a_group_a_row_with_64_bit_lanes():
+    """The h2o q10 shape: groups of about a row over int64 keys (past 2^32,
+    negative, one nullable) and a dictionary key, merged with an f64 sum
+    whose inputs hold NULLs (one group's all of them), a count, and min /
+    max over f64. The answer is the CPU engine's; the merge's record says
+    how its lanes were compacted — each int64 lane (three keys, the count,
+    each nullable accumulator's valid count) as two 32-bit scatters, each
+    f64 accumulator gathered — and no lane was scattered at 64 bits."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+
+    rng = np.random.default_rng(43)
+    n = 6000
+    k6 = rng.integers(0, 40, n)
+    v = np.round(rng.uniform(0, 100, n), 6)
+    null_v = (rng.random(n) < 0.2) | (k6 == 39)  # k6 = 39: every input NULL
+    t = pa.table({
+        "s": pa.array([f"id{i}" for i in rng.integers(0, 4, n)]),
+        "k4": rng.choice(np.array([-(1 << 40), 0, (1 << 33) + 1]), n),
+        "k5": pa.array(rng.integers(-5, 5, n), pa.int64(), mask=rng.random(n) < 0.1),
+        "k6": (k6 << 32) - 7,
+        "v": pa.array(v, pa.float64(), mask=null_v),
+    })
+    sql = ("SELECT s, k4, k5, k6, sum(v) AS sv, count(*) AS c, min(v) AS mn, "
+           "max(v) AS mx FROM t GROUP BY s, k4, k5, k6")
+    sc.RUN_STATS.clear()
+    tpu, cpu = _run_checked(sql, {"t": t})
+    key = ["s", "k4", "k5", "k6"]
+    tp = tpu.to_pandas().sort_values(key, na_position="first").reset_index(drop=True)
+    cp = cpu.to_pandas().sort_values(key, na_position="first").reset_index(drop=True)
+    assert len(tp) == len(cp) > n // 2  # most groups a row or two
+    for c in key + ["c"]:
+        assert tp[c].fillna(-1).tolist() == cp[c].fillna(-1).tolist(), c
+    for c in ("sv", "mn", "mx"):
+        assert tp[c].isna().tolist() == cp[c].isna().tolist(), c
+        assert tp[c].isna().any(), c
+        assert np.allclose(tp[c].fillna(0).values, cp[c].fillna(0).values,
+                           rtol=1e-12, atol=0), c
+    assert tp.mn.equals(cp.mn) and tp.mx.equals(cp.mx)  # moved, not recomputed
+    final, = (r for tag, r in sc.RUN_STATS.stages().items() if tag.startswith("final_"))
+    assert final["compact_split_lanes"] == 3 + 1 + 3
+    assert final["compact_gathered_lanes"] == 3
+
+
 def test_final_stage_having_filter():
     """HAVING lowers as a device-side filter over merged groups."""
     rng = np.random.default_rng(13)

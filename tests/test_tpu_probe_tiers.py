@@ -381,3 +381,80 @@ def test_emit_pid_routes_a_join_stage_probed_at_its_tier(tmp_path, lives, diviso
                 assert (host == int(pid_s)).all()
                 seen += got.num_rows
     assert seen == len(want.groupby(["g", "prio"]))  # every group routed, none twice
+
+
+@pytest.fixture(scope="module")
+def tpch_q18_dir(tmp_path_factory):
+    """SF0.05, seed 1: q18's HAVING keeps orders, so its outer stage's semi
+    join has a build side (at SF0.01 it has none)."""
+    from ballista_tpu.testing.tpchgen import generate_tpch
+
+    d = tmp_path_factory.mktemp("tpch-q18") / "sf005"
+    generate_tpch(str(d), scale=0.05, seed=1, files_per_table=2)
+    return str(d)
+
+
+def _sorted_stage_texts(q, data_dir):
+    """The lowered text of every sorted-path partial stage q runs, outermost
+    first, each over its table and its joins' builds as the session fills
+    them."""
+    import ballista_tpu.ops.tpu.stage_compiler as sc
+    from ballista_tpu.client.context import SessionContext
+    from ballista_tpu.config import EXECUTOR_ENGINE, BallistaConfig
+    from ballista_tpu.engine.tpu_engine import maybe_compile_tpu
+    from ballista_tpu.plan.physical import HashJoinExec, TaskContext
+    from ballista_tpu.testing.tpchgen import register_tpch
+
+    cfg = BallistaConfig({EXECUTOR_ENGINE: "tpu"})
+    ctx = SessionContext(cfg)
+    register_tpch(ctx, data_dir)
+
+    def stages(node):
+        if isinstance(node, sc.TpuStageExec):
+            yield node
+            for op in node.ops:
+                if isinstance(op, HashJoinExec):
+                    yield from stages(maybe_compile_tpu(op.left, cfg))
+        for c in node.children():
+            yield from stages(c)
+
+    texts = []
+    for stage in stages(maybe_compile_tpu(
+            ctx.create_physical_plan(ctx.sql(tpch_query(q)).plan), cfg)):
+        tc = TaskContext(cfg)
+        dt = sc.DEVICE_CACHE.get(stage.scan, stage.buckets, tc, 1 << 34)
+        key = sc.DEVICE_CACHE.key_of(stage.scan)
+        joins = [o for o in stage.ops if isinstance(o, HashJoinExec)]
+        builds = [stage._prepare_build(op, j, tc, key) for j, op in enumerate(joins)]
+        _, _, meta, lowered = stage._compile(
+            dt, list(zip(dt.kinds, dt.scales)), dt.dicts, *dt.shape, builds)
+        if meta["mode"] == "sorted":
+            texts.append((meta, lowered.as_text()))
+    return texts
+
+
+# sha256 of the lowered text of the sorted-path partial stages of q3 (SF0.01,
+# the session's tables) and q18 (SF0.05: its outer stage, then its subquery's),
+# taken from the parent commit of the PR that moved the segment compaction into
+# `kernels.SegmentCompaction` (jax 0.9.0). Their compacted lanes are all
+# integer, so that move leaves them as they were, to the byte. A jax upgrade
+# changes them: take them again from a tree known good
+INTEGER_SORTED_PROGRAMS = {
+    3: ["dba863e5f64f452a3e5a3db79200437384ed2c28de829ddf9a6a8ad3396fdc20"],
+    18: ["60310d06880266693aa2cbfa8ec548d8674a624099f45de51ff2168608b95700",
+         "9fb0109fa2299111aa85889c30e4ae2fa2a1598aebad1cd0a1c60665eef51525"],
+}
+
+
+@pytest.mark.parametrize("q", sorted(INTEGER_SORTED_PROGRAMS))
+def test_a_sorted_stage_of_integer_lanes_lowers_as_before(q, tpch_dir, tpch_q18_dir):
+    """A sorted-path stage that compacts no float64 lane splits its int64
+    lanes as it did before the compaction became one function of both
+    families: the same program to the byte (so the persistent compile cache
+    still holds it), none of its lanes gathered."""
+    texts = _sorted_stage_texts(q, tpch_q18_dir if q == 18 else tpch_dir)
+    assert [hashlib.sha256(t.encode()).hexdigest() for _, t in texts] == \
+        INTEGER_SORTED_PROGRAMS[q]
+    for meta, _ in texts:
+        assert meta["compact"]["compact_gathered_lanes"] == 0
+        assert meta["compact"]["compact_split_lanes"] > 0
