@@ -115,6 +115,8 @@ _BUILD_CACHE = LruDict(
     max_bytes=_env_int("BALLISTA_TPU_BUILD_CACHE_BYTES", 2 * 1024**3),
     sizer=lambda bt: sum(int(getattr(a, "nbytes", 0)) for a in bt.flat_arrays()),
 )
+# a stage's builds run on helper threads at once: their sums in its record
+_BUILD_STATS_LOCK = threading.Lock()
 
 
 # RunStats / StageOutcomes live in ballista_tpu/tracing.py (jax-free, so the
@@ -198,6 +200,13 @@ class BuildTable:
             tuple(self.pay_pos),
             tuple(_pow2(len(d)) if d else 0 for d in self.dicts),
         )
+
+    @property
+    def layout(self) -> str:
+        """`direct` (a key -> row table), `expansion` (key-sorted payloads
+        behind lo / cnt tables: keys with several rows) or `sorted` (binary
+        search over the sorted keys)."""
+        return "expansion" if self.cnt is not None else self.mode
 
     def padded_rows(self) -> int:
         """Padded payload length B — a compiled fn clips expansion-lane
@@ -819,25 +828,49 @@ class TpuStageExec(ExecutionPlan):
     # ------------------------------------------------------------------
 
     def _prepare_build(self, join, jidx: int, ctx: TaskContext, table_key,
-                       mesh=None, grace: tuple[int, int] | None = None) -> BuildTable:
-        """Collect + encode + sort a join's build side for device probing.
+                       mesh=None, grace: tuple[int, int] | None = None,
+                       rec: dict | None = None) -> BuildTable:
+        """A join's build side for device probing, from `_BUILD_CACHE` or
+        built (`_encode_build`) under a `bt.join.build` span — a miss alone
+        opens one. `rec` (the stage's run record; helper threads hand it in)
+        counts the hits in `build_hits` and sums the misses' seconds and rows
+        in `build_s` and `build_rows`.
 
         `grace=(bucket, n_buckets)`: keep only the build rows whose combined
         key falls in the given secondary-hash sub-bucket (the grace-split
         path). Sub-builds carry their bucket in the cache key — a sub-build
         and the whole build must never alias."""
-        import numpy as np
-
-        from ballista_tpu.ops.phys_expr import bind_expr, evaluate_to_array
-        from ballista_tpu.ops.tpu.columnar import encode_column
-
         jax = ensure_jax()
-        jnp = jax.numpy
         cache_key = (table_key, self.fingerprint, jidx, mesh.devices.size if mesh else 0,
                      ctx.device_ordinal, grace)
         hit = _BUILD_CACHE.get(cache_key)
         if hit is not None:
+            if rec is not None:
+                with _BUILD_STATS_LOCK:
+                    rec["build_hits"] = rec.get("build_hits", 0) + 1
             return hit
+        with RUN_STATS.span("bt.join.build", join_type=join.join_type) as span:
+            bt = self._encode_build(join, ctx, mesh, grace)
+            arrays = bt.flat_arrays()
+            # resident, not enqueued: the span holds the upload, as fill_s does
+            jax.block_until_ready(arrays)
+            span.set(rows=bt.n_rows, dup=bt.dup, layout=bt.layout,
+                     bytes=sum(int(a.nbytes) for a in arrays))
+        if rec is not None:
+            with _BUILD_STATS_LOCK:
+                rec["build_s"] = round(rec.get("build_s", 0.0) + span.seconds, 6)
+                rec["build_rows"] = rec.get("build_rows", 0) + bt.n_rows
+        _BUILD_CACHE[cache_key] = bt
+        return bt
+
+    def _encode_build(self, join, ctx: TaskContext, mesh,
+                      grace: tuple[int, int] | None) -> BuildTable:
+        """Collect + encode + sort a join's build side and upload it: the
+        work of a `_BUILD_CACHE` miss."""
+        import numpy as np
+
+        from ballista_tpu.ops.phys_expr import bind_expr, evaluate_to_array
+        from ballista_tpu.ops.tpu.columnar import encode_column
 
         batches = []
         for p in range(join.left.output_partition_count()):
@@ -995,7 +1028,6 @@ class TpuStageExec(ExecutionPlan):
         )
         bt.pay_pos = pay_pos
         bt.shifts = shifts
-        _BUILD_CACHE[cache_key] = bt
         return bt
 
     def _tpu_run_all(self, ctx: TaskContext) -> dict[int, list[pa.RecordBatch]]:
@@ -1137,7 +1169,7 @@ class TpuStageExec(ExecutionPlan):
                     # helper thread re-enters the executor's chip pin
                     # the helper threads' spans hang under this dispatch
                     with device_scope(ctx.device_ordinal), RUN_STATS.attach(dispatch_span):
-                        return self._prepare_build(op, jidx, ctx, table_key, mesh)
+                        return self._prepare_build(op, jidx, ctx, table_key, mesh, rec=rec)
 
                 build_futs = [pool.submit(prep, op, jidx)
                               for jidx, op in enumerate(join_ops)]
@@ -1200,7 +1232,7 @@ class TpuStageExec(ExecutionPlan):
                                   spill_pool=spill_pool)
             if sum(dt.part_rows) < self.min_rows:
                 raise BelowRowFloor(sum(dt.part_rows))
-            builds = [self._prepare_build(op, jidx, ctx, table_key, mesh)
+            builds = [self._prepare_build(op, jidx, ctx, table_key, mesh, rec=rec)
                       for jidx, op in enumerate(join_ops)]
 
         # the program is traced before admission (the overlap worker has
@@ -1294,6 +1326,7 @@ class TpuStageExec(ExecutionPlan):
             # honest figure is ITS compile time (which ran under the fill)
             rec["xla_compile_s"] = round(holder.get("xla_s", t_call), 6)
         res = self._fetch_decode(outs, meta, P, dicts, [b.dicts for b in builds])
+        _note_match_lanes(meta, rec, dispatch_span)
         exec_s = time.perf_counter() - t0
         if first_dispatch and "xla_s" not in holder:
             exec_s = max(0.0, exec_s - t_call)  # compile time isn't exec time
@@ -1340,7 +1373,7 @@ class TpuStageExec(ExecutionPlan):
             try:
                 sub_builds = [
                     self._prepare_build(op, j, ctx, table_key, mesh,
-                                        grace=(b, n_buckets))
+                                        grace=(b, n_buckets), rec=rec)
                     if j == jsplit else builds[j]
                     for j, op in enumerate(join_ops)
                 ]
@@ -1362,6 +1395,7 @@ class TpuStageExec(ExecutionPlan):
                 jax.block_until_ready(list(outs))
             res = self._fetch_decode(outs, meta, P, dicts,
                                      [sb.dicts for sb in sub_builds])
+            _note_match_lanes(meta, rec, RUN_STATS.current_span(), add=bool(buckets_run))
             for p, bl in res.items():
                 merged[p].extend(x for x in bl if x.num_rows)
             buckets_run.append(b)
@@ -1418,6 +1452,8 @@ class TpuStageExec(ExecutionPlan):
 
         lane_cells = [{"d": 0} for _ in builds]
         lane_dups: list[int] = []  # per build: lanes to unroll (1 for semi/anti)
+        join_lanes: list[int] = []  # per join: the match lanes its lowering unrolled
+        lookups = _LaneLog()  # the joins' lookups, noted as the program is traced
         outer_jidx: set[int] = set()  # joins whose build gathers are nullable-by-miss
         # (filters, joins) up to and including the FIRST join whose match mask
         # filters probe rows (inner, semi, anti): the prefix the direct path
@@ -1468,7 +1504,7 @@ class TpuStageExec(ExecutionPlan):
                 probe_fns = [lower_expr(r, ctx) for (_, r) in op.on]
                 probe_scope = f"join_probe_{jidx}"
                 finder = _scoped(probe_scope, _mk_join_finder(
-                    off, probe_fns, bt, lane_cells[jidx]))
+                    off, probe_fns, bt, lane_cells[jidx], lookups))
                 pv_idx = bt.pay_valid_flat_idx()
                 if op.join_type in ("right_semi", "right_anti"):
                     neg = op.join_type == "right_anti"
@@ -1494,7 +1530,7 @@ class TpuStageExec(ExecutionPlan):
                         combined_schema = op.left.df_schema.merge(cur_schema)
                         for d in range(bt.dup):
                             finder_d = _scoped(probe_scope, _mk_join_finder(
-                                off, probe_fns, bt, {"d": d}))
+                                off, probe_fns, bt, {"d": d}, lookups))
                             gfns, gmeta = [], []
                             for ci, pp in enumerate(bt.pay_pos):
                                 if pp is None:
@@ -1525,6 +1561,7 @@ class TpuStageExec(ExecutionPlan):
 
                         filter_fns.append(run)
                         selective_join()
+                    join_lanes.append(1 if op.filter is None else bt.dup)
                     lane_dups.append(1)
                     jidx += 1
                     continue
@@ -1532,7 +1569,7 @@ class TpuStageExec(ExecutionPlan):
                     # aggregate-through-join: ONE count gather replaces all
                     # dup match lanes; build columns are never materialized
                     counter = _scoped(probe_scope, _mk_join_counter(
-                        off, probe_fns, bt, lane_cells[jidx]))
+                        off, probe_fns, bt, lane_cells[jidx], lookups))
                     if op.join_type == "inner":
                         filter_fns.append(
                             lambda cols, luts, _c=counter:
@@ -1547,6 +1584,7 @@ class TpuStageExec(ExecutionPlan):
                     ] * n_bf + list(ctx.env_fns)
                     ctx.env_meta = [None] * n_bf + list(ctx.env_meta)
                     cur_schema = op.df_schema
+                    join_lanes.append(1)
                     lane_dups.append(1)
                     jidx += 1
                     continue
@@ -1566,6 +1604,7 @@ class TpuStageExec(ExecutionPlan):
                 else:
                     filter_fns.append(lambda cols, luts, _f=finder: _f(cols, luts)[1])
                     selective_join()
+                join_lanes.append(bt.dup)
                 lane_dups.append(bt.dup)
                 build_fns = [
                     _mk_build_gather(pay_off, ci, bt.kinds[ci], bt.scales[ci], bt.dicts[ci],
@@ -1601,6 +1640,8 @@ class TpuStageExec(ExecutionPlan):
             raise Unsupported(f"{len(lane_sets)} expansion-join lanes > {MAX_JOIN_DUP}")
         ctx.lane_sets = lane_sets
         ctx.lane_cells = lane_cells
+        ctx.lookups = lookups
+        ctx.match_lanes = sum(join_lanes)
 
         # Group-key strategy: small dictionary domains unroll into per-group
         # masked reductions (pure VPU, no scatter/sort). Everything else —
@@ -1802,6 +1843,7 @@ class TpuStageExec(ExecutionPlan):
                              < count[:, None]).reshape(-1))
 
             def branch(cols, luts, lives, counts):
+                lookups.at = cap  # what the joins look up from here on: this tier's
                 if cap < N:
                     row_sets = [compacted(cols, *lc) for lc in zip(lives, counts)]
                 else:
@@ -1853,6 +1895,7 @@ class TpuStageExec(ExecutionPlan):
             # combination (XLA CSEs lane-invariant work) and reductions
             # accumulate across lanes.
             cols = list(cols) + [a for b in build_args for a in b]
+            lookups.at = None  # over every slot, up to the tiers' switch
             outs = None
             if probe_prefix is None:
                 for lane in lane_sets:
@@ -1903,6 +1946,7 @@ class TpuStageExec(ExecutionPlan):
         # trace → meta; the Lowered also feeds the overlap worker's optional
         # AOT backend compile (which warms the persistent cache)
         lowered = jitted.lower(cols_spec, luts_spec, mask_spec, builds_spec)
+        lanes = len(lane_sets)
         meta = {
             "mode": "unrolled",
             "fused_spans": len(spans),
@@ -1916,6 +1960,12 @@ class TpuStageExec(ExecutionPlan):
             # probed): RunStats `probe_rows_live` / `probe_rows`
             "slots": P * N * len(lane_sets),
             "probe_counts": probe_prefix is not None,
+            # RunStats `match_lanes`, and `match_lane_slots` by the
+            # `probe_rows` the dispatch reads: the tier it took
+            "match_lanes": ctx.match_lanes,
+            "lane_slots": ({cap * P * lanes: lookups.slots(cap) for cap in capacities}
+                           if probe_prefix is not None
+                           else {P * N * lanes: lookups.slots(None)}),
         }
         return jitted, ctx, meta, lowered
 
@@ -1965,6 +2015,7 @@ class TpuStageExec(ExecutionPlan):
         filter_fns = ctx.stage_filter_fns
         lane_sets = ctx.lane_sets
         lane_cells = ctx.lane_cells
+        lookups = ctx.lookups
         M = P * N * len(lane_sets)
         C = _pow2(M)
         # the capacities a dispatch may order its live rows at, from M alone
@@ -2018,6 +2069,7 @@ class TpuStageExec(ExecutionPlan):
 
         def raw(cols, luts, mask, build_args):
             cols = list(cols) + [a for b in build_args for a in b]
+            lookups.at = None  # over every slot, up to the tiers' switch
             # per expansion-join match lane: valid over every slot (and what
             # its joins' finders found there); lanes concatenate into one row
             # set feeding a single ordering
@@ -2272,6 +2324,7 @@ class TpuStageExec(ExecutionPlan):
 
             def at_capacity(cap):
                 def branch(cols, luts, valid, n_live):
+                    lookups.at = cap  # what the joins look up from here on: this tier's
                     if cap == M:  # mostly alive: over the slots as they are
                         lanes = []
                         for lane, found in zip(lane_sets, founds):
@@ -2328,6 +2381,10 @@ class TpuStageExec(ExecutionPlan):
             "C": C,
             "compact": meta_holder["compact"],
             "program_bytes": out_bytes + scratch,
+            # RunStats `match_lanes`, and `match_lane_slots` by the tier the
+            # dispatch took (`sorted_rows_ordered`, = `probe_rows`)
+            "match_lanes": ctx.match_lanes,
+            "lane_slots": {cap: lookups.slots(cap) for cap in capacities},
         }
         return jitted, ctx, meta, lowered
 
@@ -2656,6 +2713,20 @@ def _mk_col_reader(i: int, kind: str, scale: int, dictionary, valid_idx=None):
     return run
 
 
+def _note_match_lanes(meta: dict, rec: dict, span, add: bool = False) -> None:
+    """RunStats `match_lanes` and `match_lane_slots` of a decoded dispatch,
+    on `rec` and on its `bt.stage.dispatch` span: the slots by the tier the
+    dispatch took, which its `probe_rows` says; `add` sums them over the
+    dispatches of a grace split."""
+    slots = meta["lane_slots"].get(rec.get("probe_rows"), 0)
+    if add:
+        slots += rec.get("match_lane_slots", 0)
+    rec["match_lanes"] = meta["match_lanes"]
+    rec["match_lane_slots"] = slots
+    if span is not None:
+        span.set(match_lanes=meta["match_lanes"], match_lane_slots=slots)
+
+
 def _set_lanes(lane_cells: list, lane, found=()) -> None:
     """Point the joins' closures at a row set, at trace time: each join's
     match lane `d` (an int; an int32 a row where the sorted path has compacted
@@ -2668,7 +2739,33 @@ def _set_lanes(lane_cells: list, lane, found=()) -> None:
         cell.update(d=d_, found=f, last=None)
 
 
-def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
+class _LaneLog:
+    """The join lookups a stage program issues, noted while it is traced (no
+    device work): each one a join's match lane over a row set, under `at` —
+    None over every slot, else the capacity of the live-row tier being
+    traced. `slots(at)` is RunStats `match_lane_slots` for a dispatch that
+    took that tier: the rows of the lookups over every slot, and of those
+    the tier issues again over its own rows. A lookup traced twice over one
+    row set (a match and its payload gather) is one lookup: XLA CSEs it."""
+
+    def __init__(self):
+        self.at = None
+        self._seen: dict = {}
+
+    def note(self, join: int, lane, rows: int) -> None:
+        # a lane that is an int32 a row (the sorted path's compacted lanes)
+        # is every lane of the join at once: one lookup over those rows
+        self._seen.setdefault(self.at, set()).add(
+            (join, lane if isinstance(lane, int) else -1, rows))
+
+    def slots(self, at) -> int:
+        seen = self._seen.get(None, set())
+        if at is not None:
+            seen = seen | self._seen.get(at, set())
+        return sum(rows for _, _, rows in seen)
+
+
+def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict, lookups: _LaneLog):
     """Closure computing (clamped build index, matched mask) for one join.
 
     'direct' unique mode: the build shipped a dense key→row int32 table —
@@ -2685,7 +2782,8 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
     gathers behind it (a lookup of its own there, `cap` rows and not `M`);
     the lookups the per-column gathers of ONE row set issue are duplicates
     XLA CSEs. The match lane is an int, or an int32 a row where the sorted
-    path has compacted several lanes' rows into one set.
+    path has compacted several lanes' rows into one set. Each lookup is
+    noted in `lookups`.
     """
     mode, shifts, dup = bt.mode, bt.shifts, bt.dup
     has_cnt = bt.cnt is not None
@@ -2712,6 +2810,7 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict):
             if v.valid is not None:
                 valid = valid & v.valid  # a NULL probe key matches nothing
         d = cell["d"]
+        lookups.note(off, d, k.size)
         if mode == "direct" and not has_cnt:
             T = keys_arr.shape[0]
             in_range = valid & (k >= 0) & (k < T)
@@ -2826,7 +2925,7 @@ def _mult_shape_check(partial_agg, ops, join) -> dict | None:
     return out
 
 
-def _mk_join_counter(off: int, probe_fns, bt: BuildTable, cell: dict):
+def _mk_join_counter(off: int, probe_fns, bt: BuildTable, cell: dict, lookups: _LaneLog):
     """Closure computing each probe row's MATCH COUNT against the build —
     the aggregate-through-join weight. Where every build-column use in the
     stage is multiplicity-shaped (count(col), count(*), probe-side sums),
@@ -2855,6 +2954,7 @@ def _mk_join_counter(off: int, probe_fns, bt: BuildTable, cell: dict):
                 k = (k << shift) | ki
             if v.valid is not None:
                 valid = valid & v.valid
+        lookups.note(off, 0, k.size)
         zero = jnp.zeros((), jnp.int32)
         if mode == "direct" and has_cnt:
             T = keys_arr.shape[0]

@@ -458,3 +458,40 @@ def test_a_sorted_stage_of_integer_lanes_lowers_as_before(q, tpch_dir, tpch_q18_
     for meta, _ in texts:
         assert meta["compact"]["compact_gathered_lanes"] == 0
         assert meta["compact"]["compact_split_lanes"] > 0
+
+
+# shape -> what its joins look up at (40, 60) and at (4000, 4000) rows alive:
+# every join's lanes over the 2 x 4096 row slots where its match is in the
+# all-slot mask (the prefix on the direct path, every filter on the sorted
+# one), then what the tier looks up again over its own rows — nothing where
+# the tier is the slots as they are and reuses what the prefix found
+LANE_SLOTS = {
+    # dim in the prefix; at N / 8 = 512 a partition dim again (its nk is
+    # dim2's key) and dim2; over the slots as they are dim2 alone
+    "chain_two_key_probe": (2, PARTS * SLOTS + 2 * PARTS * 512, 2 * PARTS * SLOTS),
+    # dim in the mask; at M / 64 = 128 again for prio, a group key
+    "sorted_one_join": (1, PARTS * SLOTS + 128, PARTS * SLOTS),
+    # the filtered semi / anti joins: a lane a build row of the key at most
+    # (dims holds 1 to 3 rows a key), each a lookup over every slot; nothing
+    # behind them looks up again
+    "semi_with_residual": (3, 3 * PARTS * SLOTS, 3 * PARTS * SLOTS),
+    "anti_with_residual": (3, 3 * PARTS * SLOTS, 3 * PARTS * SLOTS),
+}
+
+
+@pytest.mark.parametrize("lives", [(40, 60), (4000, 4000)], ids=["few_alive", "all_alive"])
+@pytest.mark.parametrize("shape", sorted(LANE_SLOTS))
+def test_match_lanes_count_the_lookups_of_the_tier_taken(shape, lives):
+    """RunStats `match_lanes` (the match lanes the stage's joins unrolled) and
+    `match_lane_slots` (the rows those lanes looked up, in the tier the
+    dispatch took) on the stage's record."""
+    sql, names, _, _, alive_in_dim = SHAPES[shape]
+    dims = _dims()
+    assert int(np.bincount(dims["dims"].column("sid2").to_numpy()).max()) == 3
+    tpu, cpu = _device_oracle(sql, {"fact": _fact(lives, alive_in_dim),
+                                    **{n: dims[n] for n in names}})
+    _assert_same_answer(tpu, cpu)
+    lanes, few, every = LANE_SLOTS[shape]
+    rec = _stage_record()
+    assert (rec["match_lanes"], rec["match_lane_slots"]) == (
+        lanes, few if lives == (40, 60) else every)
