@@ -89,7 +89,9 @@ MAX_SEGMENTS = 1 << 16
 # the program itself. The sorted path (_compile_sorted) projects, orders and
 # reduces at the first divisor only or at every slot: each of its tiers is an
 # ordering of its own to trace, compile and load, and no measured workload
-# lands between (q3 keeps 0.4 % of its slots, q18 all or 441 rows). The direct
+# lands between (q3 keeps 0.4 % of its slots, q18 all or 441 rows). Its count
+# leaves out a filter of several match lanes (q21's filtered EXISTS / NOT
+# EXISTS), which runs in the tier over the rows the others left. The direct
 # path (_compile) probes its join chain at all three, a partition's rows
 # compacted within the partition: q5's first join keeps 10.8 % of the slots,
 # and a tier there is a handful of gathers, not an ordering.
@@ -1453,6 +1455,10 @@ class TpuStageExec(ExecutionPlan):
         lane_cells = [{"d": 0} for _ in builds]
         lane_dups: list[int] = []  # per build: lanes to unroll (1 for semi/anti)
         join_lanes: list[int] = []  # per join: the match lanes its lowering unrolled
+        # filter index -> its match lanes, for a filter that unrolls several
+        # (a filtered semi / anti join): the sorted path runs these behind
+        # its live-row switch
+        tier_filters: dict[int, int] = {}
         lookups = _LaneLog()  # the joins' lookups, noted as the program is traced
         outer_jidx: set[int] = set()  # joins whose build gathers are nullable-by-miss
         # (filters, joins) up to and including the FIRST join whose match mask
@@ -1561,6 +1567,8 @@ class TpuStageExec(ExecutionPlan):
 
                         filter_fns.append(run)
                         selective_join()
+                        if bt.dup > 1:
+                            tier_filters[len(filter_fns) - 1] = bt.dup
                     join_lanes.append(1 if op.filter is None else bt.dup)
                     lane_dups.append(1)
                     jidx += 1
@@ -1635,6 +1643,7 @@ class TpuStageExec(ExecutionPlan):
                 raise Unsupported(f"op {type(op).__name__}")
         _bind_env(ctx, cur_schema)
         ctx.stage_filter_fns = filter_fns  # shared with the sorted path
+        ctx.tier_filters = tier_filters
         lane_sets = list(itertools.product(*[range(d) for d in lane_dups]))
         if len(lane_sets) > MAX_JOIN_DUP:
             raise Unsupported(f"{len(lane_sets)} expansion-join lanes > {MAX_JOIN_DUP}")
@@ -1963,6 +1972,8 @@ class TpuStageExec(ExecutionPlan):
             # RunStats `match_lanes`, and `match_lane_slots` by the
             # `probe_rows` the dispatch reads: the tier it took
             "match_lanes": ctx.match_lanes,
+            # RunStats `tier_lanes`: those of the multi-lane filters behind the prefix
+            "tier_lanes": sum(n for i, n in tier_filters.items() if i >= prefix_fns),
             "lane_slots": ({cap * P * lanes: lookups.slots(cap) for cap in capacities}
                            if probe_prefix is not None
                            else {P * N * lanes: lookups.slots(None)}),
@@ -2003,16 +2014,27 @@ class TpuStageExec(ExecutionPlan):
         slot order, through `kernels.live_slots` (the joins are probed again
         there for their payloads, `cap` rows and not `M`); at `M`, over the
         slots as they are. One body (`project`, `reduce_rows`) serves every
-        tier, each padding its outputs to [C]. The last output is int32
-        (groups, live rows, slots ordered): RunStats `sorted_groups`,
-        `sorted_rows_live` / `sorted_rows_ordered`, and `probe_rows_live` /
-        `probe_rows`, which the last two equal here.
+        tier, each padding its outputs to [C].
+
+        A filter that unrolls several match lanes — a filtered semi / anti
+        join, `dup` lookups and payload gathers OR-ed (`ctx.tier_filters`) —
+        is left out of the count and runs in the tier, over the rows the
+        other filters left (`kept_behind`): its lanes look up `cap` rows, not
+        `M` (q21's fourteen). Every other filter runs over every slot, so a
+        stage without such a filter traces what it traced before.
+
+        The last output is int32 (groups, live rows, slots ordered, and
+        where a multi-lane filter ran in the tier the rows that picked it):
+        RunStats `sorted_groups`, `sorted_rows_live` / `sorted_rows_ordered` /
+        `sorted_rows_masked`, and `probe_rows_live` / `probe_rows`, which
+        equal `sorted_rows_live` / `sorted_rows_ordered` here.
         """
         jax = ensure_jax()
         jnp = jax.numpy
         agg = self.partial_agg
         aggs = agg.aggs
         filter_fns = ctx.stage_filter_fns
+        tier_filters = ctx.tier_filters
         lane_sets = ctx.lane_sets
         lane_cells = ctx.lane_cells
         lookups = ctx.lookups
@@ -2078,10 +2100,20 @@ class TpuStageExec(ExecutionPlan):
                 _set_lanes(lane_cells, lane)
                 m = mask
                 with jax.named_scope("filter"):
-                    for ff in filter_fns:
-                        m = m & true_mask(ff(cols, luts))
+                    for i, ff in enumerate(filter_fns):
+                        if i not in tier_filters:
+                            m = m & true_mask(ff(cols, luts))
                 lane_valid.append(m.reshape(-1))
                 founds.append([cell["last"] for cell in lane_cells])
+
+            def kept_behind(cols, shape):
+                """What the multi-lane filters keep of one row set of
+                `shape`, flat: evaluated behind the switch, over the rows
+                the other filters left and the tier holds."""
+                with jax.named_scope("filter"):
+                    return functools.reduce(jnp.logical_and, [
+                        jnp.broadcast_to(true_mask(filter_fns[i](cols, luts)), shape)
+                        .reshape(-1) for i in tier_filters])
 
             def project(cols, shape):
                 """(key operands, payloads) of one row set: `cols` of `shape`,
@@ -2322,17 +2354,27 @@ class TpuStageExec(ExecutionPlan):
                     outs.append(pid)
                 return tuple(jnp.pad(o, (0, C - Ct)) for o in outs) + (n_seg,)
 
+            def reduce_kept(valid, keys, pays):
+                """`reduce_rows`, and where the multi-lane filters ran in the
+                tier the rows every filter keeps"""
+                outs = reduce_rows(valid, keys, pays)
+                return outs + (valid.sum(dtype=jnp.int32),) if tier_filters else outs
+
             def at_capacity(cap):
                 def branch(cols, luts, valid, n_live):
                     lookups.at = cap  # what the joins look up from here on: this tier's
                     if cap == M:  # mostly alive: over the slots as they are
-                        lanes = []
+                        lanes, kept = [], []
                         for lane, found in zip(lane_sets, founds):
                             _set_lanes(lane_cells, lane, found)
+                            if tier_filters:
+                                kept.append(kept_behind(cols, mask.shape))
                             lanes.append(project(cols, mask.shape))
                         keys, pays = ([jnp.concatenate(xs) for xs in zip(*part)]
                                       for part in zip(*lanes))
-                        return reduce_rows(valid, keys, pays)
+                        if tier_filters:
+                            valid = valid & jnp.concatenate(kept)
+                        return reduce_kept(valid, keys, pays)
                     with jax.named_scope("compact_live"):
                         src = live_slots(valid, cap)
                         # a row of the concatenated lanes: its lane, its slot
@@ -2341,18 +2383,32 @@ class TpuStageExec(ExecutionPlan):
                         _set_lanes(lane_cells, lane_sets[0] if len(lane_sets) == 1 else
                                    jnp.asarray(lane_sets, jnp.int32)[lane_of].T)
                     keys, pays = project(live_cols, (cap,))
-                    return reduce_rows(jnp.arange(cap, dtype=jnp.int32) < n_live, keys, pays)
+                    valid = jnp.arange(cap, dtype=jnp.int32) < n_live
+                    if tier_filters:
+                        valid = valid & kept_behind(live_cols, (cap,))
+                    return reduce_kept(valid, keys, pays)
                 return branch
 
             with jax.named_scope("sorted_agg"):
                 valid = jnp.concatenate(lane_valid)
-                # a scalar predicate outside any vmap: only the taken tier runs
+                # a scalar predicate outside any vmap: only the taken tier runs.
+                # The count is of the rows every filter but the multi-lane
+                # ones keeps: those run in the tier it picks
                 n_live = valid.sum(dtype=jnp.int32)
                 tier = sum((n_live > cap).astype(jnp.int32) for cap in capacities[:-1])
-            *outs, n_seg = jax.lax.switch(tier, [at_capacity(cap) for cap in capacities],
-                                          cols, luts, valid, n_live)
+            outs = jax.lax.switch(tier, [at_capacity(cap) for cap in capacities],
+                                  cols, luts, valid, n_live)
             _set_lanes(lane_cells, lane_sets[0])  # the cells keep no traced value
-            counts = jnp.stack([n_seg, n_live, jnp.asarray(capacities, jnp.int32)[tier]])
+            # the groups, the rows every filter keeps, the slots ordered; and
+            # where the multi-lane filters ran in the tier, the rows that
+            # picked it
+            if tier_filters:
+                *outs, n_seg, n_kept = outs
+                more = [n_live]
+            else:
+                *outs, n_seg = outs
+                n_kept, more = n_live, []
+            counts = jnp.stack([n_seg, n_kept, jnp.asarray(capacities, jnp.int32)[tier]] + more)
             return tuple(outs) + (counts,)
 
         raw.__name__ = raw.__qualname__ = "stage_partial_sorted_fused_xla"
@@ -2384,6 +2440,7 @@ class TpuStageExec(ExecutionPlan):
             # RunStats `match_lanes`, and `match_lane_slots` by the tier the
             # dispatch took (`sorted_rows_ordered`, = `probe_rows`)
             "match_lanes": ctx.match_lanes,
+            "tier_lanes": sum(tier_filters.values()),  # RunStats `tier_lanes`
             "lane_slots": {cap: lookups.slots(cap) for cap in capacities},
         }
         return jitted, ctx, meta, lowered
@@ -2424,15 +2481,19 @@ class TpuStageExec(ExecutionPlan):
         n_keyops = sum(2 if km[3] else 1 for km in key_meta)
         C = meta["C"]
         with RUN_STATS.span("bt.device.fetch", what="count"):
-            n, n_live, n_ordered = (int(x) for x in jax.device_get(outs[-1]))
+            n, n_live, n_ordered, *n_masked = (int(x) for x in jax.device_get(outs[-1]))
+        # the rows that picked the tier: where no multi-lane filter ran in
+        # it, those every filter keeps
+        n_masked = n_masked[0] if n_masked else n_live
         RUN_STATS.set("sorted_rows_live", n_live)
+        RUN_STATS.set("sorted_rows_masked", n_masked)
         RUN_STATS.set("sorted_rows_ordered", n_ordered)
         RUN_STATS.set("sorted_groups", n)
         # the projection and its probes run where the ordering runs
         RUN_STATS.set("probe_rows_live", n_live)
         RUN_STATS.set("probe_rows", n_ordered)
-        span.set(sorted_rows_live=n_live, sorted_rows_ordered=n_ordered,
-                 sorted_groups=n, sorted_capacity=C,
+        span.set(sorted_rows_live=n_live, sorted_rows_masked=n_masked,
+                 sorted_rows_ordered=n_ordered, sorted_groups=n, sorted_capacity=C,
                  probe_rows_live=n_live, probe_rows=n_ordered)
         # n <= n_live <= C by construction (C = pow2 of the row slots)
         results = {p: [_empty_batch(schema)] for p in range(P)}
@@ -2714,17 +2775,18 @@ def _mk_col_reader(i: int, kind: str, scale: int, dictionary, valid_idx=None):
 
 
 def _note_match_lanes(meta: dict, rec: dict, span, add: bool = False) -> None:
-    """RunStats `match_lanes` and `match_lane_slots` of a decoded dispatch,
-    on `rec` and on its `bt.stage.dispatch` span: the slots by the tier the
-    dispatch took, which its `probe_rows` says; `add` sums them over the
-    dispatches of a grace split."""
+    """RunStats `match_lanes`, `tier_lanes` and `match_lane_slots` of a
+    decoded dispatch, on `rec` and on its `bt.stage.dispatch` span: the slots
+    by the tier the dispatch took, which its `probe_rows` says; `add` sums
+    them over the dispatches of a grace split."""
     slots = meta["lane_slots"].get(rec.get("probe_rows"), 0)
     if add:
         slots += rec.get("match_lane_slots", 0)
-    rec["match_lanes"] = meta["match_lanes"]
-    rec["match_lane_slots"] = slots
+    lanes = {"match_lanes": meta["match_lanes"], "tier_lanes": meta["tier_lanes"],
+             "match_lane_slots": slots}
+    rec.update(lanes)
     if span is not None:
-        span.set(match_lanes=meta["match_lanes"], match_lane_slots=slots)
+        span.set(**lanes)
 
 
 def _set_lanes(lane_cells: list, lane, found=()) -> None:
@@ -2745,7 +2807,8 @@ class _LaneLog:
     None over every slot, else the capacity of the live-row tier being
     traced. `slots(at)` is RunStats `match_lane_slots` for a dispatch that
     took that tier: the rows of the lookups over every slot, and of those
-    the tier issues again over its own rows. A lookup traced twice over one
+    the tier issues over its own rows (again, or the first time for a filter
+    of several lanes on the sorted path). A lookup traced twice over one
     row set (a match and its payload gather) is one lookup: XLA CSEs it."""
 
     def __init__(self):
@@ -2779,7 +2842,8 @@ def _mk_join_finder(off: int, probe_fns, bt: BuildTable, cell: dict, lookups: _L
     keys can never alias a real build key. Shape-generic in `cols`: the
     stage calls it over every row slot where its match is the filter, and
     again over the live rows' compacted scan columns for the payload
-    gathers behind it (a lookup of its own there, `cap` rows and not `M`);
+    gathers behind it (a lookup of its own there, `cap` rows and not `M`;
+    a filter of several lanes on the sorted path looks up there alone);
     the lookups the per-column gathers of ONE row set issue are duplicates
     XLA CSEs. The match lane is an int, or an int32 a row where the sorted
     path has compacted several lanes' rows into one set. Each lookup is

@@ -73,9 +73,11 @@ def _dims():
         "dimx": pa.table({"xid": pa.array(xid, pa.int64()),
                           "mode": pa.array([f"m{i % 3}" for i in range(len(xid))]),
                           "bid": pa.array(np.arange(len(xid)), pa.int64())}),
-        # a residual that never rules a pair out: sq is no fact row's qty
+        # a residual that never rules a pair out: sq is no fact row's qty;
+        # one that does: sk2 is a fact row's sk one time in eight
         "dims": pa.table({"sid2": pa.array(xid, pa.int64()),
-                          "sq": pa.array(np.full(len(xid), -1), pa.int64())}),
+                          "sq": pa.array(np.full(len(xid), -1), pa.int64()),
+                          "sk2": pa.array(rng.integers(0, 8, len(xid)), pa.int64())}),
     }
 
 
@@ -138,6 +140,22 @@ LIVES = [
     pytest.param((513, 2), id="fullest_partition_one_over"),
     pytest.param((4000, 4000), id="all_alive"),
 ]
+
+
+# The sorted path's filters of several match lanes: a filtered EXISTS / NOT
+# EXISTS over dims (1 to 3 rows a key, a lane each), behind a filter of one
+# lane that decides which rows live — an inner join, a scan filter — grouped
+# by a large int domain. name -> (sql, tables beside fact, NOT EXISTS)
+TIER_SHAPES = {
+    "sorted_semi_behind_join": (
+        "SELECT g, count(*) AS c, sum(amt) AS s FROM fact JOIN dim ON fk = id WHERE EXISTS "
+        "(SELECT 1 FROM dims WHERE sid2 = fk AND sk2 <> sk) GROUP BY g ORDER BY g",
+        ("dim", "dims"), False),
+    "sorted_anti_behind_scan_filter": (
+        f"SELECT g, count(*) AS c, sum(amt) AS s FROM fact WHERE fk < {DIM} AND NOT EXISTS "
+        "(SELECT 1 FROM dims WHERE sid2 = fk AND sk2 <> sk) GROUP BY g ORDER BY g",
+        ("dims",), True),
+}
 
 
 def _stage_record():
@@ -245,6 +263,68 @@ def test_tiers_agree_bit_for_bit():
         outs.append(tpu.to_pydict())
     assert outs[0]["s"] == outs[1]["s"] and outs[0]["c"] == outs[1]["c"]
     assert np.allclose(outs[0]["sw"], outs[1]["sw"], rtol=1e-13)
+
+
+def _exists_in_dims(fact, dims):
+    """Per fact row: some dims row of its key has an sk2 other than its sk."""
+    fk, sk = (fact.column(c).to_numpy() for c in ("fk", "sk"))
+    rows = np.zeros((1200, 8), int)
+    np.add.at(rows, tuple(dims.column(c).to_numpy() for c in ("sid2", "sk2")), 1)
+    return rows.sum(axis=1)[fk] > rows[fk, sk]
+
+
+@pytest.mark.parametrize("lives", LIVES)
+@pytest.mark.parametrize("shape", sorted(TIER_SHAPES))
+def test_multi_lane_filters_run_at_the_live_rows_tier(shape, lives):
+    """On the sorted path a filter of several match lanes is left out of the
+    count that picks the tier and runs inside it: the rows the one-lane
+    filter keeps pick the tier (`sorted_rows_masked`), the lanes decide over
+    those alone (`tier_lanes`), and the rows every filter keeps are counted
+    after them (`sorted_rows_live`)."""
+    sql, names, anti = TIER_SHAPES[shape]
+    dims = _dims()
+    fact = _fact(lives)
+    tpu, cpu = _device_oracle(sql, {"fact": fact, **{n: dims[n] for n in names}})
+    _assert_same_answer(tpu, cpu)
+
+    rec = _stage_record()
+    assert rec["table_shape"] == [PARTS, SLOTS]
+    masked = sum(lives)
+    kept = (fact.column("fk").to_numpy() < DIM) & (_exists_in_dims(fact, dims["dims"]) != anti)
+    slots = PARTS * SLOTS
+    want = slots // 64 if masked <= slots // 64 else slots
+    assert (rec["sorted_rows_masked"], rec["sorted_rows_live"], rec["sorted_rows_ordered"]) \
+        == (masked, int(kept.sum()), want)
+    assert (rec["probe_rows_live"], rec["probe_rows"]) == (int(kept.sum()), want)
+    assert rec["tier_lanes"] == 3
+
+
+def test_multi_lane_tiers_agree_bit_for_bit():
+    """The same rows alive behind a filtered EXISTS on the sorted path, once
+    with few and once with every row alive before it (the others die in its
+    lanes: a key of one dims row whose sk2 is the row's sk): its lanes run at
+    the tier and over the slots as they are, and the answer — money sums in
+    int64 cents, counts, the group keys — comes out the same to the bit."""
+    sql = (f"SELECT g, sum(amt) AS s, sum(qty) AS q, count(*) AS c FROM fact WHERE fk < {DIM} "
+           "AND EXISTS (SELECT 1 FROM dims WHERE sid2 = fk AND sk2 <> sk) GROUP BY g ORDER BY g")
+    dims = _dims()["dims"]
+    sid2, sk2 = (dims.column(c).to_numpy() for c in ("sid2", "sk2"))
+    single = np.flatnonzero(np.bincount(sid2) == 1)
+    few = _fact((40, 60)).to_pandas()
+    many = few.copy()
+    dead = many.fk >= DIM
+    pick = single[np.arange(int(dead.sum())) % len(single)]
+    many.loc[dead, "fk"] = pick
+    many.loc[dead, "sk"] = sk2[np.searchsorted(sid2, pick)]  # sid2 is in key order
+    outs = []
+    for fact, masked, want in ((few, 100, PARTS * SLOTS // 64), (many, ROWS, PARTS * SLOTS)):
+        fact = _in_batches(pa.Table.from_pandas(fact, preserve_index=False), PARTS)
+        tpu, cpu = _device_oracle(sql, {"fact": fact, "dims": dims})
+        _assert_same_answer(tpu, cpu)
+        rec = _stage_record()
+        assert (rec["sorted_rows_masked"], rec["sorted_rows_ordered"]) == (masked, want)
+        outs.append((rec["sorted_rows_live"], tpu.to_pydict()))
+    assert outs[0][0] > 0 and outs[0] == outs[1]
 
 
 def _partial_stage_text(ctx, q):
@@ -460,38 +540,49 @@ def test_a_sorted_stage_of_integer_lanes_lowers_as_before(q, tpch_dir, tpch_q18_
         assert meta["compact"]["compact_split_lanes"] > 0
 
 
-# shape -> what its joins look up at (40, 60) and at (4000, 4000) rows alive:
-# every join's lanes over the 2 x 4096 row slots where its match is in the
-# all-slot mask (the prefix on the direct path, every filter on the sorted
-# one), then what the tier looks up again over its own rows — nothing where
-# the tier is the slots as they are and reuses what the prefix found
+# shape -> the match lanes, what its joins look up at (40, 60) and at
+# (4000, 4000) rows alive, and the lanes run behind the live-row switch: every
+# join's lanes over the 2 x 4096 row slots where its match is in the all-slot
+# mask (the prefix on the direct path, every filter of one lane on the sorted
+# one), then what the tier looks up over its own rows — again, or for the
+# first time where a filter of several lanes runs there; at the slots as they
+# are, nothing the mask already found
 LANE_SLOTS = {
     # dim in the prefix; at N / 8 = 512 a partition dim again (its nk is
     # dim2's key) and dim2; over the slots as they are dim2 alone
-    "chain_two_key_probe": (2, PARTS * SLOTS + 2 * PARTS * 512, 2 * PARTS * SLOTS),
+    "chain_two_key_probe": (2, PARTS * SLOTS + 2 * PARTS * 512, 2 * PARTS * SLOTS, 0),
     # dim in the mask; at M / 64 = 128 again for prio, a group key
-    "sorted_one_join": (1, PARTS * SLOTS + 128, PARTS * SLOTS),
+    "sorted_one_join": (1, PARTS * SLOTS + 128, PARTS * SLOTS, 0),
     # the filtered semi / anti joins: a lane a build row of the key at most
-    # (dims holds 1 to 3 rows a key), each a lookup over every slot; nothing
-    # behind them looks up again
-    "semi_with_residual": (3, 3 * PARTS * SLOTS, 3 * PARTS * SLOTS),
-    "anti_with_residual": (3, 3 * PARTS * SLOTS, 3 * PARTS * SLOTS),
+    # (dims holds 1 to 3 rows a key), each a lookup over every slot in the
+    # direct path's prefix; nothing behind them looks up again
+    "semi_with_residual": (3, 3 * PARTS * SLOTS, 3 * PARTS * SLOTS, 0),
+    "anti_with_residual": (3, 3 * PARTS * SLOTS, 3 * PARTS * SLOTS, 0),
+    # on the sorted path the same three lanes run at the tier: at M / 64 = 128
+    # behind dim in the mask, or behind the scan filter alone
+    "sorted_semi_behind_join": (4, PARTS * SLOTS + 3 * 128, 4 * PARTS * SLOTS, 3),
+    "sorted_anti_behind_scan_filter": (3, 3 * 128, 3 * PARTS * SLOTS, 3),
 }
 
 
 @pytest.mark.parametrize("lives", [(40, 60), (4000, 4000)], ids=["few_alive", "all_alive"])
 @pytest.mark.parametrize("shape", sorted(LANE_SLOTS))
 def test_match_lanes_count_the_lookups_of_the_tier_taken(shape, lives):
-    """RunStats `match_lanes` (the match lanes the stage's joins unrolled) and
+    """RunStats `match_lanes` (the match lanes the stage's joins unrolled),
     `match_lane_slots` (the rows those lanes looked up, in the tier the
-    dispatch took) on the stage's record."""
-    sql, names, _, _, alive_in_dim = SHAPES[shape]
+    dispatch took) and `tier_lanes` (those of them run behind the live-row
+    switch) on the stage's record."""
+    if shape in TIER_SHAPES:
+        sql, names, _ = TIER_SHAPES[shape]
+        alive_in_dim = True
+    else:
+        sql, names, _, _, alive_in_dim = SHAPES[shape]
     dims = _dims()
     assert int(np.bincount(dims["dims"].column("sid2").to_numpy()).max()) == 3
     tpu, cpu = _device_oracle(sql, {"fact": _fact(lives, alive_in_dim),
                                     **{n: dims[n] for n in names}})
     _assert_same_answer(tpu, cpu)
-    lanes, few, every = LANE_SLOTS[shape]
+    lanes, few, every, tier_lanes = LANE_SLOTS[shape]
     rec = _stage_record()
-    assert (rec["match_lanes"], rec["match_lane_slots"]) == (
-        lanes, few if lives == (40, 60) else every)
+    assert (rec["match_lanes"], rec["match_lane_slots"], rec["tier_lanes"]) == (
+        lanes, few if lives == (40, 60) else every, tier_lanes)
